@@ -19,4 +19,24 @@ Layout (strictly downward dependencies, reference: SURVEY.md §1):
   testing/    in-process fake control plane for hermetic tests
 """
 
+import os as _os
+import sys as _sys
+
 __version__ = "0.1.0"
+
+# JAX's persistent compilation cache, placed in ONE spot for every compute
+# entry point (prime serve/train/eval, bench.py, chip_smoke.py's children all
+# import this package) without importing JAX for the others. Where
+# JAX_COMPILATION_CACHE_DIR is set, nothing is done and no other directory is
+# set in code. Where it is not, the cache lives at <checkout>/.jax_cache: a
+# fixed path, because the path is part of the cache key — never a temporary
+# name, a pid or a time.
+if "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
+    _os.environ["JAX_COMPILATION_CACHE_DIR"] = _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))), ".jax_cache"
+    )
+    if "jax" in _sys.modules:
+        # jax read the variable when it was imported; tell it now
+        _sys.modules["jax"].config.update(
+            "jax_compilation_cache_dir", _os.environ["JAX_COMPILATION_CACHE_DIR"]
+        )

@@ -29,6 +29,7 @@ from __future__ import annotations
 import ast
 import fnmatch
 import re
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -147,43 +148,9 @@ class Waiver:
         )
 
 
-def _parse_toml(text: str, filename: str) -> dict:
-    """Parse the baseline file: stdlib ``tomllib`` when the interpreter has
-    it, else a deliberately tiny fallback grammar (``[[waiver]]`` headers +
-    ``key = "basic string"`` pairs + comments) so the linter runs on the
-    3.10 containers the test suite supports. baseline.toml stays inside that
-    subset by construction — the writer of a fancier entry finds out here."""
-    try:
-        from prime_tpu.utils.compat import TOMLLIB_AVAILABLE, tomllib
-
-        if TOMLLIB_AVAILABLE:
-            return tomllib.loads(text)
-    except ImportError:  # pragma: no cover — compat shim always importable
-        pass
-    waivers: list[dict] = []
-    current: dict | None = None
-    for lineno, raw in enumerate(text.splitlines(), start=1):
-        line = raw.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line == "[[waiver]]":
-            current = {}
-            waivers.append(current)
-            continue
-        m = re.match(r'^([A-Za-z0-9_-]+)\s*=\s*"((?:[^"\\]|\\.)*)"\s*(?:#.*)?$', line)
-        if m and current is not None:
-            current[m.group(1)] = m.group(2).replace('\\"', '"').replace("\\\\", "\\")
-            continue
-        raise ValueError(
-            f"{filename}:{lineno}: unsupported TOML (fallback parser handles "
-            f'only [[waiver]] tables with key = "string" pairs): {line!r}'
-        )
-    return {"waiver": waivers}
-
-
 def load_baseline(path: str | Path) -> list[Waiver]:
     path = Path(path)
-    data = _parse_toml(path.read_text(encoding="utf-8"), str(path))
+    data = tomllib.loads(path.read_text(encoding="utf-8"))
     waivers: list[Waiver] = []
     for i, entry in enumerate(data.get("waiver", [])):
         missing = [k for k in ("rule", "path", "symbol", "reason") if not entry.get(k)]

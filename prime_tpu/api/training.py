@@ -7,11 +7,11 @@ picks the TPU slice shape.
 
 from __future__ import annotations
 
+import tomllib
 from pathlib import Path
 from typing import Any
 
 from prime_tpu.core.client import APIClient
-from prime_tpu.utils.compat import tomllib
 
 
 def build_payload_from_toml(

@@ -3,8 +3,7 @@
 `delta` renders the committed BENCH_*.json rounds into the per-PR table
 (stdlib-only — safe on machines without jax); `smoke` runs the CPU loadgen
 fleet smoke and writes its SLO report + BENCH-schema record (docs/
-benchmarking.md). The real TPU bench stays `python bench.py` — it manages
-accelerator preflight and stray-process sweeps no CLI should hide.
+benchmarking.md). The TPU record stays `python bench.py` (v5e only).
 """
 
 from __future__ import annotations

@@ -9,9 +9,32 @@ PRIME_INFERENCE_URL) at it.
 
 from __future__ import annotations
 
+import signal
+
 import click
 
 from prime_tpu.utils.render import Renderer, output_options
+
+
+def _raise_keyboard_interrupt(signum, frame):
+    raise KeyboardInterrupt
+
+
+def _echo_device_memory(when: str) -> None:
+    """One line per local device with the allocator's view (TPU only: the
+    CPU backend reports no memory_stats) — what a replica holds once ready,
+    and its peak when it stops."""
+    import jax
+
+    for device in jax.local_devices():
+        stats = device.memory_stats()
+        if stats:
+            click.echo(
+                f"  device {device.id} ({device.device_kind}) memory at {when}: "
+                f"bytes_in_use={stats.get('bytes_in_use', 0)} "
+                f"peak_bytes_in_use={stats.get('peak_bytes_in_use', 0)} "
+                f"bytes_limit={stats.get('bytes_limit', 0)}"
+            )
 
 
 @click.group(name="serve", invoke_without_command=True)
@@ -226,6 +249,9 @@ def serve_cmd(
             "(the URL the router can reach this replica at)"
         )
 
+    # SIGTERM (a supervisor's stop) takes the same clean path as Ctrl-C:
+    # finish serve_forever, stop the engine thread, exit 0
+    signal.signal(signal.SIGTERM, _raise_keyboard_interrupt)
     try:
         server = serve_model(
             model,
@@ -285,9 +311,11 @@ def serve_cmd(
     )
     click.echo(f"  metrics: {server.url}/metrics  (prometheus: {server.url}/metrics?format=prometheus)")
     click.echo(f"  health:  {server.url}/healthz")
+    _echo_device_memory("ready")
     try:
         server.serve_forever()
     except KeyboardInterrupt:
+        _echo_device_memory("stopping")
         click.echo("\nStopped.")
         server.stop()
 

@@ -14,9 +14,8 @@ import io
 import subprocess
 import sys
 import tarfile
+import tomllib
 from pathlib import Path
-
-from prime_tpu.utils.compat import tomllib
 
 DEFAULT_EXCLUDES = [
     ".prime",  # local hub-link state (provenance.py) — never ships or hashes

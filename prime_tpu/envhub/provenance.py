@@ -59,7 +59,7 @@ def bump_post(version: str) -> str:
 
 def read_pyproject_version(env_dir: str | Path) -> str | None:
     """The [project] version in <env_dir>/pyproject.toml, or None."""
-    from prime_tpu.utils.compat import tomllib
+    import tomllib
 
     path = Path(env_dir) / "pyproject.toml"
     try:
@@ -72,7 +72,7 @@ def read_pyproject_version(env_dir: str | Path) -> str | None:
 
 def read_env_toml_version(env_dir: str | Path) -> str | None:
     """The [environment] version in <env_dir>/env.toml (what push uploads)."""
-    from prime_tpu.utils.compat import tomllib
+    import tomllib
 
     path = Path(env_dir) / "env.toml"
     try:

@@ -21,11 +21,10 @@ Alias file format::
 
 from __future__ import annotations
 
+import tomllib
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Callable
-
-from prime_tpu.utils.compat import tomllib
 
 DEFAULT_ENDPOINTS_PATH = "configs/endpoints.toml"
 # preflights must fail fast — generation timeouts (600 s) are far too long

@@ -141,7 +141,7 @@ def add_default_inference_and_key_args(
 def _collect_config_env(config_path: Path, fallback_env_dir: str) -> tuple[str, str] | None:
     """``[env] env_id`` (+ optional top-level ``env_dir_path``) from a GEPA
     TOML config; None when absent/malformed (reference: warn and skip)."""
-    from prime_tpu.utils.compat import tomllib
+    import tomllib
 
     try:
         raw = tomllib.loads(config_path.read_text())

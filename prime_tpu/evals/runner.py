@@ -119,40 +119,13 @@ class JaxGenerator:
             self.params, self.config = load_hf_checkpoint(checkpoint, dtype=dtype)
         else:
             self.config = get_config(model)
-            self.params = init_params(jax.random.PRNGKey(0), self.config, dtype=dtype)
+            self.params = None  # random init, created below once the mesh is known
         tok_vocab = getattr(self.tokenizer, "vocab_size", None)
         if tok_vocab and tok_vocab > self.config.vocab_size:
             raise ValueError(
                 f"Tokenizer vocab ({tok_vocab}) exceeds model vocab "
                 f"({self.config.vocab_size}) — ids would index out of bounds"
             )
-
-        if adapter is not None:
-            from prime_tpu.train.lora import (
-                base_fingerprint,
-                fingerprints_match,
-                load_adapters,
-                merge_lora,
-            )
-
-            adapters, lora_cfg, meta = load_adapters(adapter)
-            if meta["base_model"] != self.config.name:
-                raise ValueError(
-                    f"Adapter {adapter!r} was trained on {meta['base_model']!r} but "
-                    f"this model is {self.config.name!r} — merging would corrupt weights"
-                )
-            recorded = meta.get("base_fingerprint")
-            if recorded is not None and not fingerprints_match(
-                recorded, base_fingerprint(self.params)
-            ):
-                raise ValueError(
-                    f"Adapter {adapter!r} was trained over DIFFERENT base weights "
-                    f"than this model (same config name {self.config.name!r}, "
-                    "different weight fingerprint — e.g. adapters from a "
-                    "random-init training base merged into a real checkpoint). "
-                    "Re-train the adapters against this checkpoint."
-                )
-            self.params = merge_lora(self.params, adapters, lora_cfg)
 
         if self.config.is_moe:
             # inference must not drop tokens: capacity_factor = E/k guarantees
@@ -183,7 +156,7 @@ class JaxGenerator:
             )
         self.mesh = mesh
         # pure-argument validation first: no failure below should cost a
-        # multi-GB checkpoint placement before surfacing
+        # multi-GB parameter placement before surfacing
         if weight_quant and mesh is not None and mesh.size > 1:
             raise ValueError(
                 "weight_quant currently serves single-device only (the "
@@ -191,8 +164,6 @@ class JaxGenerator:
             )
         self._data_size = 1
         if mesh is not None:
-            from prime_tpu.parallel.sharding import shard_params
-
             tp = mesh.shape.get("tp", 1)
             if self.config.n_kv_heads % tp or self.config.n_heads % tp:
                 raise ValueError(
@@ -200,6 +171,48 @@ class JaxGenerator:
                     f"n_kv_heads={self.config.n_kv_heads} ({self.config.name})"
                 )
             self._data_size = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
+        if self.params is None:
+            if mesh is not None:
+                # created IN its shardings: a model larger than one chip
+                # (qwen3-8b over tp=4) must never be whole on any device
+                from prime_tpu.parallel.sharding import init_params_sharded
+
+                self.params = init_params_sharded(
+                    jax.random.PRNGKey(0), self.config, mesh, dtype=dtype
+                )
+            else:
+                self.params = init_params(jax.random.PRNGKey(0), self.config, dtype=dtype)
+
+        if adapter is not None:
+            from prime_tpu.train.lora import (
+                base_fingerprint,
+                fingerprints_match,
+                load_adapters,
+                merge_lora,
+            )
+
+            adapters, lora_cfg, meta = load_adapters(adapter)
+            if meta["base_model"] != self.config.name:
+                raise ValueError(
+                    f"Adapter {adapter!r} was trained on {meta['base_model']!r} but "
+                    f"this model is {self.config.name!r} — merging would corrupt weights"
+                )
+            recorded = meta.get("base_fingerprint")
+            if recorded is not None and not fingerprints_match(
+                recorded, base_fingerprint(self.params)
+            ):
+                raise ValueError(
+                    f"Adapter {adapter!r} was trained over DIFFERENT base weights "
+                    f"than this model (same config name {self.config.name!r}, "
+                    "different weight fingerprint — e.g. adapters from a "
+                    "random-init training base merged into a real checkpoint). "
+                    "Re-train the adapters against this checkpoint."
+                )
+            self.params = merge_lora(self.params, adapters, lora_cfg)
+
+        if mesh is not None:
+            from prime_tpu.parallel.sharding import shard_params
+
             self.params = shard_params(self.params, mesh, self.config)
         if weight_quant:
             # True / "int8" -> W8A16; "int4" -> W4A16 group-wise (half the
@@ -284,17 +297,7 @@ class JaxGenerator:
                 kw["attn_impl"] = "xla"
         import contextlib
 
-        # enter_mesh, not jax.set_mesh directly: the toolchain spells the
-        # ambient-mesh context jax.set_mesh, but 0.4.x builds (the thin test
-        # containers, where bench.py's eval section used to die on the
-        # AttributeError) predate it — the compat shim falls back to the
-        # Mesh's own context manager, same as every engine dispatch site
-        if self.mesh is not None:
-            from prime_tpu.parallel.compat import enter_mesh
-
-            ctx = enter_mesh(self.mesh)
-        else:
-            ctx = contextlib.nullcontext()
+        ctx = jax.set_mesh(self.mesh) if self.mesh is not None else contextlib.nullcontext()
         with ctx:
             if self.speculative:
                 from prime_tpu.models.speculative import spec_generate

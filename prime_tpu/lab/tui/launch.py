@@ -22,11 +22,10 @@ the same clients the CLI uses and reports the created run id.
 
 from __future__ import annotations
 
+import tomllib
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any
-
-from prime_tpu.utils.compat import tomllib
 
 
 class LaunchError(RuntimeError):

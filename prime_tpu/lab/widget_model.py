@@ -402,7 +402,7 @@ def _model_options_uncached(workspace: Any, kind: str) -> tuple[tuple[str, str],
 
     options: list[tuple[str, str]] = [(name, name) for name in sorted(MODEL_PRESETS)]
     if kind != "rl" and workspace is not None:
-        from prime_tpu.utils.compat import tomllib
+        import tomllib
         from pathlib import Path
 
         path = Path(workspace) / "configs" / "endpoints.toml"
@@ -425,7 +425,7 @@ def environment_options(workspace: Any = None) -> tuple[str, ...]:
 
 
 def _environment_options_uncached(workspace: Any) -> tuple[str, ...]:
-    from prime_tpu.utils.compat import tomllib
+    import tomllib
     from pathlib import Path
 
     names: list[str] = []

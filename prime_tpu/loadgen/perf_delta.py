@@ -195,8 +195,8 @@ def _round_from_record(path: str, record: dict[str, Any]) -> Round:
     # become r01's delta baseline) and label it by its filename stem
     num = int(m.group("num")) if m else None
     # the driver wraps each round's bench record: {"n", "cmd", "rc", "tail",
-    # "parsed": <last JSON line or null>}. Unwrap it; a null parse (the
-    # round-3 mid-preflight kill) becomes an explicit error record rather
+    # "parsed": <last JSON line or null>}. Unwrap it; a null parse (a run
+    # killed before it printed) becomes an explicit error record rather
     # than a skipped round — a dead round is part of the trajectory.
     if "parsed" in record and "rc" in record:
         num, record = _unwrap_driver_record(num, record)
@@ -238,8 +238,8 @@ def _unwrap_driver_record(
     num: int | None, record: dict[str, Any]
 ) -> tuple[int | None, dict[str, Any]]:
     """Unwrap the driver's ``{"n", "cmd", "rc", "tail", "parsed"}`` wrapper
-    (shared by BENCH and MULTICHIP rounds). A null parse (e.g. a
-    mid-preflight kill) becomes an explicit error record rather than a
+    (shared by BENCH and MULTICHIP rounds). A null parse (a run killed
+    before it printed) becomes an explicit error record rather than a
     skipped round — a dead round is part of the trajectory."""
     num = int(record.get("n") or num or 0)
     parsed = record["parsed"]

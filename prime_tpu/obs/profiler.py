@@ -95,21 +95,9 @@ def _install_compile_spy(listener: "DeviceProfiler") -> None:
         _SPY_LISTENERS.add(listener)
         if _SPY_INSTALLED:
             return
-        try:
-            import jax._src.compiler as compiler_mod  # noqa: PLC0415
-        except Exception:  # noqa: BLE001 — no jax, no compile accounting
-            return
-        name = next(
-            (
-                n
-                for n in ("backend_compile_and_load", "backend_compile")
-                if hasattr(compiler_mod, n)
-            ),
-            None,
-        )
-        if name is None:
-            return
-        real = getattr(compiler_mod, name)
+        import jax._src.compiler as compiler_mod  # noqa: PLC0415
+
+        real = compiler_mod.backend_compile_and_load
 
         def _spy(*args: Any, **kwargs: Any) -> Any:
             t0 = time.monotonic()
@@ -124,7 +112,7 @@ def _install_compile_spy(listener: "DeviceProfiler") -> None:
                         pass
 
         _spy.__wrapped__ = real  # type: ignore[attr-defined]
-        setattr(compiler_mod, name, _spy)
+        compiler_mod.backend_compile_and_load = _spy
         _SPY_INSTALLED = True
 
 

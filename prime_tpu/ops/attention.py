@@ -97,10 +97,19 @@ def xla_attention_causal(
 def _pallas_interpret() -> bool:
     """PRIME_TPU_PALLAS_INTERPRET=1 runs the kernels in interpret mode, so
     the pallas dispatch paths (incl. window/softcap/sink/int8 variants) can
-    be validated off-TPU — bench.py's smoke mode sets it on CPU."""
+    be validated off-TPU — bench.py's smoke mode sets it on CPU. With a TPU
+    backend it is refused: the interpreter on the chip would quietly stand in
+    for every compiled kernel."""
     from prime_tpu.core.config import env_flag
 
-    return env_flag("PRIME_TPU_PALLAS_INTERPRET", False)
+    if not env_flag("PRIME_TPU_PALLAS_INTERPRET", False):
+        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "PRIME_TPU_PALLAS_INTERPRET is set but the backend is a TPU: "
+            "interpret mode is for validating kernels off-chip; unset it"
+        )
+    return True
 
 
 def _flash_decode_min_capacity() -> int:

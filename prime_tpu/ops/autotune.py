@@ -10,8 +10,8 @@ persists never feeds a v5p process.
 
 Two sweep mechanics, dictated by each kernel's surface:
 
-- ``paged_gather`` and ``lora_mm`` take their block as an argument — the
-  candidate is passed explicitly.
+- ``lora_mm`` takes its block as an argument — the candidate is passed
+  explicitly.
 - the flash kernels resolve blocks inside their traces — candidates are
   applied through the promoted ``PRIME_TPU_BLOCK_*`` env overrides with the
   kernel's jit cache cleared per candidate, exercising exactly the
@@ -47,7 +47,6 @@ CANDIDATES: dict[str, list[dict[str, int]]] = {
     ],
     "flash_decode": [{"block_c": c} for c in (128, 256, 512)],
     "flash_decode_int8": [{"block_c": c} for c in (128, 256, 512)],
-    "paged_gather": [{"block_r": r} for r in (1024, 256, 512, 2048)],
     "lora_mm": [{"block_out": o} for o in (256, 128, 512)],
 }
 
@@ -157,32 +156,6 @@ def _sweep_flash_decode(dry_run: bool, repeats: int, interpret: bool, int8: bool
     return run
 
 
-def _sweep_paged_gather(dry_run: bool, repeats: int, interpret: bool):
-    import jax
-    import jax.numpy as jnp
-
-    from prime_tpu.ops.pallas_paged import paged_gather
-
-    page_tokens = 16
-    r_dim, num_pages, max_pages = (
-        (256, 64, 16) if dry_run else (16384, 1024, 128)
-    )
-    pool = jax.random.normal(
-        jax.random.PRNGKey(2), (num_pages, r_dim, page_tokens), dtype=jnp.float32
-    )
-    table = jnp.arange(max_pages, dtype=jnp.int32) % num_pages
-
-    def run(cand: dict[str, int]) -> float:
-        return _time_call(
-            lambda: paged_gather(
-                pool, table, block_r=cand["block_r"], interpret=interpret
-            ),
-            repeats,
-        )
-
-    return run
-
-
 def _sweep_lora_mm(dry_run: bool, repeats: int, interpret: bool):
     import jax
     import jax.numpy as jnp
@@ -236,7 +209,6 @@ def run_autotune(
         "flash_prefill": lambda: _sweep_flash_prefill(dry_run, repeats, interpret),
         "flash_decode": lambda: _sweep_flash_decode(dry_run, repeats, interpret, False),
         "flash_decode_int8": lambda: _sweep_flash_decode(dry_run, repeats, interpret, True),
-        "paged_gather": lambda: _sweep_paged_gather(dry_run, repeats, interpret),
         "lora_mm": lambda: _sweep_lora_mm(dry_run, repeats, interpret),
     }
     winners: dict[str, dict[str, Any]] = {}

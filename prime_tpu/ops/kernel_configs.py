@@ -60,7 +60,6 @@ DEFAULTS: dict[str, dict[str, int]] = {
     "flash_prefill": {"block_q": 128, "block_k": 128},
     "flash_decode": {"block_c": 128},
     "flash_decode_int8": {"block_c": 128},
-    "paged_gather": {"block_r": 1024},
     "lora_mm": {"block_out": 256},
     "int4_matmul": {"block_out": 512},
 }

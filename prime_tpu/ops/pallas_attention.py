@@ -30,11 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the TPU compiler-params dataclass was renamed TPUCompilerParams ->
-# CompilerParams after jax 0.4.x (same fields); alias whichever this build
-# ships so the kernels lower under both toolchains
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # Built-in block defaults (measured on v5e-1). Call sites resolve the
 # ACTIVE blocks through ops/kernel_configs.py — env override, then a tuned
 # per-device-kind artifact, then these — with per-shape divisibility
@@ -201,8 +196,11 @@ def _unpack_kv_nibbles(packed):
     (D, BLOCK_C) values in VMEM: low nibble = features [0, D/2), high
     nibble = [D/2, D) — the models/quantize.py packing convention. The
     packed bytes are what streamed from HBM; the widening is VMEM-local."""
-    lo = ((packed & 0xF).astype(jnp.int8) ^ 8) - 8
-    hi = ((packed >> 4).astype(jnp.int8) ^ 8) - 8
+    # sign-extend in int32, like ops/pallas_quant.py: Mosaic on v5e has no
+    # int8 vector subtract ("failed to legalize arith.subi")
+    p32 = packed.astype(jnp.int32)
+    lo = ((p32 & 0xF) ^ 8) - 8
+    hi = ((p32 >> 4) ^ 8) - 8
     return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
 
 
@@ -419,7 +417,7 @@ def flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kv_heads, group, head_dim), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -518,7 +516,7 @@ def flash_attention_causal(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from prime_tpu.parallel.compat import shard_map
 
 
 def flash_decode_sharded(
@@ -64,7 +63,7 @@ def flash_decode_sharded(
     has_sinks = sinks is not None
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, lengths_spec, sinks_spec),
         out_specs=q_spec,

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from prime_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -64,7 +63,7 @@ def sp_decode_attention(
     scale_spec = slot_spec if quantized else P()
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), slot_spec, slot_spec, scale_spec, scale_spec, P()),
         out_specs=P(),

@@ -27,7 +27,6 @@ from jax.sharding import PartitionSpec as P
 from prime_tpu.models.config import ModelConfig
 from prime_tpu.ops.norms import rms_norm
 from prime_tpu.ops.rope import rope_frequencies
-from prime_tpu.parallel.compat import pcast, shard_map
 
 
 def pipeline_param_specs(config: ModelConfig) -> dict:
@@ -145,7 +144,7 @@ def _stage_forward(
 
     # runs inside run_pipeline's shard_map: the zero init must carry the same
     # pp-varying marker the scanned layer params give the aux output
-    aux_zero = pcast(jnp.zeros((), jnp.float32), ("pp",), to="varying")
+    aux_zero = jax.lax.pcast(jnp.zeros((), jnp.float32), ("pp",), to="varying")
     (x, aux_total), _ = jax.lax.scan(
         layer_fn, (x, aux_zero), (layers_local, sliding_local)
     )
@@ -198,7 +197,7 @@ def pipeline_forward(
     layer_specs = pipeline_param_specs(config)["layers"]
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(layer_specs, P("pp"), P()),
         out_specs=(P(), P()),
@@ -236,9 +235,9 @@ def pipeline_forward(
 
         # mark the zero carries as pp-varying so the loop carry types match
         # the ppermute/masked outputs (jax's manual-axes varying tracking)
-        state0 = pcast(jnp.zeros_like(x_mb[0]), ("pp",), to="varying")
-        outs0 = pcast(jnp.zeros_like(x_mb), ("pp",), to="varying")
-        aux0 = pcast(jnp.zeros((), jnp.float32), ("pp",), to="varying")
+        state0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), ("pp",), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_mb), ("pp",), to="varying")
+        aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), ("pp",), to="varying")
         _, outs, aux_acc = jax.lax.fori_loop(
             0, n_microbatches + stages - 1, tick, (state0, outs0, aux0)
         )

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from prime_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -150,7 +149,7 @@ def ring_self_attention(
         sinks.astype(jnp.float32) if use_sinks else jnp.zeros((q.shape[1],), jnp.float32)
     )
     spec = P(batch_axis, head_axis, seq_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local, axis_name=seq_axis, sm_scale=sm_scale,
             window=window, hops=hops, softcap=softcap, use_sinks=use_sinks,

@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from prime_tpu.models.config import ModelConfig
@@ -235,6 +236,19 @@ def param_shardings(mesh, config: ModelConfig):
 def shard_params(params, mesh, config: ModelConfig):
     """Place a param pytree onto the mesh (device_put with NamedShardings)."""
     return jax.device_put(params, param_shardings(mesh, config))
+
+
+def init_params_sharded(rng, config: ModelConfig, mesh, dtype=jnp.bfloat16):
+    """Random-init params created directly in their shardings: the init runs
+    as one jitted program with ``out_shardings``, so each device generates
+    only its own shards and no device ever holds the whole model (eager
+    ``init_params`` + ``shard_params`` materialises every leaf, and its fp32
+    transients, on device 0 first)."""
+    from prime_tpu.models.llama import init_params
+
+    return jax.jit(
+        init_params, static_argnums=(1, 2), out_shardings=param_shardings(mesh, config)
+    )(rng, config, dtype)
 
 
 def shard_batch(batch, mesh, spec: P | None = None):

@@ -89,7 +89,6 @@ from __future__ import annotations
 
 import itertools
 import queue
-import sys
 import threading
 import time
 import warnings
@@ -1062,21 +1061,19 @@ class ContinuousBatchingEngine:
 
     def _mesh_ctx(self):
         """Mesh context for compiled calls — the engine thread does not
-        inherit a caller's jax.set_mesh, so every dispatch site enters it
-        (parallel.compat.enter_mesh: jax.set_mesh on the toolchain, the
-        Mesh's own context manager on 0.4.x builds)."""
+        inherit a caller's jax.set_mesh, so every dispatch site enters it."""
         import contextlib
+
+        import jax
 
         if self.mesh is None:
             return contextlib.nullcontext()
-        from prime_tpu.parallel.compat import enter_mesh
-
-        return enter_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _cache_constraint(self):
         """The sharding constraint for the engine cache inside compiled
         programs: a NamedSharding when a mesh is attached (resolves without
-        an ambient mesh — 0.4.x builds have no jax.set_mesh), else the raw
+        an ambient mesh), else the raw
         spec for historical callers that manage their own mesh context."""
         if self.cache_spec is None:
             return None
@@ -1449,22 +1446,22 @@ class ContinuousBatchingEngine:
         all-inactive mask (slot lengths are restored, so the scribbled KV is
         invisible), and finalize splices zero-length rows, so post-warmup
         state is indistinguishable from cold state. Returns the number of
-        programs executed; gated by ``PRIME_SERVE_WARMUP`` in ``start()``.
+        programs executed; ``start()`` runs it when ``warmup`` /
+        ``PRIME_SERVE_WARMUP`` asked for it, and a failure there is fatal.
         A raised dispatch reallocates device state before propagating — the
         warmup calls donate the cache/last/temps buffers, and leaving them
         consumed would fail every later admission on a deleted array."""
         # the zero-length finalize splices and donated-state chaining are
-        # only safe against an idle engine, and only on the thread that owns
-        # the device state once the loop is running
+        # only safe against an idle engine whose loop is not running
         if self._requests or any(self._active) or self._inflight:
             raise RuntimeError(
                 "warmup() requires an idle engine (admitted or in-flight "
                 "requests would be corrupted by the warmup splices)"
             )
-        if self._thread is not None and self._thread is not threading.current_thread():
+        if self._thread is not None:
             raise RuntimeError(
-                "warmup() must run on the engine thread once start()ed "
-                "(set warmup=True / PRIME_SERVE_WARMUP=1 instead)"
+                "warmup() must run before start() (set warmup=True / "
+                "PRIME_SERVE_WARMUP=1 and start() runs it)"
             )
         try:
             return self._warmup()
@@ -1772,6 +1769,13 @@ class ContinuousBatchingEngine:
     def start(self) -> None:
         if self._thread is not None:
             return
+        if self.warmup_enabled:
+            # compile before the loop exists, so no request can land
+            # mid-pipeline on a cold program — and so a warmup that fails
+            # (a kernel that does not lower, a program set that does not fit
+            # the device) stops the caller instead of becoming a line on
+            # stderr in front of a server that then fails request by request
+            self.warmup()  # reallocates its donated state before raising
         # seed the snapshot before the loop owns it: a scrape landing between
         # start() and the first tick must not observe None
         self._refresh_stats()
@@ -1854,13 +1858,6 @@ class ContinuousBatchingEngine:
     # ---- engine loop ----
 
     def _run(self) -> None:
-        if self.warmup_enabled:
-            # compile on the engine thread (it owns device state) before the
-            # first request can land mid-pipeline on a cold program
-            try:
-                self.warmup()  # reallocates its donated state on failure
-            except Exception as e:  # noqa: BLE001 — serve anyway; compiles land lazily
-                sys.stderr.write(f"prime_tpu.serve.engine: warmup failed: {e}\n")
         while self._running:
             if not self.tick():
                 # idle: wait for a submit/shutdown wake rather than popping

@@ -9,9 +9,11 @@ compile-cache zoo; (2) the allocator sees thousands of odd-sized arrays.
 `PagedKVPool` replaces that with fixed-size pages inside one pooled buffer
 per cache leaf. Segments become `PagedSegment` — a list of page ids — and
 hit-seeding gathers the pages straight into the decode row's layout with one
-program per row capacity (ops/pallas_paged.paged_gather): the page table is
-scalar-prefetched and the pool BlockSpec's index map resolves each page
-pointer, so the "gather" is pure data movement done by Mosaic's pipeline.
+XLA program per row capacity (`gather_pages`: a take over the page axis).
+It is plain XLA on every backend: a Pallas gather cannot express the pool
+(16-token pages are not a legal TPU block — Mosaic wants the last block
+dimension a multiple of 128), while XLA picks the pool's physical layout
+itself (page-token axis off the lanes) and gathers whole pages.
 
 Design points (the invariants tests pin):
 
@@ -20,8 +22,7 @@ Design points (the invariants tests pin):
   page never straddles a split: `PagedSegment.split` is a zero-copy
   repartition of the page-id list and never frees or copies a page. A
   *tuned* page size would break that invariant the moment a split landed
-  mid-page — the registry's "paged_gather" entry therefore tunes the gather
-  kernel's inner blocking (`block_r`), never the pool geometry.
+  mid-page, so the pool geometry is not a knob.
 - **Layout**: a cache leaf `(..., tokens)` is stored as pool pages
   `(num_pages, R, page_tokens)` with `R = prod(leading dims)`; gather
   returns `(..., max_pages * page_tokens)` — exactly the decode row's shape
@@ -53,6 +54,18 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["PagedKVPool", "PagedSegment"]
+
+
+@jax.jit
+def gather_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """Gather ``pool[table]`` — pool ``(num_pages, R, page_tokens)``, table
+    ``(max_pages,) int32`` with ``-1`` marking empty slots — into a contiguous
+    ``(R, max_pages*page_tokens)`` row, zeros where ``table < 0`` (matching
+    the zeros `init_cache` seeds the copy path's row with)."""
+    r_dim = pool.shape[1]
+    pages = pool[jnp.maximum(table, 0)]                  # (max_pages, R, PT)
+    pages = jnp.where((table >= 0)[:, None, None], pages, 0)
+    return jnp.swapaxes(pages, 0, 1).reshape(r_dim, -1)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -190,41 +203,19 @@ class PagedKVPool:
         self._free.extend(pages)
 
     # -- gather ----------------------------------------------------------
-    def _use_kernel(self) -> bool:
-        from prime_tpu.ops.attention import _pallas_interpret
-
-        return _pallas_interpret() or jax.default_backend() == "tpu"
-
-    def _gather(self, table: jnp.ndarray) -> dict[str, jnp.ndarray]:
-        from prime_tpu.ops.pallas_paged import paged_gather, paged_gather_xla
-        from prime_tpu.ops.attention import _pallas_interpret
-
-        if self._use_kernel():
-            fn = functools.partial(paged_gather, interpret=_pallas_interpret())
-        else:
-            fn = paged_gather_xla
-        out: dict[str, jnp.ndarray] = {}
-        for name, pool in self._leaves.items():
-            flat = fn(pool, table)  # (R, max_pages*page_tokens)
-            out[name] = flat.reshape(*self._shapes[name], flat.shape[-1])
-        return out
-
     def gather_row(self, table: np.ndarray) -> dict[str, jnp.ndarray]:
         """Gather pages into a contiguous row: ``table`` is ``(max_pages,)``
         int32 page ids with ``-1`` marking empty tail slots; each returned
         leaf is ``(..., max_pages*page_tokens)`` with zeros in the tail —
         the decode row's exact layout."""
-        return self._gather(jnp.asarray(table, dtype=jnp.int32))
+        table = jnp.asarray(table, dtype=jnp.int32)
+        out: dict[str, jnp.ndarray] = {}
+        for name, pool in self._leaves.items():
+            flat = gather_pages(pool, table)  # (R, max_pages*page_tokens)
+            out[name] = flat.reshape(*self._shapes[name], flat.shape[-1])
+        return out
 
     def materialize(self, pages: list[int], tokens: int) -> dict[str, jnp.ndarray]:
         """Loose-dict copy of a page run (host spill / wire export path)."""
-        from prime_tpu.ops.pallas_paged import paged_gather_xla
-
-        table = jnp.asarray(pages, dtype=jnp.int32)
-        out: dict[str, jnp.ndarray] = {}
-        for name, pool in self._leaves.items():
-            flat = paged_gather_xla(pool, table)
-            out[name] = flat[..., :tokens].reshape(
-                *self._shapes[name], tokens
-            )
-        return out
+        row = self.gather_row(np.asarray(pages, dtype=np.int32))
+        return {name: leaf[..., :tokens] for name, leaf in row.items()}

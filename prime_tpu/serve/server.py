@@ -1271,9 +1271,10 @@ def serve_model(
     past it get 429 + Retry-After instead of queueing unboundedly — the
     admission-control half of docs/architecture.md "Serve fleet".
     ``mesh`` (None = the ``PRIME_SERVE_MESH`` env default) is the sharded-
-    replica spec string (``"dp=1,fsdp=2,tp=2"``): the continuous engine
-    builds the mesh, shards params and the paged KV cache onto it, and
-    serves one replica across the whole slice — docs/architecture.md
+    replica spec string (``"dp=1,fsdp=2,tp=2"``): the mesh is built here,
+    the generator creates (or places) params in their shardings on it, and
+    the continuous engine shards the paged KV cache onto it and serves one
+    replica across the whole slice — docs/architecture.md
     "Sharded replica". It is the declarative alternative to ``slice_name``
     (which derives a mesh from a provisioned slice's topology); passing
     both is an error. ``role`` (None = the ``PRIME_SERVE_ROLE`` env default,
@@ -1345,10 +1346,27 @@ def serve_model(
         model, host=host, port=port, admin_token=admin_token, role=role
     )
     try:
+        # the sharded replica's mesh is built HERE and handed to the
+        # generator, so params are created (or placed) in their shardings
+        # and the engine receives them already sharded — no device ever
+        # holds the whole model
+        serve_mesh = None
+        if continuous and not slice_name:
+            import jax
+
+            from prime_tpu.serve.mesh_config import parse_mesh_spec
+
+            mesh_config = parse_mesh_spec(
+                mesh if mesh is not None else env_str("PRIME_SERVE_MESH", ""),
+                jax.device_count(),
+            )
+            if mesh_config is not None and mesh_config.total_devices > 1:
+                serve_mesh = mesh_config.build()
         generator = JaxGenerator(
             model,
             checkpoint=checkpoint,
             tokenizer=tokenizer,
+            mesh=serve_mesh,
             slice_name=slice_name,
             tensor_parallel=tensor_parallel,
             sequence_parallel=sequence_parallel,
@@ -1382,7 +1400,6 @@ def serve_model(
                 capacity=slot_capacity,
                 chunk=chunk,
                 mesh=generator.mesh,
-                mesh_config=mesh,
                 cache_spec=cache_spec,
                 kv_quant=kv_quant,
                 speculative=speculative,

@@ -8,12 +8,11 @@ detection (reference :882) switches dispatch to the dedicated trainer.
 
 from __future__ import annotations
 
+import tomllib
 from pathlib import Path
 from typing import Literal
 
 from pydantic import BaseModel, ConfigDict, Field
-
-from prime_tpu.utils.compat import tomllib
 
 # GPU-era keys → TPU replacement (or None if dropped outright)
 DEPRECATED_KEYS: dict[str, str | None] = {

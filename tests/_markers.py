@@ -1,74 +1,16 @@
 """Shared environment-capability gates for test modules.
 
-The repo targets the jax_graft toolchain; an older JAX build in a test
-container lacks part of that surface (jax.set_mesh landed after 0.4.x).
-Tests exercising such APIs skip with a visible reason instead of failing, so
-a red tier-1 signal means a broken change — not a thin environment.
-
 This lives in its own module (not conftest.py) because ``import conftest``
 from a test module is ambiguous with tests/live/conftest.py.
 """
 
-import jax
+import importlib.util
+
 import pytest
-
-from prime_tpu.utils.compat import TOMLLIB_AVAILABLE
-from prime_tpu.utils.compat import tomllib as _tomllib
-
-requires_set_mesh = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="jax.set_mesh unavailable in this jax build (toolchain env gap)",
-)
-
-# top-level jax.shard_map graduated from jax.experimental after 0.4.x; the
-# shard_map-wrapped serving/eval paths (parallel/decode_sharded.py and
-# friends) need it
-requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax.shard_map unavailable in this jax build (toolchain env gap)",
-)
-
-# the varying-axis (vma) shard_map type system (jax.lax.pcast et al.) landed
-# with the top-level shard_map; pre-vma builds reject or mis-propagate the
-# sharding patterns written against it (pipeline aux scalars, fsdp/tp
-# forward, multi-process device_put). CI runs these on the real toolchain.
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax.lax, "pcast"),
-    reason="pre-vma jax build mishandles this sharding pattern (toolchain env gap)",
-)
-
-# stdlib tomllib landed in Python 3.11; on 3.10 containers the tomli
-# backport (same API) fills in when present — prime_tpu.utils.compat is the
-# one owner of that resolution (product modules import through it too, so
-# importing them never breaks collection). Test modules call get_tomllib()
-# in the body (or decorate with requires_tomllib) so a thin environment
-# skips visibly instead of failing.
-tomllib = _tomllib if TOMLLIB_AVAILABLE else None
-
-requires_tomllib = pytest.mark.skipif(
-    not TOMLLIB_AVAILABLE,
-    reason="no tomllib (py>=3.11) or tomli backport in this environment",
-)
-
-
-def _has_module(name: str) -> bool:
-    import importlib.util
-
-    return importlib.util.find_spec(name) is not None
-
 
 # the login handshake (commands/login.py) encrypts the API key exchange with
 # an RSA keypair; containers without the cryptography wheel can't run it
 requires_cryptography = pytest.mark.skipif(
-    not _has_module("cryptography"),
+    importlib.util.find_spec("cryptography") is None,
     reason="cryptography not installed in this environment (env gap)",
 )
-
-
-def get_tomllib():
-    """In-test-body twin of ``requires_tomllib``: returns the tomllib (or
-    tomli) module, skipping the calling test when neither exists — a drop-in
-    for the bare ``import tomllib`` that broke collection on Python 3.10."""
-    if tomllib is None:
-        pytest.skip("no tomllib (py>=3.11) or tomli backport in this environment")
-    return tomllib

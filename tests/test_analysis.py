@@ -25,7 +25,7 @@ from prime_tpu.analysis import (
     obs_contract,
     run_checks,
 )
-from prime_tpu.analysis.core import Project, Waiver, _parse_toml
+from prime_tpu.analysis.core import Project, Waiver
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
@@ -466,19 +466,6 @@ def test_baseline_requires_reason(tmp_path):
     bad.write_text('[[waiver]]\nrule = "x"\npath = "y"\nsymbol = "z"\n')
     with pytest.raises(ValueError, match="missing required"):
         load_baseline(bad)
-
-
-def test_fallback_toml_parser(monkeypatch):
-    import prime_tpu.utils.compat as compat
-
-    monkeypatch.setattr(compat, "TOMLLIB_AVAILABLE", False)
-    text = DEFAULT_BASELINE.read_text()
-    data = _parse_toml(text, "baseline.toml")
-    assert all(
-        {"rule", "path", "symbol", "reason"} <= set(w) for w in data.get("waiver", [])
-    )
-    with pytest.raises(ValueError, match="unsupported TOML"):
-        _parse_toml("[table]\nkey = 3\n", "x.toml")
 
 
 # ---- the real tree ----------------------------------------------------------

@@ -23,7 +23,6 @@ from prime_tpu.train import (
     make_train_step,
 )
 
-from _markers import requires_shard_map
 
 CFG = get_config("tiny-test")
 
@@ -34,7 +33,6 @@ def _cp_put(x, mesh):
     return jax.device_put(x, NamedSharding(mesh, prune_spec(cp_batch_spec(), mesh)))
 
 
-@requires_shard_map
 def test_cp_forward_matches_plain():
     mesh = make_mesh({"dp": 1, "fsdp": 1, "sp": 8})
     params = init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
@@ -46,7 +44,6 @@ def test_cp_forward_matches_plain():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
-@requires_shard_map
 def test_cp_forward_uniform_window_and_sinks():
     """Mistral-style uniform window and GPT-OSS sinks both ride the ring."""
     mesh = make_mesh({"dp": 1, "fsdp": 1, "sp": 8})
@@ -71,7 +68,6 @@ def test_cp_forward_uniform_window_and_sinks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
-@requires_shard_map
 def test_cp_forward_softcap():
     """Gemma2-style score softcapping rides the ring fold (the canonical
     _apply_softcap, cap-before-mask)."""
@@ -86,7 +82,6 @@ def test_cp_forward_softcap():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
-@requires_shard_map
 def test_cp_composes_with_tp_and_fsdp():
     """Context parallelism on a (fsdp, tp, sp) mesh: heads shard over tp
     (megatron layout — no silent per-device replication of every head's
@@ -108,7 +103,6 @@ def test_cp_composes_with_tp_and_fsdp():
         ring_qkv_axes(make_mesh({"tp": 8}), CFG.n_kv_heads)
 
 
-@requires_shard_map
 def test_cp_train_step_matches_plain():
     """One optimizer step under context parallelism == the plain step:
     same loss, same updated parameters (the ring is exactly differentiable

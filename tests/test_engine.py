@@ -1065,10 +1065,11 @@ def test_warmup_compiles_programs_and_preserves_cold_state(monkeypatch):
     assert not make_engine().warmup_enabled
 
 
-def test_warmup_failure_reallocates_state_and_serves():
-    """A warmup dispatch that raises AFTER consuming its donated inputs must
-    not brick the engine: _run reallocates device state and the first real
-    request still decodes reference-exactly."""
+def test_warmup_failure_is_fatal_and_reallocates_state():
+    """A warmup that was asked for and fails stops start() — the server must
+    not come up in front of an engine that could not compile. The failed
+    dispatch consumed its donated inputs, so warmup reallocates device state
+    before raising: the same engine still decodes reference-exactly."""
     engine = make_engine(warmup=True)
     real_make = engine._make_decode
     boomed = []
@@ -1086,10 +1087,14 @@ def test_warmup_failure_reallocates_state_and_serves():
         return wrapper
 
     engine._make_decode = flaky_make
-    with engine:
+    with pytest.raises(RuntimeError, match="warmup boom"):
+        engine.start()
+    assert engine._thread is None and not engine._running
+    engine._decode_fn = None  # drop the wrapper that already boomed
+    engine._make_decode = real_make
+    with engine:  # warmup again, clean this time, then serve
         req = engine.submit([1, 2, 3], max_new_tokens=4)
         assert req.all_tokens(timeout=120) == reference_tokens([1, 2, 3], 4)
-    assert boomed
 
 
 def test_warmup_speculative_covers_spec_program_set():
@@ -1188,10 +1193,7 @@ def test_engine_backend_generate_blocking():
 
 
 def test_engine_under_mesh():
-    """The engine runs sharded over a device mesh (tp over kv heads).
-    No capability gate: the engine's dispatch sites enter the mesh via
-    parallel.compat.enter_mesh, which falls back to the Mesh context
-    manager on pre-set_mesh jax builds."""
+    """The engine runs sharded over a device mesh (tp over kv heads)."""
     from prime_tpu.parallel.mesh import make_mesh
     from prime_tpu.parallel.sharding import cache_spec, shard_params
 

@@ -17,8 +17,6 @@ from prime_tpu.evals.datasets import (
 from prime_tpu.evals.runner import EvalRunSpec, find_latest_run, push_eval_results, run_eval
 from prime_tpu.testing import FakeControlPlane
 
-from _markers import requires_shard_map
-
 
 @pytest.fixture
 def fake():
@@ -210,7 +208,6 @@ def test_max_new_tokens_bound(tmp_path):
         gen.generate(["hi"], max_new_tokens=600, temperature=0.0)
 
 
-@requires_shard_map
 def test_run_eval_sharded_slice(tmp_path):
     """North-star shape: eval run with --slice shards the generator over the
     (virtual) v5e-8 mesh and still writes the results contract."""
@@ -276,7 +273,6 @@ def test_run_eval_with_weight_quant(tmp_path):
     assert result.metrics["num_samples"] == 2
 
 
-@requires_shard_map
 def test_run_eval_sequence_parallel_slot_sharded_cache(tmp_path):
     """eval run --slice --sp: the KV cache's slot axis shards over sp and
     the whole eval pipeline still produces results (long-context serving

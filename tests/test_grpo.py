@@ -22,8 +22,6 @@ from prime_tpu.train.grpo import (
 )
 from prime_tpu.train.trainer import init_train_state
 
-from _markers import requires_set_mesh
-
 
 @pytest.fixture()
 def tiny():
@@ -216,7 +214,6 @@ def test_run_grpo_improves_reward():
     assert np.isfinite(report.final_loss)
 
 
-@requires_set_mesh
 def test_run_grpo_sharded_mesh():
     """One sharded GRPO step over the virtual 8-device mesh: rollout batch
     divisibility is enforced and the update executes SPMD."""
@@ -290,7 +287,6 @@ def test_run_grpo_lora_trains_adapters_only():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@requires_set_mesh
 def test_run_grpo_lora_sharded():
     from prime_tpu.parallel.mesh import make_mesh
     from prime_tpu.train.lora import LoraConfig

@@ -5,9 +5,9 @@ The pins, per docs/kernels.md "Kernel campaign & autotune":
 - **Config registry**: resolution order env > tuned artifact > default; a
   tuned artifact round-trips through ``save_artifact``/``load_tuned`` and
   flips ``source()``; malformed/mismatched artifacts degrade to defaults.
-- **Paged gather**: the pallas kernel is BIT-IDENTICAL to the XLA take
-  reference (it moves bytes, computes nothing), and the pool's
-  store/gather/split/free lifecycle round-trips exactly.
+- **Page pool**: the pool's store/gather/split/free lifecycle round-trips
+  exactly (the gather is plain XLA; tests/test_aot_lowering.py compiles it
+  for the chip).
 - **Fused gathered-LoRA**: the one-pass kernel is BIT-IDENTICAL to the
   base + gather + einsum chain it replaces (rounding contract in
   ops/pallas_lora.py), across mixed adapter ids.
@@ -44,9 +44,7 @@ def _clean_registry(monkeypatch, tmp_path):
     yield
     kernel_configs.invalidate_cache()
     from prime_tpu.ops.pallas_lora import fused_lora_matmul
-    from prime_tpu.ops.pallas_paged import paged_gather
 
-    paged_gather.clear_cache()
     fused_lora_matmul.clear_cache()
 
 
@@ -105,39 +103,7 @@ def test_wrong_device_kind_artifact_ignored(tmp_path):
     assert kernel_configs.resolve("flash_decode", "block_c") == 128
 
 
-# ---- paged gather ------------------------------------------------------------
-
-
-def _pool_and_table(seed=0, num_pages=8, r_dim=48, page_tokens=16, max_pages=6):
-    rng = np.random.default_rng(seed)
-    pool = jnp.asarray(
-        rng.normal(size=(num_pages, r_dim, page_tokens)).astype(np.float32)
-    )
-    table = np.full(max_pages, -1, dtype=np.int32)
-    used = rng.permutation(num_pages)[: max_pages - 2]  # leave a -1 tail
-    table[: len(used)] = used
-    return pool, jnp.asarray(table)
-
-
-def test_paged_gather_kernel_bit_identical_to_xla():
-    from prime_tpu.ops.pallas_paged import paged_gather, paged_gather_xla
-
-    pool, table = _pool_and_table()
-    out = paged_gather(pool, table, interpret=True)
-    ref = paged_gather_xla(pool, table)
-    assert out.shape == ref.shape == (48, 6 * 16)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
-    # empty slots are zeros (the copy path's init_cache contract)
-    assert np.all(np.asarray(out)[:, 4 * 16 :] == 0)
-
-
-def test_paged_gather_block_r_clamps_to_divisor():
-    from prime_tpu.ops.pallas_paged import paged_gather, paged_gather_xla
-
-    pool, table = _pool_and_table(r_dim=40)
-    # 7 divides nothing relevant: the wrapper walks down to a divisor of 40
-    out = paged_gather(pool, table, block_r=7, interpret=True)
-    assert np.array_equal(np.asarray(out), np.asarray(paged_gather_xla(pool, table)))
+# ---- page pool ---------------------------------------------------------------
 
 
 def test_kv_pool_store_gather_split_free():
@@ -325,10 +291,10 @@ def test_autotune_dry_run_round_trips_artifact(tmp_path):
     from prime_tpu.ops.autotune import run_autotune
 
     winners = run_autotune(
-        kernels=["paged_gather", "lora_mm"], dry_run=True
+        kernels=["flash_decode", "lora_mm"], dry_run=True
     )
-    assert set(winners) == {"paged_gather", "lora_mm"}
-    assert winners["paged_gather"]["block_r"] > 0
+    assert set(winners) == {"flash_decode", "lora_mm"}
+    assert winners["flash_decode"]["block_c"] > 0
     assert "us" in winners["lora_mm"]
 
     out = tmp_path / "tuned"
@@ -341,8 +307,8 @@ def test_autotune_dry_run_round_trips_artifact(tmp_path):
     try:
         assert kernel_configs.source() == "tuned"
         assert (
-            kernel_configs.resolve("paged_gather", "block_r")
-            == winners["paged_gather"]["block_r"]
+            kernel_configs.resolve("flash_decode", "block_c")
+            == winners["flash_decode"]["block_c"]
         )
         assert (
             kernel_configs.resolve("lora_mm", "block_out")

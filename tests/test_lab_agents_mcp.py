@@ -11,7 +11,6 @@ import pytest
 from prime_tpu.lab.agents import AgentError, AgentRuntime
 from prime_tpu.lab.mcp import build_tools, handle_request
 
-from _markers import get_tomllib
 
 # -- scripted fake agents ------------------------------------------------------
 
@@ -495,7 +494,7 @@ def test_chat_choice_selection_roundtrip():
 
 
 def test_chat_launch_proposal_writes_card(tmp_path):
-    tomllib = get_tomllib()
+    import tomllib
 
     from prime_tpu.lab.tui.chat import AgentChatScreen
 
@@ -517,7 +516,7 @@ def test_chat_launch_proposal_writes_card(tmp_path):
 
 
 def test_chat_launch_kind_normalized_and_bad_kind_rejected(tmp_path):
-    tomllib = get_tomllib()
+    import tomllib
 
     from prime_tpu.lab.tui.chat import AgentChatScreen
 
@@ -662,7 +661,7 @@ def test_mcp_eval_samples_tool(tmp_path):
 def test_chat_form_edit_launch_roundtrip(tmp_path):
     """configure_run form: field edits stamp form_values, typed errors stay
     on the form, a valid enter writes the launch card (VERDICT r4 #3)."""
-    tomllib = get_tomllib()
+    import tomllib
 
     from prime_tpu.lab.tui.chat import AgentChatScreen
 

@@ -12,8 +12,6 @@ from prime_tpu.lab.tui.app import SECTIONS
 from prime_tpu.lab.tui.keys import decode_key
 from prime_tpu.testing import FakeControlPlane
 
-from _markers import get_tomllib
-
 
 @pytest.fixture
 def fake():
@@ -671,7 +669,7 @@ def test_env_detail_versions_and_actions(app, fake, api, tmp_path):
 
 
 def test_card_editor_edit_save_roundtrip(app, tmp_path):
-    tomllib = get_tomllib()
+    import tomllib
 
     _write_card(tmp_path, "sweep", "eval")
     app.on_key("8")              # launch section
@@ -700,7 +698,7 @@ def test_card_editor_edit_save_roundtrip(app, tmp_path):
 
 
 def test_card_editor_add_delete_and_typing(app, tmp_path):
-    tomllib = get_tomllib()
+    import tomllib
 
     _write_card(tmp_path, "card2", "eval")
     app.on_key("8")
@@ -745,7 +743,7 @@ def test_card_editor_new_card_and_launch(app, fake, tmp_path):
 def test_card_editor_payload_name_key_survives(app, tmp_path):
     """A payload key literally named `name` must not collide with the
     [launch].name pseudo-field: zero-edit save keeps both intact."""
-    tomllib = get_tomllib()
+    import tomllib
 
     base = tmp_path / ".prime-lab" / "launch"
     base.mkdir(parents=True, exist_ok=True)

@@ -19,7 +19,6 @@ from prime_tpu.models import get_config
 from prime_tpu.models.llama import forward, init_cache, init_params
 from prime_tpu.models.sampler import generate
 
-from _markers import requires_set_mesh
 
 CFG = get_config("tiny-mla")
 PARAMS = init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
@@ -100,7 +99,6 @@ def test_engine_matches_one_shot_sampler():
     assert reqs[0].all_tokens(timeout=1) == ref
 
 
-@requires_set_mesh
 def test_sharded_generate_tp_fsdp(tokens):
     """MLA under the serving mesh: query heads on tp, latent cache head axis
     replicated (cache_spec_for); decoded tokens match the single-device run."""

@@ -9,7 +9,6 @@ from prime_tpu.models import get_config
 from prime_tpu.models.llama import forward, init_params
 from prime_tpu.ops.moe import expert_capacity, moe_mlp, top_k_routing
 
-from _markers import requires_set_mesh, requires_vma
 
 CFG = get_config("tiny-moe")
 
@@ -188,7 +187,6 @@ def test_moe_sharded_train_step_with_ep_axis():
     assert "ep" in str(sharding.spec)
 
 
-@requires_set_mesh
 def test_moe_sharded_generate_via_slice():
     """JaxGenerator serves an MoE model over a slice mesh, auto-carving ep."""
     from prime_tpu.evals.runner import JaxGenerator
@@ -450,7 +448,6 @@ def test_score_bias_survives_training_steps():
     )
 
 
-@requires_vma
 def test_tiny_deepseek_pipeline_train_step():
     """MLA + DeepSeekMoE staged over pp: specs cover the new keys and the
     stage forward routes through the MLA block."""

@@ -19,7 +19,6 @@ from prime_tpu.train import (
     shard_train_state,
 )
 
-from _markers import requires_set_mesh, requires_vma
 
 CFG = get_config("tiny-test")
 
@@ -66,7 +65,6 @@ def test_ring_attention_matches_dense():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
 
-@requires_vma
 def test_sharded_forward_matches_single_device():
     mesh = make_mesh({"dp": 1, "fsdp": 2, "tp": 4})
     params = init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
@@ -118,7 +116,6 @@ def test_opt_state_sharding_matches_params_by_position():
     assert mu["layers"]["wq"].sharding.spec == jax.sharding.PartitionSpec(None, "fsdp", "tp")
 
 
-@requires_set_mesh
 def test_sharded_generate_matches_single_device():
     """The eval/serve path: JaxGenerator over a mesh must produce the same
     tokens as the unsharded sampler (fp32 weights for determinism)."""
@@ -150,7 +147,6 @@ def test_sharded_generate_matches_single_device():
     np.testing.assert_array_equal(np.asarray(out.lengths), np.asarray(ref.lengths))
 
 
-@requires_set_mesh
 def test_jax_generator_mesh_pads_ragged_batch():
     from prime_tpu.evals.runner import JaxGenerator
 
@@ -158,6 +154,29 @@ def test_jax_generator_mesh_pads_ragged_batch():
     gen = JaxGenerator("tiny-test", mesh=mesh)
     outs = gen.generate(["a", "bb", "ccc"], max_new_tokens=4, temperature=0.0)
     assert len(outs) == 3  # batch of 3 padded to 4 internally, extras dropped
+
+
+def test_jax_generator_creates_params_in_their_shardings():
+    """With a mesh, random-init params are CREATED sharded (one jitted init
+    with out_shardings) — never whole on one device first — and the values
+    do not depend on the mesh they were created on."""
+    from prime_tpu.evals.runner import JaxGenerator
+    from prime_tpu.models.llama import init_params
+    from prime_tpu.parallel.sharding import param_shardings
+
+    mesh = make_mesh({"dp": 1, "fsdp": 2, "tp": 2}, devices=jax.devices()[:4])
+    gen = JaxGenerator("tiny-test", mesh=mesh)
+    want = param_shardings(mesh, gen.config)
+    placed = jax.tree.map(lambda leaf, sharding: leaf.sharding == sharding, gen.params, want)
+    assert all(jax.tree.leaves(placed))
+    unsharded = jax.jit(init_params, static_argnums=(1, 2))(
+        jax.random.PRNGKey(0), gen.config, jnp.bfloat16
+    )
+    same = jax.tree.map(
+        lambda a, b: bool((a.astype(jnp.float32) == b.astype(jnp.float32)).all()),
+        gen.params, unsharded,
+    )
+    assert all(jax.tree.leaves(same))
 
 
 def test_jax_generator_rejects_tp_not_dividing_kv_heads():
@@ -232,7 +251,6 @@ def test_sp_decode_rejects_indivisible_capacity():
         )
 
 
-@requires_set_mesh
 def test_sharded_generate_qwen_style_bias_and_decoupled_head_dim():
     """attn_bias + head_dim_override must shard and decode like the plain
     config: tp splits the bias vectors on the projection output dim."""
@@ -265,7 +283,6 @@ def test_sharded_generate_qwen_style_bias_and_decoupled_head_dim():
     np.testing.assert_array_equal(np.asarray(out.tokens), np.asarray(ref.tokens))
 
 
-@requires_set_mesh
 def test_sharded_generate_gemma_style_matches_single_device():
     """Softcap + sliding-window + post-norms must survive sharding: the
     Gemma2 masking paths are pure XLA and partition like the plain model."""
@@ -622,7 +639,6 @@ def test_sp_decode_int8_cache_matches_xla():
     np.testing.assert_allclose(np.asarray(out_fp), np.asarray(ref_fp), rtol=2e-3, atol=2e-3)
 
 
-@requires_set_mesh
 def test_generate_with_sp_sharded_cache_matches_plain():
     """Long-context serving building block: generate with the KV cache's
     SLOT axis sharded over sp (a cache bigger than one chip's HBM spreads

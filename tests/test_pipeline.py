@@ -14,7 +14,6 @@ from prime_tpu.parallel.pipeline import (
     shard_pipeline_params,
 )
 
-from _markers import requires_vma
 
 CFG = get_config("tiny-test").scaled(n_layers=4)  # 4 layers over 2 or 4 stages
 
@@ -94,7 +93,6 @@ def test_pipeline_grads_match_dense(params):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-4)
 
 
-@requires_vma
 def test_pipeline_moe_matches_dense():
     """Sparse-MoE layers pipeline too: with capacity high enough that no
     token drops, the staged logits equal the plain scan's (per-microbatch

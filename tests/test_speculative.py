@@ -15,7 +15,6 @@ from prime_tpu.models.llama import init_params
 from prime_tpu.models.sampler import generate
 from prime_tpu.models.speculative import propose_ngram_drafts, spec_generate
 
-from _markers import requires_set_mesh
 
 CFG = get_config("tiny-test")
 
@@ -107,7 +106,6 @@ def test_propose_ngram_drafts_fallback_repeats_last():
     assert drafts.tolist() == [[4, 4]]
 
 
-@requires_set_mesh
 def test_spec_generate_sharded_matches_single_device(params):
     """spec_generate under a (fsdp, tp) mesh: per-row verify windows and
     cache scatters must partition like the plain decode path."""

@@ -10,8 +10,6 @@ from prime_tpu.commands.main import cli
 from prime_tpu.testing import FakeControlPlane
 from prime_tpu.train.config import RL_TOML_TEMPLATE, load_rl_config, strip_deprecated
 
-from _markers import requires_shard_map
-
 
 @pytest.fixture
 def fake(monkeypatch):
@@ -708,7 +706,6 @@ def test_train_local_rl_lora_cli(tmp_path):
     assert adapter_dir.endswith("adapters")
 
 
-@requires_shard_map
 def test_train_local_cli_context_parallel(tmp_path):
     """--sp shards the sequence over the ring (context parallelism) through
     the real CLI: mesh reported, loss finite, metrics written."""

@@ -24,8 +24,6 @@ from prime_tpu.lab.widget_model import (
 )
 from prime_tpu.lab.widgets import render_widget
 
-from _markers import get_tomllib
-
 
 def _render_text(renderable) -> str:
     from rich.console import Console
@@ -216,7 +214,7 @@ def test_launch_card_payload_maps_kind_and_types():
 def test_chat_proposal_writes_typed_card(tmp_path):
     """End-to-end card lifecycle: agent proposal -> typed card on disk ->
     scan_cards sees it -> TOML round-trips with real types."""
-    tomllib = get_tomllib()
+    import tomllib
 
     from prime_tpu.lab.tui.chat import AgentChatScreen
     from prime_tpu.lab.tui.launch import scan_cards
