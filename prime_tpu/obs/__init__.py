@@ -18,9 +18,13 @@ this layer). Two halves:
   with windowed rate/quantile queries (the observatory's memory).
 - :mod:`prime_tpu.obs.slo` — declarative SLO policies evaluated with
   multi-window burn rates into typed ``ScaleSignal`` recommendations.
+- :mod:`prime_tpu.obs.phase` — the engine loop's phase clock: every second
+  of the loop thread charged to one phase, the same sites as annotations in
+  a ``jax.profiler`` capture, and program builds accounted through
+  ``jax.monitoring``.
 - :mod:`prime_tpu.obs.profiler` — the device-time observatory: a sampled
-  ``block_until_ready`` step clock under the serving engine, XLA compile
-  and HBM accounting, cost-model MFU attribution, and Chrome-trace export
+  ``block_until_ready`` step clock under the serving engine, HBM
+  accounting, cost-model MFU attribution, and Chrome-trace export
   (``jax`` is imported lazily inside its fencing paths, so this package
   stays importable without it).
 
@@ -49,6 +53,7 @@ from prime_tpu.obs.slo import (
     SloPolicy,
     default_policies,
 )
+from prime_tpu.obs.phase import PhaseClock
 from prime_tpu.obs.profiler import DeviceProfiler, chrome_trace
 from prime_tpu.obs.timeseries import RegistrySampler, SnapshotRing
 from prime_tpu.obs.trace import (
@@ -83,6 +88,7 @@ __all__ = [
     "default_policies",
     "FlightRecorder",
     "DeviceProfiler",
+    "PhaseClock",
     "chrome_trace",
     "Span",
     "TraceContext",

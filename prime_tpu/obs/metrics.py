@@ -129,6 +129,11 @@ class Counter(_Metric):
             series = self._series.get(self._key(labels))
             return series[0] if series else 0.0
 
+    def total(self) -> float:
+        """Sum over every series of the family."""
+        with self._lock:
+            return sum(series[0] for series in self._series.values())
+
 
 class Gauge(_Metric):
     """Value that can go up and down."""
@@ -223,6 +228,11 @@ class Histogram(_Metric):
             if series is None or series.count == 0:
                 return default
             return series.sum / series.count
+
+    def total(self) -> float:
+        """Sum of every observed value, over every series of the family."""
+        with self._lock:
+            return sum(series.sum for series in self._series.values())
 
     def series_snapshot(self, **labels: Any) -> dict | None:
         with self._lock:

@@ -16,10 +16,9 @@ one-chunk-deep overlap pipeline:
   ``serve_device_step_seconds{phase=...}``. When the profiler is inactive
   the step() call returns a shared no-op — zero added syncs, asserted by
   test.
-- **Compile accounting** — a process-wide spy around XLA's
-  ``backend_compile`` times every jit cache miss into
-  ``serve_compiles_total``/``serve_compile_seconds`` labeled with the phase
-  that triggered it, so a mid-run recompile stops being folklore.
+- **Compile accounting** lives in the engine's phase clock
+  (``obs/phase.py``: ``jax.monitoring`` listeners, always on); the summary
+  and a capture window read the clock's totals and its list of builds.
 - **HBM accounting** — ``device.memory_stats()`` + live-buffer polling into
   gauges next to the prefix-cache byte gauges (the CPU backend reports no
   memory_stats; the gauges then stay at their last value / zero).
@@ -45,6 +44,7 @@ import time
 from typing import Any, Callable
 
 from prime_tpu.obs.metrics import DEFAULT_LATENCY_BUCKETS, Registry
+from prime_tpu.obs.phase import PhaseClock
 from prime_tpu.utils.env import env_int
 
 __all__ = ["DeviceProfiler", "chrome_trace", "PEAK_TFLOPS_BF16"]
@@ -77,51 +77,6 @@ def _bucket_label(n: int) -> str:
 
 
 # ---------------------------------------------------------------------------
-# XLA compile spy: one process-wide wrapper, many listeners.
-#
-# jax's compile entry point is process-global state, so the wrapper installs
-# once and stays (an uninstall could race another wrapper); listeners attach
-# and detach per profiler. With no listeners the wrapper is a plain
-# passthrough.
-
-_SPY_LOCK = threading.Lock()
-_SPY_LISTENERS: "set[DeviceProfiler]" = set()
-_SPY_INSTALLED = False
-
-
-def _install_compile_spy(listener: "DeviceProfiler") -> None:
-    global _SPY_INSTALLED
-    with _SPY_LOCK:
-        _SPY_LISTENERS.add(listener)
-        if _SPY_INSTALLED:
-            return
-        import jax._src.compiler as compiler_mod  # noqa: PLC0415
-
-        real = compiler_mod.backend_compile_and_load
-
-        def _spy(*args: Any, **kwargs: Any) -> Any:
-            t0 = time.monotonic()
-            try:
-                return real(*args, **kwargs)
-            finally:
-                dt = time.monotonic() - t0
-                for lst in list(_SPY_LISTENERS):
-                    try:
-                        lst._note_compile(dt)
-                    except Exception:  # noqa: BLE001 — never fail a compile
-                        pass
-
-        _spy.__wrapped__ = real  # type: ignore[attr-defined]
-        compiler_mod.backend_compile_and_load = _spy
-        _SPY_INSTALLED = True
-
-
-def _remove_compile_listener(listener: "DeviceProfiler") -> None:
-    with _SPY_LOCK:
-        _SPY_LISTENERS.discard(listener)
-
-
-# ---------------------------------------------------------------------------
 # Step handles returned by DeviceProfiler.step()
 
 
@@ -143,29 +98,6 @@ class _NullStep:
 
 
 _NULL_STEP = _NullStep()
-
-
-class _PhaseStep:
-    """Active profiler, unsampled dispatch: mark the phase on the calling
-    thread (so the compile spy can attribute a surprise recompile) but add
-    no fences."""
-
-    __slots__ = ("_prof", "_phase")
-
-    def __init__(self, prof: "DeviceProfiler", phase: str) -> None:
-        self._prof = prof
-        self._phase = phase
-
-    def __enter__(self) -> "_PhaseStep":
-        self._prof._local.phase = self._phase
-        return self
-
-    def __exit__(self, *exc: Any) -> bool:
-        self._prof._local.phase = None
-        return False
-
-    def fence(self, value: Any) -> None:
-        pass
 
 
 class _SampledStep:
@@ -196,7 +128,6 @@ class _SampledStep:
     def __enter__(self) -> "_SampledStep":
         import jax  # noqa: PLC0415
 
-        self._prof._local.phase = self._phase
         if self._pre is not None:
             try:
                 jax.block_until_ready(self._pre)
@@ -209,7 +140,6 @@ class _SampledStep:
         self._out = value
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
-        self._prof._local.phase = None
         if exc_type is not None or self._out is None:
             return False
         import jax  # noqa: PLC0415
@@ -226,7 +156,7 @@ class _SampledStep:
 
 
 class DeviceProfiler:
-    """Sampled device step clock + compile/HBM/MFU accounting for one engine.
+    """Sampled device step clock + HBM/MFU accounting for one engine.
 
     Constructed unconditionally by the engine (the metric families must
     exist whether or not profiling ever turns on, so the /metrics shape is
@@ -242,8 +172,12 @@ class DeviceProfiler:
         enabled: bool = False,
         sample_every: int | None = None,
         mesh_devices: int = 1,
+        clock: PhaseClock | None = None,
     ) -> None:
         self.registry = registry
+        # the engine's phase clock owns the compile accounting (None for a
+        # profiler built without an engine: compile totals then read 0)
+        self._clock = clock
         self.enabled = bool(enabled)
         if sample_every is None:
             sample_every = env_int("PRIME_SERVE_PROFILE_SAMPLE", 16)
@@ -251,26 +185,19 @@ class DeviceProfiler:
         self.mesh_devices = max(1, int(mesh_devices or 1))
         self._mesh_label = str(self.mesh_devices)
         self._lock = threading.Lock()
-        self._local = threading.local()
         self._counts: dict[str, int] = {}  # engine-thread only
         self._agg: dict[str, list[float]] = {}  # phase -> [samples, total_s]
         self._costs: dict[str, dict[str, float]] = {}  # phase -> flops/bytes
-        self._compiles = 0
-        self._compile_s = 0.0
         self._last_mem: dict[str, float] = {}
         self._last_mem_poll = 0.0
         # capture window state (None = no capture in progress)
         self._capture: list[dict] | None = None
-        self._capture_compiles: list[dict] = []
         self._capture_t0 = 0.0
         self._capture_wall0 = 0.0
         r = registry
         # serve_device_step_seconds{phase,batch,mesh} (histogram): fenced
         # device execution seconds of one sampled dispatch, per program
-        # signature. serve_compiles_total{phase} (counter) /
-        # serve_compile_seconds{phase} (histogram): XLA jit cache misses and
-        # their compile wall time, attributed to the dispatch phase that
-        # triggered them. serve_hbm_bytes_in_use / serve_hbm_bytes_limit /
+        # signature. serve_hbm_bytes_in_use / serve_hbm_bytes_limit /
         # serve_live_buffers / serve_live_buffer_bytes (gauges): allocator
         # view next to the prefix-cache byte gauges. serve_mfu_ratio{phase}
         # (gauge): achieved FLOP/s over the per-generation roofline.
@@ -280,17 +207,6 @@ class DeviceProfiler:
             "signature (phase x batch bucket x mesh size)",
             buckets=DEFAULT_LATENCY_BUCKETS,
             labelnames=("phase", "batch", "mesh"),
-        )
-        self._m_compiles = r.counter(
-            "serve_compiles_total",
-            "XLA backend compiles (jit cache misses) by dispatch phase",
-            labelnames=("phase",),
-        )
-        self._m_compile_s = r.histogram(
-            "serve_compile_seconds",
-            "Wall seconds of one XLA backend compile, by dispatch phase",
-            buckets=DEFAULT_LATENCY_BUCKETS,
-            labelnames=("phase",),
         )
         self._m_hbm_used = r.gauge(
             "serve_hbm_bytes_in_use", "Device allocator bytes in use"
@@ -310,8 +226,6 @@ class DeviceProfiler:
             "(cost-model FLOPs; absent roofline reports 0)",
             labelnames=("phase",),
         )
-        if self.enabled:
-            _install_compile_spy(self)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -320,10 +234,6 @@ class DeviceProfiler:
         """True when any dispatch should carry a phase marker: steady-state
         sampling is on, or a capture window is open."""
         return self.enabled or self._capture is not None
-
-    def close(self) -> None:
-        """Detach from the process-wide compile spy (engine shutdown)."""
-        _remove_compile_listener(self)
 
     # -- step clock --------------------------------------------------------
 
@@ -341,8 +251,8 @@ class DeviceProfiler:
         call and hands the output array to ``handle.fence(out)``; whether
         that costs anything is the profiler's decision:
 
-        - inactive -> shared no-op (zero syncs, zero allocation),
-        - active but unsampled -> phase marker only (compile attribution),
+        - inactive, or active but unsampled -> shared no-op (zero syncs,
+          zero allocation),
         - sampled -> drain ``pre``, time the dispatch, fence the output,
           and (once per phase) lower ``cost_fn(*cost_args)`` for its XLA
           cost analysis.
@@ -353,17 +263,10 @@ class DeviceProfiler:
         n = self._counts.get(phase, 0)
         self._counts[phase] = n + 1
         if not capturing and n % self.sample_every:
-            return _PhaseStep(self, phase)
+            return _NULL_STEP
         if cost_fn is not None and phase not in self._costs:
             self._note_cost(phase, cost_fn, cost_args)
         return _SampledStep(self, phase, batch, steps, pre)
-
-    def mark(self, phase: str) -> Any:
-        """Phase marker alone (no fencing) — the warmup pass uses it so its
-        compiles land under their own label instead of "other"."""
-        if not self.active:
-            return _NULL_STEP
-        return _PhaseStep(self, phase)
 
     def _record(
         self, phase: str, t0: float, seconds: float, batch: int, steps: int
@@ -391,24 +294,6 @@ class DeviceProfiler:
                         "duration_s": seconds,
                         "batch": int(batch),
                         "steps": int(steps),
-                    }
-                )
-
-    # -- compile accounting ------------------------------------------------
-
-    def _note_compile(self, seconds: float) -> None:
-        phase = getattr(self._local, "phase", None) or "other"
-        self._m_compiles.inc(phase=phase)
-        self._m_compile_s.observe(seconds, phase=phase)
-        with self._lock:
-            self._compiles += 1
-            self._compile_s += seconds
-            if self._capture is not None:
-                self._capture_compiles.append(
-                    {
-                        "phase": phase,
-                        "start_s": time.monotonic() - seconds,
-                        "duration_s": seconds,
                     }
                 )
 
@@ -512,10 +397,8 @@ class DeviceProfiler:
             if self._capture is not None:
                 return False
             self._capture = []
-            self._capture_compiles = []
             self._capture_t0 = time.monotonic()
             self._capture_wall0 = time.time()
-        _install_compile_spy(self)
         return True
 
     def stop_capture(self) -> dict | None:
@@ -525,13 +408,15 @@ class DeviceProfiler:
             if self._capture is None:
                 return None
             samples = self._capture
-            compiles = self._capture_compiles
             t0 = self._capture_t0
             wall0 = self._capture_wall0
             self._capture = None
-            self._capture_compiles = []
-        if not self.enabled:
-            _remove_compile_listener(self)
+        # programs built inside the window, from the phase clock's list
+        compiles = [
+            {"phase": b["phase"], "start_s": b["start_s"], "duration_s": b["seconds"]}
+            for b in (self._clock.builds() if self._clock else [])
+            if b["start_s"] + b["seconds"] >= t0
+        ]
         duration_s = time.monotonic() - t0
         host_spans = self._host_spans_since(t0)
         trace = chrome_trace(
@@ -576,9 +461,11 @@ class DeviceProfiler:
         peak = self.peak_flops()
         with self._lock:
             agg = {k: list(v) for k, v in self._agg.items()}
-            compiles = self._compiles
-            compile_s = self._compile_s
             mem = dict(self._last_mem)
+        compiles = (
+            self._clock.build_totals() if self._clock
+            else {"total": 0, "seconds": 0.0, "loads": 0}
+        )
         phases: dict[str, dict] = {}
         for phase, (count, total_s) in sorted(agg.items()):
             mean_s = total_s / count if count else 0.0
@@ -607,7 +494,7 @@ class DeviceProfiler:
             "mesh_devices": self.mesh_devices,
             "peak_tflops": round(peak / 1e12, 3) if peak else None,
             "phases": phases,
-            "compiles": {"total": int(compiles), "seconds": round(compile_s, 6)},
+            "compiles": compiles,
             "memory": mem,
         }
 

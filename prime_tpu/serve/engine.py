@@ -106,6 +106,7 @@ from prime_tpu.obs.metrics import (
     DEFAULT_TOKEN_BUCKETS,
     Registry,
 )
+from prime_tpu.obs.phase import PhaseClock
 from prime_tpu.obs.profiler import DeviceProfiler
 from prime_tpu.obs.trace import TRACER, TraceContext
 from prime_tpu.serve.errors import DrainingError, QueueFullError
@@ -940,7 +941,12 @@ class ContinuousBatchingEngine:
             env_str("PRIME_SENTINEL_INJECT_MS", "")
         )
         self._dispatch_count = 0
-        # device-time observatory: sampled step clock + compile/HBM/MFU
+        # phase clock (obs/phase.py): every second of the thread that drives
+        # this engine charged to one phase, always on; the same call opens
+        # the jax.profiler annotation and the PRIME_TRACE span of each site,
+        # and program builds are accounted through jax.monitoring
+        self._clock = PhaseClock(r, tracer=TRACER)
+        # device-time observatory: sampled step clock + HBM/MFU
         # accounting into this registry (docs/observability.md "Device
         # time"). Constructed even when disabled so the metric families and
         # the /admin/profile capture surface exist on every engine.
@@ -948,6 +954,7 @@ class ContinuousBatchingEngine:
             r,
             enabled=self.profile_enabled,
             mesh_devices=self.mesh_devices,
+            clock=self._clock,
         )
         self._t0 = time.monotonic()
         # stats() snapshot, ticked by the engine loop (ADVICE engine.py:1008):
@@ -1381,30 +1388,33 @@ class ContinuousBatchingEngine:
         self._maybe_inject_delay()
         if self._spec_fn is None:
             self._spec_fn = self._make_spec_decode()
-        self._rng, rng = jax.random.split(self._rng)
-        mask = self._active.copy()
         seq = next(self._chunk_seq)
-        args = (
-            self.params, self._adapters, self._cache, self._hist,
-            self._hist_len, self._last, self._temps, self._top_ps,
-            jnp.asarray(mask), self._adapter_slots, rng,
-        )
-        with TRACER.span(
-            "serve.spec_dispatch", seq=seq, draft_len=self.draft_len,
+        # the phase holds all of the enqueue: the rng split and the mask's
+        # upload are dispatches of their own, and the device waits for them
+        with self._clock.span(
+            "serve.spec_dispatch", "dispatch", seq=seq, draft_len=self.draft_len,
             **self._span_mesh,
-        ), self._mesh_ctx(), self.profiler.step(
-            "spec", pre=self._last, batch=int(mask.sum()),
-            steps=self.draft_len + 1, cost_fn=self._spec_fn, cost_args=args,
-        ) as prof_step:
-            (
-                self._cache, self._hist, self._hist_len, self._last, toks, run_len,
-            ) = self._spec_fn(*args)
-            prof_step.fence(toks)
+        ), self._mesh_ctx():
+            self._rng, rng = jax.random.split(self._rng)
+            mask = self._active.copy()
+            args = (
+                self.params, self._adapters, self._cache, self._hist,
+                self._hist_len, self._last, self._temps, self._top_ps,
+                jnp.asarray(mask), self._adapter_slots, rng,
+            )
+            with self.profiler.step(
+                "spec", pre=self._last, batch=int(mask.sum()),
+                steps=self.draft_len + 1, cost_fn=self._spec_fn, cost_args=args,
+            ) as prof_step:
+                (
+                    self._cache, self._hist, self._hist_len, self._last, toks, run_len,
+                ) = self._spec_fn(*args)
+                prof_step.fence(toks)
         self._inflight.append(
             _InflightChunk(
                 seq=seq, toks=toks, mask=mask,
                 requests=dict(self._requests),
-                dispatched_at=time.monotonic(), run_len=run_len,
+                dispatched_at=self._clock.switched_at, run_len=run_len,
             )
         )
         self._m_inflight_depth.set(len(self._inflight))
@@ -1505,7 +1515,8 @@ class ContinuousBatchingEngine:
         # engine's own stream must stay untouched so a warmed engine's
         # sampled requests are bit-identical to a cold one's
         warm_rng = jax.random.PRNGKey(0)
-        with TRACER.span("serve.warmup"), self._mesh_ctx(), self.profiler.mark("warmup"):
+        self._clock.attach()  # the pass's builds are this engine's, as `warmup`
+        with self._clock.span("serve.warmup", "warmup"), self._mesh_ctx():
             inactive = jnp.zeros((self.max_slots,), dtype=bool)
             warm_rng, rng = jax.random.split(warm_rng)
             self._cache, self._last, toks = self._decode_fn(
@@ -1780,11 +1791,12 @@ class ContinuousBatchingEngine:
         # start() and the first tick must not observe None
         self._refresh_stats()
         self._running = True
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="prime-engine-loop"
+        )
         self._thread.start()
 
     def shutdown(self) -> None:
-        self.profiler.close()
         self._running = False
         self._pending.put(None)  # sentinel: _pop_pending skips it
         self._wake.set()  # wake the engine thread
@@ -1868,8 +1880,9 @@ class ContinuousBatchingEngine:
                 # The wake costs nothing batched: the next tick's _admit
                 # drains the whole queued burst into one prefill wave, same
                 # as the old requeue-at-front path.
-                if self._wake.wait(timeout=0.2):
-                    self._wake.clear()
+                with self._clock.enter("no_work"):
+                    if self._wake.wait(timeout=0.2):
+                        self._wake.clear()
 
     def _requeue(self, req: EngineRequest) -> None:
         """Hand a popped request back to admission ahead of the pending
@@ -1956,8 +1969,10 @@ class ContinuousBatchingEngine:
         is the one writer, so HTTP readers always see a loop-consistent view.
         """
         self._tick_busy = True
+        self._clock.attach()  # whoever ticks is the loop's thread
         try:
-            serviced = self._service_kv_jobs()
+            with self._clock.enter("kv_jobs"):
+                serviced = self._service_kv_jobs()
             return self._tick_inner() or serviced
         finally:
             self._tick_busy = False
@@ -1990,8 +2005,9 @@ class ContinuousBatchingEngine:
             self._fail_in_flight(f"decode failed: {e}")
             self._init_device_state()
             return True
-        self._retire_cancelled()
-        admitted = self._admit()
+        with self._clock.enter("admit"):
+            self._retire_cancelled()
+            admitted = self._admit()
         if admitted:
             for chunk in self._inflight:
                 chunk.clean = False
@@ -2000,8 +2016,9 @@ class ContinuousBatchingEngine:
     def _tick_sync(self) -> bool:
         """The strictly serial loop: admit, then decode (or speculate) one
         chunk and block for its tokens before any emit/admission work."""
-        admitted = self._admit()
-        self._retire_cancelled()
+        with self._clock.enter("admit"):
+            admitted = self._admit()
+            self._retire_cancelled()
         if not any(self._active):
             return admitted
         try:
@@ -2038,31 +2055,34 @@ class ContinuousBatchingEngine:
         self._maybe_inject_delay()
         if self._decode_fn is None:
             self._decode_fn = self._make_decode()
-        self._rng, rng = jax.random.split(self._rng)
-        mask = self._active.copy()
         seq = next(self._chunk_seq)
-        args = (
-            self.params, self._adapters, self._cache, self._last,
-            self._temps, self._top_ps, jnp.asarray(mask),
-            self._adapter_slots, rng,
-        )
-        # step clock: a sampled dispatch drains the in-flight predecessor
-        # (pre=self._last syncs the pipeline), times this program to
-        # readiness, and captures its XLA cost analysis once. Inactive
-        # profiler -> shared no-op: zero added syncs on the overlap path.
-        with TRACER.span(
-            "serve.dispatch", seq=seq, steps=self.chunk, **self._span_mesh
-        ), self._mesh_ctx(), self.profiler.step(
-            "decode", pre=self._last, batch=int(mask.sum()),
-            steps=self.chunk, cost_fn=self._decode_fn, cost_args=args,
-        ) as prof_step:
-            self._cache, self._last, toks = self._decode_fn(*args)
-            prof_step.fence(toks)
+        # the phase holds all of the enqueue: the rng split and the mask's
+        # upload are dispatches of their own, and the device waits for them
+        with self._clock.span(
+            "serve.dispatch", "dispatch", seq=seq, steps=self.chunk, **self._span_mesh
+        ), self._mesh_ctx():
+            self._rng, rng = jax.random.split(self._rng)
+            mask = self._active.copy()
+            args = (
+                self.params, self._adapters, self._cache, self._last,
+                self._temps, self._top_ps, jnp.asarray(mask),
+                self._adapter_slots, rng,
+            )
+            # step clock: a sampled dispatch drains the in-flight predecessor
+            # (pre=self._last syncs the pipeline), times this program to
+            # readiness, and captures its XLA cost analysis once. Inactive
+            # profiler -> shared no-op: zero added syncs on the overlap path.
+            with self.profiler.step(
+                "decode", pre=self._last, batch=int(mask.sum()),
+                steps=self.chunk, cost_fn=self._decode_fn, cost_args=args,
+            ) as prof_step:
+                self._cache, self._last, toks = self._decode_fn(*args)
+                prof_step.fence(toks)
         self._inflight.append(
             _InflightChunk(
                 seq=seq, toks=toks, mask=mask,
                 requests=dict(self._requests),
-                dispatched_at=time.monotonic(),
+                dispatched_at=self._clock.switched_at,
             )
         )
         self._m_inflight_depth.set(len(self._inflight))
@@ -2077,12 +2097,12 @@ class ContinuousBatchingEngine:
         slot's waste is the accepted-length window it decoded for nobody."""
         chunk = self._inflight.pop(0)
         spec = chunk.run_len is not None
-        t_sync = time.monotonic()
-        with TRACER.span("serve.sync", seq=chunk.seq):
+        with self._clock.span("serve.sync", "sync_wait", seq=chunk.seq):
             toks_host = np.asarray(chunk.toks)  # blocks until the chunk lands
             runs = np.asarray(chunk.run_len) if spec else None
-        t_done = time.monotonic()
-        self._m_host_stall_s.inc(t_done - t_sync)
+        # serve_host_stall_seconds_total is the sync_wait phase, published
+        # with it in _refresh_stats; the window ends at the same switch
+        t_done = self._clock.switched_at
         self._m_chunk_window_s.inc(t_done - chunk.dispatched_at)
         if chunk.clean:
             # steady-state decode only: windows that contained an admission
@@ -2094,6 +2114,13 @@ class ContinuousBatchingEngine:
                 (t_done - chunk.dispatched_at) / (1 if spec else self.chunk)
             )
         self._m_inflight_depth.set(len(self._inflight))
+        with self._clock.enter("emit"):
+            self._emit_chunk(chunk, toks_host, runs)
+
+    def _emit_chunk(self, chunk: _InflightChunk, toks_host, runs) -> None:
+        """Route a synced chunk's tokens to their streams (the slot loop of
+        _sync_decode)."""
+        spec = runs is not None
         for slot in range(self.max_slots):
             if not chunk.mask[slot]:
                 continue
@@ -2234,14 +2261,15 @@ class ContinuousBatchingEngine:
         req.admitted_at = t_start
         self.flight.event(req.id, "admitted", slot=slot)
         row_cb = row_capacity_for(len(ids), self.prefill_chunk, self.capacity)
-        start, row = self._prefix_seed(
-            self._prefix_key(ids, req.adapter_idx), row_cb, ctx=req.trace
-        )
+        with self._clock.enter("prefix_seed"):
+            start, row = self._prefix_seed(
+                self._prefix_key(ids, req.adapter_idx), row_cb, ctx=req.trace
+            )
         plan = chunk_plan(start, len(ids), self.prefill_chunk, row_cb)
         logits = None
         self._rng, rng = jax.random.split(self._rng)
-        with TRACER.span(
-            "serve.prefill", context=req.trace, slot=slot,
+        with self._clock.span(
+            "serve.prefill", "prefill", context=req.trace, slot=slot,
             prompt_len=len(ids), request=req.id, **self._span_mesh,
         ), self._mesh_ctx(), self.profiler.step(
             "prefill", pre=self._last, batch=1, steps=len(ids),
@@ -2285,11 +2313,12 @@ class ContinuousBatchingEngine:
             # first spec chunk can draft from the prompt immediately
             with self._mesh_ctx():
                 self._seed_hist([req], [len(ids)], [slot], firsts)
-        first = int(firsts[0])  # host sync: the prefill really finished here
-        self._m_prefill_s.observe(time.monotonic() - t_start)
+        with self._clock.enter("prefill_sync"):
+            first = int(firsts[0])  # host sync: the prefill really finished here
+        prefill_s = self._clock.switched_at - t_start
+        self._m_prefill_s.observe(prefill_s)
         self.flight.event(
-            req.id, "prefill_done",
-            ms=round((time.monotonic() - t_start) * 1e3, 3),
+            req.id, "prefill_done", ms=round(prefill_s * 1e3, 3),
             prefix_hit_tokens=start,
         )
         self._m_admit_batch.observe(1)
@@ -2298,7 +2327,8 @@ class ContinuousBatchingEngine:
         req.slot = slot
         self._active[slot] = True
         self._requests[slot] = req
-        self._emit(req, [first])
+        with self._clock.enter("emit"):
+            self._emit(req, [first])
 
     def _prefill_batch(
         self,
@@ -2341,8 +2371,9 @@ class ContinuousBatchingEngine:
         self._rng, rng = jax.random.split(self._rng)
         row = init_cache(self.config, n, row_cb, dtype=self._dtype, quantized=self.kv_quant)
         logits = None
-        with TRACER.span(
-            "serve.prefill_batch", batch=n, row_capacity=row_cb, **self._span_mesh
+        with self._clock.span(
+            "serve.prefill_batch", "prefill", batch=n, row_capacity=row_cb,
+            **self._span_mesh,
         ), self._mesh_ctx(), self.profiler.step(
             "prefill", pre=self._last, batch=n,
             steps=max(len(r.prompt_ids) for r in reqs),
@@ -2396,8 +2427,9 @@ class ContinuousBatchingEngine:
             self._store_prefix(
                 self._prefix_key(reqs[i].prompt_ids, reqs[i].adapter_idx), row_i
             )
-        firsts_host = [int(t) for t in np.asarray(firsts)]  # host sync
-        prefill_s = time.monotonic() - t_start
+        with self._clock.enter("prefill_sync"):
+            firsts_host = [int(t) for t in np.asarray(firsts)]  # host sync
+        prefill_s = self._clock.switched_at - t_start
         prefill_ms = round(prefill_s * 1e3, 3)
         self._m_prefill_s.observe(prefill_s)
         for req in reqs:
@@ -2414,11 +2446,12 @@ class ContinuousBatchingEngine:
         self._m_admitted.inc(len(reqs))
         if n > 1:
             self._m_batched_waves.inc()
-        for req, slot, first in zip(reqs, slots, firsts_host):
-            req.slot = slot
-            self._active[slot] = True
-            self._requests[slot] = req
-            self._emit(req, [first])
+        with self._clock.enter("emit"):
+            for req, slot, first in zip(reqs, slots, firsts_host):
+                req.slot = slot
+                self._active[slot] = True
+                self._requests[slot] = req
+                self._emit(req, [first])
 
     def _make_finalize_batch(self):
         import jax
@@ -2573,18 +2606,18 @@ class ContinuousBatchingEngine:
         # over-long runs) takes the contiguous assemble as before.
         table = self._paged_seed_table(match, row_cb)
         path = "paged" if table is not None else "copy"
-        t_seed = time.monotonic()
         try:
             # tier annotates the span so trace evidence distinguishes a pure
             # HBM hit from one that paid a host->device re-upload first
-            with TRACER.span(
-                "serve.assemble", context=ctx, hit_tokens=match.length,
-                segments=len(match.entries), row_capacity=row_cb,
-                tier="host" if host_tokens else "device",
+            with self._clock.span(
+                "serve.assemble", "prefix_seed", context=ctx,
+                hit_tokens=match.length, segments=len(match.entries),
+                row_capacity=row_cb, tier="host" if host_tokens else "device",
                 host_tokens=host_tokens, path=path,
             ), self.profiler.step(
                 "assemble", pre=self._last, batch=1, steps=match.length
             ) as prof_step:
+                t_seed = self._clock.switched_at  # the span's start
                 if table is not None:
                     row = self._paged_seed_row(table, row_cb)
                 else:
@@ -2603,7 +2636,8 @@ class ContinuousBatchingEngine:
                 prof_step.fence(row.k)
         finally:
             self.prefix_cache.release(match)
-        self._m_prefix_seed_s.observe(time.monotonic() - t_seed, path=path)
+        # the span's end: the seeding dispatch, as before
+        self._m_prefix_seed_s.observe(self._clock.switched_at - t_seed, path=path)
         self._m_prefix_hits.inc()
         if table is not None:
             self._m_prefix_paged_seeds.inc()
@@ -2728,7 +2762,8 @@ class ContinuousBatchingEngine:
         spills_before = cache.spills
         spilled_bytes_before = cache.spilled_bytes
         spill_s_before = cache.spill_seconds
-        cache.insert(list(ids[:aligned]), self._row_slicer(row))
+        with self._clock.enter("prefix_store"):
+            cache.insert(list(ids[:aligned]), self._row_slicer(row))
         if cache.spills > spills_before:
             # spills force a device sync (device_get) on the store path —
             # leave trace evidence so the profiler's tier table can price
@@ -2924,28 +2959,34 @@ class ContinuousBatchingEngine:
         self._maybe_inject_delay()
         if self._decode_fn is None:
             self._decode_fn = self._make_decode()
-        self._rng, rng = jax.random.split(self._rng)
-        active = jnp.asarray(self._active)
-        t_start = time.monotonic()
-        args = (
-            self.params, self._adapters, self._cache, self._last,
-            self._temps, self._top_ps, active, self._adapter_slots, rng,
-        )
-        with TRACER.span(
-            "serve.decode_chunk", steps=self.chunk, **self._span_mesh
-        ), self._mesh_ctx(), self.profiler.step(
-            "decode", batch=int(np.sum(self._active)), steps=self.chunk,
-            cost_fn=self._decode_fn, cost_args=args,
-        ) as prof_step:
-            self._cache, self._last, toks = self._decode_fn(*args)
-            prof_step.fence(toks)
-            toks_host = np.asarray(toks)  # (S, T) — host sync inside the span
-        self._m_decode_step_s.observe((time.monotonic() - t_start) / self.chunk)
-        for slot in range(self.max_slots):
-            if self._active[slot]:
-                req = self._requests[slot]
-                self.flight.event(req.id, "chunk")
-                self._emit(req, toks_host[slot].tolist())
+        with self._clock.span(
+            "serve.decode_chunk", "dispatch", steps=self.chunk, **self._span_mesh
+        ), self._mesh_ctx():
+            t_start = self._clock.switched_at
+            self._rng, rng = jax.random.split(self._rng)
+            args = (
+                self.params, self._adapters, self._cache, self._last,
+                self._temps, self._top_ps, jnp.asarray(self._active),
+                self._adapter_slots, rng,
+            )
+            with self.profiler.step(
+                "decode", batch=int(np.sum(self._active)), steps=self.chunk,
+                cost_fn=self._decode_fn, cost_args=args,
+            ) as prof_step:
+                self._cache, self._last, toks = self._decode_fn(*args)
+                prof_step.fence(toks)
+                with self._clock.enter("sync_wait"):
+                    toks_host = np.asarray(toks)  # (S, T) — host sync inside the span
+        # the serial loop blocks for all of its window: stall == window
+        window_s = self._clock.switched_at - t_start
+        self._m_chunk_window_s.inc(window_s)
+        self._m_decode_step_s.observe(window_s / self.chunk)
+        with self._clock.enter("emit"):
+            for slot in range(self.max_slots):
+                if self._active[slot]:
+                    req = self._requests[slot]
+                    self.flight.event(req.id, "chunk")
+                    self._emit(req, toks_host[slot].tolist())
 
     def _emit(self, req: EngineRequest, token_ids: list[int]) -> None:
         """Feed decoded ids to the request, honoring EOS and max_new_tokens;
@@ -3023,6 +3064,15 @@ class ContinuousBatchingEngine:
         """Compute the full stats dict from live state and publish it as the
         snapshot stats() serves to other threads. Called at the end of every
         tick() by the engine loop (and directly by synchronous owners)."""
+        with self._clock.enter("stats"):
+            return self._publish_stats()
+
+    def _publish_stats(self) -> dict:
+        # the loop's seconds since the last tick, by phase; the host stall is
+        # the sync_wait phase by another name (one switch feeds both)
+        stalled = self._clock.flush().get("sync_wait")
+        if stalled:
+            self._m_host_stall_s.inc(stalled)
         self._m_active_slots.set(int(self._active.sum()))
         self._m_queue_depth.set(self.queue_depth())
         # HBM/live-buffer gauges: rate-limited inside, no-op when the
@@ -3090,6 +3140,7 @@ class ContinuousBatchingEngine:
             "prefix_paged_seeds": int(values["serve_prefix_paged_seeds_total"]),
             "kv_exports": int(values["serve_kv_exports_total"]),
             "kv_imports": int(values["serve_kv_imports_total"]),
+            "program_builds": self._clock.builds(),
             "uptime_s": round(time.monotonic() - self._t0, 3),
         }
         with self._stats_lock:

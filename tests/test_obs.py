@@ -432,8 +432,8 @@ def test_engine_histograms_populate_through_streamed_completion():
             # the fleet admission/drain fields (PR 4), the host spill
             # tier fields (PR 6), the sharded-replica mesh fields, the
             # speculative-decoding fields, the disaggregated-serving KV
-            # export/import counters, and the paged-seeding counter —
-            # additive only
+            # export/import counters, the paged-seeding counter, and the
+            # phase clock's list of the last program builds — additive only
             engine_stats = httpx.get(f"{srv.url}/metrics").json()["engine"]
             assert set(engine_stats) == {
                 "requests_admitted", "requests_completed", "requests_cancelled",
@@ -448,7 +448,7 @@ def test_engine_histograms_populate_through_streamed_completion():
                 "prefix_host_tier_disabled",
                 "prefix_cache_nodes", "prefix_evictions", "prefix_spills",
                 "prefix_reuploads", "prefix_assembles", "prefix_paged_seeds",
-                "kv_exports", "kv_imports", "uptime_s",
+                "kv_exports", "kv_imports", "program_builds", "uptime_s",
             }
             assert engine_stats["requests_admitted"] == 1
             assert engine_stats["requests_completed"] == 1

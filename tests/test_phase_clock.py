@@ -1,0 +1,310 @@
+"""The engine loop's phase clock (prime_tpu/obs/phase.py), on the CPU with the
+tiny model: the phases are exclusive and exhaustive, program builds are
+accounted through jax.monitoring by one listener set a process, the same
+sites land in a jax.profiler capture as annotations on the loop thread's
+line, and the names the benchmark finds programs by are the engine's own.
+"""
+
+import json
+import re
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from prime_tpu.models import get_config
+from prime_tpu.models.llama import init_params
+from prime_tpu.obs import phase as phase_mod
+from prime_tpu.obs.metrics import snapshot_captured_at
+from prime_tpu.serve.engine import ContinuousBatchingEngine
+
+CONFIG = get_config("tiny-test")
+PARAMS = init_params(jax.random.PRNGKey(0), CONFIG, dtype=jnp.float32)
+REPO = Path(__file__).resolve().parents[1]
+PROMPT = [5, 9, 301, 42] * 5
+
+
+@pytest.fixture(autouse=True)
+def _default_env(monkeypatch):
+    for knob in (
+        "PRIME_SERVE_OVERLAP", "PRIME_SERVE_WARMUP", "PRIME_SERVE_MESH",
+        "PRIME_SERVE_SPEC", "PRIME_SERVE_PROFILE", "PRIME_TRACE",
+    ):
+        monkeypatch.delenv(knob, raising=False)
+
+
+def make_engine(**kw) -> ContinuousBatchingEngine:
+    kw.setdefault("max_slots", 2)
+    kw.setdefault("capacity", 64)
+    kw.setdefault("chunk", 4)
+    kw.setdefault("prefix_cache_mb", 8)
+    return ContinuousBatchingEngine(PARAMS, CONFIG, **kw)
+
+
+def loop_seconds(snapshot: dict) -> dict[str, float]:
+    family = snapshot.get("serve_loop_seconds_total", {})
+    return {s["labels"]["phase"]: s["value"] for s in family.get("series", [])}
+
+
+def snapshot_after_flush(engine, timeout: float = 5.0) -> dict:
+    """A registry snapshot taken right after the loop published its seconds
+    (once a tick; every 0.2 s when idle), so the counter lags by microseconds
+    and not by a tick."""
+    total = engine.registry.get("serve_loop_seconds_total").total
+
+    before, deadline = total(), time.monotonic() + timeout
+    while total() == before and time.monotonic() < deadline:
+        time.sleep(0.0005)
+    return engine.registry.snapshot()
+
+
+def delta(before: dict, after: dict) -> dict[str, float]:
+    a, b = loop_seconds(before), loop_seconds(after)
+    return {phase: b[phase] - a.get(phase, 0.0) for phase in b}
+
+
+def test_phases_are_exclusive_and_exhaustive_over_a_burst_and_an_idle_stretch():
+    engine = make_engine()
+    engine.start()
+    try:
+        s0 = snapshot_after_flush(engine)
+        # a burst that keeps the queue full for a while: 2 slots, 10 requests
+        reqs = [engine.submit(PROMPT, max_new_tokens=40) for _ in range(10)]
+        next(iter(reqs[0].tokens(timeout=120)))  # the first request is live
+        s1 = snapshot_after_flush(engine)
+        still_queued_at_s2 = False
+        s2 = s1
+        while engine.queue_depth() > 0:
+            s2 = snapshot_after_flush(engine)
+            still_queued_at_s2 = engine.queue_depth() > 0
+            if still_queued_at_s2:
+                break
+        for req in reqs:
+            req.all_tokens(timeout=120)
+        s3 = snapshot_after_flush(engine)
+        time.sleep(0.7)  # the idle stretch
+        s4 = snapshot_after_flush(engine)
+    finally:
+        engine.shutdown()
+
+    snaps = [s0, s1, s2, s3, s4]
+    # no phase ever goes down
+    for before, after in zip(snaps, snaps[1:]):
+        assert all(v >= 0.0 for v in delta(before, after).values())
+    # exhaustive: the phases sum to the wall time between the snapshots
+    for before, after in ((s0, s3), (s3, s4), (s0, s4)):
+        wall = snapshot_captured_at(after) - snapshot_captured_at(before)
+        assert sum(delta(before, after).values()) == pytest.approx(wall, rel=0.02)
+    # no_work grows only while nothing is queued or live
+    if still_queued_at_s2:
+        assert delta(s1, s2).get("no_work", 0.0) == 0.0
+    idle = delta(s3, s4)
+    idle_wall = snapshot_captured_at(s4) - snapshot_captured_at(s3)
+    assert idle["no_work"] == pytest.approx(idle_wall, rel=0.05)
+    # with work the loop blocks on the device in both of its sync phases
+    busy = delta(s0, s3)
+    assert busy["sync_wait"] > 0 and busy["prefill_sync"] > 0
+    assert busy["dispatch"] > 0 and busy["prefill"] > 0 and busy["emit"] > 0
+    assert set(busy) <= set(phase_mod.PHASES)
+    # the host stall is the sync_wait phase by another name
+    stall = engine.registry.get("serve_host_stall_seconds_total").value()
+    assert stall == pytest.approx(loop_seconds(s4)["sync_wait"], rel=1e-6)
+
+
+def test_first_use_of_a_shape_charges_program_build_once_listened():
+    """A program built on the loop thread moves its seconds into
+    program_build and counts under the phase that triggered it; two engines
+    in one process share one set of jax.monitoring listeners."""
+    from jax._src import monitoring  # the registry of listeners, to count ours
+
+    def ours() -> int:
+        return sum(cb is phase_mod._on_duration
+                   for cb in monitoring.get_event_duration_listeners())
+
+    first, second = make_engine(), make_engine()
+    assert ours() == 1
+    assert sum(cb is phase_mod._on_event for cb in monitoring.get_event_listeners()) == 1
+
+    req = first.submit(PROMPT, max_new_tokens=6)
+    for _ in range(200):
+        first.tick()
+        if req.done:
+            break
+    assert req.done
+    compiles = first.registry.get("serve_compiles_total")
+    assert compiles.value(phase="prefill") >= 2  # chunk prefill, finalize
+    assert compiles.value(phase="dispatch") >= 1  # decode
+    built = loop_seconds(first.registry.snapshot())["program_build"]
+    totals = first._clock.build_totals()
+    assert totals["total"] >= 3 and totals["loads"] == 0
+    # trace and lowering seconds ride along, so the phase holds more than
+    # the backend compiles alone
+    assert built >= totals["seconds"] > 0
+    names = [b["name"] for b in first.stats()["program_builds"]]
+    assert any("decode" in n for n in names) and any("chunk_prefill" in n for n in names)
+    assert all(b["kind"] == "compile" for b in first.stats()["program_builds"])
+    # the other engine's thread of accounting saw none of it
+    assert second._clock.build_totals()["total"] == 0
+
+    # a new prompt length is a new chunk-prefill shape: built again, charged again
+    before = compiles.value(phase="prefill")
+    req = first.submit(PROMPT + PROMPT + [7], max_new_tokens=2)
+    for _ in range(200):
+        first.tick()
+        if req.done:
+            break
+    assert compiles.value(phase="prefill") > before
+    assert loop_seconds(first.registry.snapshot())["program_build"] > built
+
+
+def test_two_engines_ticked_in_turn_on_one_thread_keep_their_builds_apart():
+    """Synchronous owners tick several engines on one thread (disaggregated
+    prefill and decode): a build is charged to the engine whose tick
+    triggered it, whichever attached last, and to nobody twice."""
+    first, second = make_engine(), make_engine(chunk=2)  # another decode program
+    reqs = [first.submit(PROMPT, max_new_tokens=6), second.submit(PROMPT, max_new_tokens=6)]
+    t0 = time.monotonic()
+    for _ in range(200):
+        first.tick()
+        second.tick()
+        if all(r.done for r in reqs):
+            break
+    wall = time.monotonic() - t0
+    assert all(r.done for r in reqs)
+    for engine in (first, second):
+        totals = engine._clock.build_totals()
+        assert totals["total"] >= 3, totals  # chunk prefill, finalize, decode: its own
+        names = {b["name"] for b in engine.stats()["program_builds"]}
+        assert any("decode" in n for n in names)
+        # an engine's phases never hold more than the thread's wall time
+        assert sum(loop_seconds(engine.registry.snapshot()).values()) <= wall * 1.001
+    assert second._clock.build_totals()["total"] + first._clock.build_totals()["total"] == sum(
+        e.registry.get("serve_compiles_total").total() for e in (first, second))
+
+
+def test_a_build_nested_in_an_outer_trace_is_charged_once():
+    """An eager op inside a function being traced compiles while the outer
+    trace is timed: the outer duration holds it, so only that is carved."""
+    engine = make_engine()
+    clock = engine._clock
+    clock.attach()
+    clock.flush()
+
+    @jax.jit
+    def outer(x):
+        with jax.ensure_compile_time_eval():  # eager: built inside the outer trace
+            table = jnp.cumsum(jnp.arange(7919) % 13)
+        return x + table[:3]
+
+    t0 = time.monotonic()
+    with clock.enter("dispatch"):
+        outer(jnp.ones(3)).block_until_ready()
+    wall = time.monotonic() - t0
+    flushed = clock.flush()
+    assert flushed["program_build"] > 0
+    assert flushed["program_build"] <= wall
+    assert sum(flushed.values()) == pytest.approx(wall, rel=0.05, abs=2e-3)
+
+
+def test_capture_holds_the_phases_on_the_loop_threads_line(tmp_path):
+    """Under jax.profiler the sites' annotations land on ONE line of plane
+    /host:CPU, readable by the benchmark's own reader, with their attributes
+    as stats, and their durations agree with the phase counters."""
+    from jax.profiler import ProfileData
+
+    from benchmark.harness import xplane
+
+    engine = make_engine()  # with the prefix cache: a repeated prompt hits
+    engine.start()
+    try:
+        # build every program first: a build's seconds leave the phase's
+        # counter for program_build but stay inside the annotation
+        for _ in range(3):
+            for req in [engine.submit(PROMPT, max_new_tokens=12) for _ in range(3)]:
+                req.all_tokens(timeout=120)
+        before = snapshot_after_flush(engine)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            reqs = [engine.submit(PROMPT, max_new_tokens=12) for _ in range(3)]
+            for req in reqs:
+                req.all_tokens(timeout=120)
+            time.sleep(0.3)
+        finally:
+            jax.profiler.stop_trace()
+        after = snapshot_after_flush(engine)
+    finally:
+        engine.shutdown()
+
+    events = xplane.read_events(tmp_path)
+    ours = [e for e in events if e["name"].startswith("serve.")]
+    assert {e["plane"] for e in ours} == {"/host:CPU"}
+    names = {e["name"] for e in ours}
+    assert {"serve.dispatch", "serve.sync", "serve.prefill", "serve.assemble",
+            "serve.no_work", "serve.emit", "serve.admit", "serve.prefill_sync",
+            "serve.prefix_seed", "serve.prefix_store"} <= names
+
+    # one line holds them all (the loop thread's), and seq is a stat
+    lines = set()
+    seqs = []
+    for path in tmp_path.rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(path)).planes:
+            for index, line in enumerate(plane.lines):
+                for event in line.events:
+                    if event.name.startswith("serve."):
+                        lines.add((plane.name, index))
+                    if event.name == "serve.dispatch":
+                        seqs.append(dict(event.stats)["seq"])
+    assert len(lines) == 1
+    assert len(seqs) >= 3 and sorted(seqs) == list(range(min(seqs), max(seqs) + 1))
+
+    moved = delta(before, after)
+    assert moved.get("program_build", 0.0) == 0.0
+    for phase, annotations in (("dispatch", ("serve.dispatch",)), ("sync_wait", ("serve.sync",)),
+                               ("prefill", ("serve.prefill", "serve.prefill_batch")),
+                               ("prefix_seed", ("serve.prefix_seed",))):
+        seconds = sum(e["dur_ns"] for e in ours if e["name"] in annotations) / 1e9
+        assert seconds == pytest.approx(moved[phase], rel=0.10), phase
+
+
+def test_serial_loop_keeps_stall_equal_to_window():
+    """PRIME_SERVE_OVERLAP=0: the loop blocks for all of its window, which the
+    two hand counters said as stall == window (overlap ratio 0)."""
+    engine = make_engine(overlap=False, prefix_cache_mb=0)
+    req = engine.submit(PROMPT, max_new_tokens=8)
+    for _ in range(200):
+        engine.tick()
+        if req.done:
+            break
+    stats = engine.stats()
+    assert 0 < stats["host_stall_s"] <= stats["chunk_window_s"]
+    phases = loop_seconds(engine.registry.snapshot())
+    assert phases["sync_wait"] == pytest.approx(stats["host_stall_s"], abs=1e-5)
+
+
+def test_programs_json_names_are_the_engines_own():
+    """benchmark/layer_metrics/programs.json finds programs in a trace by the
+    names jit gives them: the __name__ of each function the engine jits. A
+    rename has to fail here, not on the chip."""
+    layout = json.loads((REPO / "benchmark/layer_metrics/programs.json").read_text())
+    from prime_tpu.serve import kv_pool
+
+    engine = make_engine()
+    built = {
+        "decode": [engine._make_decode()],
+        "chunk_prefill": [engine._make_chunk_prefill()],
+        "finalize": [engine._make_finalize_batch()],
+        "assemble": [engine._make_assemble_row(), kv_pool.gather_pages],
+        "store": [kv_pool._store_pages],
+    }
+    trace_name = {"tpu": "jit_{}(", "cpu": "PjitFunction({})"}
+    for platform, template in trace_name.items():
+        programs = layout[platform]["programs"]
+        assert set(programs) == set(built)
+        for role, fns in built.items():
+            for fn in fns:
+                name = template.format(fn.__wrapped__.__name__)
+                assert re.search(programs[role], name), (platform, role, name)
+                others = [r for r in programs if r != role and re.search(programs[r], name)]
+                assert not others, (platform, name, others)
