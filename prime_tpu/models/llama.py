@@ -6,6 +6,12 @@ Design (TPU-first, not a port):
   depth, fast compiles, and clean (L, ...) sharding.
 - One forward serves three regimes via static shape/flags: training (no
   cache), prefill (writes the cache), decode (S=1 against the cache).
+- The stacked KV cache is the layer scan's CARRY, never its xs/ys: a block
+  writes its new K/V into the stacked buffer in place at (layer, row, 0, 0,
+  position) and attention reads its layer out of that buffer (the decode
+  kernel through its index maps). A scan cannot alias xs with ys, so a
+  cache scanned per layer costs a slice, a second buffer and a copy back:
+  three passes over the whole cache every decode step.
 - All matmuls in bf16 on the MXU with fp32 softmax/norm accumulation; the
   causal prefill path dispatches to the pallas flash kernel on TPU
   (prime_tpu.ops.pallas_attention).
@@ -28,6 +34,7 @@ from prime_tpu.ops.attention import (
     cache_prefill_attention,
     decode_attention,
     multi_head_attention,
+    write_cache,
 )
 from prime_tpu.ops.norms import rms_norm
 from prime_tpu.ops.rope import apply_rope_rows, rope_frequencies
@@ -343,19 +350,23 @@ def sliding_layer_flags(config: ModelConfig) -> jnp.ndarray:
     )
 
 
+def _scales(kv: tuple[jnp.ndarray, ...]) -> dict[str, jnp.ndarray]:
+    """The int8 cache's scale leaves as the attention ops' keywords."""
+    return dict(k_scale=kv[2], v_scale=kv[3]) if len(kv) == 4 else {}
+
+
 def _attention_block(
     x: jnp.ndarray,               # (B, S, D)
     lp: Params,                   # one layer's params
     positions: jnp.ndarray,       # (B, S)
     rope_tables: tuple[jnp.ndarray, jnp.ndarray],
     config: ModelConfig,
-    k_cache: jnp.ndarray | None,  # (B, KH, hd, C) this layer (int8 when quantized)
-    v_cache: jnp.ndarray | None,
+    kv: tuple[jnp.ndarray, ...],  # the STACKED cache leaves (L, B, KH, *, C): (k, v),
+    #                               (k, v, k_scale, v_scale) when quantized, () without a cache
+    layer: jnp.ndarray | None,    # () traced: this block's layer in the stacked leaves
     cache_lengths: jnp.ndarray | None,
     decode: bool,
     attn_impl: str,
-    k_scale: jnp.ndarray | None = None,  # (B, KH, 1, C) when quantized
-    v_scale: jnp.ndarray | None = None,
     prefill_offset: jnp.ndarray | None = None,  # () chunked prefill: write+attend at offset
     sliding: jnp.ndarray | None = None,  # () traced bool: this layer uses the window
     rope_tables_local: tuple[jnp.ndarray, jnp.ndarray] | None = None,
@@ -363,6 +374,8 @@ def _attention_block(
     #             "sharded" (serve decode: flash kernel under shard_map)
     adapter_ids: jnp.ndarray | None = None,  # (B,) multi-LoRA bank slots
 ):
+    """One layer's attention. Returns (x + attention, kv): the stacked leaves
+    with this layer's new K/V written in place (ops.attention.write_cache)."""
     batch, seq, _ = x.shape
     h, kh, hd = config.n_heads, config.n_kv_heads, config.head_dim
     sm_scale = (config.query_scale or hd) ** -0.5
@@ -402,74 +415,36 @@ def _attention_block(
     k = k.transpose(0, 2, 1, 3)
     v = v.transpose(0, 2, 1, 3)
 
-    quantized = k_scale is not None
-    new_k_cache, new_v_cache = k_cache, v_cache
-    new_k_scale, new_v_scale = k_scale, v_scale
+    def staged(k, v):
+        """This call's K/V (B, KH, S, hd) as the cache stores them:
+        feature-major blocks, plus their per-slot scales when the cache is
+        int8. The scales are PER-SLOT and every call writes its own slots, so
+        each column is quantized once, whichever regime wrote it."""
+        k_t, v_t = k.transpose(0, 1, 3, 2), v.transpose(0, 1, 3, 2)  # (B, KH, hd, S)
+        if len(kv) == 2:
+            return k_t, v_t
+        (k_q, k_s), (v_q, v_s) = quantize_kv(k_t), quantize_kv(v_t)
+        return k_q, v_q, k_s, v_s
+
     if decode:
-        assert k_cache is not None and cache_lengths is not None
-        # scatter this step's k/v column into each sequence's next free slot
-        def put(cache, col):  # cache (B, KH, *, C), col (B, KH, *, 1)
-            def one(c, n, idx):
-                return jax.lax.dynamic_update_slice(c, n, (0, 0, idx))
-
-            return jax.vmap(one)(cache, col, cache_lengths)
-
-        k_col = k.transpose(0, 1, 3, 2)  # (B, KH, hd, 1)
-        v_col = v.transpose(0, 1, 3, 2)
-        if quantized:
-            k_q, k_s = quantize_kv(k_col)
-            v_q, v_s = quantize_kv(v_col)
-            new_k_cache, new_k_scale = put(k_cache, k_q), put(k_scale, k_s)
-            new_v_cache, new_v_scale = put(v_cache, v_q), put(v_scale, v_s)
-        else:
-            new_k_cache = put(k_cache, k_col)
-            new_v_cache = put(v_cache, v_col)
+        assert kv and cache_lengths is not None
+        # this step's k/v column goes into each sequence's next free slot
+        kv = write_cache(kv, staged(k, v), layer, cache_lengths, impl=attn_impl)
         attn = decode_attention(
-            q, new_k_cache, new_v_cache, cache_lengths + 1, sm_scale, impl=attn_impl,
-            k_scale=new_k_scale, v_scale=new_v_scale, mesh=mesh, **gemma_kw,
+            q, kv[0], kv[1], cache_lengths + 1, sm_scale, impl=attn_impl,
+            mesh=mesh, layer=layer, **_scales(kv), **gemma_kw,
         )
     elif prefill_offset is not None:
         # chunked prefill: write this chunk's K/V into the cache at the
-        # offset, then attend over the cache (earlier chunks + reused prefix
-        # are visible; within-chunk attention stays causal via the mask).
-        # int8 caches are exact here too: scales are PER-SLOT and chunks
-        # write disjoint slots, so each chunk quantizes its own columns once.
-        assert k_cache is not None
-        off = prefill_offset.astype(jnp.int32)
-        k_t = k.transpose(0, 1, 3, 2)  # (B, KH, hd, S)
-        v_t = v.transpose(0, 1, 3, 2)
-        k_block_scale = v_block_scale = None
-        if quantized:
-            k_t, k_block_scale = quantize_kv(k_t)  # int8 + (B, KH, 1, S) scales
-            v_t, v_block_scale = quantize_kv(v_t)
-        if off.ndim == 0:  # one shared chunk offset
-            zero = jnp.zeros((), dtype=jnp.int32)
-
-            def put_shared(cache, block):
-                return jax.lax.dynamic_update_slice(cache, block, (zero, zero, zero, off))
-
-            new_k_cache = put_shared(k_cache, k_t)
-            new_v_cache = put_shared(v_cache, v_t)
-            if quantized:
-                new_k_scale = put_shared(k_scale, k_block_scale)
-                new_v_scale = put_shared(v_scale, v_block_scale)
-        else:  # (B,): per-row window starts (speculative verify)
-            def put_rows(cache, block):
-                def one(c, n, idx):
-                    return jax.lax.dynamic_update_slice(c, n, (0, 0, idx))
-
-                return jax.vmap(one)(cache, block, off)
-
-            new_k_cache = put_rows(k_cache, k_t)
-            new_v_cache = put_rows(v_cache, v_t)
-            if quantized:
-                new_k_scale = put_rows(k_scale, k_block_scale)
-                new_v_scale = put_rows(v_scale, v_block_scale)
+        # offset — () one shared chunk offset, (B,) per-row window starts
+        # (speculative verify) — then attend over the cache (earlier chunks
+        # + reused prefix are visible; within-chunk attention stays causal
+        # via the mask)
+        assert kv
+        kv = write_cache(kv, staged(k, v), layer, prefill_offset)
         attn = cache_prefill_attention(
-            q, new_k_cache, new_v_cache, off, sm_scale,
-            k_scale=new_k_scale if quantized else None,
-            v_scale=new_v_scale if quantized else None,
-            **gemma_kw,
+            q, kv[0], kv[1], prefill_offset.astype(jnp.int32), sm_scale,
+            layer=layer, **_scales(kv), **gemma_kw,
         )
     elif attn_impl == "ring":
         # context-parallel training: the sequence axis is sharded over the
@@ -490,20 +465,9 @@ def _attention_block(
         )
     else:
         attn = multi_head_attention(q, k, v, sm_scale, impl=attn_impl, **gemma_kw)
-        if k_cache is not None:
+        if kv:
             # prefill: stage the prompt's k/v feature-major at slots [0, S)
-            k_t = k.transpose(0, 1, 3, 2)  # (B, KH, hd, S)
-            v_t = v.transpose(0, 1, 3, 2)
-            if quantized:
-                k_q, k_s = quantize_kv(k_t)
-                v_q, v_s = quantize_kv(v_t)
-                new_k_cache = jax.lax.dynamic_update_slice(k_cache, k_q, (0, 0, 0, 0))
-                new_v_cache = jax.lax.dynamic_update_slice(v_cache, v_q, (0, 0, 0, 0))
-                new_k_scale = jax.lax.dynamic_update_slice(k_scale, k_s, (0, 0, 0, 0))
-                new_v_scale = jax.lax.dynamic_update_slice(v_scale, v_s, (0, 0, 0, 0))
-            else:
-                new_k_cache = jax.lax.dynamic_update_slice(k_cache, k_t, (0, 0, 0, 0))
-                new_v_cache = jax.lax.dynamic_update_slice(v_cache, v_t, (0, 0, 0, 0))
+            kv = write_cache(kv, staged(k, v), layer, jnp.zeros((), jnp.int32))
 
     attn = attn.transpose(0, 2, 1, 3).reshape(batch, seq, h * hd)
     out = _lora_mm(attn, lp, "wo", adapter_ids)
@@ -511,7 +475,7 @@ def _attention_block(
         out = out + lp["bo"]
     if "attn_post_norm" in lp:  # Gemma2-style post-norm before the residual add
         out = _norm(out, lp["attn_post_norm"], config)
-    return x + out, new_k_cache, new_v_cache, new_k_scale, new_v_scale
+    return x + out, kv
 
 
 def _mlp_block(
@@ -602,6 +566,17 @@ def forward(
                        long prompt (or a suffix after a reused prefix) feeds
                        in S-token chunks with O(S·C) attention memory
     - decode step:     cache=<filled>, decode=True, S must be 1
+
+    In every cached regime the returned cache IS the argument's buffers,
+    updated in place: the leaves ride one layer scan as its carry (both
+    ``first_k_dense`` stacks scan the same buffers, at layer offsets 0 and
+    kd). A caller that donates the cache, or carries it through its own scan
+    of decode steps, gets one buffer end to end and no cache-sized
+    temporary. The writes are ``ops.attention.write_cache``'s: per-row
+    ``dynamic_update_slice``, or for decode beside the flash-decode kernel
+    one aliased ``write_columns`` kernel. A scatter over the batch axis must
+    not replace them (XLA copies the whole cache inside the layer loop for
+    it).
     """
     batch, seq = tokens.shape
     if config.mla:
@@ -670,143 +645,83 @@ def forward(
     if config.scale_embed:  # Gemma normalizes hidden states by sqrt(d_model)
         x = x * jnp.asarray(config.d_model**0.5, dtype=x.dtype)
 
-    layer_params = params["layers"]
     cache_lengths = cache.lengths if cache is not None else None
-    aux0 = jnp.zeros((), jnp.float32)
     # Per-layer sliding flag rides the scan so one compiled body serves both
     # kinds. The pattern is an explicit config field (ModelConfig.sliding_pattern)
     # so non-Gemma2 window schemes can't silently inherit the even alternation.
     sliding_flags = sliding_layer_flags(config)
 
-    quantized = cache is not None and cache.quantized
-
     def layer_fn(carry, scanned):
-        x, aux_sum = carry
-        if quantized:
-            lp, sliding, k_c, v_c, k_s, v_s = scanned
-        else:
-            lp, sliding, k_c, v_c = scanned
-            k_s = v_s = None
+        x, aux_sum, kv = carry
+        layer, lp, sliding = scanned
         if config.mla:
             from prime_tpu.models.mla import mla_attention_block
 
-            x, new_k, new_v, new_ks, new_vs = mla_attention_block(
+            x, kv = mla_attention_block(
                 x, lp, positions, rope_tables, config,
-                k_c, v_c, cache_lengths, decode, attn_impl,
+                kv, layer, cache_lengths, decode, attn_impl,
                 prefill_offset=prefill_offset,
             )
         else:
-            x, new_k, new_v, new_ks, new_vs = _attention_block(
+            x, kv = _attention_block(
                 x, lp, positions, rope_tables, config,
-                k_c, v_c, cache_lengths, decode, attn_impl,
-                k_scale=k_s, v_scale=v_s, prefill_offset=prefill_offset,
+                kv, layer, cache_lengths, decode, attn_impl,
+                prefill_offset=prefill_offset,
                 sliding=sliding, rope_tables_local=rope_tables_local,
                 mesh=mesh, adapter_ids=adapter_ids,
             )
         x, aux = _mlp_block(x, lp, config, adapter_ids=adapter_ids)
-        ys = (new_k, new_v, new_ks, new_vs) if quantized else (new_k, new_v)
-        return (x, aux_sum + aux), ys
+        return (x, aux_sum + aux, kv), None
+
+    if remat not in ("none", "full", "dots"):
+        raise ValueError(f"Unknown remat {remat!r} (want 'none' | 'full' | 'dots')")
+    if remat != "none" and cache is None:
+        # WITHOUT this, reverse-mode AD through the scan saves every
+        # layer's residuals (activation memory = n_layers × per-layer);
+        # checkpointing recomputes them in the backward pass. "dots"
+        # keeps matmul outputs (cheap HBM, expensive to recompute on the
+        # MXU) and drops the elementwise rest — the usual TPU trade.
+        policy = (
+            None
+            if remat == "full"
+            else jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        )
+        layer_fn = jax.checkpoint(layer_fn, policy=policy, prevent_cse=False)
 
     # DeepSeek first_k_dense: the dense-prefix stack scans first, then the
     # MoE stack — same layer_fn (the MLP branch keys off each stack's own
-    # params), cache arrays split at the static boundary and re-joined.
-    # The join concatenates the full cache each step — the price of keeping
-    # ONE uniform KVCache contract for every consumer (engine slots,
-    # sp_cache_spec, checkpoints); acceptable while prefix models serve
-    # single-host (kd<=3), revisit with a pre-split cache if it shows up
-    # on a profile
+    # params), over the same carried cache at layer offsets 0 and kd.
     kd = config.first_k_dense
     stacks = (
-        [(params["dense_layers"], slice(0, kd)), (layer_params, slice(kd, None))]
+        [(params["dense_layers"], slice(0, kd)), (params["layers"], slice(kd, None))]
         if kd
-        else [(layer_params, slice(0, None))]
+        else [(params["layers"], slice(0, None))]
     )
-    # multi-LoRA bank: the per-target (L, A, ...) factor stacks ride the
-    # layer scan under reserved lora:* keys, sliced by each stack's rows —
-    # _lora_mm gathers each batch row's factors by adapter_ids inside the
-    # scanned body (serve/adapters.py; no bank → byte-identical programs)
-    stacks = [
-        (merge_adapter_stacks(stack, adapters, rows), rows)
-        for stack, rows in stacks
-    ]
-
+    # The stacked cache leaves are the scan's CARRY, never its xs/ys: a scan
+    # cannot alias xs with ys, so a cache scanned per layer is sliced out,
+    # written into a second buffer and copied back, three passes over the
+    # whole cache every step. As a carry that each block updates in place it
+    # is one buffer from the caller's (donated) argument to the result.
+    kv = ()
     if cache is not None:
-        new_ks = new_vs = None
-        k_parts, v_parts, ks_parts, vs_parts = [], [], [], []
-        aux_total = aux0
-        for stack, rows in stacks:
-            if quantized:
-                xs = (
-                    stack, sliding_flags[rows], cache.k[rows], cache.v[rows],
-                    cache.k_scale[rows], cache.v_scale[rows],
-                )
-                (x, aux_total), (part_k, part_v, part_ks, part_vs) = jax.lax.scan(
-                    layer_fn, (x, aux_total), xs
-                )
-                ks_parts.append(part_ks)
-                vs_parts.append(part_vs)
-            else:
-                (x, aux_total), (part_k, part_v) = jax.lax.scan(
-                    layer_fn, (x, aux_total),
-                    (stack, sliding_flags[rows], cache.k[rows], cache.v[rows]),
-                )
-            k_parts.append(part_k)
-            v_parts.append(part_v)
-
-        def join(parts):
-            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-
-        if quantized:
-            new_ks, new_vs = join(ks_parts), join(vs_parts)
-        new_lengths = cache.lengths + (1 if decode else seq)
-        new_cache = KVCache(
-            k=join(k_parts), v=join(v_parts), lengths=new_lengths,
-            k_scale=new_ks, v_scale=new_vs,
+        kv = (cache.k, cache.v) + ((cache.k_scale, cache.v_scale) if cache.quantized else ())
+    carry = (x, jnp.zeros((), jnp.float32), kv)
+    layer_ids = jnp.arange(config.n_layers, dtype=jnp.int32)
+    for stack, rows in stacks:
+        # multi-LoRA bank: the per-target (L, A, ...) factor stacks ride the
+        # layer scan under reserved lora:* keys, sliced by each stack's rows —
+        # _lora_mm gathers each batch row's factors by adapter_ids inside the
+        # scanned body (serve/adapters.py; no bank → byte-identical programs)
+        stack = merge_adapter_stacks(stack, adapters, rows)
+        carry, _ = jax.lax.scan(
+            layer_fn, carry, (layer_ids[rows], stack, sliding_flags[rows])
         )
-    else:
-
-        def layer_fn_nocache(carry, scanned):
-            lp, sliding = scanned
-            x, aux_sum = carry
-            if config.mla:
-                from prime_tpu.models.mla import mla_attention_block
-
-                x, _, _, _, _ = mla_attention_block(
-                    x, lp, positions, rope_tables, config,
-                    None, None, None, False, attn_impl,
-                )
-            else:
-                x, _, _, _, _ = _attention_block(
-                    x, lp, positions, rope_tables, config, None, None, None, False, attn_impl,
-                    sliding=sliding, rope_tables_local=rope_tables_local,
-                    mesh=mesh, adapter_ids=adapter_ids,
-                )
-            x, aux = _mlp_block(x, lp, config, adapter_ids=adapter_ids)
-            return (x, aux_sum + aux), None
-
-        if remat not in ("none", "full", "dots"):
-            raise ValueError(f"Unknown remat {remat!r} (want 'none' | 'full' | 'dots')")
-        if remat != "none":
-            # WITHOUT this, reverse-mode AD through the scan saves every
-            # layer's residuals (activation memory = n_layers × per-layer);
-            # checkpointing recomputes them in the backward pass. "dots"
-            # keeps matmul outputs (cheap HBM, expensive to recompute on the
-            # MXU) and drops the elementwise rest — the usual TPU trade.
-            policy = (
-                None
-                if remat == "full"
-                else jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            )
-            layer_fn_nocache = jax.checkpoint(
-                layer_fn_nocache, policy=policy, prevent_cse=False
-            )
-
-        aux_total = aux0
-        for stack, rows in stacks:
-            (x, aux_total), _ = jax.lax.scan(
-                layer_fn_nocache, (x, aux_total), (stack, sliding_flags[rows])
-            )
-        new_cache = None
+    x, aux_total, kv = carry
+    new_cache = None
+    if cache is not None:
+        new_cache = KVCache(
+            kv[0], kv[1], cache.lengths + (1 if decode else seq), *kv[2:]
+        )
 
     x = _norm(x, params["final_norm"], config)
     if last_positions is not None:

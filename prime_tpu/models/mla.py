@@ -36,11 +36,15 @@ paper's textbook form) and exists as the parity oracle for tests.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from prime_tpu.models.config import ModelConfig
-from prime_tpu.ops.attention import decode_attention, multi_head_attention
+from prime_tpu.ops.attention import (
+    cache_prefill_attention,
+    decode_attention,
+    multi_head_attention,
+    write_cache,
+)
 from prime_tpu.ops.rope import apply_rope_rows
 
 
@@ -173,17 +177,20 @@ def _project_out(ctx_latent, lp, config: ModelConfig):
 
 def mla_attention_block(
     x, lp, positions, rope_tables, config: ModelConfig,
-    k_cache, v_cache, cache_lengths, decode: bool, attn_impl: str,
+    kv, layer, cache_lengths, decode: bool, attn_impl: str,
     prefill_offset=None,
 ):
     """Drop-in replacement for llama._attention_block on MLA configs.
 
     Cache contract: the joint latent rides the standard KVCache ``k`` array
     with KH=1 and head width rank+rope; ``v`` is a 1-wide dummy that passes
-    through untouched (llama.init_cache allocates it). The attention ops
-    receive the SAME latent array as both K and V and the rope tail of the
-    weighted sum is discarded — probs @ [c_kv;k_pe] restricted to the first
-    `rank` columns equals probs @ c_kv exactly.
+    through untouched (llama.init_cache allocates it). ``kv`` is the stacked
+    (k, v) pair the layer scan carries (() without a cache) and ``layer``
+    this block's layer in it; the latent is written in place
+    (ops.attention.write_cache). The attention ops receive the SAME latent
+    array as both K and V and the rope tail of the weighted sum is discarded
+    — probs @ [c_kv;k_pe] restricted to the first `rank` columns equals
+    probs @ c_kv exactly.
     """
     batch, seq, _ = x.shape
     rank = config.kv_lora_rank
@@ -198,47 +205,35 @@ def mla_attention_block(
     normed = _rms(x, lp["attn_norm"], config) if "attn_norm" in lp else x
     q_joint, latent = _queries_and_latent(normed, lp, config, cos_rows, sin_rows)
 
-    new_k_cache = k_cache
+    def write(start, impl="xla"):  # the latent block (B, 1, rank+rope, S) at slots [start, start+S)
+        (k_cache,) = write_cache(
+            kv[:1], (latent.transpose(0, 2, 1)[:, None],), layer, start, impl=impl
+        )
+        return k_cache, kv[1]
+
     if decode:
-        assert k_cache is not None and cache_lengths is not None
-        col = latent.transpose(0, 2, 1)[:, None]  # (B, 1, rank+rope, 1)
-
-        def one(c, n, idx):
-            return jax.lax.dynamic_update_slice(c, n, (0, 0, idx))
-
-        new_k_cache = jax.vmap(one)(k_cache, col, cache_lengths)
+        assert kv and cache_lengths is not None
+        kv = write(cache_lengths, attn_impl)
         ctx = decode_attention(
-            q_joint, new_k_cache, new_k_cache, cache_lengths + 1, sm_scale,
-            impl=attn_impl,
+            q_joint, kv[0], kv[0], cache_lengths + 1, sm_scale, impl=attn_impl,
+            layer=layer,
         )
     elif prefill_offset is not None:
-        from prime_tpu.ops.attention import cache_prefill_attention
-
-        off = prefill_offset.astype(jnp.int32)
-        block = latent.transpose(0, 2, 1)[:, None]  # (B, 1, rank+rope, S)
-        if off.ndim == 0:
-            zero = jnp.zeros((), dtype=jnp.int32)
-            new_k_cache = jax.lax.dynamic_update_slice(
-                k_cache, block, (zero, zero, zero, off)
-            )
-        else:
-            def one_row(c, n, idx):
-                return jax.lax.dynamic_update_slice(c, n, (0, 0, idx))
-
-            new_k_cache = jax.vmap(one_row)(k_cache, block, off)
-        ctx = cache_prefill_attention(q_joint, new_k_cache, new_k_cache, off, sm_scale)
+        kv = write(prefill_offset)
+        ctx = cache_prefill_attention(
+            q_joint, kv[0], kv[0], prefill_offset.astype(jnp.int32), sm_scale,
+            layer=layer,
+        )
     else:
         kj = latent[:, None]  # (B, 1, S, rank+rope): one shared kv head
         ctx = multi_head_attention(q_joint, kj, kj, sm_scale, impl=attn_impl)
-        if k_cache is not None:
-            new_k_cache = jax.lax.dynamic_update_slice(
-                k_cache, latent.transpose(0, 2, 1)[:, None], (0, 0, 0, 0)
-            )
+        if kv:
+            kv = write(jnp.zeros((), jnp.int32))
 
     out = _project_out(ctx[..., :rank], lp, config)
     if "attn_post_norm" in lp:
         out = _rms(out, lp["attn_post_norm"], config)
-    return x + out, new_k_cache, v_cache, None, None
+    return x + out, kv
 
 
 def naive_mla_attention(x, lp, positions, rope_tables, config: ModelConfig):

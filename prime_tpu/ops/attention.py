@@ -16,6 +16,57 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def write_cache(leaves, blocks, layer, start, impl: str = "xla"):
+    """Write one layer's new ``blocks`` (B, KH, *, S) into the stacked cache
+    ``leaves`` (L, B, KH, *, C) IN PLACE, at slots [start, start + S) of
+    ``layer``. ``start`` () is one offset for every row (a prompt at 0, a
+    prefill chunk at its offset): one ``dynamic_update_slice``. ``start``
+    (B,) is per-row (decode's next free slot, the speculative verify
+    window): one ``dynamic_update_slice`` a row, or, for decode's single
+    column where ``impl`` sends the attention to the flash-decode kernel
+    (the same gate, so TPU and a long cache), one ``write_columns`` kernel
+    for all rows and leaves. All are forms XLA updates in the buffer the
+    layer scan carries. A scatter over the batch axis
+    (``.at[layer, arange(B), :, :, start].set``, or ``vmap`` of the update)
+    must NOT be used here: XLA gives the scatter its own layout and copies
+    the whole stacked cache inside the layer loop (compiler, PR 25)."""
+    zero = jnp.zeros((), jnp.int32)
+    layer, start = jnp.asarray(layer, jnp.int32), start.astype(jnp.int32)
+    if start.ndim and blocks[0].shape[-1] == 1 and _decode_uses_kernel(impl, leaves[0]):
+        from prime_tpu.ops.pallas_attention import write_columns
+
+        return write_columns(
+            tuple(leaves), tuple(blocks), layer, start, interpret=_pallas_interpret()
+        )
+
+    def put(leaf, block):
+        block = block[None]
+        if start.ndim == 0:
+            return jax.lax.dynamic_update_slice(leaf, block, (layer, zero, zero, zero, start))
+        for row in range(block.shape[1]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, block[:, row : row + 1],
+                (layer, jnp.int32(row), zero, zero, start[row]),
+            )
+        return leaf
+
+    return tuple(put(leaf, block) for leaf, block in zip(leaves, blocks))
+
+
+def _layer_of(cache: jnp.ndarray | None, layer) -> jnp.ndarray | None:
+    """One layer of a stacked (L, B, KH, *, C) leaf for the einsum paths; a
+    4-D leaf is one layer already. The barrier keeps the einsum's preferred
+    operand layout on the slice: without it XLA's layout assignment carries
+    that layout up into the buffer the layer scan carries and re-lays the
+    WHOLE stacked V leaf, a copy in and a copy out of every chunk prefill
+    (compiler, PR 25), where the slice is one layer's bytes."""
+    if cache is None or cache.ndim == 4:
+        return cache
+    return jax.lax.optimization_barrier(
+        jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    )
+
+
 def _pallas_eligible(q: jnp.ndarray, head_dim: int) -> bool:
     if jax.default_backend() != "tpu":
         return False
@@ -120,7 +171,7 @@ def _flash_decode_min_capacity() -> int:
 
 def _decode_int4(
     q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-    k_scale, v_scale, softcap, window, sliding, sinks,
+    k_scale, v_scale, softcap, window, sliding, sinks, layer,
 ):
     """int4-KV decode dispatch: a nibble-packed uint8 cache (a QUARTER of
     the bf16 bytes) rides the flash-decode kernel behind the same scales
@@ -134,7 +185,7 @@ def _decode_int4(
     from prime_tpu.models.quantize import _mesh_context_active, unpack_kv_int4
 
     interpret = _pallas_interpret()
-    capacity = k_cache.shape[3]
+    capacity = k_cache.shape[-1]
     kernel_ok = (
         not _mesh_context_active()
         and (
@@ -151,10 +202,10 @@ def _decode_int4(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, layer=layer, interpret=interpret,
         )
-    k_f = unpack_kv_int4(k_cache) * k_scale
-    v_f = unpack_kv_int4(v_cache) * v_scale
+    k_f = unpack_kv_int4(_layer_of(k_cache, layer)) * _layer_of(k_scale, layer)
+    v_f = unpack_kv_int4(_layer_of(v_cache, layer)) * _layer_of(v_scale, layer)
     return decode_attention(
         q, k_f, v_f, cache_lengths, sm_scale, impl="xla",
         softcap=softcap, window=window, sliding=sliding, sinks=sinks,
@@ -164,7 +215,7 @@ def _decode_int4(
 def _decode_pallas_eligible(k_cache: jnp.ndarray) -> bool:
     if jax.default_backend() != "tpu":
         return False
-    capacity = k_cache.shape[3]
+    capacity = k_cache.shape[-1]
     from prime_tpu.ops.pallas_attention import BLOCK_C
 
     # Short caches: XLA wins. The decode step is weight-bandwidth-bound; at
@@ -181,6 +232,13 @@ def _decode_pallas_eligible(k_cache: jnp.ndarray) -> bool:
     return capacity % BLOCK_C == 0
 
 
+def _decode_uses_kernel(impl: str, k_cache: jnp.ndarray) -> bool:
+    """Whether ``impl`` sends this cache's decode to the bare (unsharded)
+    flash-decode kernel. One owner: the decode attention and the kernel that
+    writes the step's column beside it take the same path."""
+    return impl == "pallas" or (impl == "auto" and _decode_pallas_eligible(k_cache))
+
+
 def _sharded_decode_eligible(k_cache, mesh, quantized: bool) -> bool:
     """Whether the shard_mapped flash-decode kernel can serve this step on
     ``mesh``: the kernel itself must be worth it (_decode_pallas_eligible —
@@ -194,26 +252,31 @@ def _sharded_decode_eligible(k_cache, mesh, quantized: bool) -> bool:
     tp = int(shape.get("tp", 1))
     if int(shape.get("sp", 1)) > 1:
         return False  # slot-sharded caches take the sp decode path, not the kernel
-    batch, kv_heads = k_cache.shape[0], k_cache.shape[1]
+    batch, kv_heads = k_cache.shape[-4], k_cache.shape[-3]
     return batch % max(1, data) == 0 and kv_heads % max(1, tp) == 0
 
 
 def decode_attention(
     q: jnp.ndarray,          # (B, H, 1, D)
-    k_cache: jnp.ndarray,    # (B, KH, D, C) feature-major (see models.llama.KVCache)
-    v_cache: jnp.ndarray,    # (B, KH, D, C)
+    k_cache: jnp.ndarray,    # (L, B, KH, D, C) stacked feature-major (see
+    v_cache: jnp.ndarray,    # models.llama.KVCache), or one layer's (B, KH, D, C)
     cache_lengths: jnp.ndarray,  # (B,) number of valid cache entries
     sm_scale: float,
     impl: str = "auto",      # auto | pallas | xla | sharded
-    k_scale: jnp.ndarray | None = None,  # (B, KH, 1, C) int8-cache dequant scales
+    k_scale: jnp.ndarray | None = None,  # ([L,] B, KH, 1, C) int8-cache dequant scales
     v_scale: jnp.ndarray | None = None,
     softcap: float = 0.0,                # Gemma2 score softcapping
     window: int = 0,                     # sliding-window size (0 = global)
     sliding: jnp.ndarray | None = None,  # traced per-layer bool for `window`
     sinks: jnp.ndarray | None = None,    # (H,) per-head sink logits (GPT-OSS)
     mesh=None,                           # impl="sharded": the serving mesh
+    layer: jnp.ndarray | int = 0,        # () layer of a stacked cache to attend over
 ) -> jnp.ndarray:
     """One decode step against the cache, masking invalid (future) slots.
+
+    The model hands over the stacked cache its layer scan carries and the
+    traced ``layer``: the kernel paths put the layer in their index maps and
+    read it in place; the einsum paths slice it out first (``_layer_of``).
 
     On TPU with a long cache (capacity >= PRIME_TPU_FLASH_DECODE_MIN_C,
     default 2048) this dispatches to the pallas flash-decode kernel
@@ -239,7 +302,7 @@ def decode_attention(
         # multi-device gate allows, XLA widen-and-fold reference otherwise
         return _decode_int4(
             q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-            k_scale, v_scale, softcap, window, sliding, sinks,
+            k_scale, v_scale, softcap, window, sliding, sinks, layer,
         )
     if impl == "sharded":
         if mesh is not None and _sharded_decode_eligible(
@@ -250,10 +313,10 @@ def decode_attention(
             return flash_decode_sharded(
                 q, k_cache, v_cache, cache_lengths, mesh, sm_scale=sm_scale,
                 softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-                interpret=_pallas_interpret(),
+                layer=layer, interpret=_pallas_interpret(),
             )
         impl = "xla"  # SPMD-safe einsum path, partitioned by GSPMD
-    if impl == "pallas" or (impl == "auto" and _decode_pallas_eligible(k_cache)):
+    if _decode_uses_kernel(impl, k_cache):
         from prime_tpu.ops.pallas_attention import flash_decode
 
         # softcap/sliding-window/sinks ride the kernel (Gemma2/3, Mistral,
@@ -264,9 +327,12 @@ def decode_attention(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, interpret=_pallas_interpret(),
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            interpret=_pallas_interpret(),
         )
 
+    k_cache, v_cache = _layer_of(k_cache, layer), _layer_of(v_cache, layer)
+    k_scale, v_scale = _layer_of(k_scale, layer), _layer_of(v_scale, layer)
     batch, num_heads, _, head_dim = q.shape
     kv_heads = k_cache.shape[1]
     group = num_heads // kv_heads
@@ -313,16 +379,17 @@ def decode_attention(
 
 def cache_prefill_attention(
     q: jnp.ndarray,          # (B, H, S, D) queries for a prompt CHUNK
-    k_cache: jnp.ndarray,    # (B, KH, D, C) feature-major, chunk already written
-    v_cache: jnp.ndarray,    # (B, KH, D, C)
+    k_cache: jnp.ndarray,    # ([L,] B, KH, D, C) feature-major, chunk already written
+    v_cache: jnp.ndarray,    # ([L,] B, KH, D, C)
     offset: jnp.ndarray,     # () or (B,) first cache slot of this chunk (traced)
     sm_scale: float,
     softcap: float = 0.0,
     window: int = 0,
     sliding: jnp.ndarray | None = None,
-    k_scale: jnp.ndarray | None = None,  # (B, KH, 1, C) int8-cache dequant scales
+    k_scale: jnp.ndarray | None = None,  # ([L,] B, KH, 1, C) int8-cache dequant scales
     v_scale: jnp.ndarray | None = None,
     sinks: jnp.ndarray | None = None,    # (H,) per-head sink logits (GPT-OSS)
+    layer: jnp.ndarray | int = 0,        # () layer of a stacked cache to attend over
 ) -> jnp.ndarray:
     """Attention for chunked prefill: the chunk's K/V are first *written* into
     the cache at ``offset``, then each chunk query attends over the whole
@@ -335,6 +402,8 @@ def cache_prefill_attention(
     per-query-head. O(S·C) scores per chunk keeps peak memory bounded for
     long prompts (vs O(S_total²) for one-shot prefill).
     """
+    k_cache, v_cache = _layer_of(k_cache, layer), _layer_of(v_cache, layer)
+    k_scale, v_scale = _layer_of(k_scale, layer), _layer_of(v_scale, layer)
     batch, num_heads, seq, head_dim = q.shape
     kv_heads = k_cache.shape[1]
     group = num_heads // kv_heads

@@ -207,6 +207,7 @@ def _unpack_kv_nibbles(packed):
 def _decode_kernel(
     lengths_ref,  # (B,) scalar-prefetch, SMEM
     window_ref,   # (1,) scalar-prefetch: effective window (0 = global layer)
+    layer_ref,    # (1,) scalar-prefetch: read by the K/V index maps only
     q_ref,        # (1, 1, G, D)
     k_ref,        # (1, 1, D, BLOCK_C) the live cache block for this step
                   # (int4: (1, 1, D/2, BLOCK_C) nibble-packed uint8)
@@ -308,22 +309,30 @@ def _decode_kernel(
 )
 def flash_decode(
     q: jnp.ndarray,              # (B, H, 1, D)
-    k_cache: jnp.ndarray,        # (B, KH, D, C) feature-major
-    v_cache: jnp.ndarray,        # (B, KH, D, C)
+    k_cache: jnp.ndarray,        # (L, B, KH, D, C) stacked, feature-major; or one
+    v_cache: jnp.ndarray,        # layer's (B, KH, D, C), served as k[None], layer 0
     cache_lengths: jnp.ndarray,  # (B,) valid entries per sequence
     sm_scale: float | None = None,
     softcap: float = 0.0,                # Gemma2 score softcapping
     window: int = 0,                     # sliding-window size (0 = global)
     sliding: jnp.ndarray | None = None,  # traced per-layer bool for `window`
     sinks: jnp.ndarray | None = None,    # (H,) per-head sink logits (GPT-OSS)
-    k_scale: jnp.ndarray | None = None,  # (B, KH, 1, C) int8-cache dequant scales
+    k_scale: jnp.ndarray | None = None,  # ([L,] B, KH, 1, C) int8-cache dequant scales
     v_scale: jnp.ndarray | None = None,
+    layer: jnp.ndarray | int = 0,        # () which layer of the stacked cache to read
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One fused decode step: for each (batch, kv-head) program, stream the
     cache through VMEM with online softmax, stopping at the sequence's true
     length (scalar-prefetched). C must be a multiple of BLOCK_C. The
     feature-major cache keeps reads lane-aligned for any head_dim.
+
+    The cache operand is the model's whole stacked (L, B, KH, D, C) buffer and
+    ``layer`` a third scalar-prefetch operand that the K/V (and scale) index
+    maps put in the leading block coordinate: the layer scan carries one
+    buffer and the kernel reads its layer straight out of it, so no
+    one-layer slice is ever made in HBM. A caller with a single layer's 4-D
+    arrays is served by the same call on ``k[None]`` (a bitcast) at layer 0.
 
     Gemma/GPT-OSS variants ride the same kernel: ``softcap`` tanh-caps the
     scores, ``window`` (+ the traced per-layer ``sliding`` flag the model
@@ -336,18 +345,22 @@ def flash_decode(
     A uint8 cache with scales is the int4 variant (models/quantize.py
     nibble packing along head_dim — a QUARTER of the bf16 bytes): the
     kernel widens the packed block in VMEM behind the same scales plumbing."""
+    quantized = k_scale is not None
+    assert quantized == (v_scale is not None), "k_scale and v_scale go together"
+    if k_cache.ndim == 4:
+        k_cache, v_cache = k_cache[None], v_cache[None]
+        if quantized:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     batch, num_heads, _, head_dim = q.shape
-    kv_heads, capacity = k_cache.shape[1], k_cache.shape[3]
+    kv_heads, capacity = k_cache.shape[2], k_cache.shape[4]
     assert num_heads % kv_heads == 0
     group = num_heads // kv_heads
     if sm_scale is None:
         sm_scale = head_dim**-0.5
-    quantized = k_scale is not None
-    assert quantized == (v_scale is not None), "k_scale and v_scale go together"
     quant = None
     if quantized:
         quant = "int4" if k_cache.dtype == jnp.uint8 else "int8"
-    kv_dim = k_cache.shape[2]  # head_dim, or head_dim/2 nibble-packed
+    kv_dim = k_cache.shape[3]  # head_dim, or head_dim/2 nibble-packed
     if quant == "int4":
         assert kv_dim * 2 == head_dim, "int4 cache must be nibble-packed along head_dim"
     else:
@@ -371,19 +384,21 @@ def flash_decode(
     window_arr = _window_scalar(window, sliding)
     use_sinks, sinks_arr = _sinks_operand(sinks, kv_heads, group)
 
-    def kv_map(b, h, cb, lens, win):
+    def kv_map(b, h, cb, lens, win, lay):
         # shared by k/v AND the int8 scale blocks: the scale block must
         # always ride the same live-block index as its cache block
-        return (b, h, 0, _decode_live_block(b, cb, lens, win, block_c))
+        return (lay[0], b, h, 0, _decode_live_block(b, cb, lens, win, block_c))
 
+    # the layer axis is squeezed (None): the kernel sees the same
+    # (1, 1, D, BLOCK_C) blocks whichever layer the index map picked
     qkv_specs = [
         pl.BlockSpec((1, 1, group, head_dim), lambda b, h, cb, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, kv_dim, block_c), kv_map),
-        pl.BlockSpec((1, 1, kv_dim, block_c), kv_map),
+        pl.BlockSpec((None, 1, 1, kv_dim, block_c), kv_map),
+        pl.BlockSpec((None, 1, 1, kv_dim, block_c), kv_map),
     ]
     scale_specs = [
-        pl.BlockSpec((1, 1, 1, block_c), kv_map),
-        pl.BlockSpec((1, 1, 1, block_c), kv_map),
+        pl.BlockSpec((None, 1, 1, 1, block_c), kv_map),
+        pl.BlockSpec((None, 1, 1, 1, block_c), kv_map),
     ]
     sinks_spec = pl.BlockSpec((kv_heads, group), lambda b, h, cb, *_: (0, 0))
     kernel = functools.partial(
@@ -397,7 +412,7 @@ def flash_decode(
         in_specs = qkv_specs + [sinks_spec]
         operands = (k_cache, v_cache, sinks_arr)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         # the cache-block axis is a GRID dimension: blocks outside a
         # sequence's live range revisit a resident block (index-map clip)
         # and their copies are elided, so HBM traffic tracks true lengths,
@@ -430,15 +445,98 @@ def flash_decode(
             # time), so charge the mid-generation expectation of capacity/2
             # (docs/kernels.md "Cost estimates").
             flops=2 * 2 * batch * num_heads * capacity * head_dim,
-            bytes_accessed=(k_cache.size + v_cache.size) * k_cache.dtype.itemsize // 2,
+            # (K + V of the one layer read, halved: the stacked operand's
+            # other layers are never touched)
+            bytes_accessed=batch * kv_heads * kv_dim * capacity * k_cache.dtype.itemsize,
             transcendentals=batch * num_heads * capacity,
         ),
         interpret=interpret,
     )(
         cache_lengths.astype(jnp.int32), window_arr,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         q.reshape(batch, kv_heads, group, head_dim), *operands,
     )
     return out.reshape(batch, num_heads, 1, head_dim)
+
+
+def _column_kernel(
+    slots_ref,  # (B,) scalar-prefetch: the slot each row's column goes to
+    layer_ref,  # (1,) scalar-prefetch: read by the index maps only
+    *refs,      # per leaf: the column (1, HB, d, 1); then per leaf the cache
+                # block (1, HB, d, LANES) holding that slot; then the same
+                # blocks again as outputs (aliased to the cache operands)
+    lanes: int,
+):
+    count = len(refs) // 3
+    lane_of_slot = slots_ref[pl.program_id(0)] % lanes
+    for col_ref, block_ref, out_ref in zip(
+        refs[:count], refs[count : 2 * count], refs[2 * count :]
+    ):
+        # widen for the select: the v5e has no 16- or 8-bit vector select, and
+        # bf16 -> f32 -> bf16 and int8 -> int32 -> int8 are exact
+        wide = jnp.float32 if jnp.issubdtype(out_ref.dtype, jnp.floating) else jnp.int32
+        lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, out_ref.ndim - 1)
+        out_ref[...] = jnp.where(
+            lane == lane_of_slot, col_ref[...].astype(wide), block_ref[...].astype(wide)
+        ).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def write_columns(
+    leaves: tuple[jnp.ndarray, ...],   # stacked cache leaves (L, B, KH, d_i, C)
+    columns: tuple[jnp.ndarray, ...],  # this step's columns (B, KH, d_i, 1)
+    layer: jnp.ndarray | int,          # () the layer to write
+    slots: jnp.ndarray,                # (B,) the slot of each row's column
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, ...]:
+    """Decode's cache write as ONE kernel for every row and leaf: each program
+    brings the 128-slot block that holds its row's slot into VMEM, puts the
+    column in its lane and writes the block back, into the SAME buffers
+    (``input_output_aliases``), so the layer scan still carries one cache. As
+    per-row ``dynamic_update_slice`` the same write is 8 operations a leaf of
+    4-5 us each, 16 a layer for a bf16 cache of 8 rows: 2.4 ms of a 22.6 ms
+    decode step on the v5e (PR 25). A slot past the end is clamped to the
+    last one, as ``dynamic_update_slice`` clamps its start."""
+    batch, kv_heads, capacity = leaves[0].shape[1], leaves[0].shape[2], leaves[0].shape[4]
+    lanes = BLOCK_C if capacity % BLOCK_C == 0 else capacity
+    # all of a row's kv heads in one program while the widest block stays small
+    # in VMEM (each is held in, out and double-buffered); else a head a program
+    widest = max(leaf.shape[3] * leaf.dtype.itemsize for leaf in leaves)
+    heads = kv_heads if kv_heads * widest * lanes <= 512 * 1024 else 1
+
+    def column_spec(leaf):
+        return pl.BlockSpec((1, heads, leaf.shape[3], 1), lambda b, h, *_: (b, h, 0, 0))
+
+    def block_spec(leaf):
+        return pl.BlockSpec(
+            (None, 1, heads, leaf.shape[3], lanes),
+            lambda b, h, slots, lay: (lay[0], b, h, 0, slots[b] // lanes),
+        )
+
+    count = len(leaves)
+    blocks = [block_spec(leaf) for leaf in leaves]
+    return tuple(
+        pl.pallas_call(
+            functools.partial(_column_kernel, lanes=lanes),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(batch, kv_heads // heads),
+                in_specs=[column_spec(leaf) for leaf in leaves] + blocks,
+                out_specs=blocks,
+            ),
+            out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in leaves],
+            # operands count the two prefetched scalars and the columns first
+            input_output_aliases={2 + count + i: i for i in range(count)},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+            ),
+            interpret=interpret,
+        )(
+            jnp.clip(slots.astype(jnp.int32), 0, capacity - 1),
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            *columns, *leaves,
+        )
+    )
 
 
 @functools.partial(

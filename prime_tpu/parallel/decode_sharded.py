@@ -26,8 +26,8 @@ from jax.sharding import PartitionSpec as P
 
 def flash_decode_sharded(
     q: jnp.ndarray,              # (B, H, 1, D)
-    k_cache: jnp.ndarray,        # (B, KH, D, C) feature-major
-    v_cache: jnp.ndarray,        # (B, KH, D, C)
+    k_cache: jnp.ndarray,        # ([L,] B, KH, D, C) feature-major, stacked or
+    v_cache: jnp.ndarray,        # one layer, as flash_decode takes them
     cache_lengths: jnp.ndarray,  # (B,)
     mesh,
     sm_scale: float | None = None,
@@ -35,6 +35,7 @@ def flash_decode_sharded(
     window: int = 0,
     sliding: jnp.ndarray | None = None,
     sinks: jnp.ndarray | None = None,  # (H,) per-head sink logits (GPT-OSS)
+    layer: jnp.ndarray | int = 0,      # () layer of a stacked cache, replicated
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Per-shard pallas flash decode over a (dp, fsdp, tp[, ...]) mesh.
@@ -51,7 +52,7 @@ def flash_decode_sharded(
     # one source of truth for the serving layout: prune the canonical specs
     # down to the axes this mesh actually has
     q_spec = sharding.prune_spec(P(("dp", "fsdp"), "tp", None, None), mesh)
-    kv_spec = q_spec
+    kv_spec = q_spec if k_cache.ndim == 4 else P(None, *q_spec)
     lengths_spec = sharding.prune_spec(sharding.lengths_spec(), mesh)
     sinks_spec = sharding.prune_spec(P("tp"), mesh)
     if sinks is None:
@@ -65,16 +66,19 @@ def flash_decode_sharded(
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec, lengths_spec, sinks_spec),
+        in_specs=(q_spec, kv_spec, kv_spec, lengths_spec, sinks_spec, P()),
         out_specs=q_spec,
         # pallas_call's out ShapeDtypeStruct carries no varying-axes metadata
         check_vma=False,
     )
-    def local_decode(q_local, k_local, v_local, lengths_local, sinks_local):
+    def local_decode(q_local, k_local, v_local, lengths_local, sinks_local, layer):
         return flash_decode(
             q_local, k_local, v_local, lengths_local, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding,
-            sinks=sinks_local if has_sinks else None, interpret=interpret,
+            sinks=sinks_local if has_sinks else None, layer=layer,
+            interpret=interpret,
         )
 
-    return local_decode(q, k_cache, v_cache, cache_lengths, sinks_in)
+    return local_decode(
+        q, k_cache, v_cache, cache_lengths, sinks_in, jnp.asarray(layer, jnp.int32)
+    )

@@ -131,12 +131,12 @@ def _stage_forward(
         if config.mla:
             from prime_tpu.models.mla import mla_attention_block
 
-            x, _, _, _, _ = mla_attention_block(
-                x, lp, positions, rope_tables, config, None, None, None, False, "xla"
+            x, _ = mla_attention_block(
+                x, lp, positions, rope_tables, config, (), None, None, False, "xla"
             )
         else:
-            x, _, _, _, _ = _attention_block(
-                x, lp, positions, rope_tables, config, None, None, None, False, "xla",
+            x, _ = _attention_block(
+                x, lp, positions, rope_tables, config, (), None, None, False, "xla",
                 sliding=sliding, rope_tables_local=rope_tables_local,
             )
         x, aux = _mlp_block(x, lp, config)

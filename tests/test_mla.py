@@ -43,7 +43,7 @@ def test_absorbed_equals_naive_oracle(preset):
     positions = jnp.broadcast_to(jnp.arange(12)[None], (2, 12))
     tables = rope_frequencies(config.qk_rope_head_dim, 64, config.rope_theta)
     absorbed, *_ = mla_attention_block(
-        x, lp, positions, tables, config, None, None, None, False, "xla"
+        x, lp, positions, tables, config, (), None, None, False, "xla"
     )
     naive = naive_mla_attention(x, lp, positions, tables, config)
     assert float(jnp.max(jnp.abs(absorbed - naive))) < 1e-5

@@ -224,6 +224,12 @@ def test_capture_holds_the_phases_on_the_loop_threads_line(tmp_path):
         for _ in range(3):
             for req in [engine.submit(PROMPT, max_new_tokens=12) for _ in range(3)]:
                 req.all_tokens(timeout=120)
+        # a request is whole before the loop has synced the chunk it left in
+        # flight: let that trailing sync land before the counter is read, as
+        # the sleep below does before the capture stops, or it falls between
+        # `before` and the capture and the counter holds a sync that no
+        # annotation shows
+        time.sleep(0.3)
         before = snapshot_after_flush(engine)
         jax.profiler.start_trace(str(tmp_path))
         try:
