@@ -182,27 +182,16 @@ def _decode_int4(
     widens the nibbles in-graph, folds the scales, and runs the standard
     fp path (the ground truth the kernel is tested against, under the
     documented int4 rounding tolerance, not bit-identity)."""
-    from prime_tpu.models.quantize import _mesh_context_active, unpack_kv_int4
+    from prime_tpu.models.quantize import unpack_kv_int4
 
-    interpret = _pallas_interpret()
-    capacity = k_cache.shape[-1]
-    kernel_ok = (
-        not _mesh_context_active()
-        and (
-            interpret
-            or (
-                jax.default_backend() == "tpu"
-                and capacity >= _flash_decode_min_capacity()
-            )
-        )
-    )
-    if impl == "pallas" or (impl == "auto" and kernel_ok):
+    if _int4_uses_kernel(impl, k_cache):
         from prime_tpu.ops.pallas_attention import flash_decode
 
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, layer=layer, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            interpret=_pallas_interpret(),
         )
     k_f = unpack_kv_int4(_layer_of(k_cache, layer)) * _layer_of(k_scale, layer)
     v_f = unpack_kv_int4(_layer_of(v_cache, layer)) * _layer_of(v_scale, layer)
@@ -210,6 +199,21 @@ def _decode_int4(
         q, k_f, v_f, cache_lengths, sm_scale, impl="xla",
         softcap=softcap, window=window, sliding=sliding, sinks=sinks,
     ).astype(q.dtype)
+
+
+def _int4_uses_kernel(impl: str, k_cache: jnp.ndarray) -> bool:
+    """Whether ``impl`` sends a nibble-packed cache's decode to the kernel
+    (``_decode_int4``'s gate)."""
+    from prime_tpu.models.quantize import _mesh_context_active
+
+    kernel_ok = not _mesh_context_active() and (
+        _pallas_interpret()
+        or (
+            jax.default_backend() == "tpu"
+            and k_cache.shape[-1] >= _flash_decode_min_capacity()
+        )
+    )
+    return impl == "pallas" or (impl == "auto" and kernel_ok)
 
 
 def _decode_pallas_eligible(k_cache: jnp.ndarray) -> bool:
@@ -254,6 +258,36 @@ def _sharded_decode_eligible(k_cache, mesh, quantized: bool) -> bool:
         return False  # slot-sharded caches take the sp decode path, not the kernel
     batch, kv_heads = k_cache.shape[-4], k_cache.shape[-3]
     return batch % max(1, data) == 0 and kv_heads % max(1, tp) == 0
+
+
+def decode_kernel_tiling(
+    impl: str, num_heads: int, k_cache, quantized: bool, mesh=None
+) -> tuple[int, int, int]:
+    """(grid steps, block_c, kv heads a step) of the flash-decode call that
+    ``decode_attention`` makes for this cache under ``impl``, from the same
+    gates and the kernel's own shape function; zeros where decode takes an XLA
+    path. ``k_cache`` needs a shape and a dtype only. Under a mesh the kernel
+    sees a device's local rows and kv heads, and so does this."""
+    from prime_tpu.ops.pallas_attention import decode_call_tiling
+
+    batch, kv_heads, kv_dim, capacity = k_cache.shape[-4:]
+    if quantized and k_cache.dtype == jnp.uint8:
+        uses_kernel = _int4_uses_kernel(impl, k_cache)
+    elif impl == "sharded":
+        uses_kernel = mesh is not None and _sharded_decode_eligible(
+            k_cache, mesh, quantized=quantized
+        )
+        if uses_kernel:
+            tp = int(mesh.shape.get("tp", 1))
+            batch //= int(mesh.shape.get("dp", 1)) * int(mesh.shape.get("fsdp", 1))
+            kv_heads, num_heads = kv_heads // tp, num_heads // tp
+    else:
+        uses_kernel = _decode_uses_kernel(impl, k_cache)
+    if not uses_kernel:
+        return 0, 0, 0
+    local = jax.ShapeDtypeStruct((batch, kv_heads, kv_dim, capacity), k_cache.dtype)
+    heads, block_c = decode_call_tiling(num_heads, local, quantized)
+    return batch * (kv_heads // heads) * (capacity // block_c), block_c, heads
 
 
 def decode_attention(

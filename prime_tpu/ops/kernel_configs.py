@@ -55,11 +55,16 @@ __all__ = [
 SCHEMA_VERSION = 1
 
 # Built-in defaults: the values the kernels hardcoded before the registry
-# existed (measured on v5e-1; docs/kernels.md "Kernel campaign & autotune").
+# existed (docs/kernels.md "Kernel campaign & autotune"). flash-decode's 0
+# means "no preference": its block comes from the cache's shape
+# (ops/pallas_attention.py::decode_tiling, every kv head of a row and the
+# widest block that fits VMEM): at 128 slots and one head a step a call is
+# 1024 grid steps at the served shapes, and the ledger's PR 26 traces show it
+# bound by those steps at a tenth of the v5e's bandwidth.
 DEFAULTS: dict[str, dict[str, int]] = {
     "flash_prefill": {"block_q": 128, "block_k": 128},
-    "flash_decode": {"block_c": 128},
-    "flash_decode_int8": {"block_c": 128},
+    "flash_decode": {"block_c": 0},
+    "flash_decode_int8": {"block_c": 0},
     "lora_mm": {"block_out": 256},
     "int4_matmul": {"block_out": 512},
 }

@@ -6,8 +6,11 @@ scratch — O(S) memory instead of the O(S^2) score matrix, and every matmul
 lands on the MXU at (BLOCK, head_dim)x(head_dim, BLOCK) granularity.
 
 The KV/cache-block axis is a GRID dimension in both kernels — prefill:
-(batch, q_heads, S // BLOCK_Q, S // BLOCK_K); decode: (batch, kv_heads,
-C // block_c) — with the online-softmax state carried across the innermost
+(batch, q_heads, S // BLOCK_Q, S // BLOCK_K); decode: (batch, kv_heads //
+heads, C // block_c), where a grid step carries ``heads`` kv heads of a row
+and a cache block as wide as VMEM allows (``decode_tiling``: every head and
+512 slots at the served shapes, so a call is tens of steps, not a
+thousand) — with the online-softmax state carried across the innermost
 axis ("arbitrary" semantics). The index maps clip each step's block
 coordinate into the live range (causal diagonal / sliding-window band /
 scalar-prefetched cache lengths); out-of-range steps revisit an already-
@@ -37,8 +40,17 @@ from jax.experimental.pallas import tpu as pltpu
 # the tiling itself.
 BLOCK_Q = 128
 BLOCK_K = 128
-BLOCK_C = 128  # flash-decode cache-slot block (lane dimension of the kv cache)
+# flash-decode's NARROWEST cache-slot block (the lane tile of the kv cache,
+# and the block write_columns rewrites); the block a call uses comes from
+# decode_tiling
+BLOCK_C = 128
 NEG_INF = -1e30
+
+# What one flash-decode grid step may hold in VMEM: the double-buffered K and V
+# (and scale) blocks plus the body's float32 temporaries. A v5e's scoped
+# default is 16 MiB; the rest is left to q, the output, the scratch and
+# whatever Mosaic spills.
+DECODE_VMEM_BUDGET = 10 * 1024 * 1024
 
 
 def _resolve_block(kernel: str, param: str, default: int) -> int:
@@ -204,18 +216,83 @@ def _unpack_kv_nibbles(packed):
     return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
 
 
+def decode_step_vmem_bytes(
+    heads: int, kv_dim: int, block_c: int, itemsize: int,
+    group: int, head_dim: int, quantized: bool,
+) -> int:
+    """VMEM a flash-decode grid step of ``heads`` kv heads and ``block_c``
+    slots needs: the K and V blocks (and their per-slot scale blocks, padded to
+    8 sublanes), each double-buffered by the pipeline, and the body's float32
+    temporaries for ONE head (the heads are a static loop): the widened V
+    block, the widened K block where the cache is not fed to the MXU as it is
+    stored (int8 / int4), and the scores, the probabilities and the mask."""
+    blocks = 2 * 2 * heads * kv_dim * block_c * itemsize
+    if quantized:
+        blocks += 2 * 2 * heads * 8 * block_c * 4
+    rows = -(-group // 8) * 8
+    widened = (2 if quantized else 1) * head_dim * block_c * 4
+    return blocks + widened + 3 * rows * block_c * 4
+
+
+def decode_tiling(
+    kv_heads: int, kv_dim: int, capacity: int, itemsize: int,
+    group: int, head_dim: int, quantized: bool = False, prefer: int = 0,
+) -> tuple[int, int]:
+    """(heads, block_c) of one flash-decode grid step, from what the call can
+    see of the cache: the widest cache block of ``prefer`` (an explicit
+    PRIME_TPU_BLOCK_C or a tuned artifact's value; 0 = none), 512, 256, 128
+    that divides the capacity, and with it the most kv heads of a row (a
+    divisor of ``kv_heads``) whose step stays inside DECODE_VMEM_BUDGET. A
+    step costs 0.2 us on a v5e whether or not it moves a block (PERF.md, PR
+    27), so fewer and wider steps win until the blocks no longer fit: qwen3-4b's
+    8 x 128 x 2048 bf16 rows come out at (8, 512), 4 steps a row; a 576-wide
+    latent of one head at (1, 512); a 32-head MHA cache at (16, 512). A
+    capacity that none of the widths divides is one block."""
+    widths = [
+        b for b in dict.fromkeys((prefer, 512, 256, BLOCK_C))
+        if 0 < b <= capacity and capacity % b == 0
+    ] or [capacity]
+    divisors = [h for h in range(kv_heads, 0, -1) if kv_heads % h == 0]
+    for block_c in widths:
+        for heads in divisors:
+            need = decode_step_vmem_bytes(
+                heads, kv_dim, block_c, itemsize, group, head_dim, quantized
+            )
+            if need <= DECODE_VMEM_BUDGET:
+                return heads, block_c
+    return 1, widths[-1]
+
+
+def decode_call_tiling(num_heads: int, k_cache, quantized: bool) -> tuple[int, int]:
+    """``decode_tiling`` for a call's operands (``k_cache`` needs a shape and a
+    dtype only). The config registry's value (env override > tuned
+    per-device-kind artifact; 0 when neither is set) is the block tried
+    first, and the divisibility walk inside ``decode_tiling`` is the fallback
+    that keeps an ill-fitting tuned value harmless."""
+    kv_heads, kv_dim, capacity = k_cache.shape[-3:]
+    int4 = quantized and k_cache.dtype == jnp.uint8
+    return decode_tiling(
+        kv_heads, kv_dim, capacity, k_cache.dtype.itemsize,
+        num_heads // kv_heads, kv_dim * (2 if int4 else 1), quantized=quantized,
+        prefer=_resolve_block(
+            "flash_decode_int8" if quantized else "flash_decode", "block_c", 0
+        ),
+    )
+
+
 def _decode_kernel(
     lengths_ref,  # (B,) scalar-prefetch, SMEM
     window_ref,   # (1,) scalar-prefetch: effective window (0 = global layer)
     layer_ref,    # (1,) scalar-prefetch: read by the K/V index maps only
-    q_ref,        # (1, 1, G, D)
-    k_ref,        # (1, 1, D, BLOCK_C) the live cache block for this step
-                  # (int4: (1, 1, D/2, BLOCK_C) nibble-packed uint8)
-    v_ref,        # (1, 1, D, BLOCK_C)
-    *rest,        # int8/int4 path: k_scale_ref, v_scale_ref (1, 1, 1, BLOCK_C);
-                  # then sinks_ref (KH, G), o_ref (1, 1, G, D),
-                  # scratch: m (G, 128), l (G, 128), acc (G, D) — all fp32,
-                  # carried across the cache-block grid dimension
+    q_ref,        # (1, HEADS, G, D): every query head of this step's kv heads
+    k_ref,        # (1, HEADS, D, BLOCK_C) the live cache block for this step
+                  # (int4: (1, HEADS, D/2, BLOCK_C) nibble-packed uint8)
+    v_ref,        # (1, HEADS, D, BLOCK_C)
+    *rest,        # int8/int4 path: k_scale_ref, v_scale_ref (1, HEADS, 1, BLOCK_C);
+                  # then sinks_ref (KH, G), o_ref (1, HEADS, G, D),
+                  # scratch: m (HEADS, G, 128), l (HEADS, G, 128),
+                  # acc (HEADS, G, D) — all fp32, carried across the
+                  # cache-block grid dimension
     sm_scale: float,
     block_c: int,
     softcap: float,
@@ -227,13 +304,17 @@ def _decode_kernel(
     else:
         sinks_ref, o_ref, m_scr, l_scr, acc_scr = rest
         k_scale_ref = v_scale_ref = None
+    heads, group = q_ref.shape[1], q_ref.shape[2]
 
     # program ids hoisted out of the pl.when closures: inside them the HLO
     # interpreter (CPU tests) has no lowering for the primitive
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    cb = pl.program_id(2)
-    last_cb = pl.num_programs(2) - 1
+    hb = pl.program_id(1)
+    step = pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
+    # a row's blocks are walked from the far end (flash_decode's kv_map): the
+    # steps past its length come first, its live blocks last
+    cb = last_step - step
     length = lengths_ref[b]
     window = window_ref[0]
     # the query sits at position length-1; a sliding layer sees slots
@@ -242,7 +323,7 @@ def _decode_kernel(
     first = first_slot // block_c
     num = pl.cdiv(length, block_c)
 
-    @pl.when(cb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, dtype=jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, dtype=jnp.float32)
@@ -250,58 +331,70 @@ def _decode_kernel(
 
     @pl.when((cb >= first) & (cb < num))
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (G, D)
-        if quant == "int4":
-            # int4 streams a QUARTER of the bf16 bytes from HBM; the nibble
-            # widening happens on the VMEM-resident block, and the same
-            # per-slot scales the int8 path uses fold into the epilogues
-            k = _unpack_kv_nibbles(k_ref[0, 0])          # (D, BC)
-            v = _unpack_kv_nibbles(v_ref[0, 0])
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)          # (D, BC)
-            v = v_ref[0, 0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G, BC)
-        if quant is not None:
-            # int8/int4 stream from HBM at reduced bytes and widen to fp32
-            # in VMEM; the per-slot scales are column-constant so they fold
-            # into the epilogues, no dequantized cache is materialized
-            scores = scores * k_scale_ref[0, 0].astype(jnp.float32)  # (1, BC)
-        if softcap:
-            scores = jnp.tanh(scores / softcap) * softcap
-        slots = cb * block_c + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores = jnp.where((slots < length) & (slots >= first_slot), scores, NEG_INF)
-
-        m_prev = m_scr[:, :1]  # (G, 1)
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        weighted = (
-            p if quant is None else p * v_scale_ref[0, 0].astype(jnp.float32)
+        slots = cb * block_c + jax.lax.broadcasted_iota(
+            jnp.int32, (group, block_c), 1
         )
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            weighted, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G, D)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        live = (slots < length) & (slots >= first_slot)
+        # a cache stored as q is (bf16 under bf16 queries) goes to the MXU as it
+        # is: products of bf16 values are exact in the float32 accumulator, so
+        # only the summation order differs from widening first, and the K block
+        # is not widened. V always is: the probabilities stay float32
+        widen = quant is not None or k_ref.dtype != q_ref.dtype
+        # the same online softmax for each kv head the step holds: a static
+        # loop, so one head's widened block is live at a time
+        for h in range(heads):
+            q, k, v = q_ref[0, h], k_ref[0, h], v_ref[0, h]  # (G, D), (D, BC) x 2
+            if quant == "int4":
+                # int4 streams a QUARTER of the bf16 bytes from HBM; the nibble
+                # widening happens on the VMEM-resident block, and the same
+                # per-slot scales the int8 path uses fold into the epilogues
+                k, v = _unpack_kv_nibbles(k), _unpack_kv_nibbles(v)
+            else:
+                v = v.astype(jnp.float32)
+            if widen:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ) * sm_scale  # (G, BC)
+            if quant is not None:
+                # int8/int4 stream from HBM at reduced bytes and widen to fp32
+                # in VMEM; the per-slot scales are column-constant so they fold
+                # into the epilogues, no dequantized cache is materialized
+                scores = scores * k_scale_ref[0, h].astype(jnp.float32)  # (1, BC)
+            if softcap:
+                scores = jnp.tanh(scores / softcap) * softcap
+            scores = jnp.where(live, scores, NEG_INF)
 
-    @pl.when(cb == last_cb)
+            m_prev = m_scr[h, :, :1]  # (G, 1)
+            l_prev = l_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            weighted = (
+                p if quant is None else p * v_scale_ref[0, h].astype(jnp.float32)
+            )
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                weighted, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (G, D)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(step == last_step)
     def _finalize():
-        group = q_ref.shape[2]
-        # the sinks block is the FULL (KH, G) array (a (1, G) slice would
-        # break the TPU lowering's sublane-divisibility rule); pick this
-        # program's row
-        sink = (
-            sinks_ref[h].astype(jnp.float32).reshape(group, 1)
-            if use_sinks
-            else None
-        )
-        o_ref[0, 0] = _finalize_attention(
-            acc_scr[...], m_scr[:, :1], l_scr[:, :1], sink
-        ).astype(o_ref.dtype)
+        for h in range(heads):
+            # the sinks block is the FULL (KH, G) array (a (1, G) slice would
+            # break the TPU lowering's sublane-divisibility rule); pick this
+            # head's row
+            sink = (
+                sinks_ref[hb * heads + h].astype(jnp.float32).reshape(group, 1)
+                if use_sinks
+                else None
+            )
+            o_ref[0, h] = _finalize_attention(
+                acc_scr[h], m_scr[h, :, :1], l_scr[h, :, :1], sink
+            ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -322,10 +415,12 @@ def flash_decode(
     layer: jnp.ndarray | int = 0,        # () which layer of the stacked cache to read
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """One fused decode step: for each (batch, kv-head) program, stream the
-    cache through VMEM with online softmax, stopping at the sequence's true
-    length (scalar-prefetched). C must be a multiple of BLOCK_C. The
-    feature-major cache keeps reads lane-aligned for any head_dim.
+    """One fused decode step: each program takes a row and as many of its kv
+    heads as VMEM holds (``decode_tiling``: all of them at the served shapes)
+    and streams the cache through VMEM in wide blocks with online softmax,
+    stopping at the sequence's true length (scalar-prefetched). C must be a
+    multiple of BLOCK_C. The feature-major cache keeps reads lane-aligned for
+    any head_dim.
 
     The cache operand is the model's whole stacked (L, B, KH, D, C) buffer and
     ``layer`` a third scalar-prefetch operand that the K/V (and scale) index
@@ -365,42 +460,37 @@ def flash_decode(
         assert kv_dim * 2 == head_dim, "int4 cache must be nibble-packed along head_dim"
     else:
         assert kv_dim == head_dim
-    # biggest supported block that divides the capacity: fewer, larger DMAs.
-    # The preference comes from the config registry (env override > tuned
-    # per-device-kind artifact > 128 default); the divisibility walk below
-    # is the fallback that keeps an ill-fitting tuned value harmless.
-    pref = _resolve_block(
-        "flash_decode" if quant is None else "flash_decode_int8", "block_c", BLOCK_C
-    )
-    block_c = next(
-        (
-            b
-            for b in dict.fromkeys((pref, 512, 256, BLOCK_C))
-            if capacity % b == 0 and b <= capacity
-        ),
-        capacity,
-    )
+    heads, block_c = decode_call_tiling(num_heads, k_cache, quantized)
 
     window_arr = _window_scalar(window, sliding)
     use_sinks, sinks_arr = _sinks_operand(sinks, kv_heads, group)
 
-    def kv_map(b, h, cb, lens, win, lay):
+    blocks = capacity // block_c
+
+    def kv_map(b, hb, step, lens, win, lay):
         # shared by k/v AND the int8 scale blocks: the scale block must
-        # always ride the same live-block index as its cache block
-        return (lay[0], b, h, 0, _decode_live_block(b, cb, lens, win, block_c))
+        # always ride the same live-block index as its cache block. A row's
+        # blocks are walked from the far end, so the steps past its length
+        # come FIRST and revisit its last live block: that block is asked for
+        # at the row's first step and streams in under the previous row's
+        # last block of work. Walked from the front, a row of one live block
+        # (most of a chat batch) waits for its whole copy in the open
+        # (PERF.md, PR 27)
+        cb = blocks - 1 - step
+        return (lay[0], b, hb, 0, _decode_live_block(b, cb, lens, win, block_c))
 
     # the layer axis is squeezed (None): the kernel sees the same
-    # (1, 1, D, BLOCK_C) blocks whichever layer the index map picked
+    # (1, HEADS, D, BLOCK_C) blocks whichever layer the index map picked
     qkv_specs = [
-        pl.BlockSpec((1, 1, group, head_dim), lambda b, h, cb, *_: (b, h, 0, 0)),
-        pl.BlockSpec((None, 1, 1, kv_dim, block_c), kv_map),
-        pl.BlockSpec((None, 1, 1, kv_dim, block_c), kv_map),
+        pl.BlockSpec((1, heads, group, head_dim), lambda b, hb, step, *_: (b, hb, 0, 0)),
+        pl.BlockSpec((None, 1, heads, kv_dim, block_c), kv_map),
+        pl.BlockSpec((None, 1, heads, kv_dim, block_c), kv_map),
     ]
     scale_specs = [
-        pl.BlockSpec((None, 1, 1, 1, block_c), kv_map),
-        pl.BlockSpec((None, 1, 1, 1, block_c), kv_map),
+        pl.BlockSpec((None, 1, heads, 1, block_c), kv_map),
+        pl.BlockSpec((None, 1, heads, 1, block_c), kv_map),
     ]
-    sinks_spec = pl.BlockSpec((kv_heads, group), lambda b, h, cb, *_: (0, 0))
+    sinks_spec = pl.BlockSpec((kv_heads, group), lambda b, hb, step, *_: (0, 0))
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, block_c=block_c, softcap=softcap,
         use_sinks=use_sinks, quant=quant,
@@ -413,19 +503,20 @@ def flash_decode(
         operands = (k_cache, v_cache, sinks_arr)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        # the cache-block axis is a GRID dimension: blocks outside a
-        # sequence's live range revisit a resident block (index-map clip)
-        # and their copies are elided, so HBM traffic tracks true lengths,
-        # not capacity
-        grid=(batch, kv_heads, capacity // block_c),
+        # a program is a row and `heads` of its kv heads (all of them where
+        # they fit, so the middle axis is 1). The cache-block axis is a GRID
+        # dimension: blocks outside a sequence's live range revisit a
+        # resident block (index-map clip) and their copies are elided, so HBM
+        # traffic tracks true lengths, to the block, not capacity
+        grid=(batch, kv_heads // heads, blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, group, head_dim), lambda b, h, cb, *_: (b, h, 0, 0)
+            (1, heads, group, head_dim), lambda b, hb, step, *_: (b, hb, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),     # running max
-            pltpu.VMEM((group, 128), jnp.float32),     # running denominator
-            pltpu.VMEM((group, head_dim), jnp.float32),  # output accumulator
+            pltpu.VMEM((heads, group, 128), jnp.float32),       # running max
+            pltpu.VMEM((heads, group, 128), jnp.float32),       # running denominator
+            pltpu.VMEM((heads, group, head_dim), jnp.float32),  # output accumulator
         ],
     )
     out = pl.pallas_call(
@@ -437,12 +528,13 @@ def flash_decode(
         ),
         cost_estimate=pl.CostEstimate(
             # flops/transcendentals stay full-capacity: the grid executes
-            # every cache-block step (a dead block revisits a resident block
-            # and its masked compute still runs). bytes_accessed uses the
-            # elided-read convention, same as flash_attention_causal: the
-            # live-block index-map clip copies only ~length/capacity of the
-            # cache from HBM, and lengths are traced (unknown at estimate
-            # time), so charge the mid-generation expectation of capacity/2
+            # every cache-block step, but only the steps inside a row's live
+            # range compute (the pl.when gate), so this is the ceiling.
+            # bytes_accessed uses the elided-read convention, same as
+            # flash_attention_causal: the live-block index-map clip copies
+            # only ~length/capacity of the cache from HBM, rounded up to the
+            # block, and lengths are traced (unknown at estimate time), so
+            # charge the mid-generation expectation of capacity/2
             # (docs/kernels.md "Cost estimates").
             flops=2 * 2 * batch * num_heads * capacity * head_dim,
             # (K + V of the one layer read, halved: the stacked operand's

@@ -780,6 +780,29 @@ class ContinuousBatchingEngine:
         self._m_kernel_config_source.set(
             {"default": 0, "tuned": 1, "env": 2}[kernel_configs.source()]
         )
+        # the tiling the decode-attention kernel resolved for the served cache
+        # (ops/pallas_attention.py::decode_tiling): grid steps a call, slots a
+        # cache block, kv heads a step; all 0 when decode takes an XLA path
+        from prime_tpu.ops.attention import decode_kernel_tiling
+
+        grid_steps, block_c, heads_per_step = decode_kernel_tiling(
+            self.attn_impl, config.n_heads, self._cache.k, self._cache.quantized,
+            mesh=self.mesh,
+        )
+        r.gauge(
+            "serve_decode_attn_grid_steps",
+            "Grid steps one decode-attention kernel call makes over the served "
+            "cache (0 = decode takes the XLA path)",
+        ).set(grid_steps)
+        r.gauge(
+            "serve_decode_attn_block_c",
+            "Cache slots in one block of the decode-attention kernel (0 = XLA path)",
+        ).set(block_c)
+        r.gauge(
+            "serve_decode_attn_heads_per_step",
+            "KV heads of a row one grid step of the decode-attention kernel "
+            "carries (0 = XLA path)",
+        ).set(heads_per_step)
         # disaggregated serving (docs/architecture.md "Disaggregated
         # serving"): prefix-KV segments shipped over the versioned wire
         # format — exports serve GET /admin/kv on a prefill replica, imports

@@ -69,27 +69,66 @@ def _kv(dtype, d=D):
 _SCALE = ((B, KH, 1, C), jnp.float32)
 
 
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` that ``fn`` makes on these (shape,
+    dtype) operands, read off its jaxpr: what ``_compile`` hands to Mosaic."""
+    shapes = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args]
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    return grids
+
+
+def _compile_decode(topo, fn, *args):
+    """Compile a flash-decode call for the v5e and hold its grid to the shape
+    function's answer for the operands: a later edit that falls back to a
+    kv head and 128 slots a step (1024 steps at the served shapes) fails
+    here, on the CPU, before a chip run shows it."""
+    from prime_tpu.ops.pallas_attention import decode_call_tiling
+
+    _compile(topo, fn, *args)
+    (q_shape, _), (k_shape, k_dtype) = args[0], args[1]
+    cache = jax.ShapeDtypeStruct(k_shape, k_dtype)
+    quantized = jnp.dtype(k_dtype).itemsize == 1
+    heads, block_c = decode_call_tiling(q_shape[1], cache, quantized)
+    batch, kv_heads, capacity = k_shape[-4], k_shape[-3], k_shape[-1]
+    grid = (batch, kv_heads // heads, capacity // block_c)
+    assert _pallas_grids(fn, *args) == [grid]
+    return grid
+
+
 @pytest.mark.parametrize(
     "variant",
     ["bf16", "window_sinks", "int8", "int4", "mha", "stacked", "stacked_int8", "latent_64_heads"],
 )
-def test_flash_decode_lowers(topo, variant):
+def test_flash_decode_lowers(topo, variant, monkeypatch):
     from prime_tpu.ops.pallas_attention import flash_decode
 
+    # the tiling under test is the one from the shapes, whatever the
+    # environment of the run says
+    monkeypatch.delenv("PRIME_TPU_BLOCK_C", raising=False)
+    served = {"bf16": (8, 1, 4), "stacked": (8, 1, 4), "latent_64_heads": (64, 1, 4)}
     if variant.startswith("stacked"):
         # what the model's layer scan hands over: the whole (L, B, KH, D, C)
         # buffer and a traced layer for the index maps
         layers, scalar = 4, ((), jnp.int32)
         if variant == "stacked":
             kv = ((layers, B, KH, D, C), jnp.bfloat16)
-            _compile(
+            grid = _compile_decode(
                 topo, lambda q, k, v, n, l: flash_decode(q, k, v, n, layer=l),
                 _Q, kv, kv, _LENS, scalar,
             )
         else:
             kv = ((layers, B, KH, D, C), jnp.int8)
             scale = ((layers, B, KH, 1, C), jnp.float32)
-            _compile(
+            grid = _compile_decode(
                 topo,
                 lambda q, k, v, n, ks, vs, l: flash_decode(
                     q, k, v, n, k_scale=ks, v_scale=vs, layer=l
@@ -100,33 +139,37 @@ def test_flash_decode_lowers(topo, variant):
         # LongCat-Flash's served shape: 64 slots, 64 query heads on ONE latent
         # head of rank + rope = 576, 8 attention rows, the latent as K and V
         kv = ((8, 64, 1, 576, C), jnp.bfloat16)
-        _compile(
+        grid = _compile_decode(
             topo, lambda q, k, n, l: flash_decode(q, k, k, n, layer=l),
             ((64, 64, 1, 576), jnp.bfloat16), kv, ((64,), jnp.int32), ((), jnp.int32),
         )
     elif variant == "bf16":
-        _compile(topo, flash_decode, _Q, _kv(jnp.bfloat16), _kv(jnp.bfloat16), _LENS)
+        grid = _compile_decode(topo, flash_decode, _Q, _kv(jnp.bfloat16), _kv(jnp.bfloat16), _LENS)
     elif variant == "window_sinks":
-        _compile(
+        grid = _compile_decode(
             topo,
             lambda q, k, v, n, s: flash_decode(q, k, v, n, window=1024, sinks=s),
             _Q, _kv(jnp.bfloat16), _kv(jnp.bfloat16), _LENS, ((H,), jnp.float32),
         )
     elif variant == "int8":
-        _compile(
+        grid = _compile_decode(
             topo,
             lambda q, k, v, n, ks, vs: flash_decode(q, k, v, n, k_scale=ks, v_scale=vs),
             _Q, _kv(jnp.int8), _kv(jnp.int8), _LENS, _SCALE, _SCALE,
         )
     elif variant == "int4":
-        _compile(
+        grid = _compile_decode(
             topo,
             lambda q, k, v, n, ks, vs: flash_decode(q, k, v, n, k_scale=ks, v_scale=vs),
             _Q, _kv(jnp.uint8, D // 2), _kv(jnp.uint8, D // 2), _LENS, _SCALE, _SCALE,
         )
     else:  # MHA: group of one query head per kv head
         kv = ((B, H, D, C), jnp.bfloat16)
-        _compile(topo, flash_decode, _Q, kv, kv, _LENS)
+        grid = _compile_decode(topo, flash_decode, _Q, kv, kv, _LENS)
+    # the served shapes, spelled out: 32 steps a call for qwen3-4b (every kv
+    # head of a row, 512 slots), 256 for the latent; both were 1024
+    assert grid == served.get(variant, grid)
+    assert (variant == "mha") == (grid[1] > 1)  # only 32 MHA heads overflow a step
 
 
 @pytest.mark.parametrize("variant", ["bf16", "int8_with_scales", "mla_latent"])

@@ -87,7 +87,9 @@ def test_malformed_artifact_degrades_to_defaults(tmp_path):
         warnings.simplefilter("always")
         assert kernel_configs.load_tuned() is None
     assert any("ignoring kernel config artifact" in str(w.message) for w in caught)
-    assert kernel_configs.resolve("flash_decode", "block_c") == 128
+    # flash-decode's default is "no preference": the block comes from the
+    # cache's shape (pallas_attention.decode_tiling)
+    assert kernel_configs.resolve("flash_decode", "block_c") == 0
     assert kernel_configs.source() == "default"
 
 
@@ -100,7 +102,7 @@ def test_wrong_device_kind_artifact_ignored(tmp_path):
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
         assert kernel_configs.load_tuned() is None
-    assert kernel_configs.resolve("flash_decode", "block_c") == 128
+    assert kernel_configs.resolve("flash_decode", "block_c") == 0
 
 
 # ---- page pool ---------------------------------------------------------------
@@ -282,6 +284,192 @@ def test_int4_decode_dispatch_detects_uint8():
         q, kq, vq, lengths, 0.25, impl="auto", k_scale=ks, v_scale=vs
     )
     assert out.shape == (2, 2, 1, 16) and out.dtype == jnp.float32
+
+
+# ---- flash-decode tiling -------------------------------------------------------
+
+# name: (batch, q heads, kv heads, head_dim, capacity, block_c asked for through
+# the env knob, kv heads a step the VMEM budget is cut to, cache dtype, q dtype,
+# extra)
+_TILING_CASES = {
+    "gqa_all_heads_512": (5, 8, 2, 32, 1024, 512, 2, "float32", "float32", None),
+    "gqa_half_the_heads_256": (5, 8, 4, 32, 1024, 256, 2, "float32", "float32", None),
+    "gqa_bf16_to_the_mxu_as_stored_128": (5, 8, 2, 32, 1024, 128, 2, "bfloat16", "bfloat16", None),
+    "gqa_f32_q_on_a_bf16_cache_512": (5, 8, 2, 32, 1024, 512, 1, "bfloat16", "float32", None),
+    "mha_all_heads_256": (5, 4, 4, 32, 1024, 256, 4, "float32", "float32", None),
+    "mha_one_head_512": (5, 4, 4, 32, 1024, 512, 1, "float32", "float32", None),
+    "latent_one_head_of_576_512": (5, 8, 1, 576, 1024, 512, 1, "bfloat16", "bfloat16", "latent"),
+    "window_sinks_256": (5, 8, 4, 32, 1024, 256, 2, "float32", "float32", "window_sinks"),
+    "softcap_from_the_shapes": (5, 8, 2, 32, 1024, 0, 2, "float32", "float32", "softcap"),
+    "int8_512": (5, 8, 2, 32, 1024, 512, 2, "int8", "float32", None),
+    "int8_one_head_128": (5, 8, 2, 32, 1024, 128, 1, "int8", "float32", None),
+    "int4_half_the_heads_256": (5, 8, 4, 32, 1024, 256, 2, "int4", "float32", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILING_CASES))
+def test_flash_decode_tiling_matches_xla(case, monkeypatch):
+    """The decode kernel at every tiling its shape function can hand out
+    (every kv head of a row or fewer, blocks of 128 / 256 / 512) against the
+    XLA reference, over ragged lengths: 1, a block boundary, boundary + 1, a
+    row inside its second block and full capacity. The block is asked for
+    the way an operator asks (PRIME_TPU_BLOCK_C); the heads a step are cut by
+    shrinking the VMEM budget to what that many heads need."""
+    from prime_tpu.models.llama import quantize_kv
+    from prime_tpu.models.quantize import quantize_kv_int4
+    from prime_tpu.ops import pallas_attention as pa
+    from prime_tpu.ops.attention import decode_attention
+
+    batch, h, kh, d, c, block_c, heads, cache, q_dtype, extra = _TILING_CASES[case]
+    rng = np.random.default_rng(len(case))
+    q = jnp.asarray(rng.normal(size=(batch, h, 1, d)).astype(np.float32)).astype(q_dtype)
+    k = jnp.asarray(rng.normal(size=(batch, kh, d, c)).astype(np.float32))
+    v = k if extra == "latent" else jnp.asarray(
+        rng.normal(size=(batch, kh, d, c)).astype(np.float32)
+    )
+    lengths = jnp.asarray([1, 512, 513, 700, c], dtype=jnp.int32)
+    kw = {}
+    if cache == "int8":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw = dict(k_scale=ks, v_scale=vs)
+    elif cache == "int4":
+        (k, ks), (v, vs) = quantize_kv_int4(k), quantize_kv_int4(v)
+        kw = dict(k_scale=ks, v_scale=vs)
+    else:
+        k, v = k.astype(cache), v.astype(cache)
+    if extra == "window_sinks":
+        kw |= dict(
+            window=300, sliding=jnp.asarray(True),
+            sinks=jnp.asarray(rng.normal(size=(h,)).astype(np.float32)),
+        )
+    elif extra == "softcap":
+        kw |= dict(softcap=30.0)
+
+    quantized = cache in ("int8", "int4")
+    itemsize = k.dtype.itemsize
+    want_c = block_c or 512
+    monkeypatch.setattr(
+        pa, "DECODE_VMEM_BUDGET",
+        pa.decode_step_vmem_bytes(heads, k.shape[2], want_c, itemsize, h // kh, d, quantized),
+    )
+    if block_c:
+        monkeypatch.setenv("PRIME_TPU_BLOCK_C", str(block_c))
+    assert pa.decode_call_tiling(h, k, quantized) == (heads, want_c)
+
+    pa.flash_decode.clear_cache()
+    try:
+        out = pa.flash_decode(q, k, v, lengths, sm_scale=d**-0.5, interpret=True, **kw)
+    finally:
+        pa.flash_decode.clear_cache()  # this trace baked the patched budget in
+    ref = decode_attention(q, k, v, lengths, d**-0.5, impl="xla", **kw)
+    tol = 2e-2 if "bfloat16" in (cache, q_dtype) else 2e-3
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=tol, atol=tol
+    )
+
+
+_SERVED = dict(itemsize=2, capacity=2048)
+
+
+@pytest.mark.parametrize(
+    "name, shape, batch, tiling, steps",
+    [
+        # qwen3-4b: 8 rows x 8 kv heads x 128 x 2048 bf16 (1024 steps a call
+        # until PR 27)
+        ("qwen3_4b", dict(kv_heads=8, kv_dim=128, group=4, head_dim=128, **_SERVED), 8, (8, 512), 32),
+        # LongCat-Flash's latent: 64 rows, one head of rank + rope = 576
+        ("latent", dict(kv_heads=1, kv_dim=576, group=64, head_dim=576, **_SERVED), 64, (1, 512), 256),
+        # a 32-head MHA cache: every head of a row at 512 slots would be 16 MiB
+        # of blocks alone
+        ("mha_32_heads", dict(kv_heads=32, kv_dim=128, group=1, head_dim=128, **_SERVED), 8, (16, 512), 64),
+        ("int8_with_scales", dict(kv_heads=8, kv_dim=128, group=4, head_dim=128, capacity=2048, itemsize=1, quantized=True), 8, (8, 512), 32),
+        ("int4_packed", dict(kv_heads=8, kv_dim=64, group=4, head_dim=128, capacity=2048, itemsize=1, quantized=True), 8, (8, 512), 32),
+        # an explicit block (PRIME_TPU_BLOCK_C, a tuned artifact) is tried first
+        ("prefer_128", dict(kv_heads=8, kv_dim=128, group=4, head_dim=128, prefer=128, **_SERVED), 8, (8, 128), 128),
+        ("prefer_256", dict(kv_heads=8, kv_dim=128, group=4, head_dim=128, prefer=256, **_SERVED), 8, (8, 256), 64),
+        # ... and is harmless where it does not divide the capacity
+        ("prefer_that_does_not_fit", dict(kv_heads=8, kv_dim=128, group=4, head_dim=128, prefer=384, **_SERVED), 8, (8, 512), 32),
+        ("capacity_1536", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=1536, itemsize=4), 4, (2, 512), 12),
+        ("capacity_640", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=640, itemsize=2), 4, (2, 128), 20),
+        ("capacity_no_width_divides", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=320, itemsize=2), 4, (2, 320), 4),
+        # tp=4 hands the kernel a device's 2 local kv heads
+        ("two_local_heads", dict(kv_heads=2, kv_dim=128, group=4, head_dim=128, **_SERVED), 4, (2, 512), 16),
+    ],
+)
+def test_decode_tiling_from_the_shapes(name, shape, batch, tiling, steps):
+    from prime_tpu.ops import pallas_attention as pa
+
+    heads, block_c = pa.decode_tiling(**shape)
+    assert (heads, block_c) == tiling
+    assert shape["kv_heads"] % heads == 0 and shape["capacity"] % block_c == 0
+    assert batch * (shape["kv_heads"] // heads) * (shape["capacity"] // block_c) == steps
+    need = pa.decode_step_vmem_bytes(
+        heads, shape["kv_dim"], block_c, shape["itemsize"], shape["group"],
+        shape["head_dim"], shape.get("quantized", False),
+    )
+    # inside the stated budget, which itself leaves room under a v5e's 16 MiB
+    # scoped default for q, the output, the scratch and spills
+    assert need <= pa.DECODE_VMEM_BUDGET <= 12 * 1024 * 1024
+    # the next wider choice (one more divisor of heads) would not have fit,
+    # unless every head is already in
+    if heads < shape["kv_heads"]:
+        more = min(h for h in range(heads + 1, shape["kv_heads"] + 1) if shape["kv_heads"] % h == 0)
+        assert pa.decode_step_vmem_bytes(
+            more, shape["kv_dim"], block_c, shape["itemsize"], shape["group"],
+            shape["head_dim"], shape.get("quantized", False),
+        ) > pa.DECODE_VMEM_BUDGET
+
+
+def test_decode_kernel_tiling_follows_the_dispatch(monkeypatch):
+    """What the engine publishes as serve_decode_attn_*: the tiling of the
+    call decode_attention makes, zeros where it takes an XLA path."""
+    from prime_tpu.ops.attention import decode_kernel_tiling
+
+    cache = jax.ShapeDtypeStruct((36, 8, 8, 128, 2048), jnp.bfloat16)
+    assert decode_kernel_tiling("xla", 32, cache, False) == (0, 0, 0)
+    assert decode_kernel_tiling("auto", 32, cache, False) == (0, 0, 0)  # CPU backend
+    assert decode_kernel_tiling("pallas", 32, cache, False) == (32, 512, 8)
+    monkeypatch.setenv("PRIME_TPU_BLOCK_C", "128")
+    assert decode_kernel_tiling("pallas", 32, cache, False) == (128, 128, 8)
+    monkeypatch.delenv("PRIME_TPU_BLOCK_C")
+    latent = jax.ShapeDtypeStruct((8, 64, 1, 576, 2048), jnp.bfloat16)
+    assert decode_kernel_tiling("pallas", 64, latent, False) == (256, 512, 1)
+    int4 = jax.ShapeDtypeStruct((36, 8, 8, 64, 2048), jnp.uint8)
+    assert decode_kernel_tiling("pallas", 32, int4, True) == (32, 512, 8)
+    assert decode_kernel_tiling("sharded", 32, cache, False, mesh=None) == (0, 0, 0)
+
+
+@pytest.mark.parametrize(
+    "attn_impl, capacity, want", [("xla", 256, (0, 0, 0)), ("pallas", 256, None), ("pallas", 1024, None)]
+)
+def test_engine_publishes_the_decode_kernels_tiling(attn_impl, capacity, want):
+    """serve_decode_attn_{grid_steps,block_c,heads_per_step}: set once at
+    build from the served cache, 0 when decode takes the XLA path."""
+    from prime_tpu.models import get_config
+    from prime_tpu.models.llama import init_params
+    from prime_tpu.ops import pallas_attention as pa
+    from prime_tpu.serve.engine import ContinuousBatchingEngine
+
+    config = get_config("tiny-test")
+    params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
+    engine = ContinuousBatchingEngine(
+        params, config, max_slots=2, capacity=capacity, chunk=4, prefix_cache_mb=0,
+        attn_impl=attn_impl,
+    )
+    try:
+        got = tuple(
+            int(engine.registry.get(f"serve_decode_attn_{name}").value())
+            for name in ("grid_steps", "block_c", "heads_per_step")
+        )
+    finally:
+        engine.shutdown()
+    if want is None:
+        cache = engine._cache.k
+        heads, block_c = pa.decode_call_tiling(config.n_heads, cache, False)
+        kv_heads = cache.shape[2]
+        assert (heads, block_c) == (kv_heads, min(capacity, 512))
+        want = (2 * (kv_heads // heads) * (capacity // block_c), block_c, heads)
+    assert got == want
 
 
 # ---- autotune ----------------------------------------------------------------
