@@ -25,7 +25,7 @@ import sys as _sys
 __version__ = "0.1.0"
 
 # JAX's persistent compilation cache, placed in ONE spot for every compute
-# entry point (prime serve/train/eval, bench.py, chip_smoke.py's children all
+# entry point (prime serve/train/eval, the benchmark, chip_smoke.py's children all
 # import this package) without importing JAX for the others. Where
 # JAX_COMPILATION_CACHE_DIR is set, nothing is done and no other directory is
 # set in code. Where it is not, the cache lives at <checkout>/.jax_cache: a

@@ -3,7 +3,8 @@
 `delta` renders the committed BENCH_*.json rounds into the per-PR table
 (stdlib-only — safe on machines without jax); `smoke` runs the CPU loadgen
 fleet smoke and writes its SLO report + BENCH-schema record (docs/
-benchmarking.md). The TPU record stays `python bench.py` (v5e only).
+benchmarking.md). The chip is measured by `python3 benchmark/run.py`
+(BENCHMARK.json, PERF.md), not here.
 """
 
 from __future__ import annotations

@@ -39,7 +39,7 @@ class NumericTokenizer:
     ids loadgen feeds engines directly, so an HTTP-driven run and an
     in-process run exercise identical prompt blocks (non-numeric template
     words — role markers from the chat template — hash to stable small
-    ids). Shared by bench.py's fleet section and the loadgen smoke."""
+    ids)."""
 
     def encode(self, text: str, add_special_tokens: bool = True) -> list[int]:
         return [

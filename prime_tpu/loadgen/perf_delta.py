@@ -1,6 +1,8 @@
 """Per-PR perf trajectory: diff committed BENCH_*.json rounds into a table.
 
-Every bench round commits one JSON record (bench.py, last-JSON-line-wins).
+Every bench round committed one JSON record (last-JSON-line-wins; the
+single-chip rounds came from the root-level bench script PR 28 deleted, the
+CPU rounds from ``prime bench smoke``).
 This module — stdlib-only, importable by both ``scripts/perf_delta.py`` and
 ``prime bench delta`` — loads every committed round, labels each with its
 record schema (schema 1: the pre-loadgen rounds, headline-only fields;
@@ -130,7 +132,7 @@ def _slo_metrics(report: dict) -> dict[str, float]:
 
 def _device_profile_metrics(profile: dict) -> dict[str, float]:
     """Flatten a record's ``device_profile`` section (the profiler summary
-    bench.py embeds: per-phase step seconds, cost-model MFU, compile totals)
+    a round embeds: per-phase step seconds, cost-model MFU, compile totals)
     into "dp:"-prefixed rows — disjoint from HEADLINE_METRICS labels like
     the "slo:" rows. Rounds without the section (every pre-profiler
     baseline) simply render "—" for these rows, never an error."""
@@ -164,7 +166,7 @@ def _is_sharded_smoke_record(record: dict[str, Any]) -> bool:
     ``serve_sharded_tok_s`` metric name — so committing one under a
     BENCH_*.json name still routes it to the mc rows instead of rendering
     its sharded headline in the single-chip 'cpu-smoke tok/s' trajectory.
-    Full bench.py records are NOT matched (their sharded section fields are
+    Full single-chip bench records are NOT matched (their sharded section fields are
     ``serve_``-prefixed and their metric is the decode headline): those are
     genuinely mixed records whose family the filename decides."""
     inner = (
@@ -289,7 +291,7 @@ def _multichip_round(path: str, record: dict[str, Any]) -> Round:
         if not record.get("ok") and not record.get("error"):
             record = {**record, "error": f"dryrun failed (rc={record.get('rc')})"}
     else:
-        # the sharded headline: a full bench.py record committed as a
+        # the sharded headline: a full single-chip bench record committed as a
         # MULTICHIP round carries it under serve_sharded_tok_s (its "value"
         # is the single-chip decode headline — the wrong trajectory here);
         # the dedicated loadgen --mesh smoke record carries it as "value"

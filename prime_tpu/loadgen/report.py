@@ -334,8 +334,8 @@ def scenario_row(result) -> dict[str, Any]:
 def spec_comparison_record(
     off_row: dict[str, Any], on_row: dict[str, Any], *, digits: int | None = None
 ) -> dict[str, Any]:
-    """The ONE owner of the spec-on/off record keys both producers publish
-    (bench.py's spec section and the loadgen smoke's): spec-on/off tok/s,
+    """The ONE owner of the spec-on/off record keys the loadgen smoke
+    publishes (and the committed BENCH_*.json rounds hold): spec-on/off tok/s,
     the speedup, the accept ratio, and the TPOT p50 pair — computed from
     two :func:`scenario_row` results over the same schedule. ``digits``
     rounds the tok/s values (bench's historical 1-decimal style)."""

@@ -50,8 +50,7 @@ def loadgen_seed_default() -> int:
 
 def bench_smoke_scale() -> bool:
     """The ``PRIME_BENCH_SMOKE`` knob as loadgen sees it: builders shrink
-    their request counts/lengths to CPU-minutes scale when it is set (the
-    same flag bench.py uses for its own smoke mode)."""
+    their request counts/lengths to CPU-minutes scale when it is set."""
     return env_flag("PRIME_BENCH_SMOKE", False)
 
 
@@ -213,10 +212,9 @@ def schedule_from_prompts(
     *,
     tenant: str = "bench",
 ) -> list[PlannedRequest]:
-    """Wrap an explicit prompt list as a zero-offset burst schedule —
-    bench.py's serve sections keep their historical prompt sets (tuned to
-    exercise specific cache shapes) while riding the loadgen runner/report
-    machinery like every other scenario."""
+    """Wrap an explicit prompt list as a zero-offset burst schedule: a
+    hand-picked prompt set (tuned to exercise specific cache shapes) rides
+    the loadgen runner/report machinery like every other scenario."""
     return [
         PlannedRequest(
             index=i,
@@ -326,7 +324,7 @@ def spec_friendly(seed: int | None = None, **overrides) -> Scenario:
     prompt-lookup speculative decoding: each prompt tiles a short token
     cycle, so greedy continuations settle into loops the n-gram drafter
     predicts and verify windows accept several tokens per dispatch. Run it
-    spec-on vs spec-off (bench.py's spec section, the loadgen smoke) to
+    spec-on vs spec-off (the loadgen smoke does) to
     publish the speedup and accept ratio."""
     seed = loadgen_seed_default() if seed is None else seed
     phase = dict(

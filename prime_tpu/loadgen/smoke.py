@@ -909,7 +909,7 @@ def run_smoke(
     multi-chip serving path on CPU and stamps the record with the mesh, the
     shape a committed ``MULTICHIP_*.json`` round wants (docs/benchmarking.md)."""
     # CPU pin before jax initializes: the smoke must never touch (or wait
-    # for) an accelerator backend, exactly like bench.py's smoke mode
+    # for) an accelerator backend
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import jax

@@ -148,7 +148,7 @@ def xla_attention_causal(
 def _pallas_interpret() -> bool:
     """PRIME_TPU_PALLAS_INTERPRET=1 runs the kernels in interpret mode, so
     the pallas dispatch paths (incl. window/softcap/sink/int8 variants) can
-    be validated off-TPU — bench.py's smoke mode sets it on CPU. With a TPU
+    be validated off-TPU — `chip_smoke.py --rehearsal` sets it on CPU. With a TPU
     backend it is refused: the interpreter on the chip would quietly stand in
     for every compiled kernel."""
     from prime_tpu.core.config import env_flag

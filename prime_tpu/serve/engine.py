@@ -226,6 +226,39 @@ def _power_batches(n: int, cap: int | None = None) -> list[int]:
     return out
 
 
+def _capacity_leaves(cache: Any) -> dict[str, Any]:
+    """The capacity-axis leaves ``cache`` holds, by field name (a bf16 cache
+    has no scales)."""
+    return {
+        name: leaf
+        for name in _CAPACITY_FIELDS
+        if (leaf := getattr(cache, name, None)) is not None
+    }
+
+
+def _map_capacity_leaves(fn: Any, cache: Any, *others: Any) -> Any:
+    """``cache`` with ``fn(leaf, *the same leaf of others)`` in place of each
+    capacity-axis leaf: the one walk over the cache's fields behind placing,
+    pinning and splicing it (lengths is capacity-free and stays)."""
+    return cache._replace(**{
+        name: fn(leaf, *(getattr(other, name) for other in others))
+        for name, leaf in _capacity_leaves(cache).items()
+    })
+
+
+def _constrain(cache: Any, constraint: Any) -> Any:
+    """Pin the capacity-axis leaves of ``cache`` (the engine's cache or a
+    staging row) to ``constraint`` inside a traced program; None pins
+    nothing."""
+    if constraint is None:
+        return cache
+    import jax
+
+    return _map_capacity_leaves(
+        lambda leaf: jax.lax.with_sharding_constraint(leaf, constraint), cache
+    )
+
+
 def _segment_to_host(segment: Any) -> Any:
     """Spill-tier demotion: device KV slices -> host-RAM copies. device_get
     blocks until the segment's producing dispatch finishes and lands plain
@@ -1032,8 +1065,8 @@ class ContinuousBatchingEngine:
         self._digest_at = 0.0
         self.digest_refresh_s = 1.0
 
-    # legacy counter attributes (bench.py and older callers read these as
-    # plain ints) — now views over the registry-backed counters
+    # legacy counter attributes (older callers read these as plain ints) —
+    # now views over the registry-backed counters
     @property
     def prefix_hits(self) -> int:
         return int(self._m_prefix_hits.value())
@@ -1079,14 +1112,9 @@ class ContinuousBatchingEngine:
             from jax.sharding import NamedSharding
 
             sharding = NamedSharding(self.mesh, self.cache_spec)
-            cache = cache._replace(
-                k=jax.device_put(cache.k, sharding), v=jax.device_put(cache.v, sharding)
+            cache = _map_capacity_leaves(
+                lambda leaf: jax.device_put(leaf, sharding), cache
             )
-            if cache.quantized:
-                cache = cache._replace(
-                    k_scale=jax.device_put(cache.k_scale, sharding),
-                    v_scale=jax.device_put(cache.v_scale, sharding),
-                )
         # lengths ride inside the cache pytree (one donated unit per dispatch)
         self._cache = cache
         self._last = jnp.zeros((self.max_slots,), dtype=jnp.int32)
@@ -1205,20 +1233,6 @@ class ContinuousBatchingEngine:
             return 0
         return (2 if self.overlap else 1) * (self.draft_len + 1)
 
-    def _constrain_row_fields(self, row, constraint):
-        """Apply ``constraint`` to a staging row's capacity-axis leaves
-        inside a traced program (lengths is capacity-free and skipped)."""
-        if constraint is None:
-            return row
-        import jax
-
-        updates = {}
-        for name in _CAPACITY_FIELDS:
-            leaf = getattr(row, name, None)
-            if leaf is not None:
-                updates[name] = jax.lax.with_sharding_constraint(leaf, constraint)
-        return row._replace(**updates) if updates else row
-
     # ---- compiled programs ----
 
     def _make_chunk_prefill(self):
@@ -1229,7 +1243,6 @@ class ContinuousBatchingEngine:
 
         config, attn_impl, mesh = self.config, self.attn_impl, self.mesh
         row_constraint = self._row_constraint()
-        constrain = self._constrain_row_fields
         moe_counts = self._moe
 
         def chunk_prefill(params, adapters, row, tokens, offset, last_in_chunk, wave_ids):
@@ -1256,7 +1269,7 @@ class ContinuousBatchingEngine:
             )
             # sharded replica: pin the staged row's kv-head/tp placement so
             # the prefix segments sliced from it stay sharded in the cache
-            return (constrain(row, row_constraint), logits, *stats)
+            return (_constrain(row, row_constraint), logits, *stats)
 
         return jax.jit(chunk_prefill, donate_argnums=(2,))
 
@@ -1300,20 +1313,7 @@ class ContinuousBatchingEngine:
                 )
                 if stats:
                     stats = [stats[0] + step_stats[0]]
-                if cache_spec is not None:
-                    new_cache = new_cache._replace(
-                        k=jax.lax.with_sharding_constraint(new_cache.k, cache_spec),
-                        v=jax.lax.with_sharding_constraint(new_cache.v, cache_spec),
-                    )
-                    if new_cache.quantized:
-                        new_cache = new_cache._replace(
-                            k_scale=jax.lax.with_sharding_constraint(
-                                new_cache.k_scale, cache_spec
-                            ),
-                            v_scale=jax.lax.with_sharding_constraint(
-                                new_cache.v_scale, cache_spec
-                            ),
-                        )
+                new_cache = _constrain(new_cache, cache_spec)
                 # inactive slots must not advance: their next admission
                 # prefills the slot from position 0 again
                 new_cache = new_cache._replace(
@@ -1404,19 +1404,7 @@ class ContinuousBatchingEngine:
                 attn_impl=attn_impl, prefill_offset=offsets, mesh=mesh,
                 adapters=adapters, adapter_ids=adapter_slots,
             )
-            if cache_spec is not None:
-                constrained = {
-                    "k": jax.lax.with_sharding_constraint(new_cache.k, cache_spec),
-                    "v": jax.lax.with_sharding_constraint(new_cache.v, cache_spec),
-                }
-                if new_cache.quantized:
-                    constrained["k_scale"] = jax.lax.with_sharding_constraint(
-                        new_cache.k_scale, cache_spec
-                    )
-                    constrained["v_scale"] = jax.lax.with_sharding_constraint(
-                        new_cache.v_scale, cache_spec
-                    )
-                new_cache = new_cache._replace(**constrained)
+            new_cache = _constrain(new_cache, cache_spec)
 
             rng, accept_rng, fix_rng = jax.random.split(rng, 3)
             tokens_round, n_acc = verify_window_tokens(
@@ -1486,54 +1474,6 @@ class ContinuousBatchingEngine:
             _upload(lengths, np.int32),
             _upload(slots, np.int32), firsts,
         )
-
-    def _dispatch_spec(self) -> None:
-        """Launch one fused speculative chunk on the last-known active mask
-        and return without waiting — the spec-mode twin of _dispatch_decode.
-        The run lengths ride the _InflightChunk as a device array; the sync
-        path slices each slot's emissions by them."""
-        import jax
-        import jax.numpy as jnp
-
-        self._maybe_inject_delay()
-        if self._spec_fn is None:
-            self._spec_fn = self._make_spec_decode()
-        seq = next(self._chunk_seq)
-        # the phase holds all of the enqueue: the rng split and the mask's
-        # upload are dispatches of their own, and the device waits for them
-        with self._clock.span(
-            "serve.spec_dispatch", "dispatch", seq=seq, draft_len=self.draft_len,
-            **self._span_mesh,
-        ), self._mesh_ctx():
-            self._rng, rng = jax.random.split(self._rng)
-            mask = self._active.copy()
-            args = (
-                self.params, self._adapters, self._cache, self._hist,
-                self._hist_len, self._last, self._temps, self._top_ps,
-                jnp.asarray(mask), self._adapter_slots, rng,
-            )
-            with self.profiler.step(
-                "spec", pre=self._last, batch=int(mask.sum()),
-                steps=self.draft_len + 1, cost_fn=self._spec_fn, cost_args=args,
-            ) as prof_step:
-                (
-                    self._cache, self._hist, self._hist_len, self._last, toks, run_len,
-                ) = self._spec_fn(*args)
-                prof_step.fence(toks)
-        self._inflight.append(
-            _InflightChunk(
-                seq=seq, toks=toks, mask=mask,
-                requests=dict(self._requests),
-                dispatched_at=self._clock.switched_at, run_len=run_len,
-            )
-        )
-        self._m_inflight_depth.set(len(self._inflight))
-
-    def _spec_chunk(self) -> None:
-        """Serial speculative step: the fused dispatch synced immediately —
-        the bit-identity reference the pipelined path is pinned against."""
-        self._dispatch_spec()
-        self._sync_decode()
 
     # ---- AOT warmup ----
 
@@ -1727,13 +1667,8 @@ class ContinuousBatchingEngine:
                             self.config, 1, seg_len, dtype=self._dtype,
                             quantized=self.kv_quant,
                         )
-                        segment = {
-                            f: getattr(donor, f)
-                            for f in _CAPACITY_FIELDS
-                            if getattr(donor, f) is not None
-                        }
                         assembled = self._assemble_fn(
-                            (segment,), (seg_len,), row_cb
+                            (_capacity_leaves(donor),), (seg_len,), row_cb
                         )
                         jax.block_until_ready(assembled.k)
                         dispatches += 1
@@ -2094,10 +2029,7 @@ class ContinuousBatchingEngine:
         did = False
         try:
             if any(self._active):
-                if self.speculative:
-                    self._dispatch_spec()
-                else:
-                    self._dispatch_decode()
+                self._dispatch_decode()
                 did = True
             # one-deep pipeline: with a fresh chunk dispatched, sync the
             # previous one now (its host work overlaps the new chunk's device
@@ -2106,15 +2038,7 @@ class ContinuousBatchingEngine:
                 self._sync_decode()
                 did = True
         except Exception as e:  # noqa: BLE001 — a dead engine hangs every client
-            # the decode jit donates the cache buffers, so a raised dispatch
-            # or sync leaves them (and any in-flight lookahead chunk) invalid:
-            # drop the pipeline, fail the in-flight requests promptly, and
-            # reallocate device state so the engine keeps serving. Recovery is
-            # always synchronous — _init_device_state must not race an
-            # in-flight donated dispatch.
-            self._fail_in_flight(f"decode failed: {e}")
-            self._init_device_state()
-            return True
+            return self._recover_decode(e)
         with self._clock.enter("admit"):
             self._retire_cancelled()
             admitted = self._admit()
@@ -2124,28 +2048,34 @@ class ContinuousBatchingEngine:
         return admitted or did
 
     def _tick_sync(self) -> bool:
-        """The strictly serial loop: admit, then decode (or speculate) one
-        chunk and block for its tokens before any emit/admission work."""
+        """The strictly serial loop: admit, then dispatch one chunk and block
+        for its tokens before any emit/admission work — the bit-identity
+        reference the pipelined order is pinned against."""
         with self._clock.enter("admit"):
             admitted = self._admit()
             self._retire_cancelled()
         if not any(self._active):
             return admitted
         try:
-            if self.speculative:
-                self._spec_chunk()  # fused dispatch, synced immediately
-            else:
-                self._decode_chunk()
+            self._dispatch_decode()
+            self._sync_decode()
         except Exception as e:  # noqa: BLE001 — a dead engine hangs every client
-            # the decode jit donates the cache buffers, so a raised dispatch
-            # leaves them invalid: fail the in-flight requests promptly and
-            # reallocate device state so the engine keeps serving
-            self._fail_in_flight(f"decode failed: {e}")
-            self._init_device_state()
+            return self._recover_decode(e)
+        return True
+
+    def _recover_decode(self, e: Exception) -> bool:
+        """The chunk programs donate the cache buffers, so a raised dispatch
+        or sync leaves them (and any in-flight lookahead chunk) invalid: drop
+        the pipeline, fail the in-flight requests promptly, and reallocate
+        device state so the engine keeps serving. Recovery is always
+        synchronous — _init_device_state must not race an in-flight donated
+        dispatch. Returns True: the tick did something."""
+        self._fail_in_flight(f"decode failed: {e}")
+        self._init_device_state()
         return True
 
     def _maybe_inject_delay(self) -> None:
-        """PRIME_SENTINEL_INJECT_MS hook (all three dispatch paths): counts
+        """PRIME_SENTINEL_INJECT_MS hook (every chunk dispatch): counts
         dispatches and, once past the activation threshold, stalls the host
         for the configured delay so the step clock and TPOT genuinely
         regress mid-run. A no-op (one int increment) when the knob is
@@ -2155,26 +2085,44 @@ class ContinuousBatchingEngine:
             time.sleep(self._inject_delay_s)
 
     def _dispatch_decode(self) -> None:
-        """Launch one decode chunk and return WITHOUT waiting for it: the
-        tokens stay on the device inside an _InflightChunk until
-        _sync_decode fetches them. JAX's async dispatch makes this the whole
-        pipeline — the host returns as soon as the computation is enqueued."""
+        """Launch one chunk and return WITHOUT waiting for it: the tokens
+        stay on the device inside an _InflightChunk until _sync_decode
+        fetches them. JAX's async dispatch makes this the whole pipeline —
+        the host returns as soon as the computation is enqueued. A
+        speculative engine launches its fused propose+verify program the
+        same way: the history ring rides beside the cache, and the run
+        lengths ride the _InflightChunk as a device array the sync path
+        slices each slot's emissions by."""
         import jax
         import jax.numpy as jnp
 
         self._maybe_inject_delay()
-        if self._decode_fn is None:
-            self._decode_fn = self._make_decode()
         seq = next(self._chunk_seq)
+        spec = self.speculative
+        if spec:
+            if self._spec_fn is None:
+                self._spec_fn = self._make_spec_decode()
+            fn, kind, steps = self._spec_fn, "spec", self.draft_len + 1
+            span = self._clock.span(
+                "serve.spec_dispatch", "dispatch", seq=seq,
+                draft_len=self.draft_len, **self._span_mesh,
+            )
+        else:
+            if self._decode_fn is None:
+                self._decode_fn = self._make_decode()
+            fn, kind, steps = self._decode_fn, "decode", self.chunk
+            span = self._clock.span(
+                "serve.dispatch", "dispatch", seq=seq, steps=self.chunk,
+                **self._span_mesh,
+            )
         # the phase holds all of the enqueue: the rng split and the mask's
         # upload are dispatches of their own, and the device waits for them
-        with self._clock.span(
-            "serve.dispatch", "dispatch", seq=seq, steps=self.chunk, **self._span_mesh
-        ), self._mesh_ctx():
+        with span, self._mesh_ctx():
             self._rng, rng = jax.random.split(self._rng)
             mask = self._active.copy()
+            hist = (self._hist, self._hist_len) if spec else ()
             args = (
-                self.params, self._adapters, self._cache, self._last,
+                self.params, self._adapters, self._cache, *hist, self._last,
                 self._temps, self._top_ps, jnp.asarray(mask),
                 self._adapter_slots, rng,
             )
@@ -2183,17 +2131,24 @@ class ContinuousBatchingEngine:
             # readiness, and captures its XLA cost analysis once. Inactive
             # profiler -> shared no-op: zero added syncs on the overlap path.
             with self.profiler.step(
-                "decode", pre=self._last, batch=int(mask.sum()),
-                steps=self.chunk, cost_fn=self._decode_fn, cost_args=args,
+                kind, pre=self._last, batch=int(mask.sum()),
+                steps=steps, cost_fn=fn, cost_args=args,
             ) as prof_step:
-                self._cache, self._last, toks, *stats = self._decode_fn(*args)
+                if spec:
+                    (
+                        self._cache, self._hist, self._hist_len, self._last,
+                        toks, run_len,
+                    ) = fn(*args)
+                    carried = {"run_len": run_len}
+                else:
+                    self._cache, self._last, toks, *stats = fn(*args)
+                    carried = {"moe_stats": stats[0] if stats else None}
                 prof_step.fence(toks)
         self._inflight.append(
             _InflightChunk(
                 seq=seq, toks=toks, mask=mask,
                 requests=dict(self._requests),
-                dispatched_at=self._clock.switched_at,
-                moe_stats=stats[0] if stats else None,
+                dispatched_at=self._clock.switched_at, **carried,
             )
         )
         self._m_inflight_depth.set(len(self._inflight))
@@ -2301,10 +2256,10 @@ class ContinuousBatchingEngine:
             if not burst:
                 return admitted
             # cold requests sharing a (row capacity, chunk plan) batch
-            # together; prefix-cache hits keep the per-request path (their
-            # plans start mid-prompt and their seeded rows differ)
+            # together; a prefix-cache hit is a wave of one (its plan starts
+            # mid-prompt and its seeded row is its own)
             groups: dict[tuple, list[EngineRequest]] = {}
-            singles: list[EngineRequest] = []
+            hits: list[tuple[EngineRequest, int, list[int]]] = []
             for req in burst:
                 ids = req.prompt_ids
                 try:
@@ -2312,25 +2267,23 @@ class ContinuousBatchingEngine:
                         len(ids), self.prefill_chunk, self.capacity
                     )
                 except ValueError as e:
-                    req.error = f"prefill failed: {e}"
-                    req.done = True
-                    self._retire_flight(req, "failed", error=str(e)[:200])
-                    req.events.put(None)
+                    self._fail_admission([req], e)
                     continue
-                if self._prefix_match_len(self._prefix_key(ids, req.adapter_idx)) > 0:
-                    singles.append(req)
+                key = self._prefix_key(ids, req.adapter_idx)
+                if self._prefix_match_len(key) > 0:
+                    hits.append((req, row_cb, key))
                 else:
                     plan = tuple(chunk_plan(0, len(ids), self.prefill_chunk, row_cb))
                     groups.setdefault((row_cb, plan), []).append(req)
-            for req in singles:
+            for req, row_cb, key in hits:
                 try:
-                    self._prefill(req, free.pop(0))
+                    with self._clock.enter("prefix_seed"):
+                        start, row = self._prefix_seed(key, row_cb, ctx=req.trace)
+                    plan = chunk_plan(start, len(key), self.prefill_chunk, row_cb)
+                    self._prefill([req], [free.pop(0)], row_cb, plan, row, start)
                     admitted = True
                 except Exception as e:  # noqa: BLE001 — keep the loop alive
-                    req.error = f"prefill failed: {e}"
-                    req.done = True
-                    self._retire_flight(req, "failed", error=str(e)[:200])
-                    req.events.put(None)
+                    self._fail_admission([req], e)
             for (row_cb, plan), reqs in groups.items():
                 # power-of-two sub-batches (largest first): the compile set
                 # per plan stays O(log slots) instead of one program per
@@ -2340,131 +2293,44 @@ class ContinuousBatchingEngine:
                 for size in _power_batches(len(reqs), self.max_wave):
                     sub, remaining = remaining[:size], remaining[size:]
                     try:
-                        # size 1 rides the same path (batch-1 shapes are
-                        # identical to the old single-request prefill, and
-                        # the plan is already computed)
                         slots = [free.pop(0) for _ in sub]
-                        self._prefill_batch(sub, slots, row_cb, list(plan))
+                        self._prefill(sub, slots, row_cb, list(plan))
                         admitted = True
                     except Exception as e:  # noqa: BLE001 — keep the loop alive
-                        for req in sub:
-                            req.error = f"prefill failed: {e}"
-                            req.done = True
-                            self._retire_flight(req, "failed", error=str(e)[:200])
-                            req.events.put(None)
+                        self._fail_admission(sub, e)
 
-    def _prefill(self, req: EngineRequest, slot: int) -> None:
-        import jax
-        import jax.numpy as jnp
+    def _fail_admission(self, reqs: list[EngineRequest], e: Exception) -> None:
+        """A raised admission fails its own requests and nothing else: the
+        loop stays alive for the rest of the burst."""
+        for req in reqs:
+            req.error = f"prefill failed: {e}"
+            req.done = True
+            self._retire_flight(req, "failed", error=str(e)[:200])
+            req.events.put(None)
 
-        if self._chunk_fn is None:
-            self._chunk_fn = self._make_chunk_prefill()
-        if self._finalize_batch_fn is None:
-            self._finalize_batch_fn = self._make_finalize_batch()
-        ids = req.prompt_ids
-        t_start = time.monotonic()
-        if req.submitted_at:
-            wait = t_start - req.submitted_at
-            self._m_queue_wait.observe(wait)
-            if self.adapter_bank is not None:
-                self._m_adapter_queue_wait.observe(
-                    wait, adapter=req.adapter or "base"
-                )
-            TRACER.emit("serve.queue_wait", wait, context=req.trace, request=req.id)
-        req.admitted_at = t_start
-        self.flight.event(req.id, "admitted", slot=slot)
-        row_cb = row_capacity_for(len(ids), self.prefill_chunk, self.capacity)
-        with self._clock.enter("prefix_seed"):
-            start, row = self._prefix_seed(
-                self._prefix_key(ids, req.adapter_idx), row_cb, ctx=req.trace
-            )
-        plan = chunk_plan(start, len(ids), self.prefill_chunk, row_cb)
-        logits = None
-        moe_stats = None
-        self._rng, rng = jax.random.split(self._rng)
-        with self._clock.span(
-            "serve.prefill", "prefill", context=req.trace, slot=slot,
-            prompt_len=len(ids), request=req.id, **self._span_mesh,
-        ), self._mesh_ctx(), self.profiler.step(
-            "prefill", pre=self._last, batch=1, steps=len(ids),
-        ) as prof_step:
-            for off, size in plan:
-                chunk_ids = ids[off : off + size]
-                chunk_ids += [self.pad_id] * (size - len(chunk_ids))
-                tokens = _upload([chunk_ids], np.int32)
-                # chunk-relative last prompt position, clamped into this
-                # chunk: the gathered row only matters for the final chunk
-                # (finalize consumes that one), clamping keeps earlier
-                # chunks' gathers in bounds
-                rel = min(max(len(ids) - 1 - off, 0), size - 1)
-                chunk_args = (
-                    self.params, self._adapters, row, tokens,
-                    _upload(off, np.int32),
-                    _upload([rel], np.int32),
-                    _upload([req.adapter_idx], np.int32),
-                )
-                self.profiler.note_cost("prefill", self._chunk_fn, chunk_args)
-                row, logits, *stats = self._chunk_fn(*chunk_args)
-                moe_stats = self._sum_moe(moe_stats, stats)
-            # the batch finalize IS the single finalize at n=1 — one owner
-            # of the splice/sample/bookkeeping semantics
-            (
-                self._cache, self._last, self._temps, self._top_ps,
-                self._adapter_slots, firsts,
-            ) = self._finalize_batch_fn(
-                self._cache, self._last, self._temps, self._top_ps,
-                self._adapter_slots, row, logits,
-                _upload([len(ids)], np.int32),
-                _upload([slot], np.int32),
-                _upload([req.temperature], np.float32),
-                _upload([req.top_p], np.float32),
-                _upload([req.adapter_idx], np.int32),
-                rng,
-            )
-            prof_step.fence(firsts)
-        if self.speculative:
-            # seed the device history ring before the host sync below — the
-            # seed dispatch rides the same device queue as finalize, so the
-            # first spec chunk can draft from the prompt immediately
-            with self._mesh_ctx():
-                self._seed_hist([req], [len(ids)], [slot], firsts)
-        with self._clock.enter("prefill_sync"):
-            first = int(np.asarray(firsts)[0])  # host sync: the prefill really finished here
-            if moe_stats is not None:
-                self._count_moe(moe_stats, "prefill")
-        prefill_s = self._clock.switched_at - t_start
-        self._m_prefill_s.observe(prefill_s)
-        self.flight.event(
-            req.id, "prefill_done", ms=round(prefill_s * 1e3, 3),
-            prefix_hit_tokens=start,
-        )
-        self._m_admit_batch.observe(1)
-        self._store_prefix(self._prefix_key(ids, req.adapter_idx), row)
-        self._m_admitted.inc()
-        req.slot = slot
-        self._active[slot] = True
-        self._requests[slot] = req
-        with self._clock.enter("emit"):
-            self._emit(req, [first])
-
-    def _prefill_batch(
+    def _prefill(
         self,
         reqs: list[EngineRequest],
         slots: list[int],
         row_cb: int,
         plan: list[tuple[int, int]],
+        row: Any = None,
+        hit_tokens: int = 0,
     ) -> None:
-        """Admit a whole burst of cold same-plan requests in one batched
-        prefill: the chunk forwards run at batch N (weights stream once per
-        wave, not once per request) and ONE finalize dispatch splices every
-        staged row and samples every first token. The prefix cache is seeded
-        from the FIRST member's row only (slicing every member costs tree
-        ops per request) — enough that a recurring shared-prefix burst
-        prefix-hits from its second wave on — unless ``prefix_store_all``
-        (prefill-role replicas) asks for every member's path to be
-        exportable."""
+        """Admit a wave of same-plan requests in one batched prefill: the
+        chunk forwards run at batch N (weights stream once per wave, not
+        once per request) and ONE finalize dispatch splices every staged row
+        and samples every first token. A cold wave starts from empty rows; a
+        prefix hit is a wave of one whose ``row`` _admit seeded with its
+        first ``hit_tokens`` positions and whose plan starts there. The
+        prefix cache is fed from the FIRST member's row only (slicing every
+        member costs tree ops per request) — enough that a recurring
+        shared-prefix burst prefix-hits from its second wave on — unless
+        ``prefix_store_all`` (prefill-role replicas) asks for every member's
+        path to be exportable. The store comes BEFORE the wait for the first
+        tokens, so its host time (and the programs it builds on first use)
+        runs while the chip is still busy with the prefill."""
         import jax
-        import jax.numpy as jnp
 
         from prime_tpu.models.llama import init_cache
 
@@ -2486,15 +2352,17 @@ class ContinuousBatchingEngine:
             req.admitted_at = t_start
             self.flight.event(req.id, "admitted", slot=slot, wave=n)
         self._rng, rng = jax.random.split(self._rng)
-        row = init_cache(self.config, n, row_cb, dtype=self._dtype, quantized=self.kv_quant)
+        if row is None:
+            row = init_cache(self.config, n, row_cb, dtype=self._dtype, quantized=self.kv_quant)
+        lengths = [len(r.prompt_ids) for r in reqs]
+        adapter_ids = _upload([r.adapter_idx for r in reqs], np.int32)
         logits = None
         moe_stats = None
         with self._clock.span(
-            "serve.prefill_batch", "prefill", batch=n, row_capacity=row_cb,
+            "serve.prefill", "prefill", batch=n, row_capacity=row_cb,
             **self._span_mesh,
         ), self._mesh_ctx(), self.profiler.step(
-            "prefill", pre=self._last, batch=n,
-            steps=max(len(r.prompt_ids) for r in reqs),
+            "prefill", pre=self._last, batch=n, steps=max(lengths),
         ) as prof_step:
             for off, size in plan:
                 chunk_rows = []
@@ -2504,13 +2372,17 @@ class ContinuousBatchingEngine:
                     chunk_ids = ids[off : off + size]
                     chunk_ids = list(chunk_ids) + [self.pad_id] * (size - len(chunk_ids))
                     chunk_rows.append(chunk_ids)
+                    # chunk-relative last prompt position, clamped into this
+                    # chunk: the gathered row only matters for the final
+                    # chunk (finalize consumes that one), clamping keeps
+                    # earlier chunks' gathers in bounds
                     rels.append(min(max(len(ids) - 1 - off, 0), size - 1))
-                tokens = _upload(chunk_rows, np.int32)
                 chunk_args = (
-                    self.params, self._adapters, row, tokens,
+                    self.params, self._adapters, row,
+                    _upload(chunk_rows, np.int32),
                     _upload(off, np.int32),
                     _upload(rels, np.int32),
-                    _upload([r.adapter_idx for r in reqs], np.int32),
+                    adapter_ids,
                 )
                 self.profiler.note_cost("prefill", self._chunk_fn, chunk_args)
                 row, logits, *stats = self._chunk_fn(*chunk_args)
@@ -2521,27 +2393,27 @@ class ContinuousBatchingEngine:
             ) = self._finalize_batch_fn(
                 self._cache, self._last, self._temps, self._top_ps,
                 self._adapter_slots, row, logits,
-                _upload([len(r.prompt_ids) for r in reqs], np.int32),
+                _upload(lengths, np.int32),
                 _upload(slots, np.int32),
                 _upload([r.temperature for r in reqs], np.float32),
                 _upload([r.top_p for r in reqs], np.float32),
-                _upload([r.adapter_idx for r in reqs], np.int32),
+                adapter_ids,
                 rng,
             )
             prof_step.fence(firsts)
         if self.speculative:
+            # seed the device history ring before the host sync below — the
+            # seed dispatch rides the same device queue as finalize, so the
+            # first spec chunk can draft from the prompt immediately
             with self._mesh_ctx():
-                self._seed_hist(
-                    reqs, [len(r.prompt_ids) for r in reqs], slots, firsts
-                )
-        # lazy per-leaf slices: member 0 only by default (a handful of tiny
-        # ops per WAVE — enough that a recurring shared-prefix burst hits
-        # from its second wave on); EVERY member on a prefix_store_all
-        # (prefill-role) engine, whose exports must cover batched admissions
-        # (none without a prefix cache: each slice is a copy and a program)
+                self._seed_hist(reqs, lengths, slots, firsts)
+        # member 0 only by default, EVERY member on a prefix_store_all
+        # (prefill-role) engine, whose exports must cover batched admissions;
+        # none without a prefix cache. A wave of one stores its row as it is:
+        # taking a member out of a wider row is a copy and a program a shape
         stored = 0 if self.prefix_cache is None else n if self.prefix_store_all else 1
         for i in range(stored):
-            row_i = jax.tree_util.tree_map(
+            row_i = row if n == 1 else jax.tree_util.tree_map(
                 lambda x, i=i: x[:, i : i + 1] if x.ndim >= 2 else x[i : i + 1],
                 row,
             )
@@ -2549,24 +2421,28 @@ class ContinuousBatchingEngine:
                 self._prefix_key(reqs[i].prompt_ids, reqs[i].adapter_idx), row_i
             )
         with self._clock.enter("prefill_sync"):
-            firsts_host = [int(t) for t in np.asarray(firsts)]  # host sync
+            # host sync: the prefill really finished here
+            firsts_host = [int(t) for t in np.asarray(firsts)]
             if moe_stats is not None:
                 self._count_moe(moe_stats, "prefill")
         prefill_s = self._clock.switched_at - t_start
         prefill_ms = round(prefill_s * 1e3, 3)
         self._m_prefill_s.observe(prefill_s)
         for req in reqs:
-            self.flight.event(req.id, "prefill_done", ms=prefill_ms, wave=n)
+            self.flight.event(
+                req.id, "prefill_done", ms=prefill_ms, wave=n,
+                prefix_hit_tokens=hit_tokens,
+            )
             # per-request prefill attribution under each request's OWN trace
-            # (the batched wave span above is process-local): the wave's wall
-            # time is every member's prefill time — they shared the dispatch
+            # (the wave span above is process-local): the wave's wall time is
+            # every member's prefill time — they shared the dispatch
             TRACER.emit(
                 "serve.prefill", prefill_s, context=req.trace,
                 request=req.id, batch=n, prompt_len=len(req.prompt_ids),
                 **self._span_mesh,
             )
         self._m_admit_batch.observe(n)
-        self._m_admitted.inc(len(reqs))
+        self._m_admitted.inc(n)
         if n > 1:
             self._m_batched_waves.inc()
         with self._clock.enter("emit"):
@@ -2599,19 +2475,9 @@ class ContinuousBatchingEngine:
                         acc, row_i, (zero, slots[i], zero, zero, zero)
                     )
 
-                out = jax.lax.fori_loop(0, n, body, cache_leaf)
-                if cache_spec is not None:
-                    out = jax.lax.with_sharding_constraint(out, cache_spec)
-                return out
+                return jax.lax.fori_loop(0, n, body, cache_leaf)
 
-            new_cache = cache._replace(
-                k=splice_all(cache.k, rows.k), v=splice_all(cache.v, rows.v)
-            )
-            if cache.quantized:
-                new_cache = new_cache._replace(
-                    k_scale=splice_all(cache.k_scale, rows.k_scale),
-                    v_scale=splice_all(cache.v_scale, rows.v_scale),
-                )
+            new_cache = _constrain(_map_capacity_leaves(splice_all, cache, rows), cache_spec)
             firsts = _sample_batch(logits[:, 0, :], temps_new, top_ps_new, rng)
             # the first sampled tokens' KV is not in the cache yet: the next
             # decode step writes each at position ``length`` (put() scatters
@@ -2682,15 +2548,10 @@ class ContinuousBatchingEngine:
 
         config, dtype, quantized = self.config, self._dtype, self.kv_quant
         row_constraint = self._row_constraint()
-        constrain = self._constrain_row_fields
 
         def assemble(segments, takes, target_cb):
             row = init_cache(config, 1, target_cb, dtype=dtype, quantized=quantized)
-            out = {
-                f: getattr(row, f)
-                for f in _CAPACITY_FIELDS
-                if getattr(row, f) is not None
-            }
+            out = _capacity_leaves(row)
             off = 0
             for seg, take in zip(segments, takes):
                 for name, leaf in seg.items():
@@ -2703,7 +2564,7 @@ class ContinuousBatchingEngine:
             # Sharded replica: the assembled row keeps the segments' tp
             # placement (cached segments were sliced from constrained rows),
             # so a prefix hit never funnels KV through one device.
-            return constrain(row._replace(**out), row_constraint)
+            return _constrain(row._replace(**out), row_constraint)
 
         return jax.jit(assemble, static_argnums=(1, 2))
 
@@ -2846,10 +2707,7 @@ class ContinuousBatchingEngine:
 
         def slicer(start: int, stop: int):
             out = {}
-            for name in _CAPACITY_FIELDS:
-                leaf = getattr(row, name)
-                if leaf is None:
-                    continue
+            for name, leaf in _capacity_leaves(row).items():
                 assert leaf.shape[-1] == src_cb, f"{name} is not capacity-major"
                 out[name] = leaf[..., start:stop]
             return out
@@ -3073,45 +2931,6 @@ class ContinuousBatchingEngine:
         self._m_kv_import_bytes.inc(added)
         self._sync_prefix_metrics()
         return added
-
-    def _decode_chunk(self) -> None:
-        import jax.numpy as jnp
-
-        import jax
-
-        self._maybe_inject_delay()
-        if self._decode_fn is None:
-            self._decode_fn = self._make_decode()
-        with self._clock.span(
-            "serve.decode_chunk", "dispatch", steps=self.chunk, **self._span_mesh
-        ), self._mesh_ctx():
-            t_start = self._clock.switched_at
-            self._rng, rng = jax.random.split(self._rng)
-            args = (
-                self.params, self._adapters, self._cache, self._last,
-                self._temps, self._top_ps, jnp.asarray(self._active),
-                self._adapter_slots, rng,
-            )
-            with self.profiler.step(
-                "decode", batch=int(np.sum(self._active)), steps=self.chunk,
-                cost_fn=self._decode_fn, cost_args=args,
-            ) as prof_step:
-                self._cache, self._last, toks, *stats = self._decode_fn(*args)
-                prof_step.fence(toks)
-                with self._clock.enter("sync_wait"):
-                    toks_host = np.asarray(toks)  # (S, T) — host sync inside the span
-                    if stats:
-                        self._count_moe(stats[0], "decode")
-        # the serial loop blocks for all of its window: stall == window
-        window_s = self._clock.switched_at - t_start
-        self._m_chunk_window_s.inc(window_s)
-        self._m_decode_step_s.observe(window_s / self.chunk)
-        with self._clock.enter("emit"):
-            for slot in range(self.max_slots):
-                if self._active[slot]:
-                    req = self._requests[slot]
-                    self.flight.event(req.id, "chunk")
-                    self._emit(req, toks_host[slot].tolist())
 
     def _emit(self, req: EngineRequest, token_ids: list[int]) -> None:
         """Feed decoded ids to the request, honoring EOS and max_new_tokens;

@@ -1,16 +1,12 @@
-"""Phase-level profile of the continuous-batching engine's bench scenario.
+"""Offline reports over what a serve run leaves behind.
 
-Answers ONE question: where does the serve bench's wall-clock go on the real
-chip — admissions (prefill dispatches), decode dispatch/sync, or mid-run XLA
-compiles? The serve roofline in bench.py says ~2% of HBM peak, which means
-the engine is host/dispatch-bound there, not bandwidth-bound; this script
-attributes the time so the fix targets the right layer.
+Where a live engine's wall-clock goes is the phase clock's to say
+(``serve_loop_seconds_total{phase}``, always on; docs/observability.md
+"Engine loop phases"), and which phase each device idle gap fell in is
+``scripts/loop_gaps.py``'s; what the benchmark measures is
+``python3 benchmark/run.py`` and ``PERF.md``. This script reads files and
+scrapes a router:
 
-Two modes:
-
-- ``python scripts/serve_profile.py``  (single real chip; ~2 min) — run the
-  bench serve scenario with per-phase timers. Set ``PRIME_TRACE=trace.jsonl``
-  first and the run also leaves a span log the second mode can analyze.
 - ``python scripts/serve_profile.py --trace trace.jsonl`` — read a
   PRIME_TRACE JSONL (from any serve run) and print the per-chunk
   dispatch-vs-sync overlap report: for every decode chunk, how long the
@@ -30,11 +26,11 @@ Two modes:
   distribution and outcomes per replica, affinity hit ratio (the fraction of
   keyed requests the consistent-hash scheduler landed on their prefix-warm
   replica), reroute reasons, breaker states, and admission-gate queue waits.
-- ``--slo report.json`` (with ``--trace``) — merge a loadgen SLO report's
-  per-scenario rows (docs/benchmarking.md) into the trace output: the
-  scenario table prints first (which scenario regressed), the waterfalls
-  below it say where inside a request the time went. Repeatable to compare
-  two reports side by side.
+- ``--slo report.json`` (alone, or with either of the above) — merge a
+  loadgen SLO report's per-scenario rows (docs/benchmarking.md) into the
+  output: the scenario table prints first (which scenario regressed), the
+  waterfalls below it say where inside a request the time went. Repeatable
+  to compare two reports side by side.
 """
 
 from __future__ import annotations
@@ -42,40 +38,14 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
-import time
 from collections import defaultdict
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-TIMES: dict[str, float] = defaultdict(float)
-COUNTS: dict[str, int] = defaultdict(int)
-
-
-def _wrap(obj, name: str) -> None:
-    """Time a method into TIMES[name], EXCLUDING any XLA-compile seconds that
-    fire inside it (they land in TIMES['xla_compile'] via the compiler spy) —
-    the report's buckets must be disjoint or mid-run compiles get attributed
-    to the phase they happened to fire in."""
-    fn = getattr(obj, name)
-
-    def timed(*a, **k):
-        compile_before = TIMES["xla_compile"]
-        t0 = time.perf_counter()
-        out = fn(*a, **k)
-        elapsed = time.perf_counter() - t0
-        TIMES[name] += elapsed - (TIMES["xla_compile"] - compile_before)
-        COUNTS[name] += 1
-        return out
-
-    setattr(obj, name, timed)
 
 
 def overlap_report(path: str, quiet: bool = False) -> None:
     """Pair serve.dispatch / serve.sync spans by chunk seq and print the
     per-chunk host-stall breakdown plus aggregates. One PRIME_TRACE file can
-    hold several engines' spans back-to-back (bench.py builds a fresh engine
-    per serve section, each restarting seq at 0): a dispatch whose seq was
+    hold several engines' spans back-to-back (a process that builds a fresh
+    engine per section, each restarting seq at 0): a dispatch whose seq was
     already seen starts a new run, so runs are reported separately instead
     of silently overwriting each other. Concurrent engines interleaving one
     sink are not disambiguated. ``quiet`` suppresses the no-engine-spans
@@ -106,10 +76,6 @@ def overlap_report(path: str, quiet: bool = False) -> None:
     if not runs:
         if not quiet:
             print(f"no paired serve.dispatch/serve.sync spans in {path}")
-            print(
-                "(synchronous loop? PRIME_SERVE_OVERLAP=0 emits "
-                "serve.decode_chunk only)"
-            )
         return
     tot_stall = tot_window = 0.0
     for i, (dispatch, sync) in enumerate(runs):
@@ -387,9 +353,8 @@ def main() -> None:
     parser.add_argument(
         "--trace", metavar="JSONL", action="append", default=None,
         help="Print the dispatch-vs-sync overlap report from a PRIME_TRACE "
-             "JSONL instead of running the profile. Repeatable: multiple "
-             "files (router + replicas) are also stitched by trace id into "
-             "per-request cross-process waterfalls.",
+             "JSONL. Repeatable: multiple files (router + replicas) are also "
+             "stitched by trace id into per-request cross-process waterfalls.",
     )
     parser.add_argument(
         "--trace-id", metavar="HEX", default=None,
@@ -398,7 +363,7 @@ def main() -> None:
     parser.add_argument(
         "--fleet", metavar="ROUTER_URL", default=None,
         help="Print the routing report scraped from a running "
-             "`prime serve fleet` router instead of running the profile.",
+             "`prime serve fleet` router.",
     )
     parser.add_argument(
         "--slo", metavar="REPORT_JSON", action="append", default=None,
@@ -406,8 +371,10 @@ def main() -> None:
              "output (above the waterfalls). Repeatable.",
     )
     args = parser.parse_args()
-    # --slo composes with every offline mode: scenario rows print first,
-    # then whichever detail view (--trace waterfalls / --fleet routing)
+    if not (args.slo or args.trace or args.fleet):
+        parser.error("one of --trace, --fleet or --slo is required")
+    # --slo composes with every mode: scenario rows print first, then
+    # whichever detail view (--trace waterfalls / --fleet routing)
     if args.slo:
         slo_report(args.slo)
     if args.trace:
@@ -415,104 +382,8 @@ def main() -> None:
             overlap_report(path, quiet=len(args.trace) > 1)
         tier_report(args.trace)
         waterfall_report(args.trace, trace_id=args.trace_id)
-        return
-    if args.fleet:
+    elif args.fleet:
         fleet_report(args.fleet)
-        return
-    if args.slo:
-        return
-
-    import jax
-    import jax.numpy as jnp
-
-    from prime_tpu.models import get_config
-    from prime_tpu.models.llama import init_params
-    from prime_tpu.serve.engine import ContinuousBatchingEngine
-
-    # the scenario comes from bench.py so this profiles EXACTLY the workload
-    # the bench's serve section measures
-    import bench
-
-    config = get_config("llama3.2-1b")
-    params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.bfloat16)
-    req_new = bench.SERVE_NEW
-    prompts = bench.serve_prompts_for(config)
-    engine = ContinuousBatchingEngine(
-        params, config, pad_id=0, max_slots=bench.SERVE_SLOTS,
-        capacity=bench.SERVE_CAPACITY, chunk=bench.SERVE_CHUNK,
-    )
-    # count XLA compiles
-    import jax._src.compiler as _c
-
-    real_compile = _c.backend_compile_and_load
-
-    def spy(*a, **k):
-        t0 = time.perf_counter()
-        out = real_compile(*a, **k)
-        TIMES["xla_compile"] += time.perf_counter() - t0
-        COUNTS["xla_compile"] += 1
-        return out
-
-    _c.backend_compile_and_load = spy
-
-    _wrap(engine, "_prefill")
-    _wrap(engine, "_prefill_batch")
-    # prefix-cache attribution: seed covers the radix match + the single
-    # assemble_row dispatch on hits, store the block split/insert path
-    _wrap(engine, "_prefix_seed")
-    _wrap(engine, "_store_prefix")
-    if engine.overlap:
-        # the pipelined loop: dispatch is host enqueue time, sync is the
-        # blocked fetch — their gap is exactly what overlap bought
-        _wrap(engine, "_dispatch_decode")
-        _wrap(engine, "_sync_decode")
-    else:
-        _wrap(engine, "_decode_chunk")
-    decode_keys = (
-        ("_dispatch_decode", "_sync_decode") if engine.overlap else ("_decode_chunk",)
-    )
-    for phase in ("warm1", "warm2", "measured"):
-        TIMES.clear()
-        COUNTS.clear()
-        t0 = time.perf_counter()
-        if phase.startswith("warm"):
-            reqs = [engine.submit(prompts[0], max_new_tokens=req_new)]
-        else:
-            reqs = [engine.submit(ids, max_new_tokens=req_new) for ids in prompts]
-        while not all(r.done for r in reqs):
-            engine.tick()
-        elapsed = time.perf_counter() - t0
-        # snapshot the phase timers BEFORE draining the lookahead chunk: the
-        # drain's sync time is outside `elapsed` and must not skew the
-        # attribution (nor leak into the next phase's timed window)
-        times, counts = dict(TIMES), dict(COUNTS)
-        engine.tick()
-        total = sum(len(r.all_tokens(timeout=1)) for r in reqs)
-        print(f"--- {phase}: {total} tokens in {elapsed:.2f}s = {total/elapsed:.1f} tok/s")
-        for k in sorted(times):
-            print(f"    {k}: {times[k]:.2f}s over {counts[k]} calls")
-        other = elapsed - sum(
-            times.get(k, 0.0)
-            for k in ("_prefill", "_prefill_batch", "xla_compile", *decode_keys)
-        )
-        print(f"    other (host glue): {other:.2f}s")
-    stats = engine.stats()
-    print(
-        f"--- engine: overlap_ratio {stats['overlap_ratio']}, host stall "
-        f"{stats['host_stall_s']}s of {stats['chunk_window_s']}s window, "
-        f"wasted decode tokens {stats['wasted_decode_tokens']}"
-    )
-    print(
-        f"--- prefix cache: {stats['prefix_cache_bytes'] / 1e6:.1f} MB device "
-        f"+ {stats['prefix_cache_host_bytes'] / 1e6:.1f} MB host in "
-        f"{stats['prefix_cache_nodes']} nodes, {engine.prefix_hits} hits / "
-        f"{stats['prefix_assembles']} assembles, "
-        f"{stats['prefix_spills']} spills / {stats['prefix_reuploads']} "
-        f"re-uploads, {stats['prefix_evictions']} evictions"
-    )
-    if os.environ.get("PRIME_TRACE"):
-        print(f"--- spans at {os.environ['PRIME_TRACE']}: rerun with "
-              f"--trace {os.environ['PRIME_TRACE']} for the per-chunk report")
 
 
 if __name__ == "__main__":

@@ -689,15 +689,15 @@ def test_decode_failure_fails_requests_and_recovers():
     req = engine.submit([1, 2, 3], max_new_tokens=8)
     engine._admit()
     boom = [True]
-    real_chunk = engine._decode_chunk
+    real_dispatch = engine._dispatch_decode
 
     def exploding():
         if boom[0]:
             boom[0] = False
             raise RuntimeError("chip on fire")
-        real_chunk()
+        real_dispatch()
 
-    engine._decode_chunk = exploding
+    engine._dispatch_decode = exploding
     engine.tick()
     with pytest.raises(RuntimeError, match="chip on fire"):
         req.all_tokens(timeout=1)

@@ -348,6 +348,35 @@ def test_a_warmed_engine_builds_no_program_whatever_the_wave(preset):
         engine.shutdown()
 
 
+def test_a_second_identical_hit_builds_no_program():
+    """With a paged prefix cache, a hit's first admission builds what the
+    page path builds lazily (gather, store, the tail's slice); the same
+    prompt again builds nothing: a hit is a wave of one that stores its row
+    as it is, with no member taken out of it."""
+    config = get_config("tiny-test")
+    params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
+    engine = ContinuousBatchingEngine(
+        params, config, max_slots=2, capacity=128, chunk=4, prefill_chunk=32,
+        prefix_cache_mb=8, warmup=True,
+    )
+    assert engine.paged_prefix
+    engine.start()
+    try:
+        system = list(range(3, 51))  # three blocks
+        question = system + [60 + i for i in range(20)]
+        for prompt in (system, question):
+            assert len(engine.submit(prompt, max_new_tokens=6).all_tokens(timeout=300)) == 6
+        assert engine.prefix_hits == 1
+        first_hit = engine._clock.build_totals()
+        assert len(engine.submit(question, max_new_tokens=6).all_tokens(timeout=300)) == 6
+        after = engine._clock.build_totals()
+        built = [b["name"] for b in engine._clock.builds()][-(after["total"] - first_hit["total"]):]
+        assert engine.prefix_hits == 2 and engine.stats()["prefix_paged_seeds"] == 2
+        assert (after["total"], after["loads"]) == (first_hit["total"], first_hit["loads"]), built
+    finally:
+        engine.shutdown()
+
+
 @pytest.mark.parametrize("preset,sliced", [("tiny-longcat", True), ("tiny-test", False)])
 def test_a_vocabulary_slice_is_served_without_an_end_of_sequence_id(preset, sliced):
     """serve_model: a preset that states a slice of a sharded vocabulary

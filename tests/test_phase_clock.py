@@ -277,10 +277,38 @@ def test_capture_holds_the_phases_on_the_loop_threads_line(tmp_path):
     moved = delta(before, after)
     assert moved.get("program_build", 0.0) == 0.0
     for phase, annotations in (("dispatch", ("serve.dispatch",)), ("sync_wait", ("serve.sync",)),
-                               ("prefill", ("serve.prefill", "serve.prefill_batch")),
+                               ("prefill", ("serve.prefill",)),
                                ("prefix_seed", ("serve.prefix_seed",))):
         seconds = sum(e["dur_ns"] for e in ours if e["name"] in annotations) / 1e9
         assert seconds == pytest.approx(moved[phase], rel=0.10), phase
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "sync"])
+def test_a_hit_stores_its_pages_before_it_waits_for_the_first_token(overlap):
+    """A prefix hit is admitted as a wave of one: its new pages are stored
+    while the chip is still busy with the prefill, and only then does the
+    loop block for the first token. (Until PR 28 a hit had an admission path
+    of its own that blocked first and stored with the chip idle.)"""
+    engine = make_engine(overlap=overlap)
+    shared = PROMPT + [7] * 12  # two cached blocks
+    cold = engine.submit(shared, max_new_tokens=2)
+    while not cold.done:
+        engine.tick()
+    phases: list[str] = []
+    switch = engine._clock.switch
+
+    def recording(phase: str) -> None:
+        phases.append(phase)
+        switch(phase)
+
+    engine._clock.switch = recording
+    hit = engine.submit(shared + [11] * 17, max_new_tokens=2)  # one block more to store
+    while not hit.done:
+        engine.tick()
+    assert engine.prefix_hits == 1
+    assert phases.count("prefix_store") == 1 and phases.count("prefill_sync") == 1
+    assert (phases.index("prefix_seed") < phases.index("prefill")
+            < phases.index("prefix_store") < phases.index("prefill_sync"))
 
 
 def test_serial_loop_keeps_stall_equal_to_window():

@@ -346,9 +346,7 @@ def test_sharded_dispatch_spans_carry_mesh_devices(tmp_path):
     assert by_name["serve.prefill"] and all(
         a.get("mesh_devices") == 4 for a in by_name["serve.prefill"]
     )
-    device_spans = by_name.get("serve.dispatch", []) + by_name.get(
-        "serve.decode_chunk", []
-    )
+    device_spans = by_name.get("serve.dispatch", [])
     assert device_spans and all(a.get("mesh_devices") == 4 for a in device_spans)
 
 
