@@ -420,6 +420,9 @@ def _attention_block(
     cache_lengths: jnp.ndarray | None,
     decode: bool,
     attn_impl: str,
+    attend_lengths: jnp.ndarray | None = None,  # (B,) decode: slots each row attends over
+    #                                             (None: its cache and this step's column)
+    park: jnp.ndarray | None = None,            # (B,) decode: the kernel's table for them
     prefill_offset: jnp.ndarray | None = None,  # () chunked prefill: write+attend at offset
     sliding: jnp.ndarray | None = None,  # () traced bool: this layer uses the window
     rope_tables_local: tuple[jnp.ndarray, jnp.ndarray] | None = None,
@@ -484,7 +487,8 @@ def _attention_block(
         # this step's k/v column goes into each sequence's next free slot
         kv = write_cache(kv, staged(k, v), layer, cache_lengths, impl=attn_impl)
         attn = decode_attention(
-            q, kv[0], kv[1], cache_lengths + 1, sm_scale, impl=attn_impl,
+            q, kv[0], kv[1], cache_lengths + 1 if attend_lengths is None else attend_lengths,
+            sm_scale, impl=attn_impl, park=park,
             mesh=mesh, layer=layer, **_scales(kv), **gemma_kw,
         )
     elif prefill_offset is not None:
@@ -678,7 +682,7 @@ def forward(
     last_positions: jnp.ndarray | None = None,  # (B,) → logits only at these rows
     adapters: dict | None = None,  # multi-LoRA bank stacks (serve/adapters.py)
     adapter_ids: jnp.ndarray | None = None,  # (B,) int32 per-row bank slots
-    token_mask: jnp.ndarray | None = None,  # (B, S) bool: rows the dropless experts skip
+    token_mask: jnp.ndarray | None = None,  # (B, S) bool: False rows belong to nobody
     return_moe_stats: bool = False,
 ):
     """Run the transformer. Returns (logits (B, S, V) fp32, updated cache),
@@ -699,6 +703,14 @@ def forward(
                        long prompt (or a suffix after a reused prefix) feeds
                        in S-token chunks with O(S·C) attention memory
     - decode step:     cache=<filled>, decode=True, S must be 1
+
+    ``token_mask`` marks the rows that belong to somebody (None: all of them).
+    The others cost as little as the program can make them: the dropless
+    experts skip them, and a decode step attends them at length 0, which is
+    zero and reads no cache block (``ops.attention.decode_attention``). Only
+    the attended length changes: such a row's position, the slot its column is
+    written to and the lengths the cache carries are what they are without a
+    mask, so the bytes a finished row left below its length stay as they are.
 
     In every cached regime the returned cache IS the argument's buffers,
     updated in place: the leaves ride one layer scan as its carry (both
@@ -779,6 +791,15 @@ def forward(
         x = x * jnp.asarray(config.d_model**0.5, dtype=x.dtype)
 
     cache_lengths = cache.lengths if cache is not None else None
+    nobody = {}
+    if decode and token_mask is not None:
+        from prime_tpu.ops.pallas_attention import decode_park_rows
+
+        # the slots a row attends over: the cache and this step's column, and
+        # none for a row that belongs to nobody; with them the kernel's table of
+        # what such a row names instead of a block. Once, here, for every layer
+        attend_lengths = jnp.where(token_mask[:, 0], cache_lengths + 1, 0)
+        nobody = dict(attend_lengths=attend_lengths, park=decode_park_rows(attend_lengths))
     # Per-layer sliding flag rides the scan so one compiled body serves both
     # kinds. The pattern is an explicit config field (ModelConfig.sliding_pattern)
     # so non-Gemma2 window schemes can't silently inherit the even alternation.
@@ -791,12 +812,12 @@ def forward(
             return mla_attention_block(
                 x, lp, positions, rope_tables, config,
                 kv, layer, cache_lengths, decode, attn_impl,
-                prefill_offset=prefill_offset,
+                prefill_offset=prefill_offset, **nobody,
             )
         return _attention_block(
             x, lp, positions, rope_tables, config,
             kv, layer, cache_lengths, decode, attn_impl,
-            prefill_offset=prefill_offset,
+            prefill_offset=prefill_offset, **nobody,
             sliding=sliding, rope_tables_local=rope_tables_local,
             mesh=mesh, adapter_ids=adapter_ids,
         )

@@ -171,7 +171,7 @@ def _flash_decode_min_capacity() -> int:
 
 def _decode_int4(
     q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-    k_scale, v_scale, softcap, window, sliding, sinks, layer,
+    k_scale, v_scale, softcap, window, sliding, sinks, layer, park,
 ):
     """int4-KV decode dispatch: a nibble-packed uint8 cache (a QUARTER of
     the bf16 bytes) rides the flash-decode kernel behind the same scales
@@ -190,7 +190,7 @@ def _decode_int4(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            k_scale=k_scale, v_scale=v_scale, layer=layer, park=park,
             interpret=_pallas_interpret(),
         )
     k_f = unpack_kv_int4(_layer_of(k_cache, layer)) * _layer_of(k_scale, layer)
@@ -305,8 +305,15 @@ def decode_attention(
     sinks: jnp.ndarray | None = None,    # (H,) per-head sink logits (GPT-OSS)
     mesh=None,                           # impl="sharded": the serving mesh
     layer: jnp.ndarray | int = 0,        # () layer of a stacked cache to attend over
+    park: jnp.ndarray | None = None,     # (B,) pallas_attention.decode_park_rows(
+    #                                      cache_lengths) made once for every layer
 ) -> jnp.ndarray:
-    """One decode step against the cache, masking invalid (future) slots.
+    """One decode step against the cache, masking invalid (future) slots. A
+    row of length 0 (a slot that belongs to nobody) comes back zero on every
+    path, and on the kernel paths reads no cache block. ``park`` is that
+    kernel's table of which block such a row names instead: the bare kernel
+    takes the caller's, the shard_mapped one makes its own from its local rows
+    and the einsum path has no use for it.
 
     The model hands over the stacked cache its layer scan carries and the
     traced ``layer``: the kernel paths put the layer in their index maps and
@@ -336,7 +343,7 @@ def decode_attention(
         # multi-device gate allows, XLA widen-and-fold reference otherwise
         return _decode_int4(
             q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-            k_scale, v_scale, softcap, window, sliding, sinks, layer,
+            k_scale, v_scale, softcap, window, sliding, sinks, layer, park,
         )
     if impl == "sharded":
         if mesh is not None and _sharded_decode_eligible(
@@ -361,7 +368,7 @@ def decode_attention(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            k_scale=k_scale, v_scale=v_scale, layer=layer, park=park,
             interpret=_pallas_interpret(),
         )
 
@@ -408,6 +415,9 @@ def decode_attention(
         ).astype(q.dtype)
     else:
         out = jnp.einsum("bkgc,bkdc->bkgd", probs.astype(q.dtype), v_cache)
+    # a row of length 0 attends to nothing and is zero, as the kernel returns
+    # it (its softmax over an all-masked row is uniform, not empty)
+    out = jnp.where(lengths_b > 0, out, 0)
     return out.reshape(batch, num_heads, 1, head_dim)
 
 

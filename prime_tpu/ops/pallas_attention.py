@@ -187,9 +187,9 @@ def _flash_kernel(
 
 
 def _decode_live_block(b, cb, lengths_ref, window_ref, block_c: int):
-    """The cache block this grid step should have resident: the block
-    coordinate clipped into the sequence's live [first, last] range. Out-of-
-    range steps REVISIT an edge block — Mosaic elides the operand copy when
+    """The cache block of row ``b`` this grid step should have resident: the
+    block coordinate clipped into the sequence's live [first, last] range. Out-
+    of-range steps REVISIT an edge block — Mosaic elides the operand copy when
     the index map returns the same block as the previous iteration, so the
     clip turns the mask-level early exit into an actual HBM-bytes saving
     (the previous design DMA'd the full capacity into VMEM per program and
@@ -201,6 +201,44 @@ def _decode_live_block(b, cb, lengths_ref, window_ref, block_c: int):
     first_slot = jnp.where(window > 0, jnp.maximum(length - window, 0), 0)
     first = first_slot // block_c
     return jnp.clip(cb, first, jnp.maximum(num - 1, first))
+
+
+def decode_park_rows(lengths: jnp.ndarray) -> jnp.ndarray:
+    """(B,) the row whose cache block each row's grid steps name: itself for a
+    live row, and for a row of length 0 (a slot that belongs to nobody) the
+    next live row, else the last live row before it, else row 0. A dead row
+    reads nothing, so all it can do for the pipeline is not to ask for a block
+    of its own: ``_decode_block_index`` points every one of its steps at the
+    block its neighbour's steps name, and equal consecutive indices copy
+    nothing. A function of the lengths alone, so the model makes it once a
+    step, outside its layer loop (inside, the v5e's compiler hoists the two
+    scans and leaves the select, a launch a layer)."""
+    batch = lengths.shape[0]
+    rows = jnp.arange(batch, dtype=jnp.int32)
+    live = lengths > 0
+    ahead = jax.lax.cummin(jnp.where(live, rows, batch), reverse=True)
+    behind = jax.lax.cummax(jnp.where(live, rows, -1))
+    return jnp.where(ahead < batch, ahead, jnp.maximum(behind, 0))
+
+
+def _decode_block_index(
+    b, hb, step, lengths_ref, park_ref, window_ref,
+    block_c: int, blocks: int, head_blocks: int,
+):
+    """(row, kv-head block, cache block) of the K/V block grid step
+    ``(b, hb, step)`` names. A live row walks its own blocks from the far end
+    (``flash_decode``'s kv_map). Every step of a dead row names ONE block that
+    a live neighbour's steps name (``decode_park_rows``): the block the next
+    live row asks for first, which then arrives early instead of wasted, or
+    the block the last live row ended on. Scalar work a grid step, on a core
+    that has 0.2 us for it: a parked row is told from its own by the table
+    alone (row 0 of a call whose rows are all dead names its own block 0)."""
+    row = park_ref[b]
+    ahead, behind = row > b, row < b
+    cb = jnp.where(ahead, blocks - 1, jnp.where(behind, 0, blocks - 1 - step))
+    if head_blocks > 1:
+        hb = jnp.where(ahead, 0, jnp.where(behind, head_blocks - 1, hb))
+    return row, hb, _decode_live_block(row, cb, lengths_ref, window_ref, block_c)
 
 
 def _unpack_kv_nibbles(packed):
@@ -282,6 +320,7 @@ def decode_call_tiling(num_heads: int, k_cache, quantized: bool) -> tuple[int, i
 
 def _decode_kernel(
     lengths_ref,  # (B,) scalar-prefetch, SMEM
+    park_ref,     # (B,) scalar-prefetch: read by the K/V index maps only
     window_ref,   # (1,) scalar-prefetch: effective window (0 = global layer)
     layer_ref,    # (1,) scalar-prefetch: read by the K/V index maps only
     q_ref,        # (1, HEADS, G, D): every query head of this step's kv heads
@@ -413,6 +452,8 @@ def flash_decode(
     k_scale: jnp.ndarray | None = None,  # ([L,] B, KH, 1, C) int8-cache dequant scales
     v_scale: jnp.ndarray | None = None,
     layer: jnp.ndarray | int = 0,        # () which layer of the stacked cache to read
+    park: jnp.ndarray | None = None,     # (B,) decode_park_rows(cache_lengths), where
+    #                                      the caller made it once for all its layers
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One fused decode step: each program takes a row and as many of its kv
@@ -420,7 +461,8 @@ def flash_decode(
     and streams the cache through VMEM in wide blocks with online softmax,
     stopping at the sequence's true length (scalar-prefetched). C must be a
     multiple of BLOCK_C. The feature-major cache keeps reads lane-aligned for
-    any head_dim.
+    any head_dim. A row of length 0 (the engine's retired slots) comes back
+    zero and fetches no cache block (``decode_park_rows``).
 
     The cache operand is the model's whole stacked (L, B, KH, D, C) buffer and
     ``layer`` a third scalar-prefetch operand that the K/V (and scale) index
@@ -466,8 +508,11 @@ def flash_decode(
     use_sinks, sinks_arr = _sinks_operand(sinks, kv_heads, group)
 
     blocks = capacity // block_c
+    lengths = cache_lengths.astype(jnp.int32)
+    if park is None:
+        park = decode_park_rows(lengths)
 
-    def kv_map(b, hb, step, lens, win, lay):
+    def kv_map(b, hb, step, lens, parked, win, lay):
         # shared by k/v AND the int8 scale blocks: the scale block must
         # always ride the same live-block index as its cache block. A row's
         # blocks are walked from the far end, so the steps past its length
@@ -475,9 +520,11 @@ def flash_decode(
         # at the row's first step and streams in under the previous row's
         # last block of work. Walked from the front, a row of one live block
         # (most of a chat batch) waits for its whole copy in the open
-        # (PERF.md, PR 27)
-        cb = blocks - 1 - step
-        return (lay[0], b, hb, 0, _decode_live_block(b, cb, lens, win, block_c))
+        # (PERF.md, PR 27). A row of length 0 asks for no block of its own
+        row, hb, block = _decode_block_index(
+            b, hb, step, lens, parked, win, block_c, blocks, kv_heads // heads
+        )
+        return (lay[0], row, hb, 0, block)
 
     # the layer axis is squeezed (None): the kernel sees the same
     # (1, HEADS, D, BLOCK_C) blocks whichever layer the index map picked
@@ -502,7 +549,7 @@ def flash_decode(
         in_specs = qkv_specs + [sinks_spec]
         operands = (k_cache, v_cache, sinks_arr)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         # a program is a row and `heads` of its kv heads (all of them where
         # they fit, so the middle axis is 1). The cache-block axis is a GRID
         # dimension: blocks outside a sequence's live range revisit a
@@ -544,7 +591,7 @@ def flash_decode(
         ),
         interpret=interpret,
     )(
-        cache_lengths.astype(jnp.int32), window_arr,
+        lengths, park, window_arr,
         jnp.asarray(layer, jnp.int32).reshape(1),
         q.reshape(batch, kv_heads, group, head_dim), *operands,
     )

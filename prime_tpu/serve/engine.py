@@ -836,6 +836,15 @@ class ContinuousBatchingEngine:
             "KV heads of a row one grid step of the decode-attention kernel "
             "carries (0 = XLA path)",
         ).set(heads_per_step)
+        # how often that kernel's dead-row path engages: a retired slot's row is
+        # attended at length 0 and fetches no cache block (models/llama.py
+        # forward's token_mask); retired / total is the share of rows it spares
+        self._m_decode_rows = r.counter(
+            "serve_decode_rows_total",
+            "Rows x steps of the decode chunks dispatched, by the row's state in "
+            "the dispatch mask (live | retired)",
+            labelnames=("state",),
+        )
         # disaggregated serving (docs/architecture.md "Disaggregated
         # serving"): prefix-KV segments shipped over the versioned wire
         # format — exports serve GET /admin/kv on a prefill replica, imports
@@ -1292,10 +1301,6 @@ class ContinuousBatchingEngine:
             temps = jnp.where(active, temps, 0.0)
             top_ps = jnp.where(active, top_ps, 1.0)
 
-            # MoE: retired slots route to no expert, and the layers' counts
-            # ride the step scan out with the tokens
-            moe = dict(token_mask=active[:, None], return_moe_stats=True) if zero_stats else {}
-
             def step(carry, _):
                 cache, tok, rng, *stats = carry
                 logits, new_cache, *step_stats = forward(
@@ -1309,7 +1314,11 @@ class ContinuousBatchingEngine:
                     mesh=mesh,
                     adapters=adapters,
                     adapter_ids=adapter_slots,
-                    **moe,
+                    # retired slots belong to nobody: they attend over nothing
+                    # and route to no expert. MoE: the layers' counts ride the
+                    # step scan out with the tokens
+                    token_mask=active[:, None],
+                    return_moe_stats=bool(zero_stats),
                 )
                 if stats:
                     stats = [stats[0] + step_stats[0]]
@@ -2130,8 +2139,9 @@ class ContinuousBatchingEngine:
             # (pre=self._last syncs the pipeline), times this program to
             # readiness, and captures its XLA cost analysis once. Inactive
             # profiler -> shared no-op: zero added syncs on the overlap path.
+            live = int(mask.sum())
             with self.profiler.step(
-                kind, pre=self._last, batch=int(mask.sum()),
+                kind, pre=self._last, batch=live,
                 steps=steps, cost_fn=fn, cost_args=args,
             ) as prof_step:
                 if spec:
@@ -2143,6 +2153,8 @@ class ContinuousBatchingEngine:
                 else:
                     self._cache, self._last, toks, *stats = fn(*args)
                     carried = {"moe_stats": stats[0] if stats else None}
+                    self._m_decode_rows.inc(live * steps, state="live")
+                    self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
                 prof_step.fence(toks)
         self._inflight.append(
             _InflightChunk(

@@ -308,34 +308,62 @@ def _results(compiled, opcode):
     return pattern.findall(compiled.as_text())
 
 
+def _assert_parked_outside_the_layer_loop(compiled, batch=B):
+    """The flash-decode call's lengths and park table (its first two
+    operands) reach the layer loop's body as loop-carried values, and the
+    table's two scans over the rows are not in that body: it is made once a
+    step."""
+    text = compiled.as_text()
+    scan_over_rows = re.compile(rf"= s32\[{batch}(,1)?\]\S* reduce-window\(")
+    bodies = [c for c in re.split(r"\n\n(?=%|ENTRY)", text) if "flash_decode" in c and "tpu_custom_call" in c]
+    assert bodies
+    for body in bodies:
+        for operands in re.findall(r"%flash_decode\S* = \S+ custom-call\(([^)]*)\)", body):
+            lengths, park = [name.strip() for name in operands.split(",")[:2]]
+            assert lengths.startswith("%get-tuple-element") and park.startswith("%get-tuple-element")
+        assert not scan_over_rows.search(body)
+    assert scan_over_rows.search(text)  # ... and made it is
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["every_row_live", "retired_rows_masked"])
 @pytest.mark.parametrize("n_layers", [2, 4])
-def test_decode_keeps_the_cache_in_place(topo, n_layers):
+def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
     """The engine's decode program (a scan of steps over ``forward(decode=
     True)``, cache donated) at the served shape: the cache rides both scans as
     a carry and each layer writes its column in place, so the program's
     temporaries stay under ONE layer's K + V (with the cache as the layer
     scan's xs/ys they exceeded the whole cache), nothing copies the stacked
     cache, and the kernel reads its layer out of the stacked buffer, so no
-    one-layer slice is made."""
+    one-layer slice is made. With the engine's mask of retired slots the same
+    holds, and the table that parks their rows is made outside the layer
+    loop's body."""
     from prime_tpu.models.llama import forward
 
     config = _qwen3_4b(n_layers)
     params, cache = _abstract_model(config, B, C)
 
-    def decode(params, cache, tok):
+    def decode(params, cache, tok, active):
         def step(carry, _):
             cache, tok = carry
-            logits, cache = forward(
+            logits, new_cache = forward(
                 params, tok[:, None], config, positions=cache.lengths[:, None],
                 cache=cache, decode=True, attn_impl="pallas",
+                token_mask=active[:, None] if masked else None,
+            )
+            new_cache = new_cache._replace(
+                lengths=jnp.where(active, new_cache.lengths, cache.lengths)
             )
             tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            return (cache, tok), tok
+            return (new_cache, tok), tok
 
         (cache, _), toks = jax.lax.scan(step, (cache, tok), None, length=2)
         return cache, toks
 
-    compiled = _compile(topo, decode, params, cache, ((B,), jnp.int32), donate=(1,))
+    compiled = _compile(
+        topo, decode, params, cache, ((B,), jnp.int32), ((B,), jnp.bool_), donate=(1,)
+    )
+    if masked:
+        _assert_parked_outside_the_layer_loop(compiled)
     one_layer_kv = 2 * B * KH * D * C * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_kv
     stacked, one_layer = f"bf16[{n_layers},{B},{KH},{D},{C}]", f"bf16[{B},{KH},{D},{C}]"
@@ -425,6 +453,7 @@ def test_shortcut_block_decode_reads_its_weights_in_place(topo, monkeypatch):
     assert not top_level("bf16[16,6144,2048]") and not top_level("bf16[16,2048,6144]")
     assert not top_level("bf16[2,6144,12288]") and not top_level("bf16[2,12288,6144]")
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    _assert_parked_outside_the_layer_loop(compiled, batch=64)  # the latent's 64 x 1 x 4 grid
 
 
 def test_sharded_decode_lowers_tp4(topo):
@@ -448,11 +477,13 @@ def test_sharded_decode_lowers_tp4(topo):
     ).compile()
 
 
-def test_forward_decode_lowers_tp4(topo, monkeypatch):
+@pytest.mark.parametrize("masked", [False, True], ids=["every_row_live", "retired_rows_masked"])
+def test_forward_decode_lowers_tp4(topo, monkeypatch, masked):
     """``forward`` decode as the tp=4 replica runs it (``attn_impl="sharded"``):
     the stacked cache, kv heads over tp, goes through the layer scan as a
     carry into the shard_mapped kernel with the layer index replicated; no
-    device gathers or copies its cache shard."""
+    device gathers or copies its cache shard. With retired rows masked the
+    kernel parks them by a table made from each device's local rows."""
     from prime_tpu.models.llama import forward
     from prime_tpu.ops import attention
     from prime_tpu.parallel.sharding import param_shardings, serving_cache_spec
@@ -474,15 +505,19 @@ def test_forward_decode_lowers_tp4(topo, monkeypatch):
         k=sds(cache.k, kv), v=sds(cache.v, kv), lengths=sds(cache.lengths, rep)
     )
 
-    def step(params, cache, tokens):
+    def step(params, cache, tokens, active):
         return forward(
             params, tokens, config, cache=cache, decode=True,
             attn_impl="sharded", mesh=mesh,
+            token_mask=active[:, None] if masked else None,
         )
 
     compiled = (
         jax.jit(step, donate_argnums=(1,))
-        .lower(params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=rep))
+        .lower(
+            params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=rep),
+        )
         .compile()
     )
     text = compiled.as_text()

@@ -1410,3 +1410,114 @@ def test_max_queue_env_default(monkeypatch):
     assert engine.max_queue == 7
     monkeypatch.delenv("PRIME_SERVE_MAX_QUEUE")
     assert make_engine().max_queue == 0  # unbounded by default
+
+
+# ---- a retired slot belongs to nobody: attended at length 0 ----------------------
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "sync"])
+def test_retired_slot_beside_a_live_one(overlap):
+    """A slot that retired while another decodes: the live slot's greedy
+    tokens are the one-shot sampler's, the retired row's cache columns below
+    its length are byte-identical after further chunks (only the ATTENDED
+    length went to 0: the column write still lands at the row's length, which
+    does not move), a later admission into that slot answers as a fresh
+    engine does, and serve_decode_rows_total counts rows x steps by state."""
+    import numpy as np
+
+    engine = make_engine(max_slots=2, overlap=overlap)
+    masks = []
+    dispatch = engine._dispatch_decode
+
+    def recording_dispatch():
+        masks.append(engine._active.copy())
+        dispatch()
+
+    engine._dispatch_decode = recording_dispatch
+    short = engine.submit([3, 8, 21, 144], max_new_tokens=3)
+    long = engine.submit([7, 7, 101, 19, 250, 6], max_new_tokens=40)
+    drain(engine, short)
+    slot = next(s for s in range(2) if s not in engine._requests)
+    for _ in range(2):  # a chunk dispatched on the stale mask lands
+        engine.tick()
+    assert not long.done and not engine._active[slot]
+    length = int(np.asarray(engine._cache.lengths)[slot])
+    assert length >= 4 + 3 - 1
+    below = [np.asarray(leaf[:, slot, :, :, :length]) for leaf in (engine._cache.k, engine._cache.v)]
+
+    drain(engine, long)
+    assert long.all_tokens(timeout=1) == reference_tokens([7, 7, 101, 19, 250, 6], 40)
+    assert short.all_tokens(timeout=1) == reference_tokens([3, 8, 21, 144], 3)
+    assert int(np.asarray(engine._cache.lengths)[slot]) == length
+    for before, leaf in zip(below, (engine._cache.k, engine._cache.v)):
+        np.testing.assert_array_equal(before, np.asarray(leaf[:, slot, :, :, :length]))
+    # the column at the row's length was rewritten by every chunk meanwhile
+    retired_steps = sum(int((~m).sum()) for m in masks) * engine.chunk
+    live_steps = sum(int(m.sum()) for m in masks) * engine.chunk
+    assert retired_steps > 0 and live_steps > 0
+    rows = engine.registry.get("serve_decode_rows_total")
+    assert rows.value(state="live") == live_steps
+    assert rows.value(state="retired") == retired_steps
+    assert live_steps + retired_steps == len(masks) * 2 * engine.chunk
+
+    # the freed slot serves a new request as a fresh engine does
+    again = engine.submit([9, 9, 12, 300, 41], max_new_tokens=6)
+    drain(engine, again)
+    assert again.all_tokens(timeout=1) == reference_tokens([9, 9, 12, 300, 41], 6)
+    engine.shutdown()
+
+
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-moe", "tiny-mla", "tiny-longcat", "tiny-gptoss"])
+def test_decode_step_masks_only_the_attended_length(preset):
+    """forward(decode=True) with rows masked out: the live rows' logits are
+    bit-equal to the unmasked step's, the cache below every row's length is
+    untouched, the live rows' new columns are the unmasked step's, the carried
+    lengths advance for every row as before (the engine holds a retired row's
+    back), and nothing is NaN. token_mask=None adds nothing to the program:
+    no table, no select on the lengths."""
+    import numpy as np
+
+    from prime_tpu.models.llama import forward, init_cache
+
+    config = get_config(preset)
+    params = init_params(jax.random.PRNGKey(1), config, dtype=jnp.float32)
+    batch, capacity = 4, 64
+    cache = init_cache(config, batch, capacity, dtype=jnp.float32)
+    prompt = jax.random.randint(jax.random.PRNGKey(2), (batch, 12), 3, config.vocab_size)
+    _, cache = forward(params, prompt, config, cache=cache)
+    cache = cache._replace(lengths=jnp.asarray([12, 5, 9, 12], jnp.int32))
+    tok = jnp.asarray([[4], [5], [6], [7]], jnp.int32)
+    live = jnp.asarray([True, False, True, False])
+
+    def step(mask):
+        return forward(
+            params, tok, config, positions=cache.lengths[:, None], cache=cache,
+            decode=True, attn_impl="xla", token_mask=mask,
+        )
+
+    (logits, new), (ref_logits, ref_new) = step(live[:, None]), step(None)
+    rows = np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(logits)[rows], np.asarray(ref_logits)[rows])
+    assert np.isfinite(np.asarray(logits)).all()
+    np.testing.assert_array_equal(np.asarray(new.lengths), np.asarray(ref_new.lengths))
+    for leaf, ref_leaf, old in zip(new[:2], ref_new[:2], cache[:2]):
+        leaf, ref_leaf, old = (np.asarray(a) for a in (leaf, ref_leaf, old))
+        np.testing.assert_array_equal(leaf[:, rows], ref_leaf[:, rows])
+        for row, n in enumerate(np.asarray(cache.lengths)):
+            np.testing.assert_array_equal(leaf[:, row, ..., :n], old[:, row, ..., :n])
+
+    def primitives(mask):
+        names = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                names.append(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(lambda: step(mask))().jaxpr)
+        return names
+
+    plain, masked = primitives(None), primitives(live[:, None])
+    assert "cummin" not in plain and "cummax" not in plain
+    assert masked.count("cummin") == masked.count("cummax") == 1  # once, outside the layer scan

@@ -307,27 +307,24 @@ _TILING_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_TILING_CASES))
-def test_flash_decode_tiling_matches_xla(case, monkeypatch):
-    """The decode kernel at every tiling its shape function can hand out
-    (every kv head of a row or fewer, blocks of 128 / 256 / 512) against the
-    XLA reference, over ragged lengths: 1, a block boundary, boundary + 1, a
-    row inside its second block and full capacity. The block is asked for
-    the way an operator asks (PRIME_TPU_BLOCK_C); the heads a step are cut by
-    shrinking the VMEM budget to what that many heads need."""
+def _decode_operands(
+    seed, batch, h, kh, d, c, cache, q_dtype, extra, *, heads, block_c, monkeypatch
+):
+    """(q, k, v, keywords) of a flash-decode call over a random cache of the
+    given kind (float dtype | "int8" | "int4"; ``extra``: "latent" reads K as V,
+    "window_sinks", "softcap"), with the kernel's tiling pinned to ``heads`` kv
+    heads a step (the VMEM budget cut to what they need) and ``block_c`` slots
+    (asked for the way an operator asks, PRIME_TPU_BLOCK_C; 0 = from the shapes)."""
     from prime_tpu.models.llama import quantize_kv
     from prime_tpu.models.quantize import quantize_kv_int4
     from prime_tpu.ops import pallas_attention as pa
-    from prime_tpu.ops.attention import decode_attention
 
-    batch, h, kh, d, c, block_c, heads, cache, q_dtype, extra = _TILING_CASES[case]
-    rng = np.random.default_rng(len(case))
+    rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(batch, h, 1, d)).astype(np.float32)).astype(q_dtype)
     k = jnp.asarray(rng.normal(size=(batch, kh, d, c)).astype(np.float32))
     v = k if extra == "latent" else jnp.asarray(
         rng.normal(size=(batch, kh, d, c)).astype(np.float32)
     )
-    lengths = jnp.asarray([1, 512, 513, 700, c], dtype=jnp.int32)
     kw = {}
     if cache == "int8":
         (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
@@ -346,15 +343,34 @@ def test_flash_decode_tiling_matches_xla(case, monkeypatch):
         kw |= dict(softcap=30.0)
 
     quantized = cache in ("int8", "int4")
-    itemsize = k.dtype.itemsize
     want_c = block_c or 512
     monkeypatch.setattr(
         pa, "DECODE_VMEM_BUDGET",
-        pa.decode_step_vmem_bytes(heads, k.shape[2], want_c, itemsize, h // kh, d, quantized),
+        pa.decode_step_vmem_bytes(heads, k.shape[2], want_c, k.dtype.itemsize, h // kh, d, quantized),
     )
     if block_c:
         monkeypatch.setenv("PRIME_TPU_BLOCK_C", str(block_c))
     assert pa.decode_call_tiling(h, k, quantized) == (heads, want_c)
+    return q, k, v, kw
+
+
+@pytest.mark.parametrize("case", sorted(_TILING_CASES))
+def test_flash_decode_tiling_matches_xla(case, monkeypatch):
+    """The decode kernel at every tiling its shape function can hand out
+    (every kv head of a row or fewer, blocks of 128 / 256 / 512) against the
+    XLA reference, over ragged lengths: 1, a block boundary, boundary + 1, a
+    row inside its second block and full capacity. The block is asked for
+    the way an operator asks (PRIME_TPU_BLOCK_C); the heads a step are cut by
+    shrinking the VMEM budget to what that many heads need."""
+    from prime_tpu.ops import pallas_attention as pa
+    from prime_tpu.ops.attention import decode_attention
+
+    batch, h, kh, d, c, block_c, heads, cache, q_dtype, extra = _TILING_CASES[case]
+    q, k, v, kw = _decode_operands(
+        len(case), batch, h, kh, d, c, cache, q_dtype, extra,
+        heads=heads, block_c=block_c, monkeypatch=monkeypatch,
+    )
+    lengths = jnp.asarray([1, 512, 513, 700, c], dtype=jnp.int32)
 
     pa.flash_decode.clear_cache()
     try:
@@ -366,6 +382,137 @@ def test_flash_decode_tiling_matches_xla(case, monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=tol, atol=tol
     )
+
+
+# ---- rows of length 0: a slot that belongs to nobody -------------------------------
+
+# name: (q heads, kv heads, head_dim, kv heads a step, cache dtype, extra)
+_DEAD_ROW_VARIANTS = {
+    "bf16": (8, 2, 32, 2, "bfloat16", None),
+    "int8": (8, 2, 32, 2, "int8", None),
+    "int4": (8, 4, 32, 4, "int4", None),
+    "latent_576": (8, 1, 576, 1, "bfloat16", "latent"),
+    "window_sinks": (8, 4, 32, 4, "float32", "window_sinks"),
+    "two_head_blocks": (4, 4, 32, 2, "float32", None),  # hb > 1: the middle grid axis is 2
+}
+_DEAD_ROWS = {"first": [0], "last": [4], "two_in_a_row": [1, 2], "all_but_one": [0, 1, 3, 4]}
+
+
+@pytest.mark.parametrize("dead", sorted(_DEAD_ROWS))
+@pytest.mark.parametrize("variant", sorted(_DEAD_ROW_VARIANTS))
+def test_flash_decode_dead_rows(variant, dead, monkeypatch):
+    """Rows at length 0 (the engine's retired slots) beside live ones: the
+    live rows equal the XLA reference and are BIT-equal to the same call with
+    the dead rows left at their stale lengths (same blocks, same order, same
+    float32 state), and the dead rows come back finite and zero from the
+    kernel and from the reference alike."""
+    from prime_tpu.ops import pallas_attention as pa
+    from prime_tpu.ops.attention import decode_attention
+
+    h, kh, d, heads, cache, extra = _DEAD_ROW_VARIANTS[variant]
+    batch, c = 5, 1024
+    q, k, v, kw = _decode_operands(
+        len(variant), batch, h, kh, d, c, cache,
+        "bfloat16" if cache == "bfloat16" else "float32", extra,
+        heads=heads, block_c=256, monkeypatch=monkeypatch,
+    )
+
+    stale = np.asarray([1, 256, 257, 700, c], dtype=np.int32)  # where the rows' requests ended
+    lengths = stale.copy()
+    lengths[_DEAD_ROWS[dead]] = 0
+    live = lengths > 0
+    pa.flash_decode.clear_cache()
+    try:
+        out = pa.flash_decode(q, k, v, jnp.asarray(lengths), sm_scale=d**-0.5, interpret=True, **kw)
+        unmasked = pa.flash_decode(q, k, v, jnp.asarray(stale), sm_scale=d**-0.5, interpret=True, **kw)
+    finally:
+        pa.flash_decode.clear_cache()  # these traces baked the patched budget in
+    ref = decode_attention(q, k, v, jnp.asarray(lengths), d**-0.5, impl="xla", **kw)
+    out, unmasked, ref = (np.asarray(a, np.float32) for a in (out, unmasked, ref))
+    tol = 2e-2 if cache == "bfloat16" else 2e-3
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+    np.testing.assert_array_equal(out[live], unmasked[live])
+    assert np.all(out[~live] == 0) and np.all(ref[~live] == 0)
+
+
+def _walk_decode_blocks(lengths, blocks, block_c, head_blocks=1, window=0):
+    """(row, kv-head block, cache block) that flash-decode's K/V index map
+    names at every grid step, in the grid's order (row, head block, step)."""
+    from prime_tpu.ops import pallas_attention as pa
+
+    lengths = jnp.asarray(lengths, jnp.int32)
+    grid = np.stack(
+        np.meshgrid(
+            np.arange(len(lengths)), np.arange(head_blocks), np.arange(blocks), indexing="ij"
+        ),
+        axis=-1,
+    ).reshape(-1, 3)
+    index = jax.vmap(
+        lambda b, hb, step: jnp.stack(pa._decode_block_index(
+            b, hb, step, lengths, pa.decode_park_rows(lengths),
+            jnp.asarray([window], jnp.int32), block_c, blocks, head_blocks,
+        ))
+    )
+    return grid, np.asarray(index(*jnp.asarray(grid.T, jnp.int32)))
+
+
+@pytest.mark.parametrize(
+    "lengths",
+    [
+        "chat_no_row_dead", "chat_six_of_eight_dead", "leading_and_trailing_dead",
+        "dead_between_live", "only_the_last_live", "every_row_dead",
+    ],
+)
+@pytest.mark.parametrize("grid", ["qwen3_4b_8x1x4", "latent_64x1x4"])
+def test_a_dead_row_names_no_block_of_its_own(grid, lengths):
+    """The K/V index map walked over the whole grid: the pipeline copies a
+    block whenever consecutive steps name different ones, so the changes of
+    index are the copies, and they equal the live rows' live blocks (one copy
+    when every row is dead, the warm-up's call). A call with no row of length 0
+    names what it named before this table existed: each row its own blocks,
+    clipped into its live range, from the far end."""
+    batch = 8 if grid.startswith("qwen3") else 64
+    blocks, block_c = 4, 512
+    rng = np.random.default_rng(batch)
+    stale = rng.integers(1, 2049, size=batch)
+    stale[:4] = [1, 512, 513, 2048]  # the block boundaries
+    dead = {
+        "chat_no_row_dead": [],
+        "chat_six_of_eight_dead": [b for b in range(batch) if b % 4 not in (1, 2)][: batch * 3 // 4],
+        "leading_and_trailing_dead": [0, 1, batch - 2, batch - 1],
+        "dead_between_live": [2, 3, 4],
+        "only_the_last_live": list(range(batch - 1)),
+        "every_row_dead": list(range(batch)),
+    }[lengths]
+    lens = stale.copy()
+    lens[dead] = 0
+    steps, named = _walk_decode_blocks(lens, blocks, block_c)
+
+    copies = 1 + int(np.any(named[1:] != named[:-1], axis=1).sum())
+    live_blocks = int(np.sum(-(-lens // block_c)))
+    assert copies == max(live_blocks, 1)
+    # a live row's steps name its own blocks and nothing else
+    b, step = steps[:, 0], steps[:, 2]
+    own = lens[b] > 0
+    last = -(-lens[b] // block_c) - 1
+    np.testing.assert_array_equal(named[own, 0], b[own])
+    np.testing.assert_array_equal(named[own, 2], np.minimum(blocks - 1 - step, last)[own])
+    # a dead row's steps name a block some live row's steps name
+    live_named = {tuple(n) for n in named[own]}
+    assert all(tuple(n) in live_named for n in named[~own]) or not own.any()
+
+
+def test_dead_rows_park_with_head_blocks_and_a_window():
+    """The same count where a row's kv heads take two grid steps' worth of
+    blocks (MHA past the VMEM budget) and a sliding window front-skips
+    blocks: a live row's copies are head blocks x (last - first + 1)."""
+    lens = np.asarray([0, 1800, 0, 0, 300, 2048, 0], dtype=np.int32)
+    blocks, block_c, head_blocks, window = 4, 512, 2, 700
+    _, named = _walk_decode_blocks(lens, blocks, block_c, head_blocks, window)
+    copies = 1 + int(np.any(named[1:] != named[:-1], axis=1).sum())
+    first = np.maximum(lens - window, 0) // block_c
+    last = -(-lens // block_c) - 1
+    assert copies == int(np.sum(np.where(lens > 0, head_blocks * (last - first + 1), 0)))
 
 
 _SERVED = dict(itemsize=2, capacity=2048)
