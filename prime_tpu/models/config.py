@@ -140,8 +140,22 @@ class ModelConfig:
     # (width dense_ff) each, and one MoE (expert width d_ff) that reads the
     # first sub-layer's post-attention norm and is added after the second
     # sub-layer's FFN. Its attention/FFN/norm params stack (L, 2, ...), and
-    # the cache holds two rows a layer (cache_rows).
+    # the cache holds two rows a layer (cache_rows). "sambay"
+    # (Phi-4-mini-flash): layers come in pairs, each layer a mixer and a
+    # feed-forward under LayerNorm, no positional encoding. n_layers/4 pairs
+    # of (state-space, window attention), one pair of (state-space that also
+    # hands on its scan output, full attention), then n_layers/4 - 1 pairs
+    # of (gated memory unit over that output, cross attention over the full
+    # layer's K/V). Attention is differential. The cache holds a ring of
+    # sliding_window columns a window layer, ONE full-length row, and a
+    # recurrent state and a conv tail a state-space layer (models/llama.py
+    # CACHE_LEAVES).
     block: str = "standard"
+    # --- state-space (Mamba-1) mixer of the "sambay" block ---
+    ssm_d_inner: int | None = None   # None: 2 * d_model
+    ssm_d_state: int = 16
+    ssm_d_conv: int = 4
+    ssm_dt_rank: int | None = None   # None: ceil(d_model / 16)
     # zero-compute (identity) experts: router outputs n_experts.. add
     # gate * input, no weights (LongCat-Flash zero_expert_num)
     n_zero_experts: int = 0
@@ -160,8 +174,35 @@ class ModelConfig:
 
     @property
     def cache_rows(self) -> int:
-        """Rows of the stacked KV cache's leading axis: one an attention."""
+        """Rows of the stacked KV cache's leading axis: one an attention that
+        keeps a full-length row (the sambay block has one such layer)."""
+        if self.block == "sambay":
+            return 1
         return self.n_layers * (2 if self.block == "shortcut_moe" else 1)
+
+    @property
+    def window_pairs(self) -> int:
+        """sambay: (state-space, window attention) pairs; 0 for other blocks."""
+        return self.n_layers // 4 if self.block == "sambay" else 0
+
+    @property
+    def cross_pairs(self) -> int:
+        """sambay: (memory unit, cross attention) pairs; 0 for other blocks."""
+        return self.n_layers // 4 - 1 if self.block == "sambay" else 0
+
+    @property
+    def cross_half_layers(self) -> int:
+        """Layers that keep no state and whose output only the head reads
+        (the sambay block's cross half); 0 for other blocks."""
+        return 2 * self.cross_pairs
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_d_inner or 2 * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.d_model // 16)
 
     @property
     def experts_held(self) -> int:
@@ -231,6 +272,23 @@ class ModelConfig:
         else:
             mlp = 3 * self.d_model * self.d_ff
         norms = ((2 if self.pre_norms else 0) + (2 if self.post_norms else 0)) * self.d_model
+        if self.block == "sambay":
+            d, di, n, r = self.d_model, self.d_inner, self.ssm_d_state, self.dt_rank
+            qw, kvw = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+            ffn = 3 * d * self.d_ff + 4 * d  # two LayerNorms (weight, bias) a layer
+            ssm = (
+                d * 2 * di + di * (self.ssm_d_conv + 1) + di * (r + 2 * n)
+                + r * di + di + di * n + di + di * d
+            )
+            differential = 4 * self.head_dim + 2 * self.head_dim  # the lambdas, the 2-head norm
+            self_attn = d * (qw + 2 * kvw) + qw + 2 * kvw + qw * d + d + differential
+            cross_attn = d * qw + qw + qw * d + d + differential
+            memory = 2 * d * di
+            pairs = self.window_pairs + 1
+            return (
+                embed + head + 2 * d + self.n_layers * ffn
+                + pairs * (ssm + self_attn) + self.cross_pairs * (memory + cross_attn)
+            )
         if self.block == "shortcut_moe":
             # two attentions, two dense FFNs and their norms; one router (+
             # selection bias) and the experts HELD here
@@ -916,6 +974,42 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         moe_score_bias=True,
         routed_scaling_factor=6.0,
         norm_topk=False,
+    ),
+    # Phi-4-mini-flash-reasoning whole (microsoft/Phi-4-mini-flash-reasoning
+    # config.json): 32 layers at the published widths and vocabulary. The
+    # state-space sizes, the biases and the differential attention are the
+    # published model class's defaults (benchmark/configs/phi4-mini-flash.json
+    # lists them under `assumed`); they give the published 3.85 B parameters.
+    "phi4-mini-flash": ModelConfig(
+        name="phi4-mini-flash",
+        vocab_size=200064,
+        d_model=2560,
+        n_layers=32,
+        n_heads=40,
+        n_kv_heads=20,
+        d_ff=10240,
+        max_seq_len=262144,
+        rms_eps=1e-5,          # layer_norm_eps
+        tie_embeddings=True,
+        sliding_window=512,
+        block="sambay",
+    ),
+    # the same block kind at test scale: 8 layers, so each of the six layer
+    # kinds is there (2 window pairs, the middle pair, 1 cross pair)
+    "tiny-phi4flash": ModelConfig(
+        name="tiny-phi4flash",
+        vocab_size=512,
+        d_model=64,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        max_seq_len=512,
+        rms_eps=1e-5,
+        tie_embeddings=True,
+        sliding_window=8,
+        block="sambay",
+        ssm_d_state=4,
     ),
     # GPT-OSS architecture at test scale: sinks + biased clamped-GLU MoE +
     # alternating window + non-truncated yarn, all exercised on CPU

@@ -67,6 +67,12 @@ class KVCache(NamedTuple):
     lengths: jnp.ndarray  # (B,) valid entries per sequence
     k_scale: jnp.ndarray | None = None  # (L, B, KH, 1, C) fp32 when quantized
     v_scale: jnp.ndarray | None = None
+    # the sambay block (models/sambay.py): k/v are its ONE full-length row;
+    # beside it a window layer's ring and a state-space layer's carried state
+    ring_k: jnp.ndarray | None = None     # (window layers, B, KH, D, window)
+    ring_v: jnp.ndarray | None = None
+    ssm_state: jnp.ndarray | None = None  # (state layers, B, d_state, d_inner) float32
+    conv_tail: jnp.ndarray | None = None  # (state layers, B, d_conv - 1, d_inner)
 
     @property
     def capacity(self) -> int:
@@ -75,6 +81,32 @@ class KVCache(NamedTuple):
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+
+class CacheLeaf(NamedTuple):
+    """What a walk over the cache has to know of one of its leaves."""
+
+    slot_axis: int             # the axis a slot (a sequence) is a row of
+    capacity_axis: int | None  # the axis that grows with the slot capacity; None: a
+    #                            slot's part is as large whatever the capacity, and
+    #                            moves whole (a ring wraps, a state has no positions)
+    kind: str                  # what memory reports it as: "full" | "ring" | "state"
+
+
+# THE declaration of the cache's leaves (``lengths`` aside, which is per slot
+# and nothing else): a new kind of per-slot state is a field above, a line here
+# and its allocation in ``init_cache``; the engine's one walk over a cache
+# (serve/engine.py cache_leaves) places, pins, splices and counts from this.
+CACHE_LEAVES: dict[str, CacheLeaf] = {
+    "k": CacheLeaf(1, 4, "full"),
+    "v": CacheLeaf(1, 4, "full"),
+    "k_scale": CacheLeaf(1, 4, "full"),
+    "v_scale": CacheLeaf(1, 4, "full"),
+    "ring_k": CacheLeaf(1, None, "ring"),
+    "ring_v": CacheLeaf(1, None, "ring"),
+    "ssm_state": CacheLeaf(1, None, "state"),
+    "conv_tail": CacheLeaf(1, None, "state"),
+}
 
 
 def init_cache(
@@ -95,6 +127,28 @@ def init_cache(
             k=jnp.zeros((config.cache_rows, batch, 1, config.mla_cache_dim, capacity), dtype=dtype),
             v=jnp.zeros((config.cache_rows, batch, 1, 1, capacity), dtype=dtype),
             lengths=jnp.zeros((batch,), dtype=jnp.int32),
+        )
+    if config.block == "sambay":
+        # pairs of K/V heads are stored as one head of twice the width
+        # (models/sambay.py); the recurrent state is float32 whatever ``dtype``
+        if quantized:
+            raise ValueError("the sambay block's cache is not quantized: kv_quant is unsupported")
+        heads, width = config.n_kv_heads // 2, 2 * config.head_dim
+        state_layers = config.window_pairs + 1
+        row = (1, batch, heads, width, capacity)
+        ring = (config.window_pairs, batch, heads, width, config.sliding_window)
+        return KVCache(
+            k=jnp.zeros(row, dtype=dtype),
+            v=jnp.zeros(row, dtype=dtype),
+            lengths=jnp.zeros((batch,), dtype=jnp.int32),
+            ring_k=jnp.zeros(ring, dtype=dtype),
+            ring_v=jnp.zeros(ring, dtype=dtype),
+            ssm_state=jnp.zeros(
+                (state_layers, batch, config.ssm_d_state, config.d_inner), dtype=jnp.float32
+            ),
+            conv_tail=jnp.zeros(
+                (state_layers, batch, config.ssm_d_conv - 1, config.d_inner), dtype=dtype
+            ),
         )
     shape = (config.cache_rows, batch, config.n_kv_heads, config.head_dim, capacity)
     scale_shape = (config.cache_rows, batch, config.n_kv_heads, 1, capacity)
@@ -211,6 +265,10 @@ def init_params(rng: jax.Array, config: ModelConfig, dtype=jnp.bfloat16) -> Para
     """Random init (truncated-normal-ish scaled); checkpoint loaders overwrite."""
     if config.block == "shortcut_moe":
         return _init_shortcut_params(rng, config, dtype)
+    if config.block == "sambay":
+        from prime_tpu.models.sambay import init_params as init_sambay_params
+
+        return init_sambay_params(rng, config, dtype)
     keys = jax.random.split(rng, 17)
     d, hd = config.d_model, config.head_dim
     h, kh, ff, layers = config.n_heads, config.n_kv_heads, config.d_ff, config.n_layers
@@ -724,6 +782,11 @@ def forward(
     it).
     """
     batch, seq = tokens.shape
+    if config.block == "sambay":
+        return _forward_sambay(
+            params, tokens, config, cache, decode, attn_impl, return_aux,
+            prefill_offset, mesh, last_positions, adapters, token_mask, return_moe_stats,
+        )
     if config.mla:
         from prime_tpu.models.mla import validate_mla_config
 
@@ -855,7 +918,9 @@ def forward(
             return (x, aux_sum + aux, kv, stats), None
 
     else:
-        raise ValueError(f"Unknown block {config.block!r} (want 'standard' | 'shortcut_moe')")
+        raise ValueError(
+            f"Unknown block {config.block!r} (want 'standard' | 'shortcut_moe' | 'sambay')"
+        )
 
     if remat not in ("none", "full", "dots"):
         raise ValueError(f"Unknown remat {remat!r} (want 'none' | 'full' | 'dots')")
@@ -922,3 +987,45 @@ def forward(
     logits = _apply_softcap((x @ head).astype(jnp.float32), config.final_softcap)
     out = (logits, new_cache) + ((aux_total,) if return_aux else ())
     return out + ((moe_stats,) if return_moe_stats else ())
+
+
+def _forward_sambay(
+    params, tokens, config, cache, decode, attn_impl, return_aux, prefill_offset,
+    mesh, last_positions, adapters, token_mask, return_moe_stats,
+):
+    """``forward`` for the sambay block kind (models/sambay.py): the same
+    regimes and results. There are no positions to encode, so ``positions``
+    is not looked at. ``token_mask`` marks, at decode, the rows that belong to
+    somebody (the others attend over nothing; their recurrent state runs on
+    and reaches no one, since an admission copies a slot's state whole), and
+    in a prompt's chunk the real tokens (padding past a row's last token
+    leaves ring, state and conv tail as they were)."""
+    from prime_tpu.models.sambay import sambay_layers
+    from prime_tpu.ops.norms import layer_norm
+
+    if mesh is not None and getattr(mesh, "size", 1) > 1:
+        raise ValueError(
+            "the sambay block is not laid out for a mesh: its ring and recurrent "
+            "state have no sharding rule yet"
+        )
+    if adapters is not None:
+        raise ValueError("the sambay block serves no LoRA bank")
+    seq = tokens.shape[1]
+    x, leaves = sambay_layers(
+        params, params["embed"][tokens], config, cache, decode, attn_impl,
+        prefill_offset, token_mask, last_positions,
+    )
+    new_cache = None
+    if cache is not None:
+        new_cache = KVCache(
+            leaves[0], leaves[1], cache.lengths + (1 if decode else seq),
+            ring_k=leaves[2], ring_v=leaves[3], ssm_state=leaves[4], conv_tail=leaves[5],
+        )
+    x = layer_norm(x, params["final_norm"], params["final_norm_bias"], config.rms_eps)
+    logits = (x @ params["embed"].T).astype(jnp.float32)
+    out = (logits, new_cache) + ((jnp.zeros((), jnp.float32),) if return_aux else ())
+    if return_moe_stats:
+        from prime_tpu.ops.moe import MOE_STATS
+
+        out += (jnp.zeros((len(MOE_STATS),), jnp.int32),)
+    return out

@@ -41,7 +41,13 @@ QUANTIZED_LAYER_KEYS = (
     "wkv_a", "wq_a", "wq_b", "wkv_b",
     # DeepSeekMoE always-on shared expert (dense path through matmul())
     "w_shared_gate", "w_shared_up", "w_shared_down",
+    # the sambay block (models/sambay.py): fused qkv, state-space and memory
+    # unit in/out projections
+    "wqkv", "w_in", "w_out",
 )
+# the layer stacks a params tree may hold (dense_layers: the DeepSeek prefix;
+# mid_layers, cross_layers: the sambay block, whose stacks nest a dict a mixer)
+LAYER_SUBTREES = ("layers", "dense_layers", "mid_layers", "cross_layers")
 
 
 def quantize_weight(w: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -58,15 +64,20 @@ def quantize_params_int8(params: dict) -> dict:
     MoE expert stacks quantize the same way (the per-output-channel axis is
     still the last one). The rest of the tree is shared by reference.
     """
+    def walk(layers: dict) -> dict:
+        out = {}
+        for key, value in layers.items():
+            if isinstance(value, dict):
+                value = walk(value)
+            elif key in QUANTIZED_LAYER_KEYS and not isinstance(value, tuple):
+                value = quantize_weight(value)
+            out[key] = value
+        return out
+
     out = dict(params)
-    for subtree in ("layers", "dense_layers"):  # dense_layers: DeepSeek prefix
-        if subtree not in params:
-            continue
-        layers = dict(params[subtree])
-        for key in QUANTIZED_LAYER_KEYS:
-            if key in layers and not isinstance(layers[key], tuple):
-                layers[key] = quantize_weight(layers[key])
-        out[subtree] = layers
+    for subtree in LAYER_SUBTREES:
+        if subtree in params:
+            out[subtree] = walk(params[subtree])
     return out
 
 

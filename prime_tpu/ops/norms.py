@@ -1,4 +1,4 @@
-"""RMSNorm (the Llama-family norm).
+"""RMSNorm (the Llama-family norm) and LayerNorm.
 
 Computed in float32 regardless of input dtype (bf16 accumulation of squares
 loses precision at d_model >= 4096), cast back on output. XLA fuses this into
@@ -7,6 +7,7 @@ neighboring ops; a pallas kernel buys nothing here.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -23,3 +24,13 @@ def rms_norm(
     if plus_one:
         w32 = w32 + 1.0
     return (normed * w32).astype(dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """LayerNorm with weight and bias, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    centred = x32 - mean
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(var + eps)
+    return (normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)

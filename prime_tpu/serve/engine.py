@@ -87,6 +87,7 @@ lock), so an HTTP scrape never reads live counters mid-tick.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -128,11 +129,6 @@ DEFAULT_PREFIX_CACHE_MB = 256.0
 # larger than HBM, so deployments chasing millions-of-users prefix reuse set
 # this to several GB (--prefix-cache-host-mb / PRIME_SERVE_PREFIX_CACHE_HOST_MB)
 DEFAULT_PREFIX_CACHE_HOST_MB = 0.0
-# KVCache fields with a capacity axis (the segment/assemble unit); lengths is
-# capacity-free and rebuilt by init_cache at assemble time
-_CAPACITY_FIELDS = ("k", "v", "k_scale", "v_scale")
-
-
 def bucket_for(length: int, capacity: int) -> int:
     """Smallest power-of-two bucket (>= MIN_BUCKET, <= capacity) holding
     ``length`` — bounds the number of compiled prefill programs."""
@@ -226,23 +222,40 @@ def _power_batches(n: int, cap: int | None = None) -> list[int]:
     return out
 
 
-def _capacity_leaves(cache: Any) -> dict[str, Any]:
-    """The capacity-axis leaves ``cache`` holds, by field name (a bf16 cache
-    has no scales)."""
+def cache_leaves(cache: Any) -> dict[str, tuple[Any, Any]]:
+    """``{field: (leaf, its models.llama.CacheLeaf)}`` of the leaves ``cache``
+    holds (lengths aside): THE walk over a cache. What a leaf is (its slot
+    axis, whether it grows with the capacity, what memory reports it as) is
+    read from the declaration beside ``init_cache``, never from the model's
+    kind."""
+    from prime_tpu.models.llama import CACHE_LEAVES
+
     return {
-        name: leaf
-        for name in _CAPACITY_FIELDS
+        name: (leaf, spec)
+        for name, spec in CACHE_LEAVES.items()
         if (leaf := getattr(cache, name, None)) is not None
     }
 
 
-def _map_capacity_leaves(fn: Any, cache: Any, *others: Any) -> Any:
-    """``cache`` with ``fn(leaf, *the same leaf of others)`` in place of each
-    capacity-axis leaf: the one walk over the cache's fields behind placing,
-    pinning and splicing it (lengths is capacity-free and stays)."""
+def _capacity_leaves(cache: Any) -> dict[str, Any]:
+    """The capacity-axis leaves ``cache`` holds, by field name: what the prefix
+    cache cuts into segments (a bf16 cache has no scales)."""
+    return {
+        name: leaf for name, (leaf, spec) in cache_leaves(cache).items()
+        if spec.capacity_axis is not None
+    }
+
+
+def _map_cache_leaves(fn: Any, cache: Any, *others: Any, capacity_only: bool = False) -> Any:
+    """``cache`` with ``fn(spec, leaf, *the same leaf of others)`` in place of
+    each leaf: the one walk behind placing, pinning and splicing a cache
+    (lengths is per slot and stays). ``capacity_only`` leaves out the leaves
+    that have no capacity axis: a mesh's cache spec describes the
+    (L, B, KH, D, C) leaves and no other."""
     return cache._replace(**{
-        name: fn(leaf, *(getattr(other, name) for other in others))
-        for name, leaf in _capacity_leaves(cache).items()
+        name: fn(spec, leaf, *(getattr(other, name) for other in others))
+        for name, (leaf, spec) in cache_leaves(cache).items()
+        if not capacity_only or spec.capacity_axis is not None
     })
 
 
@@ -254,8 +267,9 @@ def _constrain(cache: Any, constraint: Any) -> Any:
         return cache
     import jax
 
-    return _map_capacity_leaves(
-        lambda leaf: jax.lax.with_sharding_constraint(leaf, constraint), cache
+    return _map_cache_leaves(
+        lambda _, leaf: jax.lax.with_sharding_constraint(leaf, constraint),
+        cache, capacity_only=True,
     )
 
 
@@ -659,6 +673,26 @@ class ContinuousBatchingEngine:
         # keeps the capacity einsum: a mesh, quantized experts)
         self._moe = bool(getattr(config, "is_moe", False))
         self.min_prefix = max(min_prefix, MIN_BUCKET)
+        # what a slot holds beside K/V columns, from the cache's declaration:
+        # leaves with no capacity axis (a ring, a recurrent state) move whole
+        held = cache_leaves(self._cache)
+        whole = sorted(n for n, (_, spec) in held.items() if spec.capacity_axis is None)
+        self._state_leaves = any(spec.kind == "state" for _, spec in held.values())
+        self._ring_columns = next(
+            (leaf.shape[-1] for leaf, spec in held.values() if spec.kind == "ring"), 0
+        )
+        if whole:
+            # the one refusal by block kind: a stored K/V segment is useless
+            # without the state at its end, and a verify window that is
+            # rejected would have to rewind it (ROADMAP R7: the state snapshot)
+            if prefix_cache_mb or self.speculative:
+                raise ValueError(
+                    f"a {config.block!r} model's slots hold state that is not K/V columns "
+                    f"({', '.join(whole)}): the prefix cache stores K/V segments without it "
+                    "and speculative decoding cannot rewind it. Serve with "
+                    "prefix_cache_mb=0 (--prefix-cache-mb 0) and without --speculative"
+                )
+            prefix_cache_mb = 0.0  # the default budget does not apply either
         if prefix_cache_mb is None:
             prefix_cache_mb = env_float(
                 "PRIME_SERVE_PREFIX_CACHE_MB", DEFAULT_PREFIX_CACHE_MB
@@ -973,6 +1007,35 @@ class ContinuousBatchingEngine:
             "serve_overlap_ratio",
             "1 - host-stall/chunk-window: fraction of the decode window the host overlapped",
         )
+        # what the slots hold, by kind of leaf (models/llama.py CACHE_LEAVES):
+        # full-length K/V rows, window rings, recurrent state
+        cache_bytes = r.gauge(
+            "serve_cache_bytes",
+            "Bytes of the slot cache at ready, by kind of leaf (full | ring | state)",
+            labelnames=("kind",),
+        )
+        for kind in ("full", "ring", "state"):
+            cache_bytes.set(
+                sum(leaf.nbytes for leaf, spec in held.values() if spec.kind == kind),
+                kind=kind,
+            )
+        self._m_state_resets = r.counter(
+            "serve_state_resets_total",
+            "Slots whose recurrent state an admission started from zero (one an "
+            "admitted request on a model that carries state; none on one that does not)",
+        )
+        self._m_prefill_layer_tokens = r.counter(
+            "serve_prefill_layer_tokens_total",
+            "Token-layers the chunk prefills computed, by half of the model: self "
+            "(the layers that keep K/V or state, at every prompt token) | cross (the "
+            "layers only the head reads: at a prompt's last position alone)",
+            labelnames=("half",),
+        )
+        self._m_ring_wraps = r.counter(
+            "serve_ring_wraps_total",
+            "Decode steps of live rows that overwrote a live column of a window ring "
+            "(a position at or past the ring's width)",
+        )
         self._m_warmup_programs = r.gauge(
             "serve_warmup_programs", "Programs executed by the AOT warmup pass"
         )
@@ -1121,8 +1184,8 @@ class ContinuousBatchingEngine:
             from jax.sharding import NamedSharding
 
             sharding = NamedSharding(self.mesh, self.cache_spec)
-            cache = _map_capacity_leaves(
-                lambda leaf: jax.device_put(leaf, sharding), cache
+            cache = _map_cache_leaves(
+                lambda _, leaf: jax.device_put(leaf, sharding), cache, capacity_only=True
             )
         # lengths ride inside the cache pytree (one donated unit per dispatch)
         self._cache = cache
@@ -1265,16 +1328,16 @@ class ContinuousBatchingEngine:
             # wave_ids are the wave members' adapter bank slots: the staged
             # KV is computed UNDER each request's adapter, which is why the
             # prefix cache keys adapter paths in a salted token space.
-            moe = {}
-            if moe_counts:
-                # the experts skip a row's padding past its last prompt position
-                live = jnp.arange(tokens.shape[1])[None, :] <= last_in_chunk[:, None]
-                moe = dict(token_mask=live, return_moe_stats=True)
+            # a row's padding past its last prompt position belongs to nobody:
+            # the experts skip it, and a ring or a recurrent state is left as
+            # the row's last real token left it
+            live = jnp.arange(tokens.shape[1])[None, :] <= last_in_chunk[:, None]
             logits, row, *stats = forward(
                 params, tokens, config, cache=row, decode=False,
                 attn_impl=attn_impl, prefill_offset=offset,
                 last_positions=last_in_chunk, mesh=mesh,
-                adapters=adapters, adapter_ids=wave_ids, **moe,
+                adapters=adapters, adapter_ids=wave_ids, token_mask=live,
+                return_moe_stats=moe_counts,
             )
             # sharded replica: pin the staged row's kv-head/tp placement so
             # the prefix segments sliced from it stay sharded in the cache
@@ -1618,10 +1681,11 @@ class ContinuousBatchingEngine:
                 cold_sizes = {s for _, s in chunk_plan(0, row_cb, self.prefill_chunk, row_cb)}
                 # prefix-hit suffixes admit singly with mid-prompt plans:
                 # every power-of-two chunk size up to min(prefill_chunk, row)
-                # is reachable at batch 1
+                # is reachable at batch 1 (with a prefix cache; without one no
+                # plan starts mid-prompt and the cold sizes are all there is)
                 prefix_sizes = set(cold_sizes)
                 s = MIN_BUCKET
-                while s <= min(self.prefill_chunk, row_cb):
+                while self.prefix_cache is not None and s <= min(self.prefill_chunk, row_cb):
                     prefix_sizes.add(s)
                     s *= 2
                 for n in batch_sizes:
@@ -2224,6 +2288,12 @@ class ContinuousBatchingEngine:
                 self._emit(req, toks_host[slot][: int(runs[slot])].tolist())
             else:
                 self.flight.event(req.id, "chunk", seq=chunk.seq)
+                if self._ring_columns:
+                    # the chunk's steps wrote positions first .. first + chunk - 1
+                    first = len(req.prompt_ids) + req.emitted - 1
+                    self._m_ring_wraps.inc(
+                        min(self.chunk, max(0, first + self.chunk - self._ring_columns))
+                    )
                 self._emit(req, toks_host[slot].tolist())
 
     def _retire_cancelled(self) -> None:
@@ -2364,9 +2434,13 @@ class ContinuousBatchingEngine:
             req.admitted_at = t_start
             self.flight.event(req.id, "admitted", slot=slot, wave=n)
         self._rng, rng = jax.random.split(self._rng)
+        # the new tenants' recurrent state starts from a fresh row's zeros;
+        # finalize copies it over whatever the slots' last tenants left
+        state_resets = n if row is None and self._state_leaves else 0
         if row is None:
             row = init_cache(self.config, n, row_cb, dtype=self._dtype, quantized=self.kv_quant)
         lengths = [len(r.prompt_ids) for r in reqs]
+        real_tokens = 0
         adapter_ids = _upload([r.adapter_idx for r in reqs], np.int32)
         logits = None
         moe_stats = None
@@ -2399,19 +2473,37 @@ class ContinuousBatchingEngine:
                 self.profiler.note_cost("prefill", self._chunk_fn, chunk_args)
                 row, logits, *stats = self._chunk_fn(*chunk_args)
                 moe_stats = self._sum_moe(moe_stats, stats)
-            (
-                self._cache, self._last, self._temps, self._top_ps,
-                self._adapter_slots, firsts,
-            ) = self._finalize_batch_fn(
-                self._cache, self._last, self._temps, self._top_ps,
-                self._adapter_slots, row, logits,
-                _upload(lengths, np.int32),
-                _upload(slots, np.int32),
-                _upload([r.temperature for r in reqs], np.float32),
-                _upload([r.top_p for r in reqs], np.float32),
-                adapter_ids,
-                rng,
+                real_tokens += sum(min(max(length - off, 0), size) for length in lengths)
+            # a chunk computes the cross half at one position a row
+            # (last_positions), every other layer at each real token
+            cross_half = self.config.cross_half_layers
+            self._m_prefill_layer_tokens.inc(
+                real_tokens * (self.config.n_layers - cross_half), half="self"
             )
+            self._m_prefill_layer_tokens.inc(len(plan) * n * cross_half, half="cross")
+            # the splice copies K/V by the row's columns and, where the slots
+            # hold them, ring and recurrent state whole: that copy has a span
+            state_copy = (
+                TRACER.span(
+                    "serve.state_copy", batch=n, row_capacity=row_cb,
+                    ring_columns=self._ring_columns,
+                )
+                if self._state_leaves else contextlib.nullcontext()
+            )
+            with state_copy:
+                (
+                    self._cache, self._last, self._temps, self._top_ps,
+                    self._adapter_slots, firsts,
+                ) = self._finalize_batch_fn(
+                    self._cache, self._last, self._temps, self._top_ps,
+                    self._adapter_slots, row, logits,
+                    _upload(lengths, np.int32),
+                    _upload(slots, np.int32),
+                    _upload([r.temperature for r in reqs], np.float32),
+                    _upload([r.top_p for r in reqs], np.float32),
+                    adapter_ids,
+                    rng,
+                )
             prof_step.fence(firsts)
         if self.speculative:
             # seed the device history ring before the host sync below — the
@@ -2455,6 +2547,7 @@ class ContinuousBatchingEngine:
             )
         self._m_admit_batch.observe(n)
         self._m_admitted.inc(n)
+        self._m_state_resets.inc(state_resets)  # beside the admissions it is read against
         if n > 1:
             self._m_batched_waves.inc()
         with self._clock.enter("emit"):
@@ -2480,16 +2573,20 @@ class ContinuousBatchingEngine:
             n = lengths.shape[0]
             zero = jnp.zeros((), jnp.int32)
 
-            def splice_all(cache_leaf, rows_leaf):
+            def splice_all(spec, cache_leaf, rows_leaf):
+                # a staged row lands at the start of its slot: a leaf with a
+                # capacity axis by the row's columns (the slot's length masks
+                # the rest), any other leaf whole (a ring, a recurrent state:
+                # the new tenant's, so nothing of the old one's outlives it)
                 def body(i, acc):
-                    row_i = jax.lax.dynamic_slice_in_dim(rows_leaf, i, 1, axis=1)
-                    return jax.lax.dynamic_update_slice(
-                        acc, row_i, (zero, slots[i], zero, zero, zero)
-                    )
+                    row_i = jax.lax.dynamic_slice_in_dim(rows_leaf, i, 1, axis=spec.slot_axis)
+                    at = [zero] * acc.ndim
+                    at[spec.slot_axis] = slots[i]
+                    return jax.lax.dynamic_update_slice(acc, row_i, tuple(at))
 
                 return jax.lax.fori_loop(0, n, body, cache_leaf)
 
-            new_cache = _constrain(_map_capacity_leaves(splice_all, cache, rows), cache_spec)
+            new_cache = _constrain(_map_cache_leaves(splice_all, cache, rows), cache_spec)
             firsts = _sample_batch(logits[:, 0, :], temps_new, top_ps_new, rng)
             # the first sampled tokens' KV is not in the cache yet: the next
             # decode step writes each at position ``length`` (put() scatters

@@ -526,3 +526,83 @@ def test_forward_decode_lowers_tp4(topo, monkeypatch, masked):
     for opcode in ("copy", "all-gather"):
         assert not [r for r in _results(compiled, opcode) if r.startswith(shard)]
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * B * KH * D * C * 2 // 4
+
+
+# ---- the sambay block at the served size: phi4-mini-flash, 64 slots x 4096 -----
+
+
+def _phi4_flash_engine_programs():
+    """The engine's decode and chunk_prefill bodies (serve/engine.py), with the
+    sampler left out: a scan of decode steps over the donated cache, and a
+    chunk of a staging row at an offset with the padding masked."""
+    from prime_tpu.models.config import get_config
+    from prime_tpu.models.llama import forward
+
+    config = get_config("phi4-mini-flash")
+
+    def decode(params, cache, tok, active):
+        def step(carry, _):
+            cache, tok = carry
+            logits, new_cache = forward(
+                params, tok[:, None], config, positions=cache.lengths[:, None], cache=cache,
+                decode=True, attn_impl="auto", token_mask=active[:, None],
+            )
+            new_cache = new_cache._replace(
+                lengths=jnp.where(active, new_cache.lengths, cache.lengths)
+            )
+            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            return (new_cache, tok), tok
+
+        (cache, _), toks = jax.lax.scan(step, (cache, tok), None, length=8)
+        return cache, toks
+
+    def chunk_prefill(params, row, tokens, offset, last_in_chunk):
+        live = jnp.arange(tokens.shape[1])[None, :] <= last_in_chunk[:, None]
+        logits, row = forward(
+            params, tokens, config, cache=row, attn_impl="auto", prefill_offset=offset,
+            last_positions=last_in_chunk, token_mask=live,
+        )
+        return row, logits
+
+    return config, decode, chunk_prefill
+
+
+def test_sambay_decode_fits_the_chip_with_ring_state_and_row_in_place(topo, monkeypatch):
+    """phi4-mini-flash's engine-shaped decode at 64 slots x 4096: all 32 layers
+    at the published widths beside the whole cache, under 15 GB on a v5e; the
+    full row and the rings go through the flash-decode kernel and the column
+    write, and the step holds no copy of a ring, of the state or of the row."""
+    from prime_tpu.ops import attention
+
+    # the chip's choice: a TPU backend sends a 4096-column row to the kernel
+    monkeypatch.setattr(attention, "_decode_pallas_eligible", lambda k: k.shape[-1] >= 2048)
+    config, decode, _ = _phi4_flash_engine_programs()
+    params, cache = _abstract_model(config, 64, 4096)
+    compiled = _compile(
+        topo, decode, params, cache, ((64,), jnp.int32), ((64,), jnp.bool_), donate=(1,)
+    )
+    memory = compiled.memory_analysis()
+    weights_and_cache = memory.argument_size_in_bytes
+    assert 10.0e9 < weights_and_cache < 11.5e9  # 7.7 GB of weights, 2.9 GB of slots
+    assert weights_and_cache + memory.temp_size_in_bytes < 15.0e9
+    # no cache-sized temporary: the largest leaf is a ring of 8 x 64 x 10 x 128 x 512 bf16
+    assert memory.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    assert len(re.findall("tpu_custom_call", text)) >= 4  # ring + row: write and attend
+    for leaf in ("bf16[8,64,10,128,512]", "bf16[1,64,10,128,4096]", "f32[9,64,16,5120]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+
+
+@pytest.mark.parametrize("wave", [1, 2])
+def test_sambay_chunk_prefill_fits_beside_the_engines_cache(topo, wave):
+    """A 512-token chunk of a 4096-column staging row, a wave of one and of two
+    (the widest the engine forms at these shapes): weights, row and temporaries
+    under the 15 GB less the 2.9 GB the engine's own slots hold."""
+    config, _, chunk_prefill = _phi4_flash_engine_programs()
+    params, row = _abstract_model(config, wave, 4096)
+    compiled = _compile(
+        topo, chunk_prefill, params, row, ((wave, 512), jnp.int32), ((), jnp.int32),
+        ((wave,), jnp.int32), donate=(1,),
+    )
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 2.9e9

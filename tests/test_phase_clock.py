@@ -351,3 +351,32 @@ def test_programs_json_names_are_the_engines_own():
                 assert re.search(programs[role], name), (platform, role, name)
                 others = [r for r in programs if r != role and re.search(programs[r], name)]
                 assert not others, (platform, name, others)
+
+
+def test_programs_json_finds_the_programs_of_an_engine_whose_slots_hold_state():
+    """The sambay block's engine (ring, row and recurrent state in a slot) jits
+    the same three functions under the same names, so the trace readers find
+    its decode, chunk prefill and finalize without an entry of their own."""
+    import jax
+    import jax.numpy as jnp
+
+    from prime_tpu.models import get_config
+    from prime_tpu.models.llama import init_params
+
+    layout = json.loads((REPO / "benchmark/layer_metrics/programs.json").read_text())
+    config = get_config("tiny-phi4flash")
+    engine = ContinuousBatchingEngine(
+        init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32), config,
+        max_slots=2, capacity=64, chunk=4,
+    )
+    assert engine.prefix_cache is None and engine._assemble_fn is None
+    built = {
+        "decode": engine._make_decode(),
+        "chunk_prefill": engine._make_chunk_prefill(),
+        "finalize": engine._make_finalize_batch(),
+    }
+    for platform, template in {"tpu": "jit_{}(", "cpu": "PjitFunction({})"}.items():
+        programs = layout[platform]["programs"]
+        for role, fn in built.items():
+            name = template.format(fn.__wrapped__.__name__)
+            assert [r for r in programs if re.search(programs[r], name)] == [role], (platform, name)
