@@ -480,7 +480,7 @@ def _attention_block(
     attn_impl: str,
     attend_lengths: jnp.ndarray | None = None,  # (B,) decode: slots each row attends over
     #                                             (None: its cache and this step's column)
-    park: jnp.ndarray | None = None,            # (B,) decode: the kernel's table for them
+    work: tuple | None = None,                  # decode: the kernel's work list for them
     prefill_offset: jnp.ndarray | None = None,  # () chunked prefill: write+attend at offset
     sliding: jnp.ndarray | None = None,  # () traced bool: this layer uses the window
     rope_tables_local: tuple[jnp.ndarray, jnp.ndarray] | None = None,
@@ -546,7 +546,7 @@ def _attention_block(
         kv = write_cache(kv, staged(k, v), layer, cache_lengths, impl=attn_impl)
         attn = decode_attention(
             q, kv[0], kv[1], cache_lengths + 1 if attend_lengths is None else attend_lengths,
-            sm_scale, impl=attn_impl, park=park,
+            sm_scale, impl=attn_impl, work=work,
             mesh=mesh, layer=layer, **_scales(kv), **gemma_kw,
         )
     elif prefill_offset is not None:
@@ -742,11 +742,15 @@ def forward(
     adapter_ids: jnp.ndarray | None = None,  # (B,) int32 per-row bank slots
     token_mask: jnp.ndarray | None = None,  # (B, S) bool: False rows belong to nobody
     return_moe_stats: bool = False,
+    return_attn_steps: bool = False,
 ):
     """Run the transformer. Returns (logits (B, S, V) fp32, updated cache),
     plus the summed MoE load-balance aux loss when ``return_aux``, plus the
     dropless expert layers' summed counts (``ops.moe.MOE_STATS``; zeros where
-    none ran) when ``return_moe_stats``.
+    none ran) when ``return_moe_stats``, plus, when ``return_attn_steps``, a
+    (2,) int32 vector: the grid steps this decode step's attention kernel calls
+    made and the most they could have made (``ops.attention.decode_work``;
+    zeros where no work list was made).
     With ``last_positions`` the head matmul runs on ONE gathered position per
     row and logits are (B, 1, V): a prefill that only needs each sequence's
     next-token logits skips S× the unembedding FLOPs and never materializes
@@ -786,6 +790,7 @@ def forward(
         return _forward_sambay(
             params, tokens, config, cache, decode, attn_impl, return_aux,
             prefill_offset, mesh, last_positions, adapters, token_mask, return_moe_stats,
+            return_attn_steps,
         )
     if config.mla:
         from prime_tpu.models.mla import validate_mla_config
@@ -855,14 +860,22 @@ def forward(
 
     cache_lengths = cache.lengths if cache is not None else None
     nobody = {}
+    attn_steps = jnp.zeros((2,), jnp.int32)
     if decode and token_mask is not None:
-        from prime_tpu.ops.pallas_attention import decode_park_rows
+        from prime_tpu.ops.attention import decode_work
 
         # the slots a row attends over: the cache and this step's column, and
-        # none for a row that belongs to nobody; with them the kernel's table of
-        # what such a row names instead of a block. Once, here, for every layer
+        # none for a row that belongs to nobody; with them the kernel's list of
+        # the grid steps those lengths need. Once, here, for every layer (a
+        # config whose layers alternate a window leaves the list to the kernel,
+        # which makes a layer's from its own window)
         attend_lengths = jnp.where(token_mask[:, 0], cache_lengths + 1, 0)
-        nobody = dict(attend_lengths=attend_lengths, park=decode_park_rows(attend_lengths))
+        nobody = dict(attend_lengths=attend_lengths)
+        if not config.sliding_window:
+            nobody["work"], attn_steps = decode_work(
+                attn_impl, config.n_heads, cache.k, cache.quantized, attend_lengths, mesh
+            )
+            attn_steps = attn_steps * cache.k.shape[0]  # a call a cache row
     # Per-layer sliding flag rides the scan so one compiled body serves both
     # kinds. The pattern is an explicit config field (ModelConfig.sliding_pattern)
     # so non-Gemma2 window schemes can't silently inherit the even alternation.
@@ -986,12 +999,13 @@ def forward(
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
     logits = _apply_softcap((x @ head).astype(jnp.float32), config.final_softcap)
     out = (logits, new_cache) + ((aux_total,) if return_aux else ())
-    return out + ((moe_stats,) if return_moe_stats else ())
+    out += (moe_stats,) if return_moe_stats else ()
+    return out + ((attn_steps,) if return_attn_steps else ())
 
 
 def _forward_sambay(
     params, tokens, config, cache, decode, attn_impl, return_aux, prefill_offset,
-    mesh, last_positions, adapters, token_mask, return_moe_stats,
+    mesh, last_positions, adapters, token_mask, return_moe_stats, return_attn_steps,
 ):
     """``forward`` for the sambay block kind (models/sambay.py): the same
     regimes and results. There are no positions to encode, so ``positions``
@@ -1011,7 +1025,7 @@ def _forward_sambay(
     if adapters is not None:
         raise ValueError("the sambay block serves no LoRA bank")
     seq = tokens.shape[1]
-    x, leaves = sambay_layers(
+    x, leaves, attn_steps = sambay_layers(
         params, params["embed"][tokens], config, cache, decode, attn_impl,
         prefill_offset, token_mask, last_positions,
     )
@@ -1028,4 +1042,4 @@ def _forward_sambay(
         from prime_tpu.ops.moe import MOE_STATS
 
         out += (jnp.zeros((len(MOE_STATS),), jnp.int32),)
-    return out
+    return out + ((attn_steps,) if return_attn_steps else ())

@@ -203,7 +203,7 @@ def _project_out(ctx_latent, lp, config: ModelConfig):
 def mla_attention_block(
     x, lp, positions, rope_tables, config: ModelConfig,
     kv, layer, cache_lengths, decode: bool, attn_impl: str,
-    attend_lengths=None, park=None, prefill_offset=None,
+    attend_lengths=None, work=None, prefill_offset=None,
 ):
     """Drop-in replacement for llama._attention_block on MLA configs.
 
@@ -241,7 +241,7 @@ def mla_attention_block(
         kv = write(cache_lengths, attn_impl)
         ctx = decode_attention(
             q_joint, kv[0], kv[0], cache_lengths + 1 if attend_lengths is None else attend_lengths,
-            sm_scale, impl=attn_impl, layer=layer, park=park,
+            sm_scale, impl=attn_impl, layer=layer, work=work,
         )
     elif prefill_offset is not None:
         kv = write(prefill_offset)

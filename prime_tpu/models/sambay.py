@@ -57,6 +57,7 @@ from prime_tpu.ops.attention import (
     attention_at_positions,
     cache_prefill_attention,
     decode_attention,
+    decode_work,
     ring_positions,
     write_cache,
     write_ring,
@@ -237,9 +238,11 @@ def sambay_layers(
     #                                   past a row's last token is
     last_positions: jnp.ndarray | None,
 ):
-    """Returns (x, cache leaves). ``x`` is (B, S, d), or (B, 1, d) at
-    ``last_positions`` where they were given. The leaves are ``(k, v, ring_k,
-    ring_v, ssm_state, conv_tail)`` updated, or () without a cache."""
+    """Returns (x, cache leaves, attention steps). ``x`` is (B, S, d), or
+    (B, 1, d) at ``last_positions`` where they were given. The leaves are
+    ``(k, v, ring_k, ring_v, ssm_state, conv_tail)`` updated, or () without a
+    cache. The steps are ``forward``'s ``return_attn_steps`` vector: a decode
+    step's flash-decode grid steps, made and at most, rings and row summed."""
     batch, seq, _ = x.shape
     hd = config.head_dim
     sm_scale = hd**-0.5
@@ -265,20 +268,25 @@ def sambay_layers(
         else jnp.full((batch,), seq, jnp.int32)
     )
 
-    lengths = attend = ring_attend = park = ring_impl = None
+    lengths = attend = ring_attend = work = ring_work = ring_impl = None
+    attn_steps = jnp.zeros((2,), jnp.int32)
     if decode:
-        from prime_tpu.ops.pallas_attention import decode_park_rows
-
         lengths = cache.lengths
         attend = lengths + 1
         if token_mask is not None:
             attend = jnp.where(token_mask[:, 0], attend, 0)
         ring_attend = jnp.minimum(attend, window)
-        park = decode_park_rows(attend)  # which rows are nobody's: the same for ring and row
         # the ring takes the path the slot's full row takes: beside the
         # flash-decode kernel, per-row dynamic_update_slice writes of a wide
         # batch would be a launch a row a layer
         ring_impl = "pallas" if _decode_uses_kernel(attn_impl, cache.k) else "xla"
+        # the kernel's grid steps for this step's lengths, once for the eight
+        # ring calls (a block a live row) and once for the row's eight
+        ring_work, ring_steps = decode_work(
+            ring_impl, config.n_heads, cache.ring_k, False, ring_attend
+        )
+        work, steps = decode_work(attn_impl, config.n_heads, cache.k, False, attend)
+        attn_steps = ring_steps * n_window + steps * (1 + config.cross_pairs)
 
     q_pos = offset + jnp.arange(seq, dtype=jnp.int32)[None, :]  # (1, S)
 
@@ -287,7 +295,7 @@ def sambay_layers(
         if decode:
             ring = write_cache(ring, (k_t, v_t), row, lengths % window, impl=ring_impl)
             attn = decode_attention(
-                q, ring[0], ring[1], ring_attend, sm_scale, impl=ring_impl, layer=row, park=park,
+                q, ring[0], ring[1], ring_attend, sm_scale, impl=ring_impl, layer=row, work=ring_work,
             )
         elif cached:
             # the ring as it stood, then the chunk's own columns
@@ -349,7 +357,7 @@ def sambay_layers(
     full = (cache.k, cache.v) if cached else ()
     if decode:
         full = write_cache(full, (k_t, v_t), zero, lengths, impl=attn_impl)
-        a = decode_attention(q, full[0], full[1], attend, sm_scale, impl=attn_impl, layer=zero, park=park)
+        a = decode_attention(q, full[0], full[1], attend, sm_scale, impl=attn_impl, layer=zero, work=work)
     elif cached:
         full = write_cache(full, (k_t, v_t), zero, offset)
         a = cache_prefill_attention(q, full[0], full[1], offset, sm_scale, layer=zero)
@@ -369,7 +377,7 @@ def sambay_layers(
         q = _pad_queries(_mm(h, lp["wqkv"]) + lp["bqkv"], config)
         if decode:
             return decode_attention(
-                q, full[0], full[1], attend, sm_scale, impl=attn_impl, layer=zero, park=park
+                q, full[0], full[1], attend, sm_scale, impl=attn_impl, layer=zero, work=work
             )
         if not cached:
             return attention_at_positions(
@@ -392,4 +400,4 @@ def sambay_layers(
     x, _ = jax.lax.scan(
         cross_pair, x, (jnp.arange(config.cross_pairs, dtype=jnp.int32), params["cross_layers"])
     )
-    return x, ((*full, *ring, state, tail) if cached else ())
+    return x, ((*full, *ring, state, tail) if cached else ()), attn_steps

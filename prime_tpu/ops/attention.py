@@ -171,7 +171,7 @@ def _flash_decode_min_capacity() -> int:
 
 def _decode_int4(
     q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-    k_scale, v_scale, softcap, window, sliding, sinks, layer, park,
+    k_scale, v_scale, softcap, window, sliding, sinks, layer, work,
 ):
     """int4-KV decode dispatch: a nibble-packed uint8 cache (a QUARTER of
     the bf16 bytes) rides the flash-decode kernel behind the same scales
@@ -190,7 +190,7 @@ def _decode_int4(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, layer=layer, park=park,
+            k_scale=k_scale, v_scale=v_scale, layer=layer, work=work,
             interpret=_pallas_interpret(),
         )
     k_f = unpack_kv_int4(_layer_of(k_cache, layer)) * _layer_of(k_scale, layer)
@@ -243,6 +243,12 @@ def _decode_uses_kernel(impl: str, k_cache: jnp.ndarray) -> bool:
     return impl == "pallas" or (impl == "auto" and _decode_pallas_eligible(k_cache))
 
 
+def _mesh_shards(mesh) -> tuple[int, int]:
+    """(ways the serving mesh splits the batch, ways it splits the kv heads)."""
+    shape = getattr(mesh, "shape", {})
+    return int(shape.get("dp", 1)) * int(shape.get("fsdp", 1)), int(shape.get("tp", 1))
+
+
 def _sharded_decode_eligible(k_cache, mesh, quantized: bool) -> bool:
     """Whether the shard_mapped flash-decode kernel can serve this step on
     ``mesh``: the kernel itself must be worth it (_decode_pallas_eligible —
@@ -251,11 +257,9 @@ def _sharded_decode_eligible(k_cache, mesh, quantized: bool) -> bool:
     non-empty (batch divisible by the data axes, kv heads by tp)."""
     if quantized or not _decode_pallas_eligible(k_cache):
         return False
-    shape = getattr(mesh, "shape", {})
-    data = int(shape.get("dp", 1)) * int(shape.get("fsdp", 1))
-    tp = int(shape.get("tp", 1))
-    if int(shape.get("sp", 1)) > 1:
+    if int(getattr(mesh, "shape", {}).get("sp", 1)) > 1:
         return False  # slot-sharded caches take the sp decode path, not the kernel
+    data, tp = _mesh_shards(mesh)
     batch, kv_heads = k_cache.shape[-4], k_cache.shape[-3]
     return batch % max(1, data) == 0 and kv_heads % max(1, tp) == 0
 
@@ -266,8 +270,10 @@ def decode_kernel_tiling(
     """(grid steps, block_c, kv heads a step) of the flash-decode call that
     ``decode_attention`` makes for this cache under ``impl``, from the same
     gates and the kernel's own shape function; zeros where decode takes an XLA
-    path. ``k_cache`` needs a shape and a dtype only. Under a mesh the kernel
-    sees a device's local rows and kv heads, and so does this."""
+    path. The grid steps are the most a call can make, every row full: a call
+    makes as many as its work list holds (``decode_work``). ``k_cache`` needs
+    a shape and a dtype only. Under a mesh the kernel sees a device's local
+    rows and kv heads, and so does this."""
     from prime_tpu.ops.pallas_attention import decode_call_tiling
 
     batch, kv_heads, kv_dim, capacity = k_cache.shape[-4:]
@@ -278,9 +284,8 @@ def decode_kernel_tiling(
             k_cache, mesh, quantized=quantized
         )
         if uses_kernel:
-            tp = int(mesh.shape.get("tp", 1))
-            batch //= int(mesh.shape.get("dp", 1)) * int(mesh.shape.get("fsdp", 1))
-            kv_heads, num_heads = kv_heads // tp, num_heads // tp
+            data, tp = _mesh_shards(mesh)
+            batch, kv_heads, num_heads = batch // data, kv_heads // tp, num_heads // tp
     else:
         uses_kernel = _decode_uses_kernel(impl, k_cache)
     if not uses_kernel:
@@ -288,6 +293,26 @@ def decode_kernel_tiling(
     local = jax.ShapeDtypeStruct((batch, kv_heads, kv_dim, capacity), k_cache.dtype)
     heads, block_c = decode_call_tiling(num_heads, local, quantized)
     return batch * (kv_heads // heads) * (capacity // block_c), block_c, heads
+
+
+def decode_work(
+    impl: str, num_heads: int, k_cache, quantized: bool, lengths: jnp.ndarray, mesh=None
+) -> tuple[tuple | None, jnp.ndarray]:
+    """(work, steps) for one decode step's attend ``lengths`` (B,) over this
+    cache: the flash-decode kernel's work list (``pallas_attention.
+    decode_work_list`` at the call's tiling), for a model to make once a step,
+    outside its layer loop, and hand to every layer's ``decode_attention``; and
+    a (2,) int32 vector, the grid steps a call makes with it and the most it
+    could make. (None, zeros) where decode takes an XLA path, and on a mesh
+    that shards the rows, where each device's kernel lists its own."""
+    from prime_tpu.ops.pallas_attention import decode_work_list
+
+    most, block_c, _ = decode_kernel_tiling(impl, num_heads, k_cache, quantized, mesh)
+    if not most or (impl == "sharded" and _mesh_shards(mesh)[0] > 1):
+        return None, jnp.zeros((2,), jnp.int32)
+    batch, blocks = k_cache.shape[-4], k_cache.shape[-1] // block_c
+    work = decode_work_list(lengths, block_c, blocks)
+    return work, jnp.stack([work[2] * (most // (batch * blocks)), jnp.int32(most)])
 
 
 def decode_attention(
@@ -305,15 +330,15 @@ def decode_attention(
     sinks: jnp.ndarray | None = None,    # (H,) per-head sink logits (GPT-OSS)
     mesh=None,                           # impl="sharded": the serving mesh
     layer: jnp.ndarray | int = 0,        # () layer of a stacked cache to attend over
-    park: jnp.ndarray | None = None,     # (B,) pallas_attention.decode_park_rows(
-    #                                      cache_lengths) made once for every layer
+    work: tuple | None = None,           # decode_work(...)[0] for these lengths,
+    #                                      made once for every layer
 ) -> jnp.ndarray:
     """One decode step against the cache, masking invalid (future) slots. A
     row of length 0 (a slot that belongs to nobody) comes back zero on every
-    path, and on the kernel paths reads no cache block. ``park`` is that
-    kernel's table of which block such a row names instead: the bare kernel
-    takes the caller's, the shard_mapped one makes its own from its local rows
-    and the einsum path has no use for it.
+    path, and on the kernel paths costs no grid step and reads no cache block.
+    ``work`` is that kernel's list of the grid steps these lengths need
+    (``decode_work``): the kernel takes the caller's or makes its own (as it
+    must where a mesh shards the rows), and the einsum path has no use for it.
 
     The model hands over the stacked cache its layer scan carries and the
     traced ``layer``: the kernel paths put the layer in their index maps and
@@ -343,7 +368,7 @@ def decode_attention(
         # multi-device gate allows, XLA widen-and-fold reference otherwise
         return _decode_int4(
             q, k_cache, v_cache, cache_lengths, sm_scale, impl,
-            k_scale, v_scale, softcap, window, sliding, sinks, layer, park,
+            k_scale, v_scale, softcap, window, sliding, sinks, layer, work,
         )
     if impl == "sharded":
         if mesh is not None and _sharded_decode_eligible(
@@ -354,7 +379,7 @@ def decode_attention(
             return flash_decode_sharded(
                 q, k_cache, v_cache, cache_lengths, mesh, sm_scale=sm_scale,
                 softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-                layer=layer, interpret=_pallas_interpret(),
+                layer=layer, work=work, interpret=_pallas_interpret(),
             )
         impl = "xla"  # SPMD-safe einsum path, partitioned by GSPMD
     if _decode_uses_kernel(impl, k_cache):
@@ -368,7 +393,7 @@ def decode_attention(
         return flash_decode(
             q, k_cache, v_cache, cache_lengths, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding, sinks=sinks,
-            k_scale=k_scale, v_scale=v_scale, layer=layer, park=park,
+            k_scale=k_scale, v_scale=v_scale, layer=layer, work=work,
             interpret=_pallas_interpret(),
         )
 

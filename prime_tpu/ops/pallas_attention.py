@@ -5,20 +5,20 @@ blocks through VMEM keeping running (max, sum, output) accumulators in fp32
 scratch — O(S) memory instead of the O(S^2) score matrix, and every matmul
 lands on the MXU at (BLOCK, head_dim)x(head_dim, BLOCK) granularity.
 
-The KV/cache-block axis is a GRID dimension in both kernels — prefill:
-(batch, q_heads, S // BLOCK_Q, S // BLOCK_K); decode: (batch, kv_heads //
-heads, C // block_c), where a grid step carries ``heads`` kv heads of a row
-and a cache block as wide as VMEM allows (``decode_tiling``: every head and
-512 slots at the served shapes, so a call is tens of steps, not a
-thousand) — with the online-softmax state carried across the innermost
-axis ("arbitrary" semantics). The index maps clip each step's block
-coordinate into the live range (causal diagonal / sliding-window band /
-scalar-prefetched cache lengths); out-of-range steps revisit an already-
-resident block, and Mosaic elides the copy when the index map repeats
-itself — so dead blocks are never READ from HBM, not merely skipped in
-compute. That distinction is load-bearing: these ops are HBM-bandwidth-
-bound, and an earlier design that DMA'd the full operand per program and
-skipped only compute lost to XLA's read-it-all path.
+The KV/cache-block axis is a GRID dimension in both kernels, with the
+online-softmax state carried across the innermost axis ("arbitrary"
+semantics). Prefill: (batch, q_heads, S // BLOCK_Q, S // BLOCK_K); the index
+maps clip each step's block coordinate into the live range (causal diagonal /
+sliding-window band), out-of-range steps revisit an already-resident block,
+and Mosaic elides the copy when the index map repeats itself — so dead blocks
+are never READ from HBM, not merely skipped in compute. Decode: (kv_heads //
+heads, n), where a grid step carries ``heads`` kv heads of a row and a cache
+block as wide as VMEM allows (``decode_tiling``: every head and 512 slots at
+the served shapes) and ``n`` is a DYNAMIC bound: the length of the step's
+work list (``decode_work_list``), the (row, cache block) pairs that hold a
+live column. A block nobody attends over has no grid step at all. These ops
+are HBM-bandwidth-bound, and an earlier design that DMA'd the full operand per
+program and skipped only compute lost to XLA's read-it-all path.
 
 GQA is handled in the BlockSpec index maps: query head h reads kv head
 h // (H // KH), so grouped KV is never materialized per-query-head in HBM.
@@ -101,8 +101,9 @@ def _prefill_band(qb, window_ref, block_q: int, block_k: int):
     causal cuts blocks strictly above the diagonal, a sliding window cuts
     blocks entirely before the band. Shared by the kernel's compute gate and
     the k/v index maps — the index-map clip makes out-of-range grid steps
-    revisit a resident block so their copies are elided (see
-    _decode_live_block for the mechanism)."""
+    revisit a resident block: Mosaic elides an operand's copy when its index
+    map returns the same block as the previous iteration, so the clip turns
+    the mask-level early exit into an actual HBM-bytes saving."""
     window = window_ref[0]
     causal_last = (qb * block_q + block_q - 1) // block_k
     band_start = jnp.where(
@@ -186,59 +187,37 @@ def _flash_kernel(
         ).astype(o_ref.dtype)
 
 
-def _decode_live_block(b, cb, lengths_ref, window_ref, block_c: int):
-    """The cache block of row ``b`` this grid step should have resident: the
-    block coordinate clipped into the sequence's live [first, last] range. Out-
-    of-range steps REVISIT an edge block — Mosaic elides the operand copy when
-    the index map returns the same block as the previous iteration, so the
-    clip turns the mask-level early exit into an actual HBM-bytes saving
-    (the previous design DMA'd the full capacity into VMEM per program and
-    the fori_loop skip saved only compute, which is why XLA's read-it-all
-    path kept winning the microbenches)."""
-    length = lengths_ref[b]
-    window = window_ref[0]
-    num = jnp.maximum(pl.cdiv(length, block_c), 1)
-    first_slot = jnp.where(window > 0, jnp.maximum(length - window, 0), 0)
-    first = first_slot // block_c
-    return jnp.clip(cb, first, jnp.maximum(num - 1, first))
-
-
-def decode_park_rows(lengths: jnp.ndarray) -> jnp.ndarray:
-    """(B,) the row whose cache block each row's grid steps name: itself for a
-    live row, and for a row of length 0 (a slot that belongs to nobody) the
-    next live row, else the last live row before it, else row 0. A dead row
-    reads nothing, so all it can do for the pipeline is not to ask for a block
-    of its own: ``_decode_block_index`` points every one of its steps at the
-    block its neighbour's steps name, and equal consecutive indices copy
-    nothing. A function of the lengths alone, so the model makes it once a
-    step, outside its layer loop (inside, the v5e's compiler hoists the two
-    scans and leaves the select, a launch a layer)."""
+def decode_work_list(
+    lengths: jnp.ndarray, block_c: int, blocks: int, window=0
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(rows, cache_blocks, n): flash-decode's grid steps for these attend
+    lengths (0 for a row that belongs to nobody) over a cache of ``blocks``
+    blocks of ``block_c`` slots. The first ``n`` entries of the two
+    (B * blocks + 1,) tables are the (row, cache block) pairs that hold a live
+    column, rows in order and a row's blocks front to back: blocks
+    [first, cdiv(length, block_c)) where ``first`` skips what a sliding
+    ``window`` (a static int or a traced scalar, 0 = none) leaves behind. The
+    tail repeats the last pair: the pipeline reads the index maps a step ahead,
+    and an equal index copies nothing. Every row full, the list is the static
+    grid's walk, B * blocks steps. A call whose rows are all dead keeps one
+    step (``n`` >= 1), whose result ``flash_decode`` discards. A function of
+    the lengths alone, so a model makes it once a decode step, outside its
+    layer loop, and hands it to every layer's call."""
     batch = lengths.shape[0]
-    rows = jnp.arange(batch, dtype=jnp.int32)
-    live = lengths > 0
-    ahead = jax.lax.cummin(jnp.where(live, rows, batch), reverse=True)
-    behind = jax.lax.cummax(jnp.where(live, rows, -1))
-    return jnp.where(ahead < batch, ahead, jnp.maximum(behind, 0))
-
-
-def _decode_block_index(
-    b, hb, step, lengths_ref, park_ref, window_ref,
-    block_c: int, blocks: int, head_blocks: int,
-):
-    """(row, kv-head block, cache block) of the K/V block grid step
-    ``(b, hb, step)`` names. A live row walks its own blocks from the far end
-    (``flash_decode``'s kv_map). Every step of a dead row names ONE block that
-    a live neighbour's steps name (``decode_park_rows``): the block the next
-    live row asks for first, which then arrives early instead of wasted, or
-    the block the last live row ended on. Scalar work a grid step, on a core
-    that has 0.2 us for it: a parked row is told from its own by the table
-    alone (row 0 of a call whose rows are all dead names its own block 0)."""
-    row = park_ref[b]
-    ahead, behind = row > b, row < b
-    cb = jnp.where(ahead, blocks - 1, jnp.where(behind, 0, blocks - 1 - step))
-    if head_blocks > 1:
-        hb = jnp.where(ahead, 0, jnp.where(behind, head_blocks - 1, hb))
-    return row, hb, _decode_live_block(row, cb, lengths_ref, window_ref, block_c)
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, blocks * block_c)
+    window = jnp.asarray(window, jnp.int32).reshape(())
+    first = jnp.where(window > 0, jnp.maximum(lengths - window, 0), 0) // block_c
+    counts = pl.cdiv(lengths, block_c) - first  # 0 for a dead row
+    ends = jnp.cumsum(counts)
+    n = ends[-1]
+    steps = jnp.minimum(jnp.arange(batch * blocks + 1, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    # the row of step i: as many rows as end at or before it (dead rows among
+    # them, which is how they are passed over)
+    rows = jnp.minimum(jnp.sum(ends[None, :] <= steps[:, None], axis=1), batch - 1)
+    # its block: first[row] + (i - the row's first step), picked without a gather
+    own = rows[:, None] == jnp.arange(batch, dtype=jnp.int32)[None, :]
+    base = jnp.sum(jnp.where(own, (first - ends + counts)[None, :], 0), axis=1)
+    return rows.astype(jnp.int32), (base + steps).astype(jnp.int32), jnp.maximum(n, 1)
 
 
 def _unpack_kv_nibbles(packed):
@@ -281,11 +260,12 @@ def decode_tiling(
     PRIME_TPU_BLOCK_C or a tuned artifact's value; 0 = none), 512, 256, 128
     that divides the capacity, and with it the most kv heads of a row (a
     divisor of ``kv_heads``) whose step stays inside DECODE_VMEM_BUDGET. A
-    step costs 0.2 us on a v5e whether or not it moves a block (PERF.md, PR
-    27), so fewer and wider steps win until the blocks no longer fit: qwen3-4b's
-    8 x 128 x 2048 bf16 rows come out at (8, 512), 4 steps a row; a 576-wide
-    latent of one head at (1, 512); a 32-head MHA cache at (16, 512). A
-    capacity that none of the widths divides is one block."""
+    step costs 0.2 us on a v5e beside what its block costs (PERF.md, PR 27),
+    so fewer and wider steps win until the blocks no longer fit: qwen3-4b's
+    8 x 128 x 2048 bf16 rows come out at (8, 512), at most 4 steps a row; a
+    576-wide latent of one head at (1, 512); a 32-head MHA cache at (16, 512).
+    A capacity that none of the widths divides is one block. Which of a cache's
+    blocks get a step is not this function's: ``decode_work_list``."""
     widths = [
         b for b in dict.fromkeys((prefer, 512, 256, BLOCK_C))
         if 0 < b <= capacity and capacity % b == 0
@@ -320,7 +300,8 @@ def decode_call_tiling(num_heads: int, k_cache, quantized: bool) -> tuple[int, i
 
 def _decode_kernel(
     lengths_ref,  # (B,) scalar-prefetch, SMEM
-    park_ref,     # (B,) scalar-prefetch: read by the K/V index maps only
+    rows_ref,     # (B * blocks + 1,) scalar-prefetch: the work list's row and
+    blocks_ref,   # cache block of each grid step (decode_work_list)
     window_ref,   # (1,) scalar-prefetch: effective window (0 = global layer)
     layer_ref,    # (1,) scalar-prefetch: read by the K/V index maps only
     q_ref,        # (1, HEADS, G, D): every query head of this step's kv heads
@@ -330,10 +311,10 @@ def _decode_kernel(
     *rest,        # int8/int4 path: k_scale_ref, v_scale_ref (1, HEADS, 1, BLOCK_C);
                   # then sinks_ref (KH, G), o_ref (1, HEADS, G, D),
                   # scratch: m (HEADS, G, 128), l (HEADS, G, 128),
-                  # acc (HEADS, G, D) — all fp32, carried across the
-                  # cache-block grid dimension
+                  # acc (HEADS, G, D) — all fp32, carried across a row's steps
     sm_scale: float,
     block_c: int,
+    capacity: int,
     softcap: float,
     use_sinks: bool,
     quant: str | None,  # None | "int8" | "int4" cache carrier
@@ -347,80 +328,74 @@ def _decode_kernel(
 
     # program ids hoisted out of the pl.when closures: inside them the HLO
     # interpreter (CPU tests) has no lowering for the primitive
-    b = pl.program_id(0)
-    hb = pl.program_id(1)
-    step = pl.program_id(2)
-    last_step = pl.num_programs(2) - 1
-    # a row's blocks are walked from the far end (flash_decode's kv_map): the
-    # steps past its length come first, its live blocks last
-    cb = last_step - step
-    length = lengths_ref[b]
+    hb = pl.program_id(0)
+    step = pl.program_id(1)
+    # every step that runs is a live block of a live row: the list says whose
+    cb = blocks_ref[step]
+    length = jnp.minimum(lengths_ref[rows_ref[step]], capacity)
     window = window_ref[0]
     # the query sits at position length-1; a sliding layer sees slots
     # [length-window, length), a global layer (window 0) sees [0, length)
     first_slot = jnp.where(window > 0, jnp.maximum(length - window, 0), 0)
-    first = first_slot // block_c
-    num = pl.cdiv(length, block_c)
 
-    @pl.when(step == 0)
+    # a row's steps are its blocks [first, num), front to back
+    @pl.when(cb == first_slot // block_c)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, dtype=jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, dtype=jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, dtype=jnp.float32)
 
-    @pl.when((cb >= first) & (cb < num))
-    def _accumulate():
-        slots = cb * block_c + jax.lax.broadcasted_iota(
-            jnp.int32, (group, block_c), 1
+    slots = cb * block_c + jax.lax.broadcasted_iota(
+        jnp.int32, (group, block_c), 1
+    )
+    live = (slots < length) & (slots >= first_slot)
+    # a cache stored as q is (bf16 under bf16 queries) goes to the MXU as it
+    # is: products of bf16 values are exact in the float32 accumulator, so
+    # only the summation order differs from widening first, and the K block
+    # is not widened. V always is: the probabilities stay float32
+    widen = quant is not None or k_ref.dtype != q_ref.dtype
+    # the same online softmax for each kv head the step holds: a static
+    # loop, so one head's widened block is live at a time
+    for h in range(heads):
+        q, k, v = q_ref[0, h], k_ref[0, h], v_ref[0, h]  # (G, D), (D, BC) x 2
+        if quant == "int4":
+            # int4 streams a QUARTER of the bf16 bytes from HBM; the nibble
+            # widening happens on the VMEM-resident block, and the same
+            # per-slot scales the int8 path uses fold into the epilogues
+            k, v = _unpack_kv_nibbles(k), _unpack_kv_nibbles(v)
+        else:
+            v = v.astype(jnp.float32)
+        if widen:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale  # (G, BC)
+        if quant is not None:
+            # int8/int4 stream from HBM at reduced bytes and widen to fp32
+            # in VMEM; the per-slot scales are column-constant so they fold
+            # into the epilogues, no dequantized cache is materialized
+            scores = scores * k_scale_ref[0, h].astype(jnp.float32)  # (1, BC)
+        if softcap:
+            scores = jnp.tanh(scores / softcap) * softcap
+        scores = jnp.where(live, scores, NEG_INF)
+
+        m_prev = m_scr[h, :, :1]  # (G, 1)
+        l_prev = l_scr[h, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        weighted = (
+            p if quant is None else p * v_scale_ref[0, h].astype(jnp.float32)
         )
-        live = (slots < length) & (slots >= first_slot)
-        # a cache stored as q is (bf16 under bf16 queries) goes to the MXU as it
-        # is: products of bf16 values are exact in the float32 accumulator, so
-        # only the summation order differs from widening first, and the K block
-        # is not widened. V always is: the probabilities stay float32
-        widen = quant is not None or k_ref.dtype != q_ref.dtype
-        # the same online softmax for each kv head the step holds: a static
-        # loop, so one head's widened block is live at a time
-        for h in range(heads):
-            q, k, v = q_ref[0, h], k_ref[0, h], v_ref[0, h]  # (G, D), (D, BC) x 2
-            if quant == "int4":
-                # int4 streams a QUARTER of the bf16 bytes from HBM; the nibble
-                # widening happens on the VMEM-resident block, and the same
-                # per-slot scales the int8 path uses fold into the epilogues
-                k, v = _unpack_kv_nibbles(k), _unpack_kv_nibbles(v)
-            else:
-                v = v.astype(jnp.float32)
-            if widen:
-                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            ) * sm_scale  # (G, BC)
-            if quant is not None:
-                # int8/int4 stream from HBM at reduced bytes and widen to fp32
-                # in VMEM; the per-slot scales are column-constant so they fold
-                # into the epilogues, no dequantized cache is materialized
-                scores = scores * k_scale_ref[0, h].astype(jnp.float32)  # (1, BC)
-            if softcap:
-                scores = jnp.tanh(scores / softcap) * softcap
-            scores = jnp.where(live, scores, NEG_INF)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            weighted, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (G, D)
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-            m_prev = m_scr[h, :, :1]  # (G, 1)
-            l_prev = l_scr[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-            p = jnp.exp(scores - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            weighted = (
-                p if quant is None else p * v_scale_ref[0, h].astype(jnp.float32)
-            )
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                weighted, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (G, D)
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-
-    @pl.when(step == last_step)
+    @pl.when(cb == pl.cdiv(length, block_c) - 1)
     def _finalize():
         for h in range(heads):
             # the sinks block is the FULL (KH, G) array (a (1, G) slice would
@@ -452,17 +427,21 @@ def flash_decode(
     k_scale: jnp.ndarray | None = None,  # ([L,] B, KH, 1, C) int8-cache dequant scales
     v_scale: jnp.ndarray | None = None,
     layer: jnp.ndarray | int = 0,        # () which layer of the stacked cache to read
-    park: jnp.ndarray | None = None,     # (B,) decode_park_rows(cache_lengths), where
-    #                                      the caller made it once for all its layers
+    work: tuple | None = None,           # decode_work_list(cache_lengths, ...) for this
+    #                                      call's tiling and window, where the caller
+    #                                      made it once for all its layers
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """One fused decode step: each program takes a row and as many of its kv
-    heads as VMEM holds (``decode_tiling``: all of them at the served shapes)
-    and streams the cache through VMEM in wide blocks with online softmax,
-    stopping at the sequence's true length (scalar-prefetched). C must be a
-    multiple of BLOCK_C. The feature-major cache keeps reads lane-aligned for
-    any head_dim. A row of length 0 (the engine's retired slots) comes back
-    zero and fetches no cache block (``decode_park_rows``).
+    """One fused decode step: a grid step takes one cache block of a row, as
+    wide as VMEM allows, with as many of the row's kv heads as fit beside it
+    (``decode_tiling``: all of them and 512 slots at the served shapes), and
+    folds it into the row's online softmax. The steps are the call's work list
+    (``decode_work_list``) under a dynamic grid bound: one for every block that
+    holds a column some row attends over, rows in order, and none for the rest
+    of the cache, so a call's steps and its HBM traffic both track the true
+    lengths, to the block. C must be a multiple of BLOCK_C. The feature-major
+    cache keeps reads lane-aligned for any head_dim. A row of length 0 (the
+    engine's retired slots) is in no step and comes back zero.
 
     The cache operand is the model's whole stacked (L, B, KH, D, C) buffer and
     ``layer`` a third scalar-prefetch operand that the K/V (and scale) index
@@ -509,27 +488,22 @@ def flash_decode(
 
     blocks = capacity // block_c
     lengths = cache_lengths.astype(jnp.int32)
-    if park is None:
-        park = decode_park_rows(lengths)
+    if work is None:
+        work = decode_work_list(lengths, block_c, blocks, window_arr)
+    rows, cache_blocks, n = work
 
-    def kv_map(b, hb, step, lens, parked, win, lay):
+    def row_map(hb, step, lens, rows, cbs, win, lay):
+        return (rows[step], hb, 0, 0)
+
+    def kv_map(hb, step, lens, rows, cbs, win, lay):
         # shared by k/v AND the int8 scale blocks: the scale block must
-        # always ride the same live-block index as its cache block. A row's
-        # blocks are walked from the far end, so the steps past its length
-        # come FIRST and revisit its last live block: that block is asked for
-        # at the row's first step and streams in under the previous row's
-        # last block of work. Walked from the front, a row of one live block
-        # (most of a chat batch) waits for its whole copy in the open
-        # (PERF.md, PR 27). A row of length 0 asks for no block of its own
-        row, hb, block = _decode_block_index(
-            b, hb, step, lens, parked, win, block_c, blocks, kv_heads // heads
-        )
-        return (lay[0], row, hb, 0, block)
+        # always ride the same block index as its cache block
+        return (lay[0], rows[step], hb, 0, cbs[step])
 
     # the layer axis is squeezed (None): the kernel sees the same
     # (1, HEADS, D, BLOCK_C) blocks whichever layer the index map picked
     qkv_specs = [
-        pl.BlockSpec((1, heads, group, head_dim), lambda b, hb, step, *_: (b, hb, 0, 0)),
+        pl.BlockSpec((1, heads, group, head_dim), row_map),
         pl.BlockSpec((None, 1, heads, kv_dim, block_c), kv_map),
         pl.BlockSpec((None, 1, heads, kv_dim, block_c), kv_map),
     ]
@@ -537,10 +511,10 @@ def flash_decode(
         pl.BlockSpec((None, 1, heads, 1, block_c), kv_map),
         pl.BlockSpec((None, 1, heads, 1, block_c), kv_map),
     ]
-    sinks_spec = pl.BlockSpec((kv_heads, group), lambda b, hb, step, *_: (0, 0))
+    sinks_spec = pl.BlockSpec((kv_heads, group), lambda hb, step, *_: (0, 0))
     kernel = functools.partial(
-        _decode_kernel, sm_scale=sm_scale, block_c=block_c, softcap=softcap,
-        use_sinks=use_sinks, quant=quant,
+        _decode_kernel, sm_scale=sm_scale, block_c=block_c, capacity=capacity,
+        softcap=softcap, use_sinks=use_sinks, quant=quant,
     )
     if quantized:
         in_specs = qkv_specs + scale_specs + [sinks_spec]
@@ -549,17 +523,14 @@ def flash_decode(
         in_specs = qkv_specs + [sinks_spec]
         operands = (k_cache, v_cache, sinks_arr)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        # a program is a row and `heads` of its kv heads (all of them where
-        # they fit, so the middle axis is 1). The cache-block axis is a GRID
-        # dimension: blocks outside a sequence's live range revisit a
-        # resident block (index-map clip) and their copies are elided, so HBM
-        # traffic tracks true lengths, to the block, not capacity
-        grid=(batch, kv_heads // heads, blocks),
+        num_scalar_prefetch=5,
+        # a step is one listed cache block of a row with `heads` of its kv
+        # heads (all of them where they fit, so the first axis is 1); the
+        # second axis is the work list, its bound traced: at most
+        # batch * blocks steps, and as many as the rows' lengths need
+        grid=(kv_heads // heads, n),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, heads, group, head_dim), lambda b, hb, step, *_: (b, hb, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, heads, group, head_dim), row_map),
         scratch_shapes=[
             pltpu.VMEM((heads, group, 128), jnp.float32),       # running max
             pltpu.VMEM((heads, group, 128), jnp.float32),       # running denominator
@@ -571,14 +542,12 @@ def flash_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kv_heads, group, head_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            # flops/transcendentals stay full-capacity: the grid executes
-            # every cache-block step, but only the steps inside a row's live
-            # range compute (the pl.when gate), so this is the ceiling.
-            # bytes_accessed uses the elided-read convention, same as
-            # flash_attention_causal: the live-block index-map clip copies
+            # flops/transcendentals stay full-capacity: the ceiling, what a
+            # call whose rows are all full computes. bytes_accessed uses the
+            # convention of flash_attention_causal: the steps that run copy
             # only ~length/capacity of the cache from HBM, rounded up to the
             # block, and lengths are traced (unknown at estimate time), so
             # charge the mid-generation expectation of capacity/2
@@ -591,10 +560,12 @@ def flash_decode(
         ),
         interpret=interpret,
     )(
-        lengths, park, window_arr,
+        lengths, rows, cache_blocks, window_arr,
         jnp.asarray(layer, jnp.int32).reshape(1),
         q.reshape(batch, kv_heads, group, head_dim), *operands,
     )
+    # no step visits a row of length 0: what the call left there is not a result
+    out = jnp.where((lengths > 0)[:, None, None, None], out, 0)
     return out.reshape(batch, num_heads, 1, head_dim)
 
 

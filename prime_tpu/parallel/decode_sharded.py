@@ -36,6 +36,7 @@ def flash_decode_sharded(
     sliding: jnp.ndarray | None = None,
     sinks: jnp.ndarray | None = None,  # (H,) per-head sink logits (GPT-OSS)
     layer: jnp.ndarray | int = 0,      # () layer of a stacked cache, replicated
+    work: tuple | None = None,         # ops.attention.decode_work's list, replicated
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Per-shard pallas flash decode over a (dp, fsdp, tp[, ...]) mesh.
@@ -62,23 +63,26 @@ def flash_decode_sharded(
     else:
         sinks_in = sinks.astype(jnp.float32)
     has_sinks = sinks is not None
+    # the kernel's work list where every device holds every row (the caller
+    # made it once for all layers); else each device lists its own rows
+    work = tuple(work or ())
 
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec, lengths_spec, sinks_spec, P()),
+        in_specs=(q_spec, kv_spec, kv_spec, lengths_spec, sinks_spec, P(), *[P()] * len(work)),
         out_specs=q_spec,
         # pallas_call's out ShapeDtypeStruct carries no varying-axes metadata
         check_vma=False,
     )
-    def local_decode(q_local, k_local, v_local, lengths_local, sinks_local, layer):
+    def local_decode(q_local, k_local, v_local, lengths_local, sinks_local, layer, *work):
         return flash_decode(
             q_local, k_local, v_local, lengths_local, sm_scale=sm_scale,
             softcap=softcap, window=window, sliding=sliding,
             sinks=sinks_local if has_sinks else None, layer=layer,
-            interpret=interpret,
+            work=work or None, interpret=interpret,
         )
 
     return local_decode(
-        q, k_cache, v_cache, cache_lengths, sinks_in, jnp.asarray(layer, jnp.int32)
+        q, k_cache, v_cache, cache_lengths, sinks_in, jnp.asarray(layer, jnp.int32), *work
     )

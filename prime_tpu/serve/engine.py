@@ -320,6 +320,10 @@ class _InflightChunk:
     # MoE engines: the chunk's dropless expert-layer counts (ops.moe.MOE_STATS,
     # a small device vector synced with toks); None where no such layer ran
     moe_stats: Any = None
+    # plain decode chunks: the (2,) device vector of the attention kernel's grid
+    # steps, made and at most, summed over the chunk (forward's
+    # return_attn_steps; synced with toks)
+    attn_steps: Any = None
     # False once an admission prefill ran inside this chunk's window: its
     # dispatch-to-sync wall time then includes host prefill blocking and must
     # not feed the per-step decode histogram (it still counts toward the
@@ -858,8 +862,8 @@ class ContinuousBatchingEngine:
         )
         r.gauge(
             "serve_decode_attn_grid_steps",
-            "Grid steps one decode-attention kernel call makes over the served "
-            "cache (0 = decode takes the XLA path)",
+            "The most grid steps one decode-attention kernel call can make over "
+            "the served cache, every row full (0 = decode takes the XLA path)",
         ).set(grid_steps)
         r.gauge(
             "serve_decode_attn_block_c",
@@ -870,6 +874,17 @@ class ContinuousBatchingEngine:
             "KV heads of a row one grid step of the decode-attention kernel "
             "carries (0 = XLA path)",
         ).set(heads_per_step)
+        # the steps its calls did make: a call walks its work list, a step for
+        # every cache block that holds a column some row attends over
+        # (ops/pallas_attention.py::decode_work_list); walked / (walked +
+        # spared) is the share of the static grid that was there to walk
+        self._m_decode_attn_steps = r.counter(
+            "serve_decode_attn_steps_total",
+            "Grid steps of the decode-attention kernel over the decode chunks "
+            "dispatched, by kind: walked (made) | spared (of the most the calls "
+            "could have made, not made)",
+            labelnames=("kind",),
+        )
         # how often that kernel's dead-row path engages: a retired slot's row is
         # attended at length 0 and fetches no cache block (models/llama.py
         # forward's token_mask); retired / total is the share of rows it spares
@@ -1354,7 +1369,10 @@ class ContinuousBatchingEngine:
         config, attn_impl, chunk = self.config, self.attn_impl, self.chunk
         mesh = self.mesh
         cache_spec = self._cache_constraint()
-        zero_stats = self._moe_zero_stats()
+        moe_counts = self._moe
+        # what the step scan carries out with the tokens: the expert layers'
+        # counts on MoE engines, then the attention kernel's grid steps
+        zero_stats = [*self._moe_zero_stats(), jnp.zeros((2,), jnp.int32)]
 
         def decode(params, adapters, cache, last, temps, top_ps, active, adapter_slots, rng):
             # neutralize retired slots' stale sampling params: a finished
@@ -1378,13 +1396,12 @@ class ContinuousBatchingEngine:
                     adapters=adapters,
                     adapter_ids=adapter_slots,
                     # retired slots belong to nobody: they attend over nothing
-                    # and route to no expert. MoE: the layers' counts ride the
-                    # step scan out with the tokens
+                    # and route to no expert
                     token_mask=active[:, None],
-                    return_moe_stats=bool(zero_stats),
+                    return_moe_stats=moe_counts,
+                    return_attn_steps=True,
                 )
-                if stats:
-                    stats = [stats[0] + step_stats[0]]
+                stats = [total + step for total, step in zip(stats, step_stats)]
                 new_cache = _constrain(new_cache, cache_spec)
                 # inactive slots must not advance: their next admission
                 # prefills the slot from position 0 again
@@ -2216,7 +2233,9 @@ class ContinuousBatchingEngine:
                     carried = {"run_len": run_len}
                 else:
                     self._cache, self._last, toks, *stats = fn(*args)
-                    carried = {"moe_stats": stats[0] if stats else None}
+                    carried = {
+                        "moe_stats": stats[0] if self._moe else None, "attn_steps": stats[-1],
+                    }
                     self._m_decode_rows.inc(live * steps, state="live")
                     self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
                 prof_step.fence(toks)
@@ -2244,6 +2263,10 @@ class ContinuousBatchingEngine:
             runs = np.asarray(chunk.run_len) if spec else None
             if chunk.moe_stats is not None:
                 self._count_moe(chunk.moe_stats, "decode")
+            if chunk.attn_steps is not None:
+                walked, most = (int(n) for n in np.asarray(chunk.attn_steps))
+                self._m_decode_attn_steps.inc(walked, kind="walked")
+                self._m_decode_attn_steps.inc(most - walked, kind="spared")
         # serve_host_stall_seconds_total is the sync_wait phase, published
         # with it in _refresh_stats; the window ends at the same switch
         t_done = self._clock.switched_at

@@ -71,14 +71,17 @@ _SCALE = ((B, KH, 1, C), jnp.float32)
 
 def _pallas_grids(fn, *args):
     """The grid of every ``pallas_call`` that ``fn`` makes on these (shape,
-    dtype) operands, read off its jaxpr: what ``_compile`` hands to Mosaic."""
+    dtype) operands, read off its jaxpr: what ``_compile`` hands to Mosaic. An
+    axis whose bound is traced (flash-decode's work list) reads ``None``."""
     shapes = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args]
     grids = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                grids.append(tuple(eqn.params["grid_mapping"].grid))
+                grids.append(tuple(
+                    n if isinstance(n, int) else None for n in eqn.params["grid_mapping"].grid
+                ))
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
@@ -88,9 +91,12 @@ def _pallas_grids(fn, *args):
 
 def _compile_decode(topo, fn, *args):
     """Compile a flash-decode call for the v5e and hold its grid to the shape
-    function's answer for the operands: a later edit that falls back to a
-    kv head and 128 slots a step (1024 steps at the served shapes) fails
-    here, on the CPU, before a chip run shows it."""
+    function's answer for the operands: the static axis is the row's head
+    blocks, the other the work list under a DYNAMIC bound, whose most (every
+    row full) is what this returns as (rows, head blocks, cache blocks). A
+    later edit that falls back to a kv head and 128 slots a step (1024 steps at
+    the served shapes), or to a static walk of the cache, fails here, on the
+    CPU, before a chip run shows it."""
     from prime_tpu.ops.pallas_attention import decode_call_tiling
 
     _compile(topo, fn, *args)
@@ -99,9 +105,8 @@ def _compile_decode(topo, fn, *args):
     quantized = jnp.dtype(k_dtype).itemsize == 1
     heads, block_c = decode_call_tiling(q_shape[1], cache, quantized)
     batch, kv_heads, capacity = k_shape[-4], k_shape[-3], k_shape[-1]
-    grid = (batch, kv_heads // heads, capacity // block_c)
-    assert _pallas_grids(fn, *args) == [grid]
-    return grid
+    assert _pallas_grids(fn, *args) == [(kv_heads // heads, None)]
+    return (batch, kv_heads // heads, capacity // block_c)
 
 
 @pytest.mark.parametrize(
@@ -166,8 +171,8 @@ def test_flash_decode_lowers(topo, variant, monkeypatch):
     else:  # MHA: group of one query head per kv head
         kv = ((B, H, D, C), jnp.bfloat16)
         grid = _compile_decode(topo, flash_decode, _Q, kv, kv, _LENS)
-    # the served shapes, spelled out: 32 steps a call for qwen3-4b (every kv
-    # head of a row, 512 slots), 256 for the latent; both were 1024
+    # the served shapes, spelled out: at most 32 steps a call for qwen3-4b
+    # (every kv head of a row, 512 slots), 256 for the latent; both were 1024
     assert grid == served.get(variant, grid)
     assert (variant == "mha") == (grid[1] > 1)  # only 32 MHA heads overflow a step
 
@@ -304,25 +309,34 @@ def test_forward_chunked_prefill_lowers(topo, attn_impl):
 
 def _results(compiled, opcode):
     """Result types of every ``opcode`` instruction in the compiled text."""
-    pattern = re.compile(rf"^\s*(?:ROOT )?%\S+ = (\S+) {opcode}\(", re.M)
-    return pattern.findall(compiled.as_text())
+    return _results_in(compiled.as_text(), opcode)
 
 
-def _assert_parked_outside_the_layer_loop(compiled, batch=B):
-    """The flash-decode call's lengths and park table (its first two
-    operands) reach the layer loop's body as loop-carried values, and the
-    table's two scans over the rows are not in that body: it is made once a
-    step."""
+def _assert_listed_outside_the_layer_loop(compiled, steps):
+    """The flash-decode call's grid bound, lengths and work list (its first
+    four operands: the list's two tables have ``steps`` + 1 entries) reach the
+    layer loop's body as loop-carried values, and nothing in that body makes a
+    table of that size: the list is made once a step."""
     text = compiled.as_text()
-    scan_over_rows = re.compile(rf"= s32\[{batch}(,1)?\]\S* reduce-window\(")
+    table = f"s32[{steps + 1}]"
     bodies = [c for c in re.split(r"\n\n(?=%|ENTRY)", text) if "flash_decode" in c and "tpu_custom_call" in c]
     assert bodies
     for body in bodies:
-        for operands in re.findall(r"%flash_decode\S* = \S+ custom-call\(([^)]*)\)", body):
-            lengths, park = [name.strip() for name in operands.split(",")[:2]]
-            assert lengths.startswith("%get-tuple-element") and park.startswith("%get-tuple-element")
-        assert not scan_over_rows.search(body)
-    assert scan_over_rows.search(text)  # ... and made it is
+        calls = re.findall(r"%flash_decode\S* = \S+ custom-call\(([^)]*)\)", body)
+        assert calls
+        for operands in calls:
+            names = [name.strip() for name in operands.split(",")[:4]]
+            assert all(name.startswith("%get-tuple-element") for name in names), names
+            kinds = [re.search(rf"{re.escape(name)} = (\S+) get-tuple-element", body)[1] for name in names]
+            bound, _, *tables = [kind.split("{")[0] for kind in kinds]
+            assert bound == "s32[]" and tables == [table, table], kinds
+        made = [r for r in _results_in(body, "fusion") + _results_in(body, "reduce") if r.startswith(table)]
+        assert not made, made
+    assert table in text
+
+
+def _results_in(text, opcode):
+    return re.compile(rf"^\s*(?:ROOT )?%\S+ = (\S+) {opcode}\(", re.M).findall(text)
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["every_row_live", "retired_rows_masked"])
@@ -335,8 +349,8 @@ def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
     scan's xs/ys they exceeded the whole cache), nothing copies the stacked
     cache, and the kernel reads its layer out of the stacked buffer, so no
     one-layer slice is made. With the engine's mask of retired slots the same
-    holds, and the table that parks their rows is made outside the layer
-    loop's body."""
+    holds, and the kernel's work list is made outside the layer loop's
+    body."""
     from prime_tpu.models.llama import forward
 
     config = _qwen3_4b(n_layers)
@@ -363,7 +377,7 @@ def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
         topo, decode, params, cache, ((B,), jnp.int32), ((B,), jnp.bool_), donate=(1,)
     )
     if masked:
-        _assert_parked_outside_the_layer_loop(compiled)
+        _assert_listed_outside_the_layer_loop(compiled, steps=B * 4)
     one_layer_kv = 2 * B * KH * D * C * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_kv
     stacked, one_layer = f"bf16[{n_layers},{B},{KH},{D},{C}]", f"bf16[{B},{KH},{D},{C}]"
@@ -453,7 +467,7 @@ def test_shortcut_block_decode_reads_its_weights_in_place(topo, monkeypatch):
     assert not top_level("bf16[16,6144,2048]") and not top_level("bf16[16,2048,6144]")
     assert not top_level("bf16[2,6144,12288]") and not top_level("bf16[2,12288,6144]")
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
-    _assert_parked_outside_the_layer_loop(compiled, batch=64)  # the latent's 64 x 1 x 4 grid
+    _assert_listed_outside_the_layer_loop(compiled, steps=64 * 4)  # the latent: at most 64 x 1 x 4 steps
 
 
 def test_sharded_decode_lowers_tp4(topo):
@@ -483,7 +497,8 @@ def test_forward_decode_lowers_tp4(topo, monkeypatch, masked):
     the stacked cache, kv heads over tp, goes through the layer scan as a
     carry into the shard_mapped kernel with the layer index replicated; no
     device gathers or copies its cache shard. With retired rows masked the
-    kernel parks them by a table made from each device's local rows."""
+    kernel's work list is made once a step, outside the layer loop, and
+    handed to every device (no axis shards the rows here)."""
     from prime_tpu.models.llama import forward
     from prime_tpu.ops import attention
     from prime_tpu.parallel.sharding import param_shardings, serving_cache_spec
@@ -526,6 +541,8 @@ def test_forward_decode_lowers_tp4(topo, monkeypatch, masked):
     for opcode in ("copy", "all-gather"):
         assert not [r for r in _results(compiled, opcode) if r.startswith(shard)]
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * B * KH * D * C * 2 // 4
+    if masked:
+        _assert_listed_outside_the_layer_loop(compiled, steps=B * 4)
 
 
 # ---- the sambay block at the served size: phi4-mini-flash, 64 slots x 4096 -----

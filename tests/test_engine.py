@@ -1473,8 +1473,8 @@ def test_decode_step_masks_only_the_attended_length(preset):
     bit-equal to the unmasked step's, the cache below every row's length is
     untouched, the live rows' new columns are the unmasked step's, the carried
     lengths advance for every row as before (the engine holds a retired row's
-    back), and nothing is NaN. token_mask=None adds nothing to the program:
-    no table, no select on the lengths."""
+    back), and nothing is NaN. The kernel's work list for the masked lengths
+    is made once a step, outside the layer scan."""
     import numpy as np
 
     from prime_tpu.models.llama import forward, init_cache
@@ -1506,18 +1506,100 @@ def test_decode_step_masks_only_the_attended_length(preset):
         for row, n in enumerate(np.asarray(cache.lengths)):
             np.testing.assert_array_equal(leaf[:, row, ..., :n], old[:, row, ..., :n])
 
-    def primitives(mask):
-        names = []
+    def primitives(mask, impl):
+        """(primitives outside every loop, primitives inside one) of the step."""
+        outside, inside = [], []
 
-        def walk(jaxpr):
+        def walk(jaxpr, names):
             for eqn in jaxpr.eqns:
                 names.append(eqn.primitive.name)
+                looped = inside if eqn.primitive.name in ("scan", "while") else names
                 for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
+                    walk(sub, looped)
 
-        walk(jax.make_jaxpr(lambda: step(mask))().jaxpr)
-        return names
+        step_fn = lambda: forward(
+            params, tok, config, positions=cache.lengths[:, None], cache=cache,
+            decode=True, attn_impl=impl, token_mask=mask,
+        )
+        walk(jax.make_jaxpr(step_fn)().jaxpr, outside)
+        return outside, inside
 
-    plain, masked = primitives(None), primitives(live[:, None])
-    assert "cummin" not in plain and "cummax" not in plain
-    assert masked.count("cummin") == masked.count("cummax") == 1  # once, outside the layer scan
+    # the kernel's work list (its one cumsum over the rows; the experts' routing
+    # has cumsums of its own, on either path) is made once a step, outside the
+    # layer scan, where the rows are masked and the kernel attends; a config whose
+    # layers alternate a window leaves it to each layer's call
+    (xla_out, xla_in), (outside, inside) = (
+        primitives(live[:, None], impl) for impl in ("xla", "pallas")
+    )
+    assert "pallas_call" in inside and "pallas_call" not in xla_in
+    made = (outside.count("cumsum") - xla_out.count("cumsum"), inside.count("cumsum") - xla_in.count("cumsum"))
+    assert made == ((0, 1) if config.sliding_window else (1, 0))
+
+
+def test_decode_attn_steps_counter_follows_the_lengths(monkeypatch):
+    """serve_decode_attn_steps_total over the synced decode chunks: walked is
+    the grid steps the decode-attention kernel's calls made (one for every
+    cache block that holds a live column; one for a call whose rows are all
+    dead), spared what is left of the most they could have made, the gauge
+    serve_decode_attn_grid_steps a call. 2 slots x 384 columns in blocks of
+    128: a prompt of 10 tokens walks 1 step of 6 a call, one of 140 walks 2."""
+    monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")
+    engine = make_engine(max_slots=2, capacity=384, attn_impl="pallas", overlap=False)
+    assert engine.registry.get("serve_decode_attn_grid_steps").value() == 2 * 3
+    synced = []
+    sync = engine._sync_decode
+    engine._sync_decode = lambda: (synced.append(1), sync())[1]
+    steps = engine.registry.get("serve_decode_attn_steps_total")
+
+    def counted():
+        return steps.value(kind="walked"), steps.value(kind="spared")
+
+    short = engine.submit(list(range(3, 13)), max_new_tokens=9)
+    drain(engine, short)
+    calls = len(synced) * engine.chunk * CONFIG.n_layers
+    assert calls > 0 and counted() == (calls, calls * 5)
+
+    long = engine.submit(list(range(3, 143)), max_new_tokens=9)
+    drain(engine, long)
+    more = len(synced) * engine.chunk * CONFIG.n_layers - calls
+    assert more > 0 and counted() == (calls + 2 * more, calls * 5 + 4 * more)
+    assert long.all_tokens(timeout=1) == reference_tokens(list(range(3, 143)), 9)
+    engine.shutdown()
+
+
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-longcat", "tiny-phi4flash"])
+def test_a_batch_of_full_rows_walks_every_step(preset, monkeypatch):
+    """forward(decode=True, return_attn_steps=True) with every row one column
+    short of its capacity: the kernel's calls walk the whole static grid
+    (walked / most = 1.0, nothing spared), the vector counting a call a cache
+    row the step attends over (the rings' and the row's eight for the sambay
+    block); with half the rows masked out, half of it."""
+    import numpy as np
+
+    from prime_tpu.models.llama import forward, init_cache
+
+    monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("PRIME_TPU_BLOCK_C", "128")
+    config = get_config(preset)
+    params = init_params(jax.random.PRNGKey(1), config, dtype=jnp.float32)
+    batch, capacity = 4, 256
+    cache = init_cache(config, batch, capacity, dtype=jnp.float32)
+    cache = cache._replace(lengths=jnp.full((batch,), capacity - 1, jnp.int32))
+    tok = jnp.asarray([[4], [5], [6], [7]], jnp.int32)
+
+    def steps(mask):
+        *_, attn_steps = forward(
+            params, tok, config, positions=cache.lengths[:, None], cache=cache,
+            decode=True, attn_impl="pallas", token_mask=jnp.asarray(mask)[:, None],
+            return_attn_steps=True,
+        )
+        return [int(n) for n in np.asarray(attn_steps)]
+
+    walked, most = steps([True] * 4)
+    assert walked == most > 0
+    if config.block == "sambay":
+        ring_blocks = -(-config.sliding_window // 128)
+        assert most == batch * (config.window_pairs * ring_blocks + (1 + config.cross_pairs) * 2)
+    else:
+        assert most == batch * 2 * cache.k.shape[0]
+    assert steps([True, False, True, False]) == [most // 2, most]

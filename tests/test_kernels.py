@@ -404,8 +404,9 @@ def test_flash_decode_dead_rows(variant, dead, monkeypatch):
     """Rows at length 0 (the engine's retired slots) beside live ones: the
     live rows equal the XLA reference and are BIT-equal to the same call with
     the dead rows left at their stale lengths (same blocks, same order, same
-    float32 state), and the dead rows come back finite and zero from the
-    kernel and from the reference alike."""
+    float32 state: a dead row's steps are simply not in the list), and the
+    dead rows come back finite and zero from the kernel and from the reference
+    alike."""
     from prime_tpu.ops import pallas_attention as pa
     from prime_tpu.ops.attention import decode_attention
 
@@ -435,25 +436,36 @@ def test_flash_decode_dead_rows(variant, dead, monkeypatch):
     assert np.all(out[~live] == 0) and np.all(ref[~live] == 0)
 
 
-def _walk_decode_blocks(lengths, blocks, block_c, head_blocks=1, window=0):
-    """(row, kv-head block, cache block) that flash-decode's K/V index map
-    names at every grid step, in the grid's order (row, head block, step)."""
+def _walk_work_list(lengths, blocks, block_c, head_blocks=1, window=0):
+    """(row, kv-head block, cache block) of every grid step a flash-decode call
+    makes for these lengths, in the grid's order (head block, then the work
+    list), after holding the list's tables to their form: one entry more than
+    the static grid has steps, the tail repeating the last pair (the pipeline
+    reads the index maps a step ahead, and an equal index copies nothing)."""
     from prime_tpu.ops import pallas_attention as pa
 
-    lengths = jnp.asarray(lengths, jnp.int32)
-    grid = np.stack(
-        np.meshgrid(
-            np.arange(len(lengths)), np.arange(head_blocks), np.arange(blocks), indexing="ij"
-        ),
-        axis=-1,
-    ).reshape(-1, 3)
-    index = jax.vmap(
-        lambda b, hb, step: jnp.stack(pa._decode_block_index(
-            b, hb, step, lengths, pa.decode_park_rows(lengths),
-            jnp.asarray([window], jnp.int32), block_c, blocks, head_blocks,
-        ))
+    rows, cache_blocks, n = (
+        np.asarray(a) for a in pa.decode_work_list(
+            jnp.asarray(lengths, jnp.int32), block_c, blocks, window
+        )
     )
-    return grid, np.asarray(index(*jnp.asarray(grid.T, jnp.int32)))
+    n = int(n)
+    assert rows.shape == cache_blocks.shape == (len(lengths) * blocks + 1,)
+    assert 1 <= n <= len(lengths) * blocks
+    assert np.all(rows[n:] == rows[n - 1]) and np.all(cache_blocks[n:] == cache_blocks[n - 1])
+    return np.asarray(
+        [(r, hb, c) for hb in range(head_blocks) for r, c in zip(rows[:n], cache_blocks[:n])]
+    )
+
+
+def _live_blocks(lengths, block_c, window=0):
+    """[(row, cache block)] that hold a column a row attends over, rows in
+    order and a row's blocks front to back: what the work list has to be."""
+    pairs = []
+    for row, length in enumerate(lengths):
+        first = max(length - window, 0) // block_c if window else 0
+        pairs += [(row, block) for block in range(first, -(-length // block_c))]
+    return pairs
 
 
 @pytest.mark.parametrize(
@@ -464,13 +476,12 @@ def _walk_decode_blocks(lengths, blocks, block_c, head_blocks=1, window=0):
     ],
 )
 @pytest.mark.parametrize("grid", ["qwen3_4b_8x1x4", "latent_64x1x4"])
-def test_a_dead_row_names_no_block_of_its_own(grid, lengths):
-    """The K/V index map walked over the whole grid: the pipeline copies a
-    block whenever consecutive steps name different ones, so the changes of
-    index are the copies, and they equal the live rows' live blocks (one copy
-    when every row is dead, the warm-up's call). A call with no row of length 0
-    names what it named before this table existed: each row its own blocks,
-    clipped into its live range, from the far end."""
+def test_the_work_list_is_the_live_rows_live_blocks(grid, lengths):
+    """The steps a call makes are its work list, and the list is the live rows'
+    live blocks and nothing else: every step names a block of its own row that
+    holds a live column, each such block has its one step, so every step is a
+    copy and the copies are the steps (one step when every row is dead, the
+    warm-up's call, whose result the caller zeroes). A dead row is in no step."""
     batch = 8 if grid.startswith("qwen3") else 64
     blocks, block_c = 4, 512
     rng = np.random.default_rng(batch)
@@ -486,33 +497,100 @@ def test_a_dead_row_names_no_block_of_its_own(grid, lengths):
     }[lengths]
     lens = stale.copy()
     lens[dead] = 0
-    steps, named = _walk_decode_blocks(lens, blocks, block_c)
+    named = _walk_work_list(lens, blocks, block_c)
 
-    copies = 1 + int(np.any(named[1:] != named[:-1], axis=1).sum())
     live_blocks = int(np.sum(-(-lens // block_c)))
-    assert copies == max(live_blocks, 1)
-    # a live row's steps name its own blocks and nothing else
-    b, step = steps[:, 0], steps[:, 2]
-    own = lens[b] > 0
-    last = -(-lens[b] // block_c) - 1
-    np.testing.assert_array_equal(named[own, 0], b[own])
-    np.testing.assert_array_equal(named[own, 2], np.minimum(blocks - 1 - step, last)[own])
-    # a dead row's steps name a block some live row's steps name
-    live_named = {tuple(n) for n in named[own]}
-    assert all(tuple(n) in live_named for n in named[~own]) or not own.any()
+    assert len(named) == max(live_blocks, 1)
+    if live_blocks:
+        assert [(r, c) for r, _, c in named] == _live_blocks(lens, block_c)
+        assert not set(named[:, 0]) & set(dead)
+        # no two consecutive steps name one block: none of them is for nothing
+        assert np.all(np.any(named[1:] != named[:-1], axis=1))
+    else:
+        assert lens[named[0, 0]] == 0 and named[0, 2] == 0
 
 
-def test_dead_rows_park_with_head_blocks_and_a_window():
+def test_the_work_list_with_head_blocks_and_a_window():
     """The same count where a row's kv heads take two grid steps' worth of
-    blocks (MHA past the VMEM budget) and a sliding window front-skips
-    blocks: a live row's copies are head blocks x (last - first + 1)."""
+    blocks (MHA past the VMEM budget: the list is walked once a head block) and
+    a sliding window front-skips blocks: a live row's steps are head blocks x
+    (last - first + 1), and they are its blocks [first, last]."""
+    from prime_tpu.ops import pallas_attention as pa
+
     lens = np.asarray([0, 1800, 0, 0, 300, 2048, 0], dtype=np.int32)
     blocks, block_c, head_blocks, window = 4, 512, 2, 700
-    _, named = _walk_decode_blocks(lens, blocks, block_c, head_blocks, window)
-    copies = 1 + int(np.any(named[1:] != named[:-1], axis=1).sum())
+    named = _walk_work_list(lens, blocks, block_c, head_blocks, window)
     first = np.maximum(lens - window, 0) // block_c
     last = -(-lens // block_c) - 1
-    assert copies == int(np.sum(np.where(lens > 0, head_blocks * (last - first + 1), 0)))
+    assert len(named) == int(np.sum(np.where(lens > 0, head_blocks * (last - first + 1), 0)))
+    want = _live_blocks(lens, block_c, window)
+    assert [(r, hb, c) for r, hb, c in named] == [(r, hb, c) for hb in range(head_blocks) for r, c in want]
+    # a traced window (the layer scan's sliding flag) lists the same steps
+    traced = jax.jit(
+        lambda w: pa.decode_work_list(jnp.asarray(lens), block_c, blocks, w)
+    )(jnp.asarray([window], jnp.int32))
+    assert int(traced[2]) == len(want) and [
+        (int(r), int(c)) for r, c in zip(traced[0][: len(want)], traced[1][: len(want)])
+    ] == want
+
+
+@pytest.mark.parametrize(
+    "rows, blocks", [(8, 4), (64, 4), (64, 8), (64, 1)],
+    ids=["qwen3_4b_8x4", "latent_64x4", "phi4_row_64x8", "phi4_ring_64x1"],
+)
+def test_the_work_list_of_full_rows_is_the_static_grid(rows, blocks):
+    """Every row full, the list is the whole static grid, row by row and block
+    by block: a call over such a batch makes the steps it made before the list
+    existed, so there is nothing for a second path to be faster at."""
+    named = _walk_work_list(np.full(rows, blocks * 512), blocks, 512)
+    grid = [(r, 0, c) for r in range(rows) for c in range(blocks)]
+    assert [tuple(step) for step in named] == grid
+
+
+# the served calls: (rows, q heads, kv heads, head_dim, capacity, cache), the row
+# count of phi4's 64 cut for the CPU's interpreter
+_SERVED_CALLS = {
+    "qwen3_4b": (8, 32, 8, 128, 2048, "bfloat16"),
+    "qwen3_4b_int8": (8, 32, 8, 128, 2048, "int8"),
+    "qwen3_4b_int4": (8, 32, 8, 128, 2048, "int4"),
+    "latent": (64, 64, 1, 576, 2048, "bfloat16"),
+    "phi4_row": (12, 40, 10, 128, 4096, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_SERVED_CALLS))
+def test_flash_decode_at_the_served_shapes_matches_xla(call, monkeypatch):
+    """The three served calls at their own widths and tilings (and the int8 and
+    int4 carriers no cell serves) over ragged lengths with dead rows among them,
+    first, last and between: the work list's steps against the XLA reference,
+    the dead rows zero."""
+    from prime_tpu.ops import pallas_attention as pa
+    from prime_tpu.ops.attention import decode_attention
+
+    batch, h, kh, d, c, cache = _SERVED_CALLS[call]
+    extra = "latent" if kh == 1 else None
+    q, k, v, kw = _decode_operands(
+        len(call), batch, h, kh, d, c, cache, "bfloat16" if cache == "bfloat16" else "float32",
+        extra, heads=kh, block_c=0, monkeypatch=monkeypatch,
+    )
+    rng = np.random.default_rng(len(call))
+    lengths = rng.integers(1, c + 1, size=batch)
+    lengths[:6] = [0, 1, 512, 513, c, 0]
+    lengths[-1] = 0
+    lengths[rng.permutation(np.arange(6, batch - 1))[: (batch - 7) // 3]] = 0
+    lengths = jnp.asarray(lengths, jnp.int32)
+
+    pa.flash_decode.clear_cache()
+    try:
+        out = pa.flash_decode(q, k, v, lengths, sm_scale=d**-0.5, interpret=True, **kw)
+    finally:
+        pa.flash_decode.clear_cache()  # this trace baked the patched budget in
+    ref = decode_attention(q, k, v, lengths, d**-0.5, impl="xla", **kw)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    dead = np.asarray(lengths) == 0
+    tol = 2e-2 if cache == "bfloat16" else 2e-3
+    np.testing.assert_allclose(out[~dead], ref[~dead], rtol=tol, atol=tol)
+    assert dead.sum() >= 3 and np.all(out[dead] == 0) and np.all(ref[dead] == 0)
 
 
 _SERVED = dict(itemsize=2, capacity=2048)
