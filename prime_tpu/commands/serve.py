@@ -153,6 +153,12 @@ def _echo_device_memory(when: str) -> None:
          "0 = unbounded. Default: 0 (PRIME_SERVE_MAX_QUEUE).",
 )
 @click.option(
+    "--max-wave", type=click.IntRange(min=1), default=None,
+    help="Widest batched admission wave (--continuous), a power of two. The "
+         "warm-up builds a program a prompt-row capacity and wave width, so "
+         "fewer widths start sooner. Default: the engine's rule from shapes.",
+)
+@click.option(
     "--role", type=click.Choice(["prefill", "decode", "any"]), default=None,
     help="Phase role in a disaggregated fleet, advertised in /healthz: a "
          "`prime serve fleet` router with both explicit roles present "
@@ -208,6 +214,7 @@ def serve_cmd(
     adapter_max_inflight: int | None,
     adapter_weight_entries: tuple[str, ...],
     max_queue: int | None,
+    max_wave: int | None,
     role: str | None,
     replica_of: str | None,
     advertise_url: str | None,
@@ -283,6 +290,7 @@ def serve_cmd(
             # to PRIME_SERVE_ADAPTER_WEIGHTS inside the engine
             adapter_weights=",".join(adapter_weight_entries) or None,
             max_queue=max_queue,
+            max_wave=max_wave,
             role=role,
         )
     except (ValueError, OSError) as e:

@@ -149,8 +149,23 @@ class ModelConfig:
     # layer's K/V). Attention is differential. The cache holds a ring of
     # sliding_window columns a window layer, ONE full-length row, and a
     # recurrent state and a conv tail a state-space layer (models/llama.py
-    # CACHE_LEAVES).
+    # CACHE_LEAVES). "gated_delta" (Qwen3-Next): the scan's unit is a PERIOD of
+    # full_attention_interval layers, each a mixer and the expert feed-forward
+    # under (1 + w) RMSNorm: linear attention (the gated delta rule,
+    # models/delta.py) in all but the period's last layer, which is full
+    # attention. The cache holds a K/V row a PERIOD and, a linear layer, a
+    # float32 matrix state a value head and a conv tail.
     block: str = "standard"
+    # --- the "gated_delta" block ---
+    full_attention_interval: int = 0  # layers a period; its last is full attention
+    linear_key_heads: int = 0         # the delta rule's key heads, each serving
+    linear_value_heads: int = 0       # value_heads / key_heads value heads
+    linear_key_dim: int = 128         # a key head's width
+    linear_value_dim: int = 128       # a value head's width
+    linear_conv: int = 4              # taps of the causal conv over [q; k; v]
+    # full attention's query projection is twice as wide: a head's second half
+    # is a gate, and the attention output is multiplied by its sigmoid
+    attn_output_gate: bool = False
     # --- state-space (Mamba-1) mixer of the "sambay" block ---
     ssm_d_inner: int | None = None   # None: 2 * d_model
     ssm_d_state: int = 16
@@ -164,6 +179,8 @@ class ModelConfig:
     # replica that holds only experts [first_expert, first_expert +
     # n_experts_held) computes their part of the MoE output and nothing for
     # picks held elsewhere (ops/moe.py moe_infer). None = all held here.
+    # Every block kind's expert layer honours it (the standard block's through
+    # the dropless layer of a cached forward only).
     n_experts_held: int | None = None
     first_expert: int = 0
     # the published vocabulary when vocab_size is this chip's slice of it:
@@ -178,7 +195,24 @@ class ModelConfig:
         keeps a full-length row (the sambay block has one such layer)."""
         if self.block == "sambay":
             return 1
+        if self.block == "gated_delta":
+            return self.n_layers // self.full_attention_interval
         return self.n_layers * (2 if self.block == "shortcut_moe" else 1)
+
+    @property
+    def linear_layers(self) -> int:
+        """gated_delta: layers that carry a matrix state; 0 for other blocks."""
+        if self.block != "gated_delta":
+            return 0
+        return self.n_layers - self.n_layers // self.full_attention_interval
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """gated_delta: the channels [q; k; v] the causal conv runs over."""
+        return (
+            2 * self.linear_key_heads * self.linear_key_dim
+            + self.linear_value_heads * self.linear_value_dim
+        )
 
     @property
     def window_pairs(self) -> int:
@@ -207,6 +241,11 @@ class ModelConfig:
     @property
     def experts_held(self) -> int:
         return self.n_experts if self.n_experts_held is None else self.n_experts_held
+
+    @property
+    def shared_ff(self) -> int:
+        """Width of the always-on shared expert(s): n_shared_experts of d_ff."""
+        return self.n_shared_experts * self.d_ff
 
     @property
     def router_width(self) -> int:
@@ -288,6 +327,26 @@ class ModelConfig:
             return (
                 embed + head + 2 * d + self.n_layers * ffn
                 + pairs * (ssm + self_attn) + self.cross_pairs * (memory + cross_attn)
+            )
+        if self.block == "gated_delta":
+            d, ff = self.d_model, self.d_ff
+            value = self.linear_value_heads * self.linear_value_dim
+            delta = (
+                d * (self.linear_conv_channels + value) + d * 2 * self.linear_value_heads
+                + self.linear_conv * self.linear_conv_channels + 2 * self.linear_value_heads
+                + self.linear_value_dim + value * d
+            )
+            full = (
+                d * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
+                + self.n_heads * self.head_dim * d + 2 * self.head_dim
+            )
+            experts = (
+                self.experts_held * 3 * d * ff + d * self.n_experts
+                + self.n_shared_experts * 3 * d * ff + d
+            )
+            return (
+                embed + head + d + self.n_layers * (experts + 2 * d)
+                + self.linear_layers * delta + self.cache_rows * full
             )
         if self.block == "shortcut_moe":
             # two attentions, two dense FFNs and their norms; one router (+
@@ -1010,6 +1069,76 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         sliding_window=8,
         block="sambay",
         ssm_d_state=4,
+    ),
+    # Qwen3-Next-80B-A3B-Instruct, one chip's share of a 2-way expert
+    # deployment (Qwen/Qwen3-Next-80B-A3B-Instruct config.json): published
+    # widths, ONE period of the 12 (three gated-delta-rule layers and a gated
+    # full attention, each followed by the expert layer), experts 0-255 of 512
+    # held (rank 0 of 2) beside the gated shared expert, half the vocabulary.
+    # benchmark/configs/qwen3-next-ep2.json states the cut.
+    "qwen3-next-ep2": ModelConfig(
+        name="qwen3-next-ep2",
+        vocab_size=75968,
+        vocab_published=151936,
+        d_model=2048,
+        n_layers=4,
+        n_heads=16,
+        n_kv_heads=2,
+        d_ff=512,              # moe_intermediate_size
+        max_seq_len=262144,
+        rope_theta=10000000.0,
+        rms_eps=1e-6,
+        head_dim_override=256,
+        qk_norm=True,
+        norm_plus_one=True,
+        partial_rotary=0.25,
+        attn_output_gate=True,
+        block="gated_delta",
+        full_attention_interval=4,
+        linear_key_heads=16,
+        linear_value_heads=32,
+        linear_key_dim=128,
+        linear_value_dim=128,
+        linear_conv=4,
+        n_experts=512,
+        n_experts_held=256,
+        first_expert=0,
+        experts_per_token=10,
+        norm_topk=True,
+        n_shared_experts=1,    # shared_expert_intermediate_size 512 = 1 x d_ff
+    ),
+    # the same block kind at test scale: two periods, 8 routed experts (4 held
+    # here, from expert 2), top-3, an eighth of the vocabulary
+    "tiny-qwen3next": ModelConfig(
+        name="tiny-qwen3next",
+        vocab_size=512,
+        vocab_published=4096,
+        d_model=64,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=32,
+        max_seq_len=512,
+        rope_theta=10000000.0,
+        rms_eps=1e-6,
+        head_dim_override=32,
+        qk_norm=True,
+        norm_plus_one=True,
+        partial_rotary=0.25,
+        attn_output_gate=True,
+        block="gated_delta",
+        full_attention_interval=4,
+        linear_key_heads=2,
+        linear_value_heads=4,
+        linear_key_dim=16,
+        linear_value_dim=16,
+        linear_conv=4,
+        n_experts=8,
+        n_experts_held=4,
+        first_expert=2,
+        experts_per_token=3,
+        norm_topk=True,
+        n_shared_experts=1,
     ),
     # GPT-OSS architecture at test scale: sinks + biased clamped-GLU MoE +
     # alternating window + non-truncated yarn, all exercised on CPU

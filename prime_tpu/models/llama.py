@@ -22,6 +22,7 @@ Design (TPU-first, not a port):
 
 from __future__ import annotations
 
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -73,6 +74,10 @@ class KVCache(NamedTuple):
     ring_v: jnp.ndarray | None = None
     ssm_state: jnp.ndarray | None = None  # (state layers, B, d_state, d_inner) float32
     conv_tail: jnp.ndarray | None = None  # (state layers, B, d_conv - 1, d_inner)
+    # the gated_delta block (models/delta.py): k/v are a row a PERIOD (its full
+    # attention), conv_tail its linear layers' [q; k; v] columns, and a linear
+    # layer's matrix state a value head
+    delta_state: jnp.ndarray | None = None  # (linear layers, B, value heads, d_k, d_v) float32
 
     @property
     def capacity(self) -> int:
@@ -106,6 +111,7 @@ CACHE_LEAVES: dict[str, CacheLeaf] = {
     "ring_v": CacheLeaf(1, None, "ring"),
     "ssm_state": CacheLeaf(1, None, "state"),
     "conv_tail": CacheLeaf(1, None, "state"),
+    "delta_state": CacheLeaf(1, None, "state"),
 }
 
 
@@ -148,6 +154,29 @@ def init_cache(
             ),
             conv_tail=jnp.zeros(
                 (state_layers, batch, config.ssm_d_conv - 1, config.d_inner), dtype=dtype
+            ),
+        )
+    if config.block == "gated_delta":
+        # K/V for the full-attention layers only, state and tail for the linear
+        # ones only; the matrix state is float32 whatever ``dtype``
+        if quantized:
+            raise ValueError(
+                "the gated_delta block's cache is not quantized: kv_quant is unsupported "
+                "(ROADMAP.md R7)"
+            )
+        row = (config.cache_rows, batch, config.n_kv_heads, config.head_dim, capacity)
+        return KVCache(
+            k=jnp.zeros(row, dtype=dtype),
+            v=jnp.zeros(row, dtype=dtype),
+            lengths=jnp.zeros((batch,), dtype=jnp.int32),
+            conv_tail=jnp.zeros(
+                (config.linear_layers, batch, config.linear_conv - 1, config.linear_conv_channels),
+                dtype=dtype,
+            ),
+            delta_state=jnp.zeros(
+                (config.linear_layers, batch, config.linear_value_heads,
+                 config.linear_key_dim, config.linear_value_dim),
+                dtype=jnp.float32,
             ),
         )
     shape = (config.cache_rows, batch, config.n_kv_heads, config.head_dim, capacity)
@@ -269,6 +298,8 @@ def init_params(rng: jax.Array, config: ModelConfig, dtype=jnp.bfloat16) -> Para
         from prime_tpu.models.sambay import init_params as init_sambay_params
 
         return init_sambay_params(rng, config, dtype)
+    if config.block == "gated_delta":
+        return _init_delta_params(rng, config, dtype)
     keys = jax.random.split(rng, 17)
     d, hd = config.d_model, config.head_dim
     h, kh, ff, layers = config.n_heads, config.n_kv_heads, config.d_ff, config.n_layers
@@ -283,24 +314,26 @@ def init_params(rng: jax.Array, config: ModelConfig, dtype=jnp.bfloat16) -> Para
         return (jax.random.normal(key, shape, dtype=jnp.float32) * (fan_in**-0.5)).astype(dtype)
 
     if config.is_moe:
-        experts = config.n_experts
+        # the experts HELD here (a share of an expert-parallel deployment, or
+        # all of them); the router keeps its published width
+        experts = config.experts_held
         mlp_weights = {
             # router stays fp32: routing decisions are precision-sensitive
-            "router": dense(keys[9], (mlp_layers, d, experts), d).astype(jnp.float32),
+            "router": dense(keys[9], (mlp_layers, d, config.n_experts), d).astype(jnp.float32),
             "w_gate": dense(keys[5], (mlp_layers, experts, d, ff), d),
             "w_up": dense(keys[6], (mlp_layers, experts, d, ff), d),
             "w_down": dense(keys[7], (mlp_layers, experts, ff, d), ff),
         }
         if config.moe_bias:  # GPT-OSS: router + every expert projection
             mlp_weights |= {
-                "router_bias": jnp.zeros((mlp_layers, experts), dtype=jnp.float32),
+                "router_bias": jnp.zeros((mlp_layers, config.n_experts), dtype=jnp.float32),
                 "b_gate": jnp.zeros((mlp_layers, experts, ff), dtype=dtype),
                 "b_up": jnp.zeros((mlp_layers, experts, ff), dtype=dtype),
                 "b_down": jnp.zeros((mlp_layers, experts, d), dtype=dtype),
             }
         if config.moe_score_bias:  # DeepSeek-V3 aux-free balance bias (fp32,
             # selection-only — updated out-of-band, not by the loss)
-            mlp_weights["score_bias"] = jnp.zeros((mlp_layers, experts), dtype=jnp.float32)
+            mlp_weights["score_bias"] = jnp.zeros((mlp_layers, config.n_experts), dtype=jnp.float32)
         if config.n_shared_experts:  # DeepSeekMoE always-on shared expert(s)
             sf = config.n_shared_experts * ff
             mlp_weights |= {
@@ -441,6 +474,87 @@ def _init_shortcut_params(rng: jax.Array, config: ModelConfig, dtype) -> Params:
     return params
 
 
+# the gated_delta block's random recipe: which of the 24 keys split from the
+# seed's key draws which leaf (benchmark/reference/qwen3_next.py restates it)
+DELTA_KEY_OF = {
+    "embed": 0, "lm_head": 1, "router": 2, "w_gate": 3, "w_up": 4, "w_down": 5,
+    "w_shared_gate": 6, "w_shared_up": 7, "w_shared_down": 8, "shared_expert_gate": 9,
+    "delta.w_qkvz": 10, "delta.w_ba": 11, "delta.conv_w": 12, "delta.A_log": 13,
+    "delta.dt_bias": 14, "delta.w_out": 15,
+    "attn.wq": 16, "attn.wk": 17, "attn.wv": 18, "attn.wo": 19,
+}
+# leaves of the gated_delta stack that are not one a layer: the linear layers'
+# and the full layers' own stacks, and the expert matrices, which the grouped
+# matmul takes whole
+_DELTA_WHOLE = ("delta", "attn", "w_gate", "w_up", "w_down")
+
+
+def _init_delta_params(rng: jax.Array, config: ModelConfig, dtype) -> Params:
+    """Random init of the gated_delta block (Qwen3-Next). A matrix is
+    ``normal * fan_in**-0.5`` rounded to ``dtype`` (the router float32), the
+    shared expert's gate vector and the conv taps the same with their fan-in;
+    ``(1 + w)`` norm weights zero, the gated norm's weight one. ``A_log`` is
+    uniform in [log 1e-3, log 1e-1] and ``dt_bias`` uniform in [-1, 1] (both
+    float32, a value head): a state then keeps a token for some 10 to 1000
+    positions, where the published initialisation (A uniform in (0, 16),
+    dt_bias 1) forgets it within one under random inputs. One jitted draw a
+    leaf, stacked over its layers: (n_layers, ...) for what every layer has,
+    (linear layers, ...) under ``delta`` and (periods, ...) under ``attn``."""
+    keys = jax.random.split(rng, 24)
+    d, ff, hd = config.d_model, config.d_ff, config.head_dim
+    layers, linear, periods = config.n_layers, config.linear_layers, config.cache_rows
+    h, kh = config.n_heads, config.n_kv_heads
+    n_v, taps, channels = config.linear_value_heads, config.linear_conv, config.linear_conv_channels
+    value = n_v * config.linear_value_dim
+    shared = config.shared_ff
+
+    def draw(name, shape, fan_in, out=dtype):
+        make = jax.jit(
+            lambda k: (jax.random.normal(k, shape, dtype=jnp.float32) * (fan_in**-0.5)).astype(out)
+        )
+        return make(keys[DELTA_KEY_OF[name]])
+
+    def uniform(name, low, high):
+        u = jax.random.uniform(keys[DELTA_KEY_OF[name]], (linear, n_v), dtype=jnp.float32)
+        return low + u * (high - low)
+
+    stack = {
+        "attn_norm": jnp.zeros((layers, d), dtype=dtype),
+        "mlp_norm": jnp.zeros((layers, d), dtype=dtype),
+        "router": draw("router", (layers, d, config.n_experts), d, out=jnp.float32),
+        "w_gate": draw("w_gate", (layers, config.experts_held, d, ff), d),
+        "w_up": draw("w_up", (layers, config.experts_held, d, ff), d),
+        "w_down": draw("w_down", (layers, config.experts_held, ff, d), ff),
+        "w_shared_gate": draw("w_shared_gate", (layers, d, shared), d),
+        "w_shared_up": draw("w_shared_up", (layers, d, shared), d),
+        "w_shared_down": draw("w_shared_down", (layers, shared, d), shared),
+        "shared_expert_gate": draw("shared_expert_gate", (layers, d), d),
+        "delta": {
+            "w_qkvz": draw("delta.w_qkvz", (linear, d, channels + value), d),
+            "w_ba": draw("delta.w_ba", (linear, d, 2 * n_v), d),
+            "conv_w": draw("delta.conv_w", (linear, taps, channels), taps),
+            "A_log": uniform("delta.A_log", math.log(1e-3), math.log(1e-1)),
+            "dt_bias": uniform("delta.dt_bias", -1.0, 1.0),
+            "gate_norm": jnp.ones((linear, config.linear_value_dim), dtype=dtype),
+            "w_out": draw("delta.w_out", (linear, value, d), value),
+        },
+        "attn": {
+            "wq": draw("attn.wq", (periods, d, 2 * h * hd), d),
+            "wk": draw("attn.wk", (periods, d, kh * hd), d),
+            "wv": draw("attn.wv", (periods, d, kh * hd), d),
+            "wo": draw("attn.wo", (periods, h * hd, d), h * hd),
+            "q_norm": jnp.zeros((periods, hd), dtype=dtype),
+            "k_norm": jnp.zeros((periods, hd), dtype=dtype),
+        },
+    }
+    return {
+        "embed": draw("embed", (config.vocab_size, d), d),
+        "layers": stack,
+        "final_norm": jnp.zeros((d,), dtype=dtype),
+        "lm_head": draw("lm_head", (d, config.vocab_size), d),
+    }
+
+
 def sliding_layer_flags(config: ModelConfig) -> jnp.ndarray:
     """(n_layers,) bool: which layers use the sliding window. The pattern is
     an explicit config field so non-Gemma2 window schemes can't silently
@@ -516,6 +630,10 @@ def _attention_block(
     if "q_norm_full" in lp:  # OLMo-2: full-width RMSNorm before the head split
         q = _norm(q, lp["q_norm_full"], config)
         k = _norm(k, lp["k_norm_full"], config)
+    gate = None
+    if config.attn_output_gate:  # a head's projection is [query; gate], hd each
+        q, gate = jnp.split(q.reshape(batch, seq, h, 2 * hd), 2, axis=-1)
+        gate = gate.reshape(batch, seq, h * hd)
     q = q.reshape(batch, seq, h, hd)
     k = k.reshape(batch, seq, kh, hd)
     v = v.reshape(batch, seq, kh, hd)
@@ -585,6 +703,8 @@ def _attention_block(
             kv = write_cache(kv, staged(k, v), layer, jnp.zeros((), jnp.int32))
 
     attn = attn.transpose(0, 2, 1, 3).reshape(batch, seq, h * hd)
+    if gate is not None:
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
     out = _lora_mm(attn, lp, "wo", adapter_ids)
     if "bo" in lp:  # Llama-arch attention_bias checkpoints bias o_proj too
         out = out + lp["bo"]
@@ -613,9 +733,13 @@ def _mlp_block(
     adapter_ids: jnp.ndarray | None = None,
     dropless: bool = False,  # inference: ops.moe.moe_infer, nothing dropped
     token_mask: jnp.ndarray | None = None,  # (B, S) rows the experts skip
+    layer: jnp.ndarray | None = None,  # () dropless: lp's expert matrices are whole
+    #                                    (L, held, ...) stacks, and this their layer
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray | None]:
     """Dense or sparse-MoE feed-forward. Returns (residual output, aux loss,
-    the dropless expert layer's counts or None)."""
+    the dropless expert layer's counts or None). The dropless layer computes
+    the picks that fall among the experts held here, ``[first_expert,
+    first_expert + held)`` of the router's width (``config.n_experts_held``)."""
     normed = _norm(x, lp["mlp_norm"], config) if "mlp_norm" in lp else x
     # key-presence decides, not config.is_moe alone: a DeepSeek dense-prefix
     # layer (first_k_dense) carries a plain MLP inside an MoE model
@@ -639,8 +763,16 @@ def _mlp_block(
         experts = (normed, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"])
         if dropless:
             with jax.named_scope("moe"):
-                y, stats = moe_infer(*experts, token_mask=token_mask, **routing)
+                y, stats = moe_infer(
+                    *experts, token_mask=token_mask, layer=layer,
+                    first_expert=config.first_expert, **routing,
+                )
             aux = jnp.zeros((), jnp.float32)
+        elif config.n_experts_held is not None:
+            raise ValueError(
+                "a share of the experts (n_experts_held) is computed by the dropless "
+                "expert layer of a cached forward only: the capacity einsum holds all of them"
+            )
         else:
             y, aux = moe_mlp(*experts, capacity_factor=config.capacity_factor, **routing)
             stats = None
@@ -648,7 +780,14 @@ def _mlp_block(
             # DeepSeekMoE shared expert(s): a dense always-on silu MLP added
             # to the routed output (every token, no capacity, no routing)
             shared_gate = jax.nn.silu(_mm(normed, lp["w_shared_gate"]))
-            y = y + _mm(shared_gate * _mm(normed, lp["w_shared_up"]), lp["w_shared_down"])
+            shared = _mm(shared_gate * _mm(normed, lp["w_shared_up"]), lp["w_shared_down"])
+            if "shared_expert_gate" in lp:  # Qwen3-Next: times sigmoid(w . x)
+                score = jnp.sum(
+                    normed.astype(jnp.float32) * lp["shared_expert_gate"].astype(jnp.float32),
+                    axis=-1, keepdims=True,
+                )
+                shared = shared * jax.nn.sigmoid(score).astype(shared.dtype)
+            y = y + shared
         if "mlp_post_norm" in lp:
             y = _norm(y, lp["mlp_post_norm"], config)
         return x + y, aux, stats
@@ -717,9 +856,77 @@ def _shortcut_block(
     return b1 + m, kv, stats
 
 
+def _delta_period(
+    x: jnp.ndarray,               # (B, S, D)
+    stack: Params,                # the WHOLE layer stack (``_init_delta_params``)
+    period: jnp.ndarray,          # () this period; its full attention is cache row ``period``
+    leaves: tuple[jnp.ndarray, ...],  # (k, v, conv_tail, delta_state), or () without a cache
+    attend,                       # (x, layer params, cache row, kv) -> (x + attention, kv)
+    config: ModelConfig,
+    token_mask: jnp.ndarray | None,
+):
+    """One period of the gated_delta block (Qwen3-Next): ``interval - 1``
+    linear-attention layers (models/delta.py) and one gated full attention,
+    every layer ``h = x + mixer(norm(x)); out = h + moe(norm(h))`` with the
+    dropless expert layer over the experts held here and the gated shared
+    expert. Returns (out, leaves, expert counts).
+
+    As in ``_shortcut_block`` a layer's matrices are indexed out of the stack
+    here, one slice a matmul, and the experts go to the grouped matmul as the
+    whole stack with the layer. A linear layer's state and tail are rows of the
+    carried leaves, read and rewritten in place."""
+    from prime_tpu.models.delta import delta_mixer
+
+    interval = config.full_attention_interval
+    kv, carried = leaves[:2], leaves[2:]
+    stats = 0
+    for i in range(interval):
+        layer = period * interval + i
+        lp = {name: w[layer] for name, w in stack.items() if name not in _DELTA_WHOLE}
+        if i < interval - 1:
+            row = period * (interval - 1) + i
+            mixer = {name: w[row] for name, w in stack["delta"].items()}
+            tail, state = (leaf[row] for leaf in carried) if carried else (None, None)
+            out, state, tail = delta_mixer(
+                _norm(x, lp["attn_norm"], config), mixer, state, tail, token_mask,
+                key_heads=config.linear_key_heads,
+            )
+            if carried:
+                carried = tuple(
+                    jax.lax.dynamic_update_index_in_dim(leaf, new, row, 0)
+                    for leaf, new in zip(carried, (tail, state))
+                )
+            x = x + out
+        else:
+            full = {name: w[period] for name, w in stack["attn"].items()}
+            with jax.named_scope("gated_attention"):
+                x, kv = attend(x, {**full, "attn_norm": lp["attn_norm"]}, period, kv)
+        experts = {name: stack[name] for name in ("w_gate", "w_up", "w_down")}
+        x, _, layer_stats = _mlp_block(
+            x, {**lp, **experts}, config, dropless=True, token_mask=token_mask, layer=layer
+        )
+        stats = stats + layer_stats
+    return x, (*kv, *carried), stats
+
+
 def _gelu_tanh(x: jnp.ndarray) -> jnp.ndarray:
     """HF's gelu_pytorch_tanh (the Gemma MLP activation)."""
     return jax.nn.gelu(x, approximate=True)
+
+
+def _refuse_for_delta(mesh, adapters, prefill_offset) -> None:
+    """What the gated_delta block does not serve (ROADMAP.md R7 queues each;
+    int8 and int4 weights are refused where they would be made,
+    models/quantize.py)."""
+    what = None
+    if mesh is not None and getattr(mesh, "size", 1) > 1:
+        what = "a mesh (its matrix state and expert share have no sharding rule)"
+    elif adapters is not None:
+        what = "a LoRA bank"
+    elif prefill_offset is not None and jnp.ndim(prefill_offset):
+        what = "a per-row chunk offset (speculative verify would have to rewind the state)"
+    if what:
+        raise ValueError(f"the gated_delta block does not serve {what}: ROADMAP.md R7")
 
 
 def forward(
@@ -915,6 +1122,17 @@ def forward(
             )
             return (x, aux_sum, kv, stats + layer_stats), None
 
+    elif config.block == "gated_delta":
+        _refuse_for_delta(mesh, adapters, prefill_offset)
+        dropless = True
+
+        def layer_fn(carry, scanned):
+            x, aux_sum, kv, stats = carry
+            x, kv, period_stats = _delta_period(
+                x, params["layers"], scanned[0], kv, attend, config, token_mask
+            )
+            return (x, aux_sum, kv, stats + period_stats), None
+
     elif config.block == "standard":
         dropless = cache is not None and config.is_moe and _moe_dropless(params["layers"])
 
@@ -932,7 +1150,8 @@ def forward(
 
     else:
         raise ValueError(
-            f"Unknown block {config.block!r} (want 'standard' | 'shortcut_moe' | 'sambay')"
+            f"Unknown block {config.block!r} "
+            "(want 'standard' | 'shortcut_moe' | 'sambay' | 'gated_delta')"
         )
 
     if remat not in ("none", "full", "dots"):
@@ -957,23 +1176,30 @@ def forward(
     stacks = (
         [(params["dense_layers"], slice(0, kd)), (params["layers"], slice(kd, None))]
         if kd
-        else [({} if config.block == "shortcut_moe" else params["layers"], slice(0, None))]
+        # (a block that indexes the stack itself scans no layer params)
+        else [({} if config.block in ("shortcut_moe", "gated_delta") else params["layers"], slice(0, None))]
     )
     # The stacked cache leaves are the scan's CARRY, never its xs/ys: a scan
     # cannot alias xs with ys, so a cache scanned per layer is sliced out,
     # written into a second buffer and copied back, three passes over the
     # whole cache every step. As a carry that each block updates in place it
     # is one buffer from the caller's (donated) argument to the result.
-    kv = ()
+    carried = ()
     if cache is not None:
-        kv = (cache.k, cache.v) + ((cache.k_scale, cache.v_scale) if cache.quantized else ())
+        carried = ("k", "v") + (("k_scale", "v_scale") if cache.quantized else ())
+        if config.block == "gated_delta":
+            carried += ("conv_tail", "delta_state")
+    kv = tuple(getattr(cache, name) for name in carried)
     from prime_tpu.ops.moe import MOE_STATS
 
     # (zeros where the capacity einsum ran and the caller asked for counts)
     counted = dropless or return_moe_stats
     moe_stats = jnp.zeros((len(MOE_STATS),), jnp.int32) if counted else None
     carry = (x, jnp.zeros((), jnp.float32), kv, moe_stats)
-    layer_ids = jnp.arange(config.n_layers, dtype=jnp.int32)
+    # the scan's unit: a layer, or the gated_delta block's period
+    units = config.cache_rows if config.block == "gated_delta" else config.n_layers
+    layer_ids = jnp.arange(units, dtype=jnp.int32)
+    sliding_flags = sliding_flags[:units]
     for stack, rows in stacks:
         # multi-LoRA bank: the per-target (L, A, ...) factor stacks ride the
         # layer scan under reserved lora:* keys, sliced by each stack's rows —
@@ -986,8 +1212,8 @@ def forward(
     x, aux_total, kv, moe_stats = carry
     new_cache = None
     if cache is not None:
-        new_cache = KVCache(
-            kv[0], kv[1], cache.lengths + (1 if decode else seq), *kv[2:]
+        new_cache = cache._replace(
+            lengths=cache.lengths + (1 if decode else seq), **dict(zip(carried, kv))
         )
 
     x = _norm(x, params["final_norm"], config)

@@ -50,6 +50,15 @@ QUANTIZED_LAYER_KEYS = (
 LAYER_SUBTREES = ("layers", "dense_layers", "mid_layers", "cross_layers")
 
 
+def _refuse_delta_stack(params: dict) -> None:
+    """The gated_delta block's stack (its linear layers under ``delta``) is
+    served in the precision it was made in: its float32 matrix state and decay
+    terms sit beside matrices no quantized path has been held to a reference
+    for."""
+    if "delta" in params.get("layers", {}):
+        raise ValueError("the gated_delta block does not serve int8 or int4 weights: ROADMAP.md R7")
+
+
 def quantize_weight(w: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-output-channel (last axis) symmetric int8."""
     absmax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2, keepdims=True)
@@ -64,6 +73,8 @@ def quantize_params_int8(params: dict) -> dict:
     MoE expert stacks quantize the same way (the per-output-channel axis is
     still the last one). The rest of the tree is shared by reference.
     """
+    _refuse_delta_stack(params)
+
     def walk(layers: dict) -> dict:
         out = {}
         for key, value in layers.items():
@@ -115,6 +126,7 @@ def quantize_params_int4(params: dict, group: int = INT4_GROUP) -> dict:
     wired through the expert dispatch path) — quantize those with
     :func:`quantize_params_int8` first if needed; int8 tuples and int4
     tuples coexist in one tree, ``matmul`` dispatches on dtype."""
+    _refuse_delta_stack(params)
     out = dict(params)
     for subtree in ("layers", "dense_layers"):  # dense_layers: DeepSeek prefix
         if subtree not in params:

@@ -426,6 +426,7 @@ class ContinuousBatchingEngine:
         warmup: bool | None = None,
         profile: bool | None = None,
         max_queue: int | None = None,
+        max_wave: int | None = None,
         prefix_store_all: bool = False,
         paged_prefix: bool | None = None,
         adapters: Any = None,
@@ -668,10 +669,18 @@ class ContinuousBatchingEngine:
         # widest batched admission wave: a chunk prefill's fp32 score tensor
         # is wave x heads x chunk x row capacity, and past 1 GiB of it a wide
         # model's wave no longer fits beside its weights (64 heads: 4 rows;
-        # 32 heads: 8). A rule from shapes, so the warm-up's program set and
-        # the waves _admit forms agree without an option.
+        # 32 heads: 8). A rule from shapes; the warm-up's program set and the
+        # waves _admit forms both read it, so they agree. ``max_wave`` asks for
+        # narrower waves than the shapes allow (a power of two, rounded down):
+        # the warm-up builds a chunk prefill and a finalize a row capacity AND
+        # width, so a deployment whose programs are slow to build or large to
+        # keep starts sooner on fewer widths. None = the rule alone.
         score_bytes = 4 * config.n_heads * self.prefill_chunk * capacity
         self.max_wave = max(1, 2 ** ((2**30 // score_bytes).bit_length() - 1))
+        if max_wave is not None:
+            if max_wave < 1:
+                raise ValueError(f"max_wave must be at least 1, got {max_wave}")
+            self.max_wave = min(self.max_wave, 2 ** (max_wave.bit_length() - 1))
         # the dropless expert layer (ops/moe.py moe_infer) returns its counts
         # with every decode chunk and prefill (zeros on engines whose forward
         # keeps the capacity einsum: a mesh, quantized experts)

@@ -1250,6 +1250,7 @@ def serve_model(
     max_queue: int | None = None,
     admin_token: str | None = None,
     role: str | None = None,
+    max_wave: int | None = None,
 ) -> InferenceServer:
     """Bind the port, then build the (optionally sharded) generator.
 
@@ -1281,7 +1282,10 @@ def serve_model(
     ``any``) declares the replica's phase in a disaggregated fleet —
     advertised in /healthz and honored by the fleet router's migration path;
     ``--mesh role:prefill`` / ``role:decode`` resolve to the matching
-    role-preset layouts (serve/mesh_config.py)."""
+    role-preset layouts (serve/mesh_config.py). ``max_wave`` (None = the
+    engine's rule from shapes) asks for narrower admission waves: the warm-up
+    builds a program a row capacity and wave width, so fewer widths start
+    sooner — docs/architecture.md "Engine pipeline"."""
     from prime_tpu.evals.runner import JaxGenerator
 
     if mesh and slice_name:
@@ -1417,6 +1421,7 @@ def serve_model(
                 prefix_cache_mb=prefix_cache_mb,
                 prefix_cache_host_mb=prefix_cache_host_mb,
                 max_queue=max_queue,
+                max_wave=max_wave,
                 # multi-LoRA bank: {name: dir} / "name=dir,..." / None
                 # (None reads PRIME_SERVE_ADAPTERS inside the engine); the
                 # inflight cap and the weighted shares drive the per-tenant

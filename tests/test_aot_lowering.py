@@ -623,3 +623,127 @@ def test_sambay_chunk_prefill_fits_beside_the_engines_cache(topo, wave):
     )
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 2.9e9
+
+
+# ---- the gated_delta block at the served size: qwen3-next-ep2, 128 slots x 4096 -----
+
+
+def _qwen3_next_engine_programs():
+    """The engine's decode and chunk_prefill bodies (serve/engine.py) for
+    qwen3-next-ep2, the sampler left out, the expert layer's counts carried."""
+    from prime_tpu.models.config import get_config
+    from prime_tpu.models.llama import forward
+    from prime_tpu.ops.moe import MOE_STATS
+
+    config = get_config("qwen3-next-ep2")
+
+    def decode(params, cache, tok, active):
+        def step(carry, _):
+            cache, tok, counts = carry
+            logits, new_cache, step_counts = forward(
+                params, tok[:, None], config, positions=cache.lengths[:, None], cache=cache,
+                decode=True, attn_impl="auto", token_mask=active[:, None], return_moe_stats=True,
+            )
+            new_cache = new_cache._replace(
+                lengths=jnp.where(active, new_cache.lengths, cache.lengths)
+            )
+            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            return (new_cache, tok, counts + step_counts), tok
+
+        zero = jnp.zeros((len(MOE_STATS),), jnp.int32)
+        (cache, _, counts), toks = jax.lax.scan(step, (cache, tok, zero), None, length=8)
+        return cache, toks, counts
+
+    def chunk_prefill(params, row, tokens, offset, last_in_chunk):
+        live = jnp.arange(tokens.shape[1])[None, :] <= last_in_chunk[:, None]
+        logits, row, counts = forward(
+            params, tokens, config, cache=row, attn_impl="auto", prefill_offset=offset,
+            last_positions=last_in_chunk, token_mask=live, return_moe_stats=True,
+        )
+        return row, logits, counts
+
+    return config, decode, chunk_prefill
+
+
+def _chip_takes_the_kernels(monkeypatch):
+    """What a TPU backend chooses: the flash-decode kernel for a 4096-column
+    row, megablox for the grouped products (the CPU backend takes neither)."""
+    from prime_tpu.ops import attention, moe
+
+    monkeypatch.setattr(attention, "_decode_pallas_eligible", lambda k: k.shape[-1] >= 2048)
+    monkeypatch.setattr(moe, "_kernel_path", lambda: (True, False))
+
+
+def test_gated_delta_decode_fits_the_chip_with_state_and_row_in_place(topo, monkeypatch):
+    """qwen3-next-ep2's engine-shaped decode at 128 slots x 4096: one period at
+    the published widths, 256 experts a layer, beside the whole cache. K/V rows
+    exist for the one full layer (2 heads of 256 through flash-decode and the
+    column write), the 12 grouped products read the expert stacks in place, and
+    the step holds no copy of the stacked state, tail or row. What it does hold
+    is one layer's state slice at a time (0.27 GB: XLA materialises the slice
+    its two readers share; ROADMAP R7 queues the kernel that does not)."""
+    _chip_takes_the_kernels(monkeypatch)
+    config, decode, _ = _qwen3_next_engine_programs()
+    params, cache = _abstract_model(config, 128, 4096)
+    compiled = _compile(
+        topo, decode, params, cache, ((128,), jnp.int32), ((128,), jnp.bool_), donate=(1,)
+    )
+    memory = compiled.memory_analysis()
+    assert 9.1e9 < memory.argument_size_in_bytes < 9.4e9  # 7.36 GB of weights, 1.90 GB of slots
+    assert memory.temp_size_in_bytes < 0.7e9
+    text = compiled.as_text()
+    assert len(re.findall("tpu_custom_call", text)) >= 14  # 12 grouped products, write and attend
+    for leaf in ("f32[3,128,32,128,128]", "bf16[3,128,3,8192]", "bf16[1,128,2,256,4096]",
+                 "bf16[4,256,2048,512]", "bf16[4,256,512,2048]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+
+
+def test_gated_delta_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
+    """A 512-token chunk of a 4096-column staging row, a wave of one (the
+    cell's ``max_wave``): weights, row and temporaries (the
+    chunked delta rule's float32 blocks, the experts' row buffers) under the
+    15 GB less the 1.9 GB the engine's own slots hold."""
+    _chip_takes_the_kernels(monkeypatch)
+    config, _, chunk_prefill = _qwen3_next_engine_programs()
+    params, row = _abstract_model(config, 1, 4096)
+    compiled = _compile(
+        topo, chunk_prefill, params, row, ((1, 512), jnp.int32), ((), jnp.int32),
+        ((1,), jnp.int32), donate=(1,),
+    )
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 1.9e9
+    assert memory.temp_size_in_bytes < 0.6e9
+
+
+def test_gated_delta_finalize_splices_state_and_row_in_place(topo):
+    """The engine's own finalize program (a staged row of 4096 columns, a wave
+    of one, into 128 slots, its first token sampled from the 75968 logits): the
+    walk over the cache's leaves splices K/V by columns and state and tail
+    whole, and keeps no second copy of a leaf."""
+    import types
+
+    from prime_tpu.models.config import get_config
+    from prime_tpu.serve.engine import ContinuousBatchingEngine
+
+    config = get_config("qwen3-next-ep2")
+    finalize = ContinuousBatchingEngine._make_finalize_batch(
+        types.SimpleNamespace(_cache_constraint=lambda: None)
+    )
+    _, cache = _abstract_model(config, 128, 4096)
+    _, rows = _abstract_model(config, 1, 4096)
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])
+        ), tree,
+    )
+    slot = lambda dtype, n=128: jax.ShapeDtypeStruct((n,), dtype)  # noqa: E731
+    compiled = finalize.lower(*place((
+        cache, slot(jnp.int32), slot(jnp.float32), slot(jnp.float32), slot(jnp.int32), rows,
+        jax.ShapeDtypeStruct((1, 1, config.vocab_size), jnp.float32),
+        slot(jnp.int32, 1), slot(jnp.int32, 1), slot(jnp.float32, 1), slot(jnp.float32, 1),
+        slot(jnp.int32, 1), slot(jnp.uint32, 2),
+    ))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.1e9
+    for leaf in ("f32[3,128,32,128,128]", "bf16[1,128,2,256,4096]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf

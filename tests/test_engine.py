@@ -1065,6 +1065,29 @@ def test_warmup_compiles_programs_and_preserves_cold_state(monkeypatch):
     assert not make_engine().warmup_enabled
 
 
+@pytest.mark.parametrize("asked, widest", [(None, 8), (16, 8), (8, 8), (5, 4), (2, 2), (1, 1)])
+def test_max_wave_narrows_the_waves_admit_forms(asked, widest, monkeypatch):
+    """``max_wave`` asks for narrower admission waves than the rule from
+    shapes allows (64 here), rounded down to a power of two: the waves
+    ``_admit`` forms of a burst of one plan stay under it, and the warm-up
+    reads the same number, so it builds no width that cannot come."""
+    engine = make_engine(max_slots=16, capacity=2048, prefill_chunk=512, max_wave=asked)
+    assert engine.max_wave == (64 if asked is None else 2 ** (asked.bit_length() - 1))
+    waves = []
+    monkeypatch.setattr(
+        engine, "_prefill", lambda reqs, slots, cap, plan, *rest: waves.append(len(reqs))
+    )
+    for _ in range(11):
+        engine.submit(list(range(1, 600)), max_new_tokens=2)
+    engine.tick()
+    assert sum(waves) == 11 and max(waves) == widest
+
+
+def test_max_wave_below_one_is_refused():
+    with pytest.raises(ValueError, match="max_wave"):
+        make_engine(max_wave=0)
+
+
 def test_warmup_failure_is_fatal_and_reallocates_state():
     """A warmup that was asked for and fails stops start() — the server must
     not come up in front of an engine that could not compile. The failed
@@ -1567,7 +1590,7 @@ def test_decode_attn_steps_counter_follows_the_lengths(monkeypatch):
     engine.shutdown()
 
 
-@pytest.mark.parametrize("preset", ["tiny-test", "tiny-longcat", "tiny-phi4flash"])
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-longcat", "tiny-phi4flash", "tiny-qwen3next"])
 def test_a_batch_of_full_rows_walks_every_step(preset, monkeypatch):
     """forward(decode=True, return_attn_steps=True) with every row one column
     short of its capacity: the kernel's calls walk the whole static grid

@@ -314,11 +314,15 @@ def test_waves_are_capped_by_the_score_tensor(weights):
         assert engine.max_wave == want
 
 
-@pytest.mark.parametrize("preset", ["tiny-longcat", "tiny-test"])
-def test_a_warmed_engine_builds_no_program_whatever_the_wave(preset):
+@pytest.mark.parametrize(
+    "preset, max_wave",
+    [("tiny-longcat", None), ("tiny-test", None), ("tiny-qwen3next", None), ("tiny-qwen3next", 1)],
+)
+def test_a_warmed_engine_builds_no_program_whatever_the_wave(preset, max_wave):
     """With the prefix cache off, warm-up reaches every program a request can
     run: admission waves of any size, one chunk or several, build nothing on
-    the loop thread. (A list uploaded with ``jnp.asarray(..., dtype=)``, an
+    the loop thread, at the widths the shapes allow and at a ``max_wave`` of
+    one (the qwen3-next cell's) alike. (A list uploaded with ``jnp.asarray(..., dtype=)``, an
     eager add of the experts' counts or a slice of the staged row is one
     program a shape on first use, and which wave shapes a run of the
     benchmark meets depends on its timing: one compile inside a window and the
@@ -327,7 +331,7 @@ def test_a_warmed_engine_builds_no_program_whatever_the_wave(preset):
     params = init_params(jax.random.PRNGKey(0), config, dtype=jnp.float32)
     engine = ContinuousBatchingEngine(
         params, config, max_slots=8, capacity=128, chunk=4, prefill_chunk=32,
-        prefix_cache_mb=0, warmup=True,
+        prefix_cache_mb=0, warmup=True, max_wave=max_wave,
     )
     engine.start()
     try:
