@@ -26,7 +26,13 @@ returns both, so a prompt's chunks hand them on.
 
 **Decode** (S = 1) is the recurrence, one read of the state for both products
 it needs (``k^T S`` and ``q^T S``: ``o = exp(g) q^T S + (q . k) d``) and one
-pass that rewrites it.
+pass that rewrites it: ``_step``, the definition and the CPU's path. Where the
+decode attention takes its kernel (a chip, or ``attn_impl="pallas"``) the model
+hands the mixer the STACKED leaf of every linear layer's state and the stack
+row (``in_place``), and ``ops/pallas_delta.py`` runs the same step in place
+over a work list of the live rows: a live row's state moves into VMEM once and
+back once, a row that belongs to nobody is not touched, and no layer's state is
+sliced out of the stack.
 
 **Prefill** is the chunked form over blocks of ``CHUNK`` positions. Inside a
 block, with ``G`` the running sum of ``g`` and ``S_0`` the state it starts from:
@@ -49,8 +55,8 @@ belongs to nobody) leave the state and the tail as they were: ``beta`` 0 and
 ``g`` 0 there.
 
 Layout: the state is (B, n_v, d_k, d_v), values minor: d_v fills the lanes.
-Everything here is plain XLA (ROADMAP R7 queues the kernel that keeps the state
-in fast memory).
+Everything here but that decode kernel is plain XLA (ROADMAP R7 queues the
+chunked prefill's products).
 """
 
 from __future__ import annotations
@@ -171,8 +177,12 @@ def delta_mixer(
     live: jnp.ndarray | None = None,  # (B, S) bool: False positions change nothing
     *,
     key_heads: int,
+    in_place: tuple | None = None,   # (stack row, work list): ``state`` is the STACKED
+    #                                  (R, B, n_v, d_k, d_v) leaf, S is 1, and the kernel
+    #                                  steps that row of it where it lies
 ):
-    """Returns (out (B, S, d), state, tail)."""
+    """Returns (out (B, S, d), state, tail); with ``in_place`` the state
+    returned is the stacked leaf."""
     batch, seq, _ = h.shape
     n_v, d_v = lp["A_log"].shape[0], lp["gate_norm"].shape[0]
     taps, channels = lp["conv_w"].shape
@@ -214,7 +224,18 @@ def delta_mixer(
         state = jnp.zeros((batch, n_v, d_k, d_v), dtype=jnp.float32)
 
     with jax.named_scope("delta_rule"):
-        if seq == 1:
+        if in_place is not None:
+            from prime_tpu.ops.attention import _pallas_interpret
+            from prime_tpu.ops.pallas_delta import delta_decode_step
+
+            row, work = in_place
+            alive = jnp.ones((batch,), bool) if live is None else live[:, 0]
+            state, o = delta_decode_step(
+                state, row, q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0], alive, work,
+                interpret=_pallas_interpret(),
+            )
+            o = o[:, None]
+        elif seq == 1:
             state, o = _step(state, q[:, 0], k[:, 0], v[:, 0], beta[:, 0], g[:, 0])
             o = o[:, None]
         else:

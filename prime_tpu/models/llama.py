@@ -864,6 +864,7 @@ def _delta_period(
     attend,                       # (x, layer params, cache row, kv) -> (x + attention, kv)
     config: ModelConfig,
     token_mask: jnp.ndarray | None,
+    state_work: tuple | None = None,  # decode under the kernel's gate: its work list
 ):
     """One period of the gated_delta block (Qwen3-Next): ``interval - 1``
     linear-attention layers (models/delta.py) and one gated full attention,
@@ -874,7 +875,10 @@ def _delta_period(
     As in ``_shortcut_block`` a layer's matrices are indexed out of the stack
     here, one slice a matmul, and the experts go to the grouped matmul as the
     whole stack with the layer. A linear layer's state and tail are rows of the
-    carried leaves, read and rewritten in place."""
+    carried leaves, read and rewritten in place: the tail (and, as plain XLA,
+    the state) sliced out and put back, the state under ``state_work`` handed to
+    the mixer as the whole leaf with its row, for the kernel that steps the
+    live rows' blocks where they lie (ops/pallas_delta.py)."""
     from prime_tpu.models.delta import delta_mixer
 
     interval = config.full_attention_interval
@@ -886,15 +890,22 @@ def _delta_period(
         if i < interval - 1:
             row = period * (interval - 1) + i
             mixer = {name: w[row] for name, w in stack["delta"].items()}
-            tail, state = (leaf[row] for leaf in carried) if carried else (None, None)
+            tail = state = in_place = None
+            if carried:
+                tails, states = carried
+                tail = tails[row]
+                # under the kernel's gate the mixer takes the stacked leaf and its row
+                state, in_place = (
+                    (states[row], None) if state_work is None else (states, (row, state_work))
+                )
             out, state, tail = delta_mixer(
                 _norm(x, lp["attn_norm"], config), mixer, state, tail, token_mask,
-                key_heads=config.linear_key_heads,
+                key_heads=config.linear_key_heads, in_place=in_place,
             )
             if carried:
-                carried = tuple(
-                    jax.lax.dynamic_update_index_in_dim(leaf, new, row, 0)
-                    for leaf, new in zip(carried, (tail, state))
+                put = jax.lax.dynamic_update_index_in_dim
+                carried = (
+                    put(tails, tail, row, 0), state if in_place else put(states, state, row, 0)
                 )
             x = x + out
         else:
@@ -950,6 +961,7 @@ def forward(
     token_mask: jnp.ndarray | None = None,  # (B, S) bool: False rows belong to nobody
     return_moe_stats: bool = False,
     return_attn_steps: bool = False,
+    return_state_steps: bool = False,
 ):
     """Run the transformer. Returns (logits (B, S, V) fp32, updated cache),
     plus the summed MoE load-balance aux loss when ``return_aux``, plus the
@@ -957,7 +969,9 @@ def forward(
     none ran) when ``return_moe_stats``, plus, when ``return_attn_steps``, a
     (2,) int32 vector: the grid steps this decode step's attention kernel calls
     made and the most they could have made (``ops.attention.decode_work``;
-    zeros where no work list was made).
+    zeros where no work list was made), plus, when ``return_state_steps``, the
+    same pair for the gated_delta block's state kernel
+    (``ops.attention.delta_decode_work``).
     With ``last_positions`` the head matmul runs on ONE gathered position per
     row and logits are (B, 1, V): a prefill that only needs each sequence's
     next-token logits skips S× the unembedding FLOPs and never materializes
@@ -1087,6 +1101,7 @@ def forward(
     # kinds. The pattern is an explicit config field (ModelConfig.sliding_pattern)
     # so non-Gemma2 window schemes can't silently inherit the even alternation.
     sliding_flags = sliding_layer_flags(config)
+    state_work = state_steps = None
 
     def attend(x, lp, layer, kv, sliding=None):
         if config.mla:
@@ -1125,11 +1140,20 @@ def forward(
     elif config.block == "gated_delta":
         _refuse_for_delta(mesh, adapters, prefill_offset)
         dropless = True
+        if decode and cache is not None:
+            from prime_tpu.ops.attention import delta_decode_work
+
+            # the live rows, listed once for every linear layer's state kernel
+            # (None where the step is plain XLA)
+            live = jnp.ones((batch,), bool) if token_mask is None else token_mask[:, 0]
+            state_work, state_steps = delta_decode_work(
+                attn_impl, cache.k, cache.delta_state, live
+            )
 
         def layer_fn(carry, scanned):
             x, aux_sum, kv, stats = carry
             x, kv, period_stats = _delta_period(
-                x, params["layers"], scanned[0], kv, attend, config, token_mask
+                x, params["layers"], scanned[0], kv, attend, config, token_mask, state_work
             )
             return (x, aux_sum, kv, stats + period_stats), None
 
@@ -1226,7 +1250,10 @@ def forward(
     logits = _apply_softcap((x @ head).astype(jnp.float32), config.final_softcap)
     out = (logits, new_cache) + ((aux_total,) if return_aux else ())
     out += (moe_stats,) if return_moe_stats else ()
-    return out + ((attn_steps,) if return_attn_steps else ())
+    out += (attn_steps,) if return_attn_steps else ()
+    if return_state_steps:
+        out += (jnp.zeros((2,), jnp.int32) if state_steps is None else state_steps,)
+    return out
 
 
 def _forward_sambay(

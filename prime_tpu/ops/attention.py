@@ -315,6 +315,29 @@ def decode_work(
     return work, jnp.stack([work[2] * (most // (batch * blocks)), jnp.int32(most)])
 
 
+def delta_decode_work(
+    impl: str, k_cache, leaf, live: jnp.ndarray
+) -> tuple[tuple | None, jnp.ndarray]:
+    """(work, steps) for one decode step of the gated delta rule over the
+    stacked state ``leaf`` (R, B, H, d_k, d_v) and the step's ``live`` rows
+    (B,): the state kernel's work list (``pallas_delta.delta_work_list``), for
+    a model to make once a step, outside its layer loop, and hand to every
+    linear layer's call; and a (2,) int32 vector, the grid steps the R calls
+    make with it and the most they could make. (None, zeros) where the step is
+    plain XLA: the gate is the decode attention's own (``_decode_uses_kernel``
+    over the block's K cache, so the column write, the attention and the state
+    take one path) and, on a chip, a state block that fills whole tiles."""
+    from prime_tpu.ops.pallas_delta import delta_step_heads, delta_work_list
+
+    layers, batch, heads, d_k, d_v = leaf.shape
+    tiled = _pallas_interpret() or (d_k % 8 == 0 and d_v % 128 == 0)
+    if not (tiled and _decode_uses_kernel(impl, k_cache)):
+        return None, jnp.zeros((2,), jnp.int32)
+    work = delta_work_list(live)
+    calls = layers * (heads // delta_step_heads(heads, d_k, d_v))
+    return work, jnp.stack([work[1], jnp.int32(batch)]) * calls
+
+
 def decode_attention(
     q: jnp.ndarray,          # (B, H, 1, D)
     k_cache: jnp.ndarray,    # (L, B, KH, D, C) stacked feature-major (see

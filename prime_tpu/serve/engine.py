@@ -324,6 +324,9 @@ class _InflightChunk:
     # steps, made and at most, summed over the chunk (forward's
     # return_attn_steps; synced with toks)
     attn_steps: Any = None
+    # the gated_delta block's decode chunks: the same pair for its state kernel
+    # (forward's return_state_steps)
+    state_steps: Any = None
     # False once an admission prefill ran inside this chunk's window: its
     # dispatch-to-sync wall time then includes host prefill blocking and must
     # not feed the per-step decode histogram (it still counts toward the
@@ -894,6 +897,15 @@ class ContinuousBatchingEngine:
             "could have made, not made)",
             labelnames=("kind",),
         )
+        # the gated_delta block's state kernel (ops/pallas_delta.py), counted the
+        # same way: a step for every live row's head block of every linear layer
+        self._m_delta_state_steps = r.counter(
+            "serve_delta_state_steps_total",
+            "Grid steps of the gated delta rule's state kernel over the decode "
+            "chunks dispatched, by kind: walked (made) | spared (of slots x head "
+            "blocks x linear layers, not made); 0 / 0 where the step is plain XLA",
+            labelnames=("kind",),
+        )
         # how often that kernel's dead-row path engages: a retired slot's row is
         # attended at length 0 and fetches no cache block (models/llama.py
         # forward's token_mask); retired / total is the share of rows it spares
@@ -1380,8 +1392,12 @@ class ContinuousBatchingEngine:
         cache_spec = self._cache_constraint()
         moe_counts = self._moe
         # what the step scan carries out with the tokens: the expert layers'
-        # counts on MoE engines, then the attention kernel's grid steps
-        zero_stats = [*self._moe_zero_stats(), jnp.zeros((2,), jnp.int32)]
+        # counts on MoE engines, then the attention kernel's grid steps, then,
+        # for the gated_delta block, its state kernel's
+        kernel_steps = {"return_attn_steps": True}
+        if config.block == "gated_delta":
+            kernel_steps["return_state_steps"] = True
+        zero_stats = [*self._moe_zero_stats(), *(jnp.zeros((2,), jnp.int32) for _ in kernel_steps)]
 
         def decode(params, adapters, cache, last, temps, top_ps, active, adapter_slots, rng):
             # neutralize retired slots' stale sampling params: a finished
@@ -1408,7 +1424,7 @@ class ContinuousBatchingEngine:
                     # and route to no expert
                     token_mask=active[:, None],
                     return_moe_stats=moe_counts,
-                    return_attn_steps=True,
+                    **kernel_steps,
                 )
                 stats = [total + step for total, step in zip(stats, step_stats)]
                 new_cache = _constrain(new_cache, cache_spec)
@@ -2242,9 +2258,8 @@ class ContinuousBatchingEngine:
                     carried = {"run_len": run_len}
                 else:
                     self._cache, self._last, toks, *stats = fn(*args)
-                    carried = {
-                        "moe_stats": stats[0] if self._moe else None, "attn_steps": stats[-1],
-                    }
+                    names = (["moe_stats"] if self._moe else []) + ["attn_steps", "state_steps"]
+                    carried = dict(zip(names, stats))
                     self._m_decode_rows.inc(live * steps, state="live")
                     self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
                 prof_step.fence(toks)
@@ -2272,10 +2287,14 @@ class ContinuousBatchingEngine:
             runs = np.asarray(chunk.run_len) if spec else None
             if chunk.moe_stats is not None:
                 self._count_moe(chunk.moe_stats, "decode")
-            if chunk.attn_steps is not None:
-                walked, most = (int(n) for n in np.asarray(chunk.attn_steps))
-                self._m_decode_attn_steps.inc(walked, kind="walked")
-                self._m_decode_attn_steps.inc(most - walked, kind="spared")
+            for steps, counter in (
+                (chunk.attn_steps, self._m_decode_attn_steps),
+                (chunk.state_steps, self._m_delta_state_steps),
+            ):
+                if steps is not None:
+                    walked, most = (int(n) for n in np.asarray(steps))
+                    counter.inc(walked, kind="walked")
+                    counter.inc(most - walked, kind="spared")
         # serve_host_stall_seconds_total is the sync_wait phase, published
         # with it in _refresh_stats; the window ends at the same switch
         t_done = self._clock.switched_at

@@ -200,6 +200,26 @@ def test_write_columns_lowers(topo, variant):
     assert compiled.memory_analysis().temp_size_in_bytes < B * kv_heads * max(dims) * C
 
 
+def test_delta_decode_step_lowers(topo):
+    """The gated delta rule's state kernel at qwen3-next-ep2's shape: 3 linear
+    layers x 128 slots x 32 heads of (128, 128) float32, the stacked leaf in
+    and the same buffer out, a row's 32 heads a grid step under the work
+    list's traced bound."""
+    from prime_tpu.ops.pallas_delta import delta_decode_step, delta_step_heads
+
+    layers, slots, heads, d_k, d_v = 3, 128, 32, 128, 128
+    leaf = ((layers, slots, heads, d_k, d_v), jnp.float32)
+    vector = lambda *shape: ((slots, heads, *shape), jnp.float32)  # noqa: E731
+    args = (leaf, ((), jnp.int32), vector(d_k), vector(d_k), vector(d_v), vector(), vector(),
+            ((slots,), jnp.bool_))
+    compiled = _compile(topo, delta_decode_step, *args, donate=(0,))
+    assert _pallas_grids(delta_decode_step, *args) == [
+        (heads // delta_step_heads(heads, d_k, d_v), None)
+    ] == [(1, None)]
+    # donated and aliased through the kernel: no second state, not one layer's
+    assert compiled.memory_analysis().temp_size_in_bytes < slots * heads * d_k * d_v * 4 // 16
+
+
 @pytest.mark.parametrize("variant", ["plain", "window_sinks"])
 def test_flash_prefill_lowers(topo, variant):
     from prime_tpu.ops.pallas_attention import flash_attention_causal
@@ -679,9 +699,10 @@ def test_gated_delta_decode_fits_the_chip_with_state_and_row_in_place(topo, monk
     the published widths, 256 experts a layer, beside the whole cache. K/V rows
     exist for the one full layer (2 heads of 256 through flash-decode and the
     column write), the 12 grouped products read the expert stacks in place, and
-    the step holds no copy of the stacked state, tail or row. What it does hold
-    is one layer's state slice at a time (0.27 GB: XLA materialises the slice
-    its two readers share; ROADMAP R7 queues the kernel that does not)."""
+    the step holds no copy of the stacked state, tail or row. Nor one layer's
+    slice of the state (0.27 GB, which the XLA step materialised for its two
+    readers): three custom calls more, the state kernel's (ops/pallas_delta.py),
+    step the live rows' blocks where they lie."""
     _chip_takes_the_kernels(monkeypatch)
     config, decode, _ = _qwen3_next_engine_programs()
     params, cache = _abstract_model(config, 128, 4096)
@@ -690,12 +711,15 @@ def test_gated_delta_decode_fits_the_chip_with_state_and_row_in_place(topo, monk
     )
     memory = compiled.memory_analysis()
     assert 9.1e9 < memory.argument_size_in_bytes < 9.4e9  # 7.36 GB of weights, 1.90 GB of slots
-    assert memory.temp_size_in_bytes < 0.7e9
+    assert memory.temp_size_in_bytes < 0.4e9
     text = compiled.as_text()
-    assert len(re.findall("tpu_custom_call", text)) >= 14  # 12 grouped products, write and attend
+    # 12 grouped products, write and attend, and the three linear layers' state kernel
+    assert len(re.findall("tpu_custom_call", text)) >= 17
     for leaf in ("f32[3,128,32,128,128]", "bf16[3,128,3,8192]", "bf16[1,128,2,256,4096]",
                  "bf16[4,256,2048,512]", "bf16[4,256,512,2048]"):
         assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+    # no operation makes one layer's state: it is never sliced out of the stack
+    assert not re.findall(r"= f32\[128,32,128,128\]", text)
 
 
 def test_gated_delta_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):

@@ -16,6 +16,7 @@ pass.
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -91,9 +92,23 @@ def _plain(params, tokens):
     return forward(params, tokens, CONFIG)[0]
 
 
-@jax.jit
-def _decode(params, column, cache):
-    return forward(params, column, CONFIG, cache=cache, decode=True, positions=cache.lengths[:, None])
+@functools.partial(jax.jit, static_argnames=("attn_impl",))
+def _decode(params, column, cache, attn_impl="auto"):
+    return forward(params, column, CONFIG, cache=cache, decode=True, positions=cache.lengths[:, None],
+                   attn_impl=attn_impl)
+
+
+# the decode step's two paths: plain XLA (what the CPU backend takes by itself),
+# and the kernels a chip takes (the column write, flash-decode and the state
+# kernel of ops/pallas_delta.py), interpreted
+STEP_PATHS = {"xla-step": "auto", "kernel-step": "pallas"}
+
+
+@pytest.fixture(params=sorted(STEP_PATHS))
+def step_impl(request, monkeypatch):
+    if request.param == "kernel-step":
+        monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")
+    return STEP_PATHS[request.param]
 
 
 @jax.jit
@@ -151,7 +166,7 @@ def test_bf16_weights_fail_the_tolerance(weights):
     assert np.abs(np.asarray(got[0]) - _reference(theirs, tokens)).max() > 100 * TOL
 
 
-def _chunks_then_decode(ours, want, tokens, sizes, spoil=None):
+def _chunks_then_decode(ours, want, tokens, sizes, spoil=None, attn_impl="auto"):
     """The worst gap of a chunked prefill of ``sizes`` and a decode of the rest;
     ``spoil(cache)`` stands for a fault between the prefill and the decode."""
     cache = init_cache(CONFIG, 1, 256, dtype=jnp.float32)
@@ -162,7 +177,7 @@ def _chunks_then_decode(ours, want, tokens, sizes, spoil=None):
         offset += size
     cache = spoil(cache) if spoil else cache
     for at in range(offset, len(tokens)):
-        logits, cache = _decode(ours, _one(tokens[at : at + 1]), cache)
+        logits, cache = _decode(ours, _one(tokens[at : at + 1]), cache, attn_impl=attn_impl)
         worst = max(worst, np.abs(np.asarray(logits[0, 0]) - want[at]).max())
     assert int(cache.lengths[0]) == len(tokens)
     return worst
@@ -170,10 +185,11 @@ def _chunks_then_decode(ours, want, tokens, sizes, spoil=None):
 
 @pytest.mark.parametrize("sizes", [(64, 64), (80, 16, 32), (16, 3, 1, 70)],
                          ids=["two-chunks", "three-chunks", "odd-chunks"])
-def test_chunked_prefill_hands_state_and_tail_on_to_decode(weights, sizes):
+def test_chunked_prefill_hands_state_and_tail_on_to_decode(weights, sizes, step_impl):
     ours, theirs = weights
     tokens = _tokens(2, sum(sizes) + 12)
-    assert _chunks_then_decode(ours, _reference(theirs, tokens), tokens, sizes) < TOL
+    worst = _chunks_then_decode(ours, _reference(theirs, tokens), tokens, sizes, attn_impl=step_impl)
+    assert worst < TOL
 
 
 @pytest.mark.parametrize("leaf", ["delta_state", "conv_tail"])
@@ -270,15 +286,19 @@ def test_the_carried_state_matters_with_this_recipes_decay_and_not_with_the_publ
     assert effect(published) < 1e-6
 
 
-def test_a_row_that_belongs_to_nobody_leaves_the_others_bit_identical(weights):
+def test_a_row_that_belongs_to_nobody_leaves_the_others_bit_identical(weights, step_impl):
     ours, _ = weights
     rows = jnp.asarray([_tokens(20, 12), _tokens(21, 12)], jnp.int32)
     cache = init_cache(CONFIG, 2, 64, dtype=jnp.float32)
     _, cache = forward(ours, rows, CONFIG, cache=cache)
     column = jnp.asarray([[7], [9]], jnp.int32)
-    at = dict(cache=cache, decode=True, positions=cache.lengths[:, None])
+    at = dict(cache=cache, decode=True, positions=cache.lengths[:, None], attn_impl=step_impl)
     plain, _ = forward(ours, column, CONFIG, **at)
-    masked, after = forward(ours, column, CONFIG, token_mask=jnp.asarray([[True], [False]]), **at)
+    masked, after, state_steps = forward(
+        ours, column, CONFIG, token_mask=jnp.asarray([[True], [False]]), return_state_steps=True, **at
+    )
+    # the state kernel visits the live row of the two, in each of the 6 linear layers
+    assert np.asarray(state_steps).tolist() == ([6, 12] if step_impl == "pallas" else [0, 0])
     assert np.array_equal(np.asarray(plain[0]), np.asarray(masked[0]))
     assert not np.array_equal(np.asarray(plain[1]), np.asarray(masked[1]))
     assert bool(jnp.all(jnp.isfinite(masked)))
@@ -438,9 +458,9 @@ def test_a_retired_slots_state_never_reaches_the_next_tenant(weights):
     assert values["serve_state_resets_total"] / values["serve_requests_admitted_total"] == 1.0
 
 
-def test_the_engine_counts_the_state_it_holds_and_the_experts_it_ran(weights):
+def test_the_engine_counts_the_state_it_holds_and_the_experts_it_ran(weights, step_impl):
     ours, _ = weights
-    engine = _engine(ours)
+    engine = _engine(ours, attn_impl=step_impl)
     snapshot = engine.registry.snapshot()
     by_kind = {s["labels"]["kind"]: s["value"] for s in snapshot["serve_cache_bytes"]["series"]}
     held = cache_leaves(engine._cache)
@@ -454,6 +474,18 @@ def test_the_engine_counts_the_state_it_holds_and_the_experts_it_ran(weights):
     # 21 prompt tokens x 8 layers x top-3, each pick local or elsewhere; no zero experts
     assert pairs[("local", "prefill")] + pairs[("elsewhere", "prefill")] == 21 * 8 * 3
     assert pairs[("local", "decode")] > 0 and pairs[("zero", "decode")] == 0
+    # the state kernel's steps, of slots x head blocks (1) x 6 linear layers x decode
+    # steps: the live rows' share of them on the kernel's path, none as plain XLA
+    steps, rows = (engine.registry.get(f"serve_{name}_total")
+                   for name in ("delta_state_steps", "decode_rows"))
+    walked, spared = steps.value(kind="walked"), steps.value(kind="spared")
+    if step_impl == "pallas":
+        # (rows are counted as a chunk is dispatched, steps as it is synced: shares)
+        live, retired = rows.value(state="live"), rows.value(state="retired")
+        assert walked > 0 and walked % (6 * engine.chunk) == 0
+        assert walked / (walked + spared) == live / (live + retired) == 0.5
+    else:
+        assert (walked, spared) == (0, 0)
 
 
 # ---- the tolerance tells each named fault ----
