@@ -184,8 +184,28 @@ def scenario_row(result) -> dict[str, Any]:
     tokens = edelta("serve_tokens_emitted_total")
     admitted = edelta("serve_requests_admitted_total")
     hits = edelta("serve_prefix_hits_total")
-    stall = edelta("serve_host_stall_seconds_total")
-    window = edelta("serve_chunk_window_seconds_total")
+
+    def phases_delta(family: str, **fixed: str) -> float:
+        return sum(
+            counter_delta(
+                _scalar_sum(before.get(name, {}), family, **fixed),
+                _scalar_sum(after[name], family, **fixed),
+            )[0]
+            for name in engines
+        )
+
+    # the phase clock's two families: of the loop's seconds with a request
+    # queued or live, the percentage in which the chip was seen to have no
+    # model program left to run; absent where an engine has no starved clock
+    with_work = phases_delta("serve_loop_seconds_total") - phases_delta(
+        "serve_loop_seconds_total", phase="no_work"
+    )
+    starved = phases_delta("serve_loop_starved_seconds_total") - phases_delta(
+        "serve_loop_starved_seconds_total", phase="no_work"
+    )
+    has_starved_clock = any(
+        "serve_loop_starved_seconds_total" in after[name] for name in engines
+    )
     spec_accepted = (ehist("serve_spec_accepted_tokens") or {"sum": 0.0})["sum"]
     spec_proposed = edelta("serve_spec_draft_tokens_total")
 
@@ -203,8 +223,9 @@ def scenario_row(result) -> dict[str, Any]:
         "completed": int(edelta("serve_requests_completed_total")),
         "cancelled": int(edelta("serve_requests_cancelled_total")),
         "failed": int(edelta("serve_requests_failed_total")),
-        "overlap_ratio": (
-            round(max(0.0, min(1.0, 1.0 - stall / window)), 4) if window > 0 else None
+        "chip_starved_share": (
+            round(100.0 * starved / with_work, 2)
+            if has_starved_clock and with_work > 0 else None
         ),
         "prefix_hit_ratio": round(hits / admitted, 4) if admitted else None,
         "prefix_hit_tokens": {

@@ -295,6 +295,11 @@ class Registry:
         with self._lock:
             return self._metrics.get(name)
 
+    def hold(self) -> threading.RLock:
+        """The one lock, for ``with registry.hold():`` around several writes
+        that every reader must see together or not at all."""
+        return self._lock
+
     def values(self) -> dict[str, float]:
         """Unlabeled counter/gauge values in one consistent read — the
         engine's ``stats()`` composes its legacy JSON from this."""

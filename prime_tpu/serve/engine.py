@@ -329,8 +329,7 @@ class _InflightChunk:
     state_steps: Any = None
     # False once an admission prefill ran inside this chunk's window: its
     # dispatch-to-sync wall time then includes host prefill blocking and must
-    # not feed the per-step decode histogram (it still counts toward the
-    # window/stall overlap counters, which measure the loop, not the device)
+    # not feed the per-step decode histogram
     clean: bool = True
 
 
@@ -997,17 +996,9 @@ class ContinuousBatchingEngine:
             "serve_admission_batch_size", "Requests admitted per prefill wave",
             buckets=DEFAULT_SIZE_BUCKETS,
         )
-        # pipeline instrumentation (overlap mode): how long the host actually
-        # blocked waiting for a chunk vs the chunk's dispatch-to-sync window,
-        # and the decode the one-chunk retirement lag threw away
-        self._m_host_stall_s = r.counter(
-            "serve_host_stall_seconds_total",
-            "Seconds the host blocked waiting for a dispatched decode chunk",
-        )
-        self._m_chunk_window_s = r.counter(
-            "serve_chunk_window_seconds_total",
-            "Seconds between decode-chunk dispatch and its host sync",
-        )
+        # the decode the one-chunk retirement lag threw away (what the host
+        # hid inside a chunk's window, and what it did not, is the phase
+        # clock's: serve_loop_seconds_total, serve_loop_starved_seconds_total)
         self._m_wasted_tokens = r.counter(
             "serve_wasted_decode_tokens_total",
             "Tokens decoded for slots already retired at dispatch (one-chunk lag)",
@@ -1038,10 +1029,6 @@ class ContinuousBatchingEngine:
         )
         self._m_inflight_depth = r.gauge(
             "serve_inflight_depth", "Dispatched-but-unfetched decode chunks"
-        )
-        self._m_overlap_ratio = r.gauge(
-            "serve_overlap_ratio",
-            "1 - host-stall/chunk-window: fraction of the decode window the host overlapped",
         )
         # what the slots hold, by kind of leaf (models/llama.py CACHE_LEAVES):
         # full-length K/V rows, window rings, recurrent state
@@ -2262,6 +2249,7 @@ class ContinuousBatchingEngine:
                     carried = dict(zip(names, stats))
                     self._m_decode_rows.inc(live * steps, state="live")
                     self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
+                self._clock.enqueued(toks)
                 prof_step.fence(toks)
         self._inflight.append(
             _InflightChunk(
@@ -2295,10 +2283,7 @@ class ContinuousBatchingEngine:
                     walked, most = (int(n) for n in np.asarray(steps))
                     counter.inc(walked, kind="walked")
                     counter.inc(most - walked, kind="spared")
-        # serve_host_stall_seconds_total is the sync_wait phase, published
-        # with it in _refresh_stats; the window ends at the same switch
-        t_done = self._clock.switched_at
-        self._m_chunk_window_s.inc(t_done - chunk.dispatched_at)
+        t_done = self._clock.switched_at  # the sync_wait phase's end
         if chunk.clean:
             # steady-state decode only: windows that contained an admission
             # prefill are dominated by host work already recorded in
@@ -2523,6 +2508,7 @@ class ContinuousBatchingEngine:
                 )
                 self.profiler.note_cost("prefill", self._chunk_fn, chunk_args)
                 row, logits, *stats = self._chunk_fn(*chunk_args)
+                self._clock.enqueued(logits)
                 moe_stats = self._sum_moe(moe_stats, stats)
                 real_tokens += sum(min(max(length - off, 0), size) for length in lengths)
             # a chunk computes the cross half at one position a row
@@ -2555,6 +2541,7 @@ class ContinuousBatchingEngine:
                     adapter_ids,
                     rng,
                 )
+            self._clock.enqueued(firsts)
             prof_step.fence(firsts)
         if self.speculative:
             # seed the device history ring before the host sync below — the
@@ -3172,11 +3159,8 @@ class ContinuousBatchingEngine:
             return self._publish_stats()
 
     def _publish_stats(self) -> dict:
-        # the loop's seconds since the last tick, by phase; the host stall is
-        # the sync_wait phase by another name (one switch feeds both)
-        stalled = self._clock.flush().get("sync_wait")
-        if stalled:
-            self._m_host_stall_s.inc(stalled)
+        # the loop's seconds since the last tick, by phase, and the starved ones
+        self._clock.flush()
         self._m_active_slots.set(int(self._active.sum()))
         self._m_queue_depth.set(self.queue_depth())
         # HBM/live-buffer gauges: rate-limited inside, no-op when the
@@ -3192,13 +3176,6 @@ class ContinuousBatchingEngine:
                     self._digest_snapshot = digest
                 self._digest_at = now
         values = self.registry.values()
-        stall = float(values["serve_host_stall_seconds_total"])
-        window = float(values["serve_chunk_window_seconds_total"])
-        # fraction of the dispatch-to-sync window the host did NOT block for:
-        # 0 in synchronous mode (stall == window), ->1 when emit/admission
-        # fully hide inside device compute
-        ratio = max(0.0, min(1.0, 1.0 - stall / window)) if window > 0 else 0.0
-        self._m_overlap_ratio.set(ratio)
         spec_ratio = (
             self._spec_accepted / self._spec_proposed if self._spec_proposed else 0.0
         )
@@ -3228,9 +3205,7 @@ class ContinuousBatchingEngine:
             "draft_len": int(self.draft_len) if self.speculative else 0,
             "spec_accept_ratio": round(spec_ratio, 4),
             "inflight_depth": int(values["serve_inflight_depth"]),
-            "host_stall_s": round(stall, 6),
-            "chunk_window_s": round(window, 6),
-            "overlap_ratio": round(ratio, 4),
+            **self._clock.totals(),  # loop_s, loop_starved_s by phase; loop_starved_unseen_s
             "wasted_decode_tokens": int(values["serve_wasted_decode_tokens_total"]),
             "warmup_programs": int(values["serve_warmup_programs"]),
             "prefix_cache_bytes": int(values["serve_prefix_cache_bytes"]),

@@ -288,7 +288,7 @@ def slo_report(paths: list[str]) -> None:
         )
         print(
             f"{'scenario':>18} {'tok/s':>8} {'ttft_p50':>9} {'ttft_p95':>9} "
-            f"{'tpot_p50':>9} {'overlap':>8} {'hit':>6} {'429s':>5}  outcomes"
+            f"{'tpot_p50':>9} {'starved%':>8} {'hit':>6} {'429s':>5}  outcomes"
         )
         for row in report.get("scenarios", []):
             outcomes = ", ".join(
@@ -301,7 +301,7 @@ def slo_report(paths: list[str]) -> None:
                 f"{row.get('scenario', '?'):>18} {row.get('tok_s', 0):>8} "
                 f"{ms(row.get('ttft_s'), 'p50'):>9} {ms(row.get('ttft_s'), 'p95'):>9} "
                 f"{ms(row.get('tpot_s'), 'p50'):>9} "
-                f"{row.get('overlap_ratio') if row.get('overlap_ratio') is not None else '—':>8} "
+                f"{row.get('chip_starved_share') if row.get('chip_starved_share') is not None else '—':>8} "
                 f"{row.get('prefix_hit_ratio') if row.get('prefix_hit_ratio') is not None else '—':>6} "
                 f"{row.get('rejected_429', 0):>5}  {outcomes}"
             )

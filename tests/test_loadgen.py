@@ -151,7 +151,7 @@ def test_captured_at_name_is_reserved():
 
 
 def _snap(captured_at, tokens, admitted=4, hits=1, ttft_counts=None,
-          stall=0.0, window=0.0, spec_accepted=0.0, spec_windows=0,
+          loop=None, starved=None, spec_accepted=0.0, spec_windows=0,
           spec_drafts=0.0):
     """Hand-built registry snapshot: the report consumes plain dicts, so the
     arithmetic is testable without clocks."""
@@ -174,10 +174,13 @@ def _snap(captured_at, tokens, admitted=4, hits=1, ttft_counts=None,
         "serve_requests_failed_total": {"type": "counter", "help": "", "series": []},
         "serve_prefix_hits_total": {"type": "counter", "help": "", "series": [
             {"labels": {}, "value": float(hits)}]},
-        "serve_host_stall_seconds_total": {"type": "counter", "help": "", "series": [
-            {"labels": {}, "value": stall}]},
-        "serve_chunk_window_seconds_total": {"type": "counter", "help": "", "series": [
-            {"labels": {}, "value": window}]},
+        # the phase clock's two families (a round from before the starved
+        # clock has only the first)
+        **{family: {"type": "counter", "help": "", "series": [
+            {"labels": {"phase": phase}, "value": seconds} for phase, seconds in phases.items()]}
+           for family, phases in (("serve_loop_seconds_total", loop),
+                                  ("serve_loop_starved_seconds_total", starved))
+           if phases is not None},
         "serve_ttft_seconds": {"type": "histogram", "help": "", "series": [{
             "labels": {}, "buckets": [0.1, 1.0], "counts": list(ttft_counts),
             "sum": 1.0, "count": sum(ttft_counts)}]},
@@ -204,15 +207,21 @@ class _FakeResult:
 
 
 def test_report_numbers_come_from_snapshot_deltas_not_client_timers():
-    before = {"engine": _snap(100.0, tokens=40, admitted=0, hits=0)}
-    after = {"engine": _snap(102.0, tokens=140, admitted=4, hits=2,
-                             ttft_counts=[3, 1, 0], stall=0.5, window=2.0)}
+    before = {"engine": _snap(100.0, tokens=40, admitted=0, hits=0,
+                              loop={"no_work": 9.0, "emit": 1.0}, starved={"no_work": 9.0})}
+    after = {"engine": _snap(102.0, tokens=140, admitted=4, hits=2, ttft_counts=[3, 1, 0],
+                             loop={"no_work": 9.4, "emit": 1.6, "sync_wait": 1.0},
+                             starved={"no_work": 9.4, "emit": 0.4})}
     row = scenario_row(_FakeResult(before, after))
     assert row["duration_s"] == pytest.approx(2.0)
     assert row["tok_s"] == pytest.approx(50.0)  # (140-40) / (102-100)
     assert row["admitted"] == 4
     assert row["prefix_hit_ratio"] == pytest.approx(0.5)
-    assert row["overlap_ratio"] == pytest.approx(0.75)  # 1 - 0.5/2.0
+    # of the loop's 1.6 s with work (2.0 less 0.4 of no_work), 0.4 starved in emit
+    assert row["chip_starved_share"] == pytest.approx(25.0)
+    # an engine from before the starved clock: absent, not zero
+    old = {"engine": _snap(102.0, tokens=140, loop={"emit": 1.6})}
+    assert scenario_row(_FakeResult(before, old))["chip_starved_share"] is None
     # p50 of [3 <= 0.1s, 1 <= 1.0s]: rank 2 of 4 inside the first bucket
     assert row["ttft_s"]["p50"] == pytest.approx(0.1 * 2 / 3, rel=1e-4)
     assert row["ttft_s"]["p95"] > row["ttft_s"]["p50"]
@@ -261,7 +270,7 @@ def test_report_field_set_is_stable():
     expected = {
         "scenario", "seed", "schedule_digest", "requests", "outcomes",
         "client_tokens", "duration_s", "tokens", "tok_s", "admitted",
-        "completed", "cancelled", "failed", "overlap_ratio",
+        "completed", "cancelled", "failed", "chip_starved_share",
         "prefix_hit_ratio", "prefix_hit_tokens", "prefix_spills",
         "prefix_reuploads", "wasted_decode_tokens", "ttft_s", "tpot_s",
         "queue_wait_s", "rejected_429",

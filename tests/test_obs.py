@@ -442,8 +442,8 @@ def test_engine_histograms_populate_through_streamed_completion():
                 "max_slots", "max_queue", "mesh_devices", "mesh_axes",
                 "adapters_loaded", "adapters", "adapter_weights", "state",
                 "overlap", "speculative", "draft_len", "spec_accept_ratio",
-                "inflight_depth", "host_stall_s", "chunk_window_s",
-                "overlap_ratio", "wasted_decode_tokens", "warmup_programs",
+                "inflight_depth", "loop_s", "loop_starved_s",
+                "loop_starved_unseen_s", "wasted_decode_tokens", "warmup_programs",
                 "prefix_cache_bytes", "prefix_cache_host_bytes",
                 "prefix_host_tier_disabled",
                 "prefix_cache_nodes", "prefix_evictions", "prefix_spills",
@@ -834,7 +834,8 @@ def test_serve_profile_waterfall_stitches_cross_process(tmp_path):
 def test_serve_profile_overlap_report(tmp_path):
     """scripts/serve_profile.py --trace: pairs serve.dispatch/serve.sync
     spans by chunk seq from a PRIME_TRACE JSONL and reports the per-chunk
-    host-stall fraction (the offline twin of serve_overlap_ratio)."""
+    host-stall fraction (the offline twin of the phase clock's sync_wait
+    over a chunk's window)."""
     import json
     import pathlib
     import subprocess

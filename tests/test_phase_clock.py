@@ -43,9 +43,16 @@ def make_engine(**kw) -> ContinuousBatchingEngine:
     return ContinuousBatchingEngine(PARAMS, CONFIG, **kw)
 
 
-def loop_seconds(snapshot: dict) -> dict[str, float]:
-    family = snapshot.get("serve_loop_seconds_total", {})
-    return {s["labels"]["phase"]: s["value"] for s in family.get("series", [])}
+STARVED = "serve_loop_starved_seconds_total"
+UNSEEN = "serve_loop_starved_unseen_seconds_total"
+
+
+def loop_seconds(snapshot: dict, family: str = "serve_loop_seconds_total") -> dict[str, float]:
+    return {s["labels"]["phase"]: s["value"] for s in snapshot.get(family, {}).get("series", [])}
+
+
+def unseen_seconds(snapshot: dict) -> float:
+    return sum(s["value"] for s in snapshot[UNSEEN]["series"])
 
 
 def snapshot_after_flush(engine, timeout: float = 5.0) -> dict:
@@ -70,8 +77,8 @@ def settle(engine, timeout: float = 30.0) -> None:
     time.sleep(0.3)
 
 
-def delta(before: dict, after: dict) -> dict[str, float]:
-    a, b = loop_seconds(before), loop_seconds(after)
+def delta(before: dict, after: dict, family: str = "serve_loop_seconds_total") -> dict[str, float]:
+    a, b = loop_seconds(before, family), loop_seconds(after, family)
     return {phase: b[phase] - a.get(phase, 0.0) for phase in b}
 
 
@@ -118,9 +125,14 @@ def test_phases_are_exclusive_and_exhaustive_over_a_burst_and_an_idle_stretch():
     assert busy["sync_wait"] > 0 and busy["prefill_sync"] > 0
     assert busy["dispatch"] > 0 and busy["prefill"] > 0 and busy["emit"] > 0
     assert set(busy) <= set(phase_mod.PHASES)
-    # the host stall is the sync_wait phase by another name
-    stall = engine.registry.get("serve_host_stall_seconds_total").value()
-    assert stall == pytest.approx(loop_seconds(s4)["sync_wait"], rel=1e-6)
+    # the starved clock charges a second time, so never more than the phase
+    # holds; with nobody to serve the chip has nothing to run all the while
+    for before, after in zip(snaps, snaps[1:]):
+        held = delta(before, after)
+        for phase, seconds in delta(before, after, STARVED).items():
+            assert -1e-9 <= seconds <= held[phase] + 1e-9, phase
+    assert delta(s3, s4, STARVED)["no_work"] == pytest.approx(idle["no_work"], abs=1e-5)
+    assert set(loop_seconds(s4, STARVED)) <= set(phase_mod.PHASES)
 
 
 def test_first_use_of_a_shape_charges_program_build_once_listened():
@@ -263,6 +275,7 @@ def test_capture_holds_the_phases_on_the_loop_threads_line(tmp_path):
     # one line holds them all (the loop thread's), and seq is a stat
     lines = set()
     seqs = []
+    idle_bits, sync_bits = [], []
     for path in tmp_path.rglob("*.xplane.pb"):
         for plane in ProfileData.from_file(str(path)).planes:
             for index, line in enumerate(plane.lines):
@@ -271,8 +284,17 @@ def test_capture_holds_the_phases_on_the_loop_threads_line(tmp_path):
                         lines.add((plane.name, index))
                     if event.name == "serve.dispatch":
                         seqs.append(dict(event.stats)["seq"])
+                    if event.name == "serve.no_work":
+                        idle_bits.append(dict(event.stats).get("starved"))
+                    if event.name == "serve.sync":
+                        sync_bits.append(dict(event.stats).get("starved"))
     assert len(lines) == 1
     assert len(seqs) >= 3 and sorted(seqs) == list(range(min(seqs), max(seqs) + 1))
+    # the starved bit rides the annotation: the loop idles only once its last
+    # chunk is drained, and a sync in the tick of its dispatch is opened with
+    # that chunk just enqueued
+    assert idle_bits and all(bit == 1 for bit in idle_bits)
+    assert None in sync_bits
 
     moved = delta(before, after)
     assert moved.get("program_build", 0.0) == 0.0
@@ -311,19 +333,269 @@ def test_a_hit_stores_its_pages_before_it_waits_for_the_first_token(overlap):
             < phases.index("prefix_store") < phases.index("prefill_sync"))
 
 
-def test_serial_loop_keeps_stall_equal_to_window():
-    """PRIME_SERVE_OVERLAP=0: the loop blocks for all of its window, which the
-    two hand counters said as stall == window (overlap ratio 0)."""
-    engine = make_engine(overlap=False, prefix_cache_mb=0)
-    req = engine.submit(PROMPT, max_new_tokens=8)
-    for _ in range(200):
-        engine.tick()
-        if req.done:
-            break
-    stats = engine.stats()
-    assert 0 < stats["host_stall_s"] <= stats["chunk_window_s"]
-    phases = loop_seconds(engine.registry.snapshot())
-    assert phases["sync_wait"] == pytest.approx(stats["host_stall_s"], abs=1e-5)
+class NeverDoneBeforeTheLoopWaits:
+    """A model program's output on a chip slower than the host: not ready
+    until the loop has blocked on the device once since it was enqueued (the
+    tiny model on a loaded CPU finishes whenever it likes)."""
+
+    def __init__(self, output, clock) -> None:
+        self.output, self.clock, self.waited_for = output, clock, False
+
+    def is_ready(self) -> bool:
+        self.waited_for = self.waited_for or self.clock.phase in phase_mod.BLOCKED_ON_DEVICE
+        return self.waited_for and self.output.is_ready()
+
+
+def run_bursts(overlap: bool) -> tuple[dict, dict]:
+    """Two bursts on a synchronously ticked engine; the snapshots around the
+    second, whose programs the first one built."""
+    engine = make_engine(overlap=overlap)
+    clock, enqueued = engine._clock, engine._clock.enqueued
+    clock.enqueued = lambda output: enqueued(NeverDoneBeforeTheLoopWaits(output, clock))
+    snaps = []
+    for token in (7, 11):
+        reqs = [engine.submit([token] + PROMPT[1:], max_new_tokens=24) for _ in range(4)]
+        for _ in range(400):
+            engine.tick()
+            if all(r.done for r in reqs) and not engine._inflight:
+                break
+        assert all(r.done for r in reqs)
+        snaps.append(engine.registry.snapshot())
+    return snaps[0], snaps[1]
+
+
+def test_serial_loop_starves_the_chip_through_its_host_phases():
+    """PRIME_SERVE_OVERLAP=0: the loop drains the queue before it does anything
+    else, so the chip has no model program all through emit, stats and kv_jobs
+    (which the two hand counters said as stall == window), and through admit
+    but for the lines between a prefill's enqueue and the wait for it. The
+    store is entered with the prefill just enqueued, and not starved at all."""
+    before, after = run_bursts(overlap=False)
+    held, starved = delta(before, after), delta(before, after, STARVED)
+    assert delta(before, after).get("program_build", 0.0) == 0.0
+    for phase in ("emit", "stats", "kv_jobs"):
+        assert held[phase] > 0
+        assert starved[phase] == pytest.approx(held[phase], abs=1e-5), phase
+    assert 0.5 * held["admit"] < starved["admit"] < held["admit"]
+    assert held["prefix_store"] > 0 and starved.get("prefix_store", 0.0) == 0.0
+    # blocked on the array it looks at: the reads' exits leave nothing unseen
+    assert held["sync_wait"] > 0 and starved.get("sync_wait", 0.0) == 0.0
+    assert unseen_seconds(after) - unseen_seconds(before) < 0.05 * sum(held.values())
+
+
+def test_pipelined_loop_hides_its_emit_behind_the_chunk_in_flight():
+    """The same bursts under the one-chunk-deep pipeline: a chunk's tokens are
+    emitted while the next chunk computes, so emit is starved strictly less
+    than whole (and a smaller share of it than in the serial loop, 1.0)."""
+    before, after = run_bursts(overlap=True)
+    held, starved = delta(before, after), delta(before, after, STARVED)
+    assert held["emit"] > 0
+    assert starved.get("emit", 0.0) < held["emit"]
+    for phase, seconds in starved.items():
+        assert seconds <= held[phase] + 1e-9, phase
+
+
+class Output:
+    """Stands in for a model program's output: the test says when it is there."""
+
+    def __init__(self, ready: bool = False) -> None:
+        self.ready = ready
+
+    def is_ready(self) -> bool:
+        return self.ready
+
+
+class Ticker:
+    """The clock's time.monotonic(), moved by hand."""
+
+    def __init__(self) -> None:
+        self.now = 1000.0
+
+    def monotonic(self) -> float:
+        return self.now
+
+
+@pytest.fixture
+def hand_clock(monkeypatch):
+    """A phase clock on a registry of its own, on a hand-moved time: `spend`
+    enters a phase, lets seconds pass, and leaves it for `other`."""
+    from prime_tpu.obs.metrics import Registry
+
+    ticker = Ticker()
+    monkeypatch.setattr(phase_mod, "time", ticker)
+    registry = Registry()
+    clock = phase_mod.PhaseClock(registry)
+    clock.attach()
+
+    def spend(phase: str, seconds: float) -> None:
+        clock.switch(phase)
+        ticker.now += seconds
+        clock.switch("other")
+
+    def read() -> tuple[dict, dict, float]:
+        clock.flush()
+        snapshot = registry.snapshot()
+        return loop_seconds(snapshot), loop_seconds(snapshot, STARVED), unseen_seconds(snapshot)
+
+    return clock, ticker, spend, read
+
+
+def test_starved_seconds_stay_within_the_phase_and_wait_for_a_ready_output(hand_clock):
+    clock, ticker, spend, read = hand_clock
+    spend("emit", 1.0)  # nothing announced yet: nothing to be starved of
+    chunk = Output()
+    clock.switch("dispatch")
+    clock.enqueued(chunk)
+    spend("emit", 2.0)  # the chunk is running
+    held, starved, unseen = read()
+    assert held["emit"] == pytest.approx(3.0) and starved == {} and unseen == 0.0
+    clock.switch("sync_wait")
+    ticker.now += 0.5
+    chunk.ready = True  # the read comes back when the chip finishes
+    clock.switch("other")
+    spend("emit", 4.0)
+    spend("admit", 1.5)
+    held, starved, unseen = read()
+    assert starved == {"emit": pytest.approx(4.0), "admit": pytest.approx(1.5)}
+    assert all(starved[phase] <= held[phase] for phase in starved) and unseen == 0.0
+
+
+def test_enqueued_ends_a_starved_stretch_inside_the_phase(hand_clock):
+    clock, ticker, spend, read = hand_clock
+    clock.enqueued(Output(ready=True))
+    spend("admit", 1.0)  # the look at its entry finds the output there
+    clock.switch("prefill")
+    ticker.now += 0.25  # planning and uploads, the chip still idle
+    clock.enqueued(Output())
+    assert clock.starved is False
+    ticker.now += 0.75  # the rest of the phase, with the prefill running
+    clock.switch("other")
+    held, starved, _ = read()
+    assert held["prefill"] == pytest.approx(1.0)
+    assert starved == {"admit": pytest.approx(1.0), "prefill": pytest.approx(0.25)}
+
+
+def test_a_ready_after_not_ready_look_is_unseen_but_at_a_blocking_reads_exit(hand_clock):
+    clock, ticker, spend, read = hand_clock
+    chunk = Output()
+    clock.enqueued(chunk)
+    clock.switch("emit")  # a look: still running
+    ticker.now += 3.0
+    chunk.ready = True  # finished somewhere inside those three seconds
+    clock.switch("admit")
+    ticker.now += 1.0
+    clock.switch("other")
+    held, starved, unseen = read()
+    assert unseen == pytest.approx(3.0)
+    assert starved == {"admit": pytest.approx(1.0)} and held["emit"] == pytest.approx(3.0)
+    for blocked in phase_mod.BLOCKED_ON_DEVICE:
+        chunk = Output()
+        clock.enqueued(chunk)
+        clock.switch(blocked)
+        ticker.now += 2.0
+        chunk.ready = True
+        clock.switch("emit")
+        ticker.now += 0.5
+        clock.switch("other")
+    _, starved, unseen = read()
+    assert unseen == pytest.approx(3.0)  # nothing added
+    assert starved["emit"] == pytest.approx(1.0)
+    # an enqueue looks at its predecessor too: it ran out before its successor came
+    chunk = Output()
+    clock.enqueued(chunk)
+    ticker.now += 0.5
+    chunk.ready = True
+    clock.enqueued(Output())
+    assert read()[2] == pytest.approx(3.5)
+
+
+def test_a_build_in_a_starved_phase_moves_its_starved_seconds_too(hand_clock):
+    clock, ticker, spend, read = hand_clock
+    clock.enqueued(Output(ready=True))
+    spend("other", 0.0)  # the look
+    clock.switch("prefix_store")
+    ticker.now += 5.0
+    clock._carve(2.0)  # a program built on first use, two of the five seconds
+    clock._carve(0.5, "gc")
+    clock.switch("other")
+    held, starved, _ = read()
+    assert held["prefix_store"] == pytest.approx(2.5) == starved["prefix_store"]
+    assert held["program_build"] == pytest.approx(2.0) == starved["program_build"]
+    assert held["gc"] == pytest.approx(0.5) == starved["gc"]
+    # with the chip busy the build is carved from the phase alone
+    clock.switch("prefill")
+    clock.enqueued(Output())
+    ticker.now += 3.0
+    clock._carve(1.0)
+    clock.switch("other")
+    held, starved, _ = read()
+    assert held["program_build"] == pytest.approx(3.0)
+    assert starved["program_build"] == pytest.approx(2.0) and "prefill" not in starved
+    # a build is long beside a chunk, so its end is a look: the chip ran out
+    # somewhere inside this one, and the phase's rest is starved
+    chunk = Output()
+    clock.switch("prefix_store")
+    clock.enqueued(chunk)
+    ticker.now += 2.0
+    chunk.ready = True
+    clock._carve(2.0)
+    assert clock.starved
+    ticker.now += 0.5
+    clock.switch("other")
+    held, starved, unseen = read()
+    assert starved["prefix_store"] == pytest.approx(3.0) and unseen == pytest.approx(2.0)
+    assert starved["program_build"] == pytest.approx(2.0)
+
+
+def test_one_flush_publishes_both_families_under_one_hold_of_the_lock(hand_clock):
+    import threading
+
+    clock, ticker, spend, _ = hand_clock
+    registry = clock._registry
+    clock.enqueued(Output(ready=True))
+    spend("other", 0.0)
+    spend("emit", 1.0)
+    assert loop_seconds(registry.snapshot()) == {} == loop_seconds(registry.snapshot(), STARVED)
+    seen: list[dict] = []
+    reader = threading.Thread(target=lambda: seen.append(registry.snapshot()))
+    inc = clock._m_loop_s.inc
+
+    def inc_while_a_reader_waits(*args, **kwargs):
+        if not reader.ident:
+            reader.start()
+            reader.join(timeout=0.2)
+            assert reader.is_alive() and not seen  # held out until the flush is whole
+        inc(*args, **kwargs)
+
+    clock._m_loop_s.inc = inc_while_a_reader_waits
+    clock.flush()
+    reader.join(timeout=5.0)
+    assert not reader.is_alive()
+    assert loop_seconds(seen[0])["emit"] == pytest.approx(1.0)
+    assert loop_seconds(seen[0], STARVED)["emit"] == pytest.approx(1.0)
+    assert clock.totals() == {"loop_s": {"emit": 1.0}, "loop_starved_s": {"emit": 1.0},
+                              "loop_starved_unseen_s": 0.0}
+
+
+def test_a_collection_on_the_loops_thread_is_the_gc_phase():
+    """The gc.callbacks hook is registered once a process and carves a
+    collection's seconds out of the phase it struck."""
+    import gc
+
+    first, second = make_engine(), make_engine()
+    assert sum(cb is phase_mod._on_gc for cb in gc.callbacks) == 1
+    clock = first._clock
+    clock.attach()
+    junk = [[i] for i in range(200_000)]  # something for a full collection to walk
+    clock.flush()
+    t0 = time.monotonic()
+    with clock.enter("emit"):
+        gc.collect()
+    wall = time.monotonic() - t0
+    flushed = clock.flush()
+    assert 0 < flushed["gc"] <= wall and junk
+    assert flushed["gc"] + flushed["emit"] == pytest.approx(wall, rel=0.05, abs=2e-3)
+    assert "gc" not in loop_seconds(second.registry.snapshot())
 
 
 def test_programs_json_names_are_the_engines_own():
