@@ -156,7 +156,9 @@ def _echo_device_memory(when: str) -> None:
     "--max-wave", type=click.IntRange(min=1), default=None,
     help="Widest batched admission wave (--continuous), a power of two. The "
          "warm-up builds a program a prompt-row capacity and wave width, so "
-         "fewer widths start sooner. Default: the engine's rule from shapes.",
+         "fewer widths start sooner; an expert model's prompt chunks grow as "
+         "long as the narrower wave's score budget allows. Default: the engine's "
+         "rule from shapes.",
 )
 @click.option(
     "--role", type=click.Choice(["prefill", "decode", "any"]), default=None,

@@ -728,6 +728,15 @@ def _moe_dropless(stack: Params) -> bool:
     )
 
 
+def runs_dropless_experts(params: Params, config: ModelConfig) -> bool:
+    """Whether a cached ``forward`` of this model runs ``ops.moe.moe_infer``
+    (under the caller's mesh context): the block kinds built on it always,
+    the standard block where ``_moe_dropless`` says so."""
+    if not config.is_moe:
+        return False
+    return config.block != "standard" or _moe_dropless(params["layers"])
+
+
 def _mlp_block(
     x: jnp.ndarray, lp: Params, config: ModelConfig,
     adapter_ids: jnp.ndarray | None = None,
@@ -1158,7 +1167,7 @@ def forward(
             return (x, aux_sum, kv, stats + period_stats), None
 
     elif config.block == "standard":
-        dropless = cache is not None and config.is_moe and _moe_dropless(params["layers"])
+        dropless = cache is not None and runs_dropless_experts(params, config)
 
         def layer_fn(carry, scanned):
             x, aux_sum, kv, stats = carry

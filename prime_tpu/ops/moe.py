@@ -261,6 +261,11 @@ def moe_mlp(
 # ---- inference: dropless, sorted, grouped ----
 
 MOE_ROW_TILE = 32  # rows a grouped-matmul tile holds (the kernel's tm)
+# the most one sorted row buffer of moe_infer may hold: a token group's gathered
+# input is ``rows x d_model``, and its gate / up / hidden / output buffers are
+# each that or narrower, all linear in tokens (the training einsum's dispatch,
+# MOE_GROUP_SIZE's, is quadratic)
+MOE_ROW_BUFFER_BYTES = 256 * 2**20
 # what moe_infer counts, in the order of its stats vector: the token-expert
 # pairs by where they went, the held experts that had a token, the rows the
 # grouped products computed, and the (layer x token-group) calls counted
@@ -361,6 +366,15 @@ def _expert_rows(rows, expert_of_row, w_gate, w_up, w_down, group_sizes, layer,
     return out
 
 
+def infer_group_size(rows_per_token: int, d_model: int, itemsize: int) -> int:
+    """Tokens ``moe_infer`` takes through as one group: the largest power of
+    two whose sorted rows (``rows_per_token`` a token, ``d_model`` wide) stay
+    inside ``MOE_ROW_BUFFER_BYTES`` (top-10 of 2048 in bfloat16: 4096 tokens;
+    top-12 of 6144: 1024)."""
+    tokens = MOE_ROW_BUFFER_BYTES // (rows_per_token * d_model * itemsize)
+    return max(MOE_ROW_TILE, 2 ** (tokens.bit_length() - 1))
+
+
 def moe_infer(
     x: jnp.ndarray,              # (B, S, D)
     router_w: jnp.ndarray,       # (D, n_routed + n_zero): the router's FULL width
@@ -372,7 +386,7 @@ def moe_infer(
     first_expert: int = 0,       # the held range is [first_expert, first_expert + held)
     n_zero: int = 0,             # trailing router outputs that are identity experts
     token_mask: jnp.ndarray | None = None,  # (B, S) bool: False rows route nowhere
-    group_size: int = MOE_GROUP_SIZE,
+    group_size: int | None = None,  # tokens a group; None: ``infer_group_size``
     norm_topk: bool = True,
     router_b: jnp.ndarray | None = None,
     b_gate: jnp.ndarray | None = None,
@@ -398,7 +412,10 @@ def moe_infer(
     worst case, and the grouped matmul computes only the row tiles a group
     owns, so the work follows the pairs (padded to ``MOE_ROW_TILE``), not
     the row budget. Tokens go through in groups of ``group_size`` so that a
-    long prefill's row buffers stay bounded."""
+    long prefill's row buffers stay bounded, and every group reads the touched
+    experts' weights once: by default a group is as long as
+    ``MOE_ROW_BUFFER_BYTES`` allows (``infer_group_size``), so that a chunk of
+    a prompt is one group and one read wherever its rows fit."""
     batch, seq, d_model = x.shape
     tokens = batch * seq
     held = w_gate.shape[0 if layer is None else 1]
@@ -407,13 +424,15 @@ def moe_infer(
     mask = (
         jnp.ones((tokens,), bool) if token_mask is None else token_mask.reshape(tokens)
     )
+    per_token = min(k, held)
+    if group_size is None:
+        group_size = infer_group_size(per_token, d_model, x.dtype.itemsize)
     group = min(group_size, tokens)
     n_groups = -(-tokens // group)
     pad = n_groups * group - tokens
     if pad:
         x_flat = jnp.concatenate([x_flat, jnp.zeros((pad, d_model), x.dtype)])
         mask = jnp.concatenate([mask, jnp.zeros((pad,), bool)])
-    per_token = min(k, held)
     rows_m = -(-group * per_token // MOE_ROW_TILE) * MOE_ROW_TILE
 
     def one_group(xg, mg):

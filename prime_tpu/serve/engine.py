@@ -189,6 +189,92 @@ def chunk_plan(start: int, length: int, max_chunk: int, row_capacity: int) -> li
     return plan
 
 
+# what a prompt's chunk and an admission wave are sized from (``prefill_shapes``)
+PREFILL_CHUNK = 512  # the chunk cap the rule starts from, and every dense model's
+SCORE_BUDGET = 2**30  # bytes of fp32 attention scores one chunk-prefill program may hold
+
+
+def row_capacities(max_chunk: int, capacity: int) -> list[int]:
+    """Every staging-row capacity row_capacity_for can produce: powers of two
+    up to ``max_chunk``, then its multiples up to the slot capacity: the
+    bounded row set that keys the chunk-prefill and finalize program shapes."""
+    rows: set[int] = set()
+    r = MIN_BUCKET
+    while r < max_chunk and r <= capacity:
+        rows.add(r)
+        r *= 2
+    if r <= capacity:
+        rows.add(r)  # smallest power of two >= max_chunk
+    m = max_chunk * 2
+    while m <= capacity:
+        rows.add(m)
+        m += max_chunk
+    return sorted(rows)
+
+
+def prefill_shapes(
+    config: Any,
+    capacity: int,
+    *,
+    prefill_chunk: int | None = None,
+    max_wave: int | None = None,
+    dropless_experts: bool = False,
+) -> tuple[int, int]:
+    """(chunk cap, widest admission wave) of an engine, from its shapes: THE
+    rule; the waves ``_admit`` forms, the plans ``chunk_plan`` cuts, the row
+    capacities and the warm-up's program set all read these two numbers.
+
+    **Width first.** A chunk prefill's fp32 score tensor is wave x heads x
+    chunk x row capacity, and past ``SCORE_BUDGET`` (1 GiB) of it a wide
+    model's wave no longer fits beside its weights: the widest wave is the
+    largest power of two whose scores at a ``PREFILL_CHUNK``-token chunk (or
+    the explicit ``prefill_chunk``) over a whole slot stay inside it (64 heads
+    at 512 x 2048: 4 rows; 32 heads: 8). ``max_wave`` asks for narrower waves
+    (a power of two, rounded down): the warm-up builds a chunk prefill and a
+    finalize a row capacity AND width, so a deployment whose programs are slow
+    to build or large to keep starts sooner on fewer widths.
+
+    **Then length**, where nobody gave one. A chunk-prefill program reads
+    every held expert's weights once whatever its length, and a dropless
+    expert layer (ops/moe.py ``moe_infer``) gives an expert ``chunk x
+    experts_per_token / n_experts`` of a prompt's rows to show for the read
+    (512 x 10 / 512 = 10). Under one ``MOE_ROW_TILE`` of rows the grouped
+    product computes a whole row tile for a part of one, so the read and the
+    tile are both paid for rows that are not there. While an expert's rows a
+    chunk do not fill a tile, and the scores of ``max_wave`` rows at twice the
+    length still fit the budget, and a slot is a whole number of chunks of
+    twice the length (rows are multiples of the cap: no prompt that fit is
+    refused one), the chunk doubles. Past a full tile an expert's rows span
+    more tile products (each tile a group overlaps is one) and a longer chunk
+    buys nothing more: on the chip 16 heads at 4096 served the same token
+    time at 2048 and at 4096 (PERF.md, PR 38), at twice the temporaries. So an
+    expert model that spends its budget on width (64 heads at 2048: a wave of
+    4) keeps 512, and one that gives width up gets length for it (16 heads at
+    4096, ``max_wave`` 1: 2048 tokens, up to 40 rows an expert a read). A
+    dense model's rows share its weights whatever the length, and a mesh or
+    quantized experts keep the capacity einsum (``dropless_experts`` False):
+    512.
+
+    An explicit ``prefill_chunk`` stands as given (at least ``MIN_BUCKET``)."""
+    from prime_tpu.ops.moe import MOE_ROW_TILE
+
+    chunk = PREFILL_CHUNK if prefill_chunk is None else max(MIN_BUCKET, prefill_chunk)
+    row_scores = 4 * config.n_heads * chunk * capacity  # bytes a row of the wave
+    wave = max(1, 2 ** ((SCORE_BUDGET // row_scores).bit_length() - 1))
+    if max_wave is not None:
+        if max_wave < 1:
+            raise ValueError(f"max_wave must be at least 1, got {max_wave}")
+        wave = min(wave, 2 ** (max_wave.bit_length() - 1))
+    if prefill_chunk is None and dropless_experts:
+        while (
+            chunk * config.experts_per_token < MOE_ROW_TILE * config.n_experts
+            and wave * 2 * row_scores <= SCORE_BUDGET
+            and capacity % (2 * chunk) == 0
+        ):
+            chunk, row_scores = 2 * chunk, 2 * row_scores
+    return chunk, wave
+
+
 def _parse_inject_spec(raw: str) -> tuple[float, int]:
     """Parse PRIME_SENTINEL_INJECT_MS: ``"MS"`` or ``"MS@AFTER"`` — a
     per-dispatch delay in milliseconds and the dispatch count after which
@@ -401,6 +487,10 @@ class ContinuousBatchingEngine:
     Thread model: callers ``submit()`` from any thread; one background engine
     thread (``start()``) owns all device state and alternates admission
     (prefill) with decode chunks. Tests drive it synchronously with ``tick()``.
+
+    ``prefill_chunk`` (the longest chunk of a prompt's plan) and ``max_wave``
+    (the widest admission wave) default to None: ``prefill_shapes``, the rule
+    from shapes, whose docstring is the one place that says it.
     """
 
     def __init__(
@@ -413,7 +503,7 @@ class ContinuousBatchingEngine:
         max_slots: int = 8,
         capacity: int = 2048,
         chunk: int = 8,
-        prefill_chunk: int = 512,
+        prefill_chunk: int | None = None,
         prefix_cache_mb: float | None = None,
         prefix_cache_host_mb: float | None = None,
         min_prefix: int = MIN_BUCKET,
@@ -667,22 +757,15 @@ class ContinuousBatchingEngine:
         # LRU demotes segments to host buffers instead of freeing them, and a
         # hit on a host-resident node re-uploads through the same one-dispatch
         # assemble path (None reads PRIME_SERVE_PREFIX_CACHE_HOST_MB; 0 = off).
-        self.prefill_chunk = max(MIN_BUCKET, prefill_chunk)
-        # widest batched admission wave: a chunk prefill's fp32 score tensor
-        # is wave x heads x chunk x row capacity, and past 1 GiB of it a wide
-        # model's wave no longer fits beside its weights (64 heads: 4 rows;
-        # 32 heads: 8). A rule from shapes; the warm-up's program set and the
-        # waves _admit forms both read it, so they agree. ``max_wave`` asks for
-        # narrower waves than the shapes allow (a power of two, rounded down):
-        # the warm-up builds a chunk prefill and a finalize a row capacity AND
-        # width, so a deployment whose programs are slow to build or large to
-        # keep starts sooner on fewer widths. None = the rule alone.
-        score_bytes = 4 * config.n_heads * self.prefill_chunk * capacity
-        self.max_wave = max(1, 2 ** ((2**30 // score_bytes).bit_length() - 1))
-        if max_wave is not None:
-            if max_wave < 1:
-                raise ValueError(f"max_wave must be at least 1, got {max_wave}")
-            self.max_wave = min(self.max_wave, 2 ** (max_wave.bit_length() - 1))
+        # the chunk cap and the widest admission wave: one rule from shapes,
+        # prefill_shapes (its docstring says it). None = the rule; an explicit
+        # prefill_chunk stands, an explicit max_wave narrows.
+        from prime_tpu.models.llama import runs_dropless_experts
+
+        self.prefill_chunk, self.max_wave = prefill_shapes(
+            config, capacity, prefill_chunk=prefill_chunk, max_wave=max_wave,
+            dropless_experts=self.mesh is None and runs_dropless_experts(params, config),
+        )
         # the dropless expert layer (ops/moe.py moe_infer) returns its counts
         # with every decode chunk and prefill (zeros on engines whose forward
         # keeps the capacity einsum: a mesh, quantized experts)
@@ -1053,6 +1136,17 @@ class ContinuousBatchingEngine:
             "(the layers that keep K/V or state, at every prompt token) | cross (the "
             "layers only the head reads: at a prompt's last position alone)",
             labelnames=("half",),
+        )
+        self._m_prefill_chunks = r.counter(
+            "serve_prefill_chunks_total",
+            "Chunk-prefill programs dispatched (one a chunk of a wave's plan, whatever its width)",
+        )
+        self._m_prefill_chunk_tokens = r.counter(
+            "serve_prefill_chunk_tokens_total",
+            "Token positions the chunk-prefill programs carried, by kind: prompt "
+            "(a request's own token) | pad (the fill of a last chunk, or of a "
+            "shorter member of the wave)",
+            labelnames=("kind",),
         )
         self._m_ring_wraps = r.counter(
             "serve_ring_wraps_total",
@@ -1578,24 +1672,6 @@ class ContinuousBatchingEngine:
 
     # ---- AOT warmup ----
 
-    def _warmup_row_capacities(self) -> list[int]:
-        """Every staging-row capacity row_capacity_for can produce for this
-        engine: powers of two up to the prefill chunk, then prefill-chunk
-        multiples up to the slot capacity — the bounded row set that keys the
-        chunk-prefill and finalize program shapes."""
-        rows: set[int] = set()
-        r = MIN_BUCKET
-        while r < self.prefill_chunk and r <= self.capacity:
-            rows.add(r)
-            r *= 2
-        if r <= self.capacity:
-            rows.add(r)  # smallest power of two >= prefill_chunk
-        m = self.prefill_chunk * 2
-        while m <= self.capacity:
-            rows.add(m)
-            m += self.prefill_chunk
-        return sorted(rows)
-
     def warmup(self) -> int:
         """Execute the engine's bounded program set once so no cold XLA
         compile ever lands mid-pipeline: the decode chunk (and spec-verify
@@ -1706,7 +1782,7 @@ class ContinuousBatchingEngine:
                     jax.block_until_ready(self._hist_len)
                     dispatches += 1
                 _observe_family("hist_seed")
-            for row_cb in self._warmup_row_capacities():
+            for row_cb in row_capacities(self.prefill_chunk, self.capacity):
                 cold_sizes = {s for _, s in chunk_plan(0, row_cb, self.prefill_chunk, row_cb)}
                 # prefix-hit suffixes admit singly with mid-prompt plans:
                 # every power-of-two chunk size up to min(prefill_chunk, row)
@@ -2511,6 +2587,12 @@ class ContinuousBatchingEngine:
                 self._clock.enqueued(logits)
                 moe_stats = self._sum_moe(moe_stats, stats)
                 real_tokens += sum(min(max(length - off, 0), size) for length in lengths)
+            # what the plan's programs carried: the prompts' tokens, and fill
+            self._m_prefill_chunks.inc(len(plan))
+            self._m_prefill_chunk_tokens.inc(real_tokens, kind="prompt")
+            self._m_prefill_chunk_tokens.inc(
+                n * sum(size for _, size in plan) - real_tokens, kind="pad"
+            )
             # a chunk computes the cross half at one position a row
             # (last_positions), every other layer at each real token
             cross_half = self.config.cross_half_layers

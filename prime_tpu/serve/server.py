@@ -1285,7 +1285,9 @@ def serve_model(
     role-preset layouts (serve/mesh_config.py). ``max_wave`` (None = the
     engine's rule from shapes) asks for narrower admission waves: the warm-up
     builds a program a row capacity and wave width, so fewer widths start
-    sooner — docs/architecture.md "Engine pipeline"."""
+    sooner, and an expert model that gives width up gets longer prompt chunks
+    for it (serve/engine.py ``prefill_shapes``) — docs/architecture.md
+    "Engine pipeline"."""
     from prime_tpu.evals.runner import JaxGenerator
 
     if mesh and slice_name:

@@ -722,21 +722,29 @@ def test_gated_delta_decode_fits_the_chip_with_state_and_row_in_place(topo, monk
     assert not re.findall(r"= f32\[128,32,128,128\]", text)
 
 
-def test_gated_delta_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
-    """A 512-token chunk of a 4096-column staging row, a wave of one (the
-    cell's ``max_wave``): weights, row and temporaries (the
-    chunked delta rule's float32 blocks, the experts' row buffers) under the
-    15 GB less the 1.9 GB the engine's own slots hold."""
+@pytest.mark.parametrize("tokens, temporaries", [(512, 0.6e9), (2048, 1.3e9)])
+def test_gated_delta_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch, tokens, temporaries):
+    """A chunk of a 4096-column staging row, a wave of one (the cell's
+    ``max_wave``), at 512 tokens and at the 2048 the rule from shapes gives
+    this engine (``prefill_shapes``): weights, row and temporaries (the chunked
+    delta rule's float32 blocks, the experts' row buffers, and at 2048 tokens
+    half a gibibyte of scores) under the 15 GB less the 1.9 GB the engine's
+    own slots hold. The longer chunk is one token group: every grouped product
+    appears once, not once a group inside a loop."""
+    from prime_tpu.serve.engine import prefill_shapes
+
     _chip_takes_the_kernels(monkeypatch)
     config, _, chunk_prefill = _qwen3_next_engine_programs()
+    assert prefill_shapes(config, 4096, max_wave=1, dropless_experts=True) == (2048, 1)
     params, row = _abstract_model(config, 1, 4096)
     compiled = _compile(
-        topo, chunk_prefill, params, row, ((1, 512), jnp.int32), ((), jnp.int32),
+        topo, chunk_prefill, params, row, ((1, tokens), jnp.int32), ((), jnp.int32),
         ((1,), jnp.int32), donate=(1,),
     )
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 1.9e9
-    assert memory.temp_size_in_bytes < 0.6e9
+    assert memory.temp_size_in_bytes < temporaries
+    assert f"bf16[{tokens * 10},2048]" in compiled.as_text()  # all of the chunk's rows, sorted once
 
 
 def test_gated_delta_finalize_splices_state_and_row_in_place(topo):

@@ -1627,3 +1627,96 @@ def test_a_batch_of_full_rows_walks_every_step(preset, monkeypatch):
     else:
         assert most == batch * 2 * cache.k.shape[0]
     assert steps([True, False, True, False]) == [most // 2, most]
+
+
+# ---- the chunk cap and the wave width: one rule from shapes (prefill_shapes) ----
+
+# the four benchmark configurations as their cells serve them: (preset, slot
+# capacity, max_wave asked) -> (chunk cap, widest wave, row capacities). The three
+# that keep 512 keep the parent's wave width and its row set, number for number.
+_ROWS_512_2048 = [16, 32, 64, 128, 256, 512, 1024, 1536, 2048]
+_ROWS_512_4096 = _ROWS_512_2048 + [2560, 3072, 3584, 4096]
+RULE_TABLE = {
+    "qwen3-next-ep2 as served": (
+        "qwen3-next-ep2", 4096, 1, 2048, 1, [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]),
+    "qwen3-next-ep2 at two rows a wave": (
+        "qwen3-next-ep2", 4096, 2, 2048, 2, [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]),
+    "qwen3-next-ep2 at the width its shapes allow": (
+        "qwen3-next-ep2", 4096, None, 512, 8, _ROWS_512_4096),
+    "longcat-flash-ep32": ("longcat-flash-ep32", 2048, None, 512, 4, _ROWS_512_2048),
+    "qwen3-4b": ("qwen3-4b", 2048, None, 512, 8, _ROWS_512_2048),
+    "phi4-mini-flash": ("phi4-mini-flash", 4096, None, 512, 2, _ROWS_512_4096),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_TABLE))
+def test_the_chunk_cap_and_the_wave_width_follow_from_shapes(case):
+    """An expert model that gives wave width up gets chunk length for it, until
+    an expert's rows a chunk fill one row tile (2048 x 10 / 512 = 40); one that
+    spends the score budget on width, a dense model, or any model served at the
+    width its shapes allow keeps 512 tokens, the parent's width and its rows."""
+    from prime_tpu.serve.engine import prefill_shapes, row_capacities
+
+    preset, capacity, asked, chunk, wave, rows = RULE_TABLE[case]
+    config = get_config(preset)
+    got = prefill_shapes(config, capacity, max_wave=asked, dropless_experts=config.is_moe)
+    assert got == (chunk, wave)
+    assert row_capacities(chunk, capacity) == rows
+    # the parent's width: the score budget at a 512-token chunk over a whole slot
+    widest = 2 ** ((2**30 // (4 * config.n_heads * 512 * capacity)).bit_length() - 1)
+    assert wave == (widest if asked is None else min(widest, asked))
+    # experts that keep the capacity einsum (a mesh, quantized experts) keep 512
+    assert prefill_shapes(config, capacity, max_wave=asked)[0] == 512
+
+
+def test_the_rule_refuses_no_prompt_that_fit_and_an_explicit_chunk_wins():
+    """Rows are multiples of the cap, so the chunk doubles only while a slot is
+    a whole number of such chunks; ``prefill_chunk=`` stands as given, and sets
+    the wave width as before."""
+    from prime_tpu.serve.engine import prefill_shapes
+
+    config = get_config("qwen3-next-ep2")
+    rule = lambda capacity, **kw: prefill_shapes(  # noqa: E731
+        config, capacity, dropless_experts=True, **kw)
+    assert rule(4096, max_wave=1) == (2048, 1)
+    assert rule(3072, max_wave=1) == (1024, 1)  # 3072 is no whole number of 2048s
+    assert rule(3000, max_wave=1) == (512, 1)
+    assert rule(8192, max_wave=1) == (2048, 1)  # 40 rows an expert: a full tile, stop
+    assert rule(4096, max_wave=1, prefill_chunk=512) == (512, 1)
+    assert rule(4096, prefill_chunk=4096) == (4096, 1)
+    assert rule(4096, prefill_chunk=3) == (16, 256)
+    with pytest.raises(ValueError, match="max_wave"):
+        rule(4096, max_wave=0)
+    # the engine reads the same rule: tiny-test is dense, tiny-moe's experts see
+    # 512 x 2 / 4 rows a chunk, and a slot of 128 holds no longer chunk anyway
+    assert make_engine().prefill_chunk == 512
+    assert make_engine(prefill_chunk=32).prefill_chunk == 32
+    assert make_engine(prefill_chunk=32, capacity=2048).max_wave == 1024  # 4 heads
+
+
+@pytest.mark.parametrize("cap, capacity", [(1024, 4096), (2048, 4096), (4096, 4096), (1024, 3072)])
+def test_chunk_plans_stay_inside_their_row_at_long_caps(cap, capacity):
+    """At caps of 1024, 2048 and the whole slot: every chunk is a power of two
+    no longer than the cap, aligned to its size, divides the row, and the plan
+    covers the prompt without a write past the row, from 0 or from a prefix
+    hit's block-aligned offset."""
+    import numpy as np
+
+    from prime_tpu.serve.engine import chunk_plan, row_capacities, row_capacity_for
+
+    rng = np.random.default_rng(cap + capacity)
+    lengths = {1, 15, 16, 17, cap - 1, cap, cap + 1, capacity - 1, capacity,
+               *rng.integers(1, capacity + 1, size=200).tolist()}
+    for length in sorted(n for n in lengths if n <= capacity):
+        row = row_capacity_for(length, cap, capacity)
+        assert length <= row <= capacity and row in row_capacities(cap, capacity)
+        for start in {0, 16 * int(rng.integers(0, (length - 1) // 16 + 1))}:
+            plan = chunk_plan(start, length, cap, row)
+            at = start
+            for off, size in plan:
+                assert off == at and size & (size - 1) == 0 and size <= cap
+                assert off % size == 0 and row % size == 0 and off + size <= row
+                at += size
+            assert at >= length and (not plan or at - plan[-1][1] < length)
+    with pytest.raises(ValueError, match=f"prefill chunk \\({cap}\\)"):
+        row_capacity_for(capacity + 1, cap, capacity)

@@ -314,6 +314,31 @@ def test_waves_are_capped_by_the_score_tensor(weights):
         assert engine.max_wave == want
 
 
+@pytest.mark.parametrize("max_wave, want", [(None, (512, 4)), (2, (1024, 2)), (1, (2048, 1))])
+def test_a_budget_spent_on_width_leaves_the_chunk_at_512(weights, max_wave, want):
+    """LongCat's shapes (64 heads, slots of 2048, top-12 of 512: 12 rows an
+    expert a 512-token chunk) with no ``prefill_chunk`` given: the score budget
+    is spent on a wave of 4, so the cell's engine keeps the parent's chunk,
+    width and rows. A deployment that asked for narrower waves would get the
+    length the width gave up: the same gibibyte of scores either way."""
+    from prime_tpu.serve.engine import prefill_shapes, row_capacities
+
+    config = get_config("longcat-flash-ep32")
+    got = prefill_shapes(config, 2048, max_wave=max_wave, dropless_experts=True)
+    assert got == want
+    if got[0] == 512:
+        assert row_capacities(got[0], 2048) == [16, 32, 64, 128, 256, 512, 1024, 1536, 2048]
+    assert 4 * 64 * got[0] * 2048 * got[1] == 2**30
+    # the tiny engine of the same block kind runs the dropless layer and asks the
+    # rule so; its 8 experts at top-3 see 192 rows of a 512-token chunk: 512
+    ours, _ = weights
+    engine = ContinuousBatchingEngine(
+        ours, dataclasses.replace(CONFIG, n_heads=64), max_slots=2, capacity=2048,
+        prefix_cache_mb=0, max_wave=max_wave,
+    )
+    assert engine._moe and engine.prefill_chunk == 512 and engine.max_wave == want[1]
+
+
 @pytest.mark.parametrize(
     "preset, max_wave",
     [("tiny-longcat", None), ("tiny-test", None), ("tiny-qwen3next", None), ("tiny-qwen3next", 1)],

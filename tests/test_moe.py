@@ -473,3 +473,59 @@ def test_tiny_deepseek_pipeline_train_step():
     t = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab_size)
     _state, metrics = step(state, t, jnp.roll(t, -1, 1), jnp.ones_like(t, jnp.float32))
     assert np.isfinite(float(metrics["loss"]))
+
+
+# -- the inference layer's token group (moe_infer) ------------------------------
+
+
+def _infer_inputs(tokens, d=32, ff=16, experts=8, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    normal = lambda i, *shape: jax.random.normal(keys[i], shape, jnp.float32)  # noqa: E731
+    return (
+        normal(0, 1, tokens, d), normal(1, d, experts) * 0.5, normal(2, experts, d, ff) * d**-0.5,
+        normal(3, experts, d, ff) * d**-0.5, normal(4, experts, ff, d) * ff**-0.5,
+    )
+
+
+@pytest.mark.parametrize("tokens", [64, 256])
+def test_a_chunk_as_one_token_group_is_the_same_tokens_in_four(tokens):
+    """By default ``moe_infer`` takes a chunk through as ONE group (one read of
+    every touched expert) wherever its row buffers fit; the same tokens in
+    groups of a quarter give the same output and the same summed counts, but
+    four times the calls, and so up to four times the experts touched."""
+    from prime_tpu.ops.moe import MOE_STATS, moe_infer
+
+    x, router, gate, up, down = _infer_inputs(tokens)
+    mask = jnp.arange(tokens)[None, :] < tokens - 5  # a padded tail routes nowhere
+    one, one_stats = moe_infer(x, router, gate[2:6], up[2:6], down[2:6], k=3, first_expert=2,
+                               token_mask=mask)
+    four, four_stats = moe_infer(x, router, gate[2:6], up[2:6], down[2:6], k=3, first_expert=2,
+                                 token_mask=mask, group_size=tokens // 4)
+    assert np.abs(np.asarray(one - four)).max() < 1e-5
+    one_counts = dict(zip(MOE_STATS, np.asarray(one_stats).tolist()))
+    four_counts = dict(zip(MOE_STATS, np.asarray(four_stats).tolist()))
+    for name in ("local", "zero", "elsewhere"):
+        assert one_counts[name] == four_counts[name]
+    assert one_counts["local"] + one_counts["elsewhere"] == (tokens - 5) * 3
+    assert (one_counts["calls"], four_counts["calls"]) == (1, 4)
+    assert one_counts["experts_touched"] == 4 and four_counts["experts_touched"] == 16
+    # a group's rows are padded to whole tiles once, not once a quarter
+    assert one_counts["rows_computed"] <= four_counts["rows_computed"]
+
+
+def test_the_token_group_comes_from_the_row_buffers_bytes():
+    """``moe_infer``'s default group is the largest power of two of tokens whose
+    sorted rows fit ``MOE_ROW_BUFFER_BYTES``: qwen3-next's 2048-token chunk (and
+    a 4096-token one) is one group; LongCat's 2048-token wave of 4 x 512 stays
+    two groups of 1024, as before; the training einsum keeps its own bound."""
+    import inspect
+
+    from prime_tpu.ops.moe import MOE_GROUP_SIZE, MOE_ROW_BUFFER_BYTES, infer_group_size, moe_infer
+
+    qwen3_next, longcat = get_config("qwen3-next-ep2"), get_config("longcat-flash-ep32")
+    assert infer_group_size(qwen3_next.experts_per_token, qwen3_next.d_model, 2) == 4096
+    assert infer_group_size(longcat.experts_per_token, longcat.d_model, 2) == 1024
+    assert 2048 * 10 * 2048 * 2 < MOE_ROW_BUFFER_BYTES < 2048 * 12 * 6144 * 2
+    assert infer_group_size(512, 8192, 4) == 32  # never under a row tile of tokens
+    assert inspect.signature(moe_infer).parameters["group_size"].default is None
+    assert inspect.signature(moe_mlp).parameters["group_size"].default == MOE_GROUP_SIZE == 1024

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from benchmark.reference import qwen3_next as ref
 from prime_tpu.models import delta, get_config
 from prime_tpu.models.llama import CACHE_LEAVES, _mlp_block, forward, init_cache, init_params
-from prime_tpu.serve.engine import ContinuousBatchingEngine, cache_leaves
+from prime_tpu.serve.engine import ContinuousBatchingEngine, cache_leaves, row_capacities
 
 TOL = 2e-4
 CONFIG = get_config("tiny-qwen3next")
@@ -415,6 +415,68 @@ def test_two_requests_of_one_wave_match_each_alone_and_the_reference(weights):
         assert _run(engine, [request])[0] == answer
     values = engine.registry.values()
     assert values["serve_state_resets_total"] == values["serve_requests_admitted_total"] == 4
+
+
+def _slot_leaves(engine, slot, length):
+    """What slot ``slot`` holds of a request of ``length`` positions: its K/V
+    columns, and its state and tail whole."""
+    out = {}
+    for name, (leaf, spec) in cache_leaves(engine._cache).items():
+        part = np.take(np.asarray(leaf), slot, axis=spec.slot_axis)
+        if spec.capacity_axis is not None:
+            axis = spec.capacity_axis - (spec.capacity_axis > spec.slot_axis)
+            part = np.take(part, np.arange(length), axis=axis)
+        out[name] = part
+    return out
+
+
+def _chunk_counts(engine):
+    tokens = {s["labels"]["kind"]: s["value"]
+              for s in engine.registry.snapshot()["serve_prefill_chunk_tokens_total"]["series"]}
+    return engine.registry.values()["serve_prefill_chunks_total"], tokens["prompt"], tokens["pad"]
+
+
+def test_one_chunk_a_prompt_is_the_split_prompt_and_the_counters_add_up(weights):
+    """A 150-token prompt as ONE chunk of 256 (the cap of a whole slot) against
+    three chunks of 64: the same first tokens and answer, the same K/V columns,
+    matrix state and conv tail in the slot within the chunk-invariance
+    tolerance, and ``serve_prefill_chunk_tokens_total`` adds up to the prompt
+    and the plan's fill."""
+    ours, theirs = weights
+    prompt = _tokens(60, 150)
+    served = {}
+    for cap in (256, 64):
+        engine = ContinuousBatchingEngine(
+            ours, CONFIG, max_slots=2, capacity=256, chunk=4, prefill_chunk=cap, prefix_cache_mb=0,
+        )
+        request = engine.submit(prompt, max_new_tokens=9, temperature=0.0)
+        answer = _run(engine, [request])[0]
+        # 150 prompt positions and the 8 answer tokens whose columns were written
+        served[cap] = (answer, _slot_leaves(engine, request.slot, 150 + 8), _chunk_counts(engine))
+    (whole, whole_leaves, whole_counts), (split, split_leaves, split_counts) = served[256], served[64]
+    assert whole == split and _gap(theirs, prompt, whole) < TOL
+    assert sorted(whole_leaves) == ["conv_tail", "delta_state", "k", "v"]
+    for name, leaf in whole_leaves.items():
+        assert np.abs(leaf).max() > 0.01, name
+        assert np.abs(leaf - split_leaves[name]).max() < TOL * max(1.0, np.abs(leaf).max()), name
+    assert whole_counts == (1, 150, 256 - 150)
+    assert split_counts == (3, 150, 3 * 64 - 150)
+
+
+@pytest.mark.parametrize("max_wave, chunk", [(1, 1024), (None, 512)])
+def test_an_engine_that_gives_width_up_gets_length(weights, max_wave, chunk):
+    """The rule in a live engine: 64 published experts at top-3 give a held
+    expert 24 rows of a 512-token chunk, under one row tile, so a prompt a wave
+    (``max_wave`` 1) is cut at 1024 (48 rows); at the width the shapes allow
+    (128 rows a wave here) the score budget is spent, and the chunk stays 512."""
+    ours, _ = weights
+    config = dataclasses.replace(CONFIG, n_experts=64)
+    params = {**ours, "layers": {**ours["layers"], "router": jnp.zeros((8, 64, 64), jnp.float32)}}
+    engine = ContinuousBatchingEngine(
+        params, config, max_slots=2, capacity=1024, chunk=4, prefix_cache_mb=0, max_wave=max_wave,
+    )
+    assert (engine.prefill_chunk, engine.max_wave) == (chunk, 128 if max_wave is None else 1)
+    assert row_capacities(engine.prefill_chunk, 1024) == [16, 32, 64, 128, 256, 512, 1024]
 
 
 def test_a_prompt_too_long_for_its_slot_is_served_and_scored_on_its_tail(weights):
