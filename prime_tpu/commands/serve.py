@@ -122,7 +122,13 @@ def _echo_device_memory(when: str) -> None:
     "--prefix-cache-mb", type=float, default=None,
     help="Byte budget (MiB) of the radix prefix-KV cache: shared prompt "
          "blocks are cached once and reused across admissions; 0 disables "
-         "(--continuous). Default: 256 (PRIME_SERVE_PREFIX_CACHE_MB).",
+         "(--continuous). Default: 256 (PRIME_SERVE_PREFIX_CACHE_MB). A model "
+         "whose slots hold recurrent state has snapshots of it kept beside the "
+         "K/V, at a stride from its shapes, inside the same budget: such a model "
+         "gets no default and asks by size (a snapshot is megabytes), without a "
+         "host tier. Refused: a model whose slots hold a ring (a window layer's "
+         "last columns); a stride at or over the slots' length (no prompt would "
+         "reach a snapshot); --speculative over any state that is not K/V columns.",
 )
 @click.option(
     "--prefix-cache-host-mb", type=float, default=None,
@@ -130,7 +136,8 @@ def _echo_device_memory(when: str) -> None:
          "(--continuous): the device LRU demotes cold KV segments to pinned "
          "host buffers instead of freeing them, and a later hit re-uploads "
          "through the same one-dispatch assemble path; 0 disables. "
-         "Default: 0 (PRIME_SERVE_PREFIX_CACHE_HOST_MB).",
+         "Default: 0 (PRIME_SERVE_PREFIX_CACHE_HOST_MB). Refused for a model "
+         "whose prefix cache keeps snapshots of state.",
 )
 @click.option(
     "--adapter-max-inflight", type=int, default=None,

@@ -166,6 +166,16 @@ class ModelConfig:
     # full attention's query projection is twice as wide: a head's second half
     # is a gate, and the attention output is multiplied by its sigmoid
     attn_output_gate: bool = False
+    # the delta rule's beta is 2 sigmoid(b), so a state's transition
+    # I - beta k k^T has eigenvalues in (-1, 1] (Olmo-Hybrid linear_allow_neg_eigval)
+    linear_neg_eigval: bool = False
+    # the delta rule's input projections are separate matrices side by side
+    # ([all q | all k | all v | all z], [all b | all a]) and not laid out a key
+    # head (models/delta.py ``grouped``)
+    linear_grouped_proj: bool = False
+    # False: the attention layers turn neither queries nor keys (a model whose
+    # position comes through its recurrent layers; Olmo-Hybrid rope_theta null)
+    rotary: bool = True
     # --- state-space (Mamba-1) mixer of the "sambay" block ---
     ssm_d_inner: int | None = None   # None: 2 * d_model
     ssm_d_state: int = 16
@@ -336,16 +346,24 @@ class ModelConfig:
                 + self.linear_conv * self.linear_conv_channels + 2 * self.linear_value_heads
                 + self.linear_value_dim + value * d
             )
+            query = self.n_heads * self.head_dim
             full = (
-                d * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
-                + self.n_heads * self.head_dim * d + 2 * self.head_dim
+                d * ((2 if self.attn_output_gate else 1) * query + 2 * self.n_kv_heads * self.head_dim)
+                + query * d
             )
-            experts = (
-                self.experts_held * 3 * d * ff + d * self.n_experts
-                + self.n_shared_experts * 3 * d * ff + d
-            )
+            if self.qk_norm:
+                full += 2 * self.head_dim
+            if self.qk_norm_full:
+                full += query + self.n_kv_heads * self.head_dim
+            if self.is_moe:
+                feed = (
+                    self.experts_held * 3 * d * ff + d * self.n_experts
+                    + self.n_shared_experts * 3 * d * ff + d
+                )
+            else:
+                feed = 3 * d * ff
             return (
-                embed + head + d + self.n_layers * (experts + 2 * d)
+                embed + head + d + self.n_layers * (feed + norms)
                 + self.linear_layers * delta + self.cache_rows * full
             )
         if self.block == "shortcut_moe":
@@ -1139,6 +1157,66 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         experts_per_token=3,
         norm_topk=True,
         n_shared_experts=1,
+    ),
+    # Olmo-Hybrid-7B, the first stage of a 2-stage pipeline (allenai/
+    # Olmo-Hybrid-7B config.json): published widths, 16 of the 32 layers (four
+    # whole periods of three gated-delta-rule layers and one full attention),
+    # each followed by a dense feed-forward, OLMo-2's post-norm residual path
+    # and whole-width q/k norm, one value head a key head at (96, 192), beta =
+    # 2 sigmoid(b), no rotary embedding (rope_theta null). This chip keeps the
+    # final norm and the head so that logits can be compared.
+    # benchmark/configs/olmo-hybrid-7b-pp2.json states the cut and what is assumed.
+    "olmo-hybrid-7b-pp2": ModelConfig(
+        name="olmo-hybrid-7b-pp2",
+        vocab_size=100352,
+        d_model=3840,
+        n_layers=16,
+        n_heads=30,
+        n_kv_heads=30,
+        d_ff=11008,
+        max_seq_len=65536,
+        rms_eps=1e-6,
+        pre_norms=False,
+        post_norms=True,
+        qk_norm_full=True,
+        rotary=False,
+        block="gated_delta",
+        full_attention_interval=4,
+        linear_key_heads=30,
+        linear_value_heads=30,
+        linear_key_dim=96,
+        linear_value_dim=192,
+        linear_conv=4,
+        linear_neg_eigval=True,
+        linear_grouped_proj=True,
+    ),
+    # the same block at test scale: two periods, 3 heads of (12, 24), so that a
+    # transposed or padded state tile fails; full-attention heads of 32, so that
+    # a snapshot of the state costs what 34 tokens of bfloat16 K/V cost and the
+    # stride rule gives 64 (serve/engine.py snapshot_stride; 32 in float32)
+    "tiny-olmohybrid": ModelConfig(
+        name="tiny-olmohybrid",
+        vocab_size=512,
+        d_model=96,
+        n_layers=8,
+        n_heads=3,
+        n_kv_heads=3,
+        d_ff=192,
+        max_seq_len=512,
+        rms_eps=1e-6,
+        pre_norms=False,
+        post_norms=True,
+        qk_norm_full=True,
+        rotary=False,
+        block="gated_delta",
+        full_attention_interval=4,
+        linear_key_heads=3,
+        linear_value_heads=3,
+        linear_key_dim=12,
+        linear_value_dim=24,
+        linear_conv=4,
+        linear_neg_eigval=True,
+        linear_grouped_proj=True,
     ),
     # GPT-OSS architecture at test scale: sinks + biased clamped-GLU MoE +
     # alternating window + non-truncated yarn, all exercised on CPU

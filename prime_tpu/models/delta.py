@@ -6,8 +6,15 @@ value heads of ``d_v`` (``n_v / n_k`` value heads a key head):
 
     [q; k; v; z] = W_qkvz h      laid out a KEY head: q d_k, k d_k, then its value
     [b; a]       = W_ba h        heads' v and z (d_v each); b and a one a value head
+           (``grouped``: all heads' q, then all k, all v, all z, and all b, then all a:
+           separate projections side by side, the order the conv takes. A
+           projection a head whose widths are no multiples of a lane tile (96,
+           96, 192, 192) is then split at four places and not thirty: split a
+           head, XLA re-lays the whole matrix out at every decode call)
     [q; k; v]    = silu(causal depthwise conv1d([q; k; v], taps))      no bias
     beta = sigmoid(b)            g = -exp(A_log) softplus(a + dt_bias)   float32
+           (2 sigmoid(b) under ``neg_eigval``: the transition I - beta k k^T then
+           has eigenvalues in (-1, 1], Olmo-Hybrid's linear_allow_neg_eigval)
     q, k: repeated to the value heads (key head j serves 2j, 2j + 1), each
           x / sqrt(sum x^2 + 1e-6) over d_k; q times d_k^-1/2
 
@@ -180,6 +187,8 @@ def delta_mixer(
     in_place: tuple | None = None,   # (stack row, work list): ``state`` is the STACKED
     #                                  (R, B, n_v, d_k, d_v) leaf, S is 1, and the kernel
     #                                  steps that row of it where it lies
+    neg_eigval: bool = False,        # beta = 2 sigmoid(b)
+    grouped: bool = False,           # W_qkvz is [all q | all k | all v | all z], W_ba [b | a]
 ):
     """Returns (out (B, S, d), state, tail); with ``in_place`` the state
     returned is the stacked leaf."""
@@ -190,11 +199,18 @@ def delta_mixer(
     d_k = (channels - n_v * d_v) // (2 * key_heads)
     key_width = key_heads * d_k
 
-    qkvz = _mm(h, lp["w_qkvz"]).reshape(batch, seq, key_heads, 2 * d_k + 2 * per * d_v)
-    q, k, v, z = jnp.split(qkvz, [d_k, 2 * d_k, 2 * d_k + per * d_v], axis=-1)
-    ba = _mm(h, lp["w_ba"]).reshape(batch, seq, key_heads, 2 * per).astype(jnp.float32)
-    b, a = (t.reshape(batch, seq, n_v) for t in (ba[..., :per], ba[..., per:]))
-    mixed = jnp.concatenate([t.reshape(batch, seq, -1) for t in (q, k, v)], axis=-1)
+    if grouped:
+        # the conv's channel order is the projection's own: nothing is regrouped
+        qkvz = _mm(h, lp["w_qkvz"])
+        mixed, z = qkvz[..., :channels], qkvz[..., channels:]
+        ba = _mm(h, lp["w_ba"]).astype(jnp.float32)
+        b, a = ba[..., :n_v], ba[..., n_v:]
+    else:
+        qkvz = _mm(h, lp["w_qkvz"]).reshape(batch, seq, key_heads, 2 * d_k + 2 * per * d_v)
+        q, k, v, z = jnp.split(qkvz, [d_k, 2 * d_k, 2 * d_k + per * d_v], axis=-1)
+        ba = _mm(h, lp["w_ba"]).reshape(batch, seq, key_heads, 2 * per).astype(jnp.float32)
+        b, a = (t.reshape(batch, seq, n_v) for t in (ba[..., :per], ba[..., per:]))
+        mixed = jnp.concatenate([t.reshape(batch, seq, -1) for t in (q, k, v)], axis=-1)
 
     if tail is None:
         tail = jnp.zeros((batch, taps - 1, channels), dtype=mixed.dtype)
@@ -216,6 +232,8 @@ def delta_mixer(
     k = unit(heads(mixed[..., key_width : 2 * key_width], key_heads))
     v = heads(mixed[..., 2 * key_width :], n_v)
     beta = jax.nn.sigmoid(b)
+    if neg_eigval:
+        beta = 2.0 * beta
     g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(a + lp["dt_bias"])
     if live is not None:
         beta = jnp.where(live[..., None], beta, 0.0)

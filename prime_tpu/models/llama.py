@@ -490,16 +490,19 @@ _DELTA_WHOLE = ("delta", "attn", "w_gate", "w_up", "w_down")
 
 
 def _init_delta_params(rng: jax.Array, config: ModelConfig, dtype) -> Params:
-    """Random init of the gated_delta block (Qwen3-Next). A matrix is
-    ``normal * fan_in**-0.5`` rounded to ``dtype`` (the router float32), the
+    """Random init of the gated_delta block (Qwen3-Next, Olmo-Hybrid). A matrix
+    is ``normal * fan_in**-0.5`` rounded to ``dtype`` (the router float32), the
     shared expert's gate vector and the conv taps the same with their fan-in;
-    ``(1 + w)`` norm weights zero, the gated norm's weight one. ``A_log`` is
-    uniform in [log 1e-3, log 1e-1] and ``dt_bias`` uniform in [-1, 1] (both
-    float32, a value head): a state then keeps a token for some 10 to 1000
-    positions, where the published initialisation (A uniform in (0, 16),
-    dt_bias 1) forgets it within one under random inputs. One jitted draw a
-    leaf, stacked over its layers: (n_layers, ...) for what every layer has,
-    (linear layers, ...) under ``delta`` and (periods, ...) under ``attn``."""
+    a norm's weight is what scales by one (zero under ``norm_plus_one``), the
+    gated norm's weight one. ``A_log`` is uniform in [log 1e-3, log 1e-1] and
+    ``dt_bias`` uniform in [-1, 1] (both float32, a value head): a state then
+    keeps a token for some 10 to 1000 positions, where the published
+    initialisation (A uniform in (0, 16), dt_bias 1) forgets it within one
+    under random inputs. One jitted draw a leaf, stacked over its layers:
+    (n_layers, ...) for what every layer has, (linear layers, ...) under
+    ``delta`` and (periods, ...) under ``attn``. Which norms a layer has, whether
+    its feed-forward is the expert layer or dense, and what the full attention
+    norms and gates follow the configuration's flags."""
     keys = jax.random.split(rng, 24)
     d, ff, hd = config.d_model, config.d_ff, config.head_dim
     layers, linear, periods = config.n_layers, config.linear_layers, config.cache_rows
@@ -518,39 +521,55 @@ def _init_delta_params(rng: jax.Array, config: ModelConfig, dtype) -> Params:
         u = jax.random.uniform(keys[DELTA_KEY_OF[name]], (linear, n_v), dtype=jnp.float32)
         return low + u * (high - low)
 
-    stack = {
-        "attn_norm": jnp.zeros((layers, d), dtype=dtype),
-        "mlp_norm": jnp.zeros((layers, d), dtype=dtype),
-        "router": draw("router", (layers, d, config.n_experts), d, out=jnp.float32),
-        "w_gate": draw("w_gate", (layers, config.experts_held, d, ff), d),
-        "w_up": draw("w_up", (layers, config.experts_held, d, ff), d),
-        "w_down": draw("w_down", (layers, config.experts_held, ff, d), ff),
-        "w_shared_gate": draw("w_shared_gate", (layers, d, shared), d),
-        "w_shared_up": draw("w_shared_up", (layers, d, shared), d),
-        "w_shared_down": draw("w_shared_down", (layers, shared, d), shared),
-        "shared_expert_gate": draw("shared_expert_gate", (layers, d), d),
-        "delta": {
-            "w_qkvz": draw("delta.w_qkvz", (linear, d, channels + value), d),
-            "w_ba": draw("delta.w_ba", (linear, d, 2 * n_v), d),
-            "conv_w": draw("delta.conv_w", (linear, taps, channels), taps),
-            "A_log": uniform("delta.A_log", math.log(1e-3), math.log(1e-1)),
-            "dt_bias": uniform("delta.dt_bias", -1.0, 1.0),
-            "gate_norm": jnp.ones((linear, config.linear_value_dim), dtype=dtype),
-            "w_out": draw("delta.w_out", (linear, value, d), value),
-        },
-        "attn": {
-            "wq": draw("attn.wq", (periods, d, 2 * h * hd), d),
-            "wk": draw("attn.wk", (periods, d, kh * hd), d),
-            "wv": draw("attn.wv", (periods, d, kh * hd), d),
-            "wo": draw("attn.wo", (periods, h * hd, d), h * hd),
-            "q_norm": jnp.zeros((periods, hd), dtype=dtype),
-            "k_norm": jnp.zeros((periods, hd), dtype=dtype),
-        },
+    def unit_norm(*shape):  # a norm weight that scales by one
+        return (jnp.zeros if config.norm_plus_one else jnp.ones)(shape, dtype=dtype)
+
+    stack = {}
+    if config.pre_norms:
+        stack.update(attn_norm=unit_norm(layers, d), mlp_norm=unit_norm(layers, d))
+    if config.post_norms:
+        stack.update(attn_post_norm=unit_norm(layers, d), mlp_post_norm=unit_norm(layers, d))
+    if config.is_moe:
+        stack.update(
+            router=draw("router", (layers, d, config.n_experts), d, out=jnp.float32),
+            w_gate=draw("w_gate", (layers, config.experts_held, d, ff), d),
+            w_up=draw("w_up", (layers, config.experts_held, d, ff), d),
+            w_down=draw("w_down", (layers, config.experts_held, ff, d), ff),
+            w_shared_gate=draw("w_shared_gate", (layers, d, shared), d),
+            w_shared_up=draw("w_shared_up", (layers, d, shared), d),
+            w_shared_down=draw("w_shared_down", (layers, shared, d), shared),
+            shared_expert_gate=draw("shared_expert_gate", (layers, d), d),
+        )
+    else:
+        stack.update(
+            w_gate=draw("w_gate", (layers, d, ff), d),
+            w_up=draw("w_up", (layers, d, ff), d),
+            w_down=draw("w_down", (layers, ff, d), ff),
+        )
+    stack["delta"] = {
+        "w_qkvz": draw("delta.w_qkvz", (linear, d, channels + value), d),
+        "w_ba": draw("delta.w_ba", (linear, d, 2 * n_v), d),
+        "conv_w": draw("delta.conv_w", (linear, taps, channels), taps),
+        "A_log": uniform("delta.A_log", math.log(1e-3), math.log(1e-1)),
+        "dt_bias": uniform("delta.dt_bias", -1.0, 1.0),
+        "gate_norm": jnp.ones((linear, config.linear_value_dim), dtype=dtype),
+        "w_out": draw("delta.w_out", (linear, value, d), value),
     }
+    attn = {
+        "wq": draw("attn.wq", (periods, d, (2 if config.attn_output_gate else 1) * h * hd), d),
+        "wk": draw("attn.wk", (periods, d, kh * hd), d),
+        "wv": draw("attn.wv", (periods, d, kh * hd), d),
+        "wo": draw("attn.wo", (periods, h * hd, d), h * hd),
+    }
+    if config.qk_norm:
+        attn.update(q_norm=unit_norm(periods, hd), k_norm=unit_norm(periods, hd))
+    if config.qk_norm_full:
+        attn.update(q_norm_full=unit_norm(periods, h * hd), k_norm_full=unit_norm(periods, kh * hd))
+    stack["attn"] = attn
     return {
         "embed": draw("embed", (config.vocab_size, d), d),
         "layers": stack,
-        "final_norm": jnp.zeros((d,), dtype=dtype),
+        "final_norm": unit_norm(d),
         "lm_head": draw("lm_head", (d, config.vocab_size), d),
     }
 
@@ -640,8 +659,9 @@ def _attention_block(
     if "q_norm" in lp:  # Qwen3/Gemma3-style per-head RMSNorm before rope
         q = _norm(q, lp["q_norm"], config)
         k = _norm(k, lp["k_norm"], config)
-    q = apply_rope_rows(q, cos_rows, sin_rows)
-    k = apply_rope_rows(k, cos_rows, sin_rows)
+    if config.rotary:
+        q = apply_rope_rows(q, cos_rows, sin_rows)
+        k = apply_rope_rows(k, cos_rows, sin_rows)
 
     q = q.transpose(0, 2, 1, 3)  # (B, H, S, hd)
     k = k.transpose(0, 2, 1, 3)
@@ -875,11 +895,15 @@ def _delta_period(
     token_mask: jnp.ndarray | None,
     state_work: tuple | None = None,  # decode under the kernel's gate: its work list
 ):
-    """One period of the gated_delta block (Qwen3-Next): ``interval - 1``
-    linear-attention layers (models/delta.py) and one gated full attention,
-    every layer ``h = x + mixer(norm(x)); out = h + moe(norm(h))`` with the
-    dropless expert layer over the experts held here and the gated shared
-    expert. Returns (out, leaves, expert counts).
+    """One period of the gated_delta block (Qwen3-Next, Olmo-Hybrid):
+    ``interval - 1`` linear-attention layers (models/delta.py) and one full
+    attention, every layer ``h = x + post(mixer(pre(x))); out = h +
+    post(feed(pre(h)))`` with the norms the stack holds (``attn_norm`` /
+    ``mlp_norm`` before a sub-layer, ``attn_post_norm`` / ``mlp_post_norm`` on
+    its output; a stack has either or both) and, for the feed-forward, the
+    dropless expert layer over the experts held here with the gated shared
+    expert, or a dense one. Returns (out, leaves, expert counts; 0 where no
+    expert layer ran).
 
     As in ``_shortcut_block`` a layer's matrices are indexed out of the stack
     here, one slice a matmul, and the experts go to the grouped matmul as the
@@ -892,10 +916,11 @@ def _delta_period(
 
     interval = config.full_attention_interval
     kv, carried = leaves[:2], leaves[2:]
+    whole = _DELTA_WHOLE if config.is_moe else ("delta", "attn")
     stats = 0
     for i in range(interval):
         layer = period * interval + i
-        lp = {name: w[layer] for name, w in stack.items() if name not in _DELTA_WHOLE}
+        lp = {name: w[layer] for name, w in stack.items() if name not in whole}
         if i < interval - 1:
             row = period * (interval - 1) + i
             mixer = {name: w[row] for name, w in stack["delta"].items()}
@@ -908,24 +933,32 @@ def _delta_period(
                     (states[row], None) if state_work is None else (states, (row, state_work))
                 )
             out, state, tail = delta_mixer(
-                _norm(x, lp["attn_norm"], config), mixer, state, tail, token_mask,
+                _norm(x, lp["attn_norm"], config) if "attn_norm" in lp else x,
+                mixer, state, tail, token_mask,
                 key_heads=config.linear_key_heads, in_place=in_place,
+                neg_eigval=config.linear_neg_eigval, grouped=config.linear_grouped_proj,
             )
             if carried:
                 put = jax.lax.dynamic_update_index_in_dim
                 carried = (
                     put(tails, tail, row, 0), state if in_place else put(states, state, row, 0)
                 )
+            if "attn_post_norm" in lp:
+                out = _norm(out, lp["attn_post_norm"], config)
             x = x + out
         else:
             full = {name: w[period] for name, w in stack["attn"].items()}
-            with jax.named_scope("gated_attention"):
-                x, kv = attend(x, {**full, "attn_norm": lp["attn_norm"]}, period, kv)
-        experts = {name: stack[name] for name in ("w_gate", "w_up", "w_down")}
-        x, _, layer_stats = _mlp_block(
-            x, {**lp, **experts}, config, dropless=True, token_mask=token_mask, layer=layer
-        )
-        stats = stats + layer_stats
+            around = {name: lp[name] for name in ("attn_norm", "attn_post_norm") if name in lp}
+            with jax.named_scope("gated_attention" if config.attn_output_gate else "full_attention"):
+                x, kv = attend(x, {**full, **around}, period, kv)
+        if config.is_moe:
+            experts = {name: stack[name] for name in ("w_gate", "w_up", "w_down")}
+            x, _, layer_stats = _mlp_block(
+                x, {**lp, **experts}, config, dropless=True, token_mask=token_mask, layer=layer
+            )
+            stats = stats + layer_stats
+        else:
+            x, _, _ = _mlp_block(x, lp, config)
     return x, (*kv, *carried), stats
 
 
@@ -1148,7 +1181,7 @@ def forward(
 
     elif config.block == "gated_delta":
         _refuse_for_delta(mesh, adapters, prefill_offset)
-        dropless = True
+        dropless = config.is_moe  # a dense feed-forward counts nothing
         if decode and cache is not None:
             from prime_tpu.ops.attention import delta_decode_work
 
@@ -1164,7 +1197,7 @@ def forward(
             x, kv, period_stats = _delta_period(
                 x, params["layers"], scanned[0], kv, attend, config, token_mask, state_work
             )
-            return (x, aux_sum, kv, stats + period_stats), None
+            return (x, aux_sum, kv, stats if stats is None else stats + period_stats), None
 
     elif config.block == "standard":
         dropless = cache is not None and runs_dropless_experts(params, config)

@@ -326,11 +326,13 @@ def delta_decode_work(
     make with it and the most they could make. (None, zeros) where the step is
     plain XLA: the gate is the decode attention's own (``_decode_uses_kernel``
     over the block's K cache, so the column write, the attention and the state
-    take one path) and, on a chip, a state block that fills whole tiles."""
+    take one path) and, on a chip, a state block whose keys fill whole
+    sublane tiles (the kernel sums over them; the values' lanes may end inside
+    a tile, which Mosaic pads in VMEM)."""
     from prime_tpu.ops.pallas_delta import delta_step_heads, delta_work_list
 
     layers, batch, heads, d_k, d_v = leaf.shape
-    tiled = _pallas_interpret() or (d_k % 8 == 0 and d_v % 128 == 0)
+    tiled = _pallas_interpret() or d_k % 8 == 0
     if not (tiled and _decode_uses_kernel(impl, k_cache)):
         return None, jnp.zeros((2,), jnp.int32)
     work = delta_work_list(live)

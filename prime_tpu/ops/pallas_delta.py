@@ -29,7 +29,10 @@ as COLUMNS (a value a sublane, broadcast along the lanes). The caller's
 (64 KB a row; the state is 2 MB), and a head's column is a static lane of the
 block. ``v``, ``beta`` and ``exp(g)`` ride as (1, d_v) rows of one
 (B, head blocks, 3, heads, d_v) operand. Every block's last two dimensions are
-its array's own, so any number of heads a step lowers.
+its array's own, so any number of heads a step lowers, and so does a state
+tile that is no multiple of (8, 128): Mosaic pads (96, 192) to whole tiles in
+VMEM, the slots hold the leaf's own bytes (olmo-hybrid-7b-pp2; the sums run
+over d_k's sublanes, which padding lanes do not enter).
 """
 
 from __future__ import annotations
@@ -50,9 +53,12 @@ def delta_step_heads(heads: int, d_k: int, d_v: int) -> int:
     inside the decode kernels' VMEM budget. A step costs 0.2-0.35 us beside
     what its block costs, so fewer and larger steps win until the blocks no
     longer fit: 32 heads of (128, 128) float32 are 2 MB a block, 8 MB held,
-    one step a row."""
+    one step a row. A head's (d_k, d_v) is counted as VMEM holds it, in whole
+    (8, 128) tiles: 30 heads of (96, 192) are 2.2 MB in the slots and 2.9 MB
+    in VMEM (192 lanes take two tiles' 256), so 15 a step, two steps a row."""
+    tile = -(-d_k // 8) * 8 * -(-d_v // 128) * 128 * 4
     for block in range(heads, 0, -1):
-        if heads % block == 0 and 4 * block * d_k * d_v * 4 <= DECODE_VMEM_BUDGET:
+        if heads % block == 0 and 4 * block * tile <= DECODE_VMEM_BUDGET:
             return block
     return 1
 

@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import math
 import queue
 import threading
 import time
@@ -273,6 +274,51 @@ def prefill_shapes(
         ):
             chunk, row_scores = 2 * chunk, 2 * row_scores
     return chunk, wave
+
+
+def snapshot_stride(cache: Any, page: int = MIN_BUCKET) -> int:
+    """The stride in tokens at which a prefix cache keeps snapshots of the
+    state a slot of ``cache`` holds, from its shapes: the smallest power-of-two
+    multiple of the prefix cache's page that is at least a snapshot's bytes
+    over the K/V bytes a token. So a snapshot never costs more than the K/V
+    of the tokens it rides with, and the cache's budget splits about evenly at
+    worst. 0 where the slots hold no state. Olmo-Hybrid's 16 layers: 27.4 MB of
+    state a slot over 61.4 KB of K/V a token is 446, so 512. Qwen3-Next's one
+    period: 6.4 MB over 2 KB is 3144, so 4096, which is its slots' whole length:
+    no prompt that fits reaches a boundary, and the engine refuses that model a
+    prefix cache by name (PERF.md section 7)."""
+    state, token = slot_bytes(cache)
+    if not state:
+        return 0
+    stride = page
+    while stride * token < state:
+        stride *= 2
+    return stride
+
+
+def slot_bytes(cache: Any) -> tuple[int, int]:
+    """(bytes of recurrent state a slot of ``cache`` holds, which is what one
+    snapshot in the prefix cache takes; bytes of K/V a token), from the leaves'
+    shapes."""
+    held = cache_leaves(cache)
+
+    def nbytes(leaf, *axes):  # of one slot (and one column) of a leaf
+        return leaf.size * leaf.dtype.itemsize // math.prod(leaf.shape[a] for a in axes)
+
+    state = sum(nbytes(leaf, spec.slot_axis) for leaf, spec in held.values() if spec.kind == "state")
+    token = sum(
+        nbytes(leaf, spec.slot_axis, spec.capacity_axis)
+        for leaf, spec in held.values() if spec.capacity_axis is not None
+    )
+    return state, token
+
+
+def _snapshot_leaves(cache: Any) -> dict[str, Any]:
+    """The leaves of kind "state" that ``cache`` holds, by field name: what a
+    snapshot is made of."""
+    return {
+        name: leaf for name, (leaf, spec) in cache_leaves(cache).items() if spec.kind == "state"
+    }
 
 
 def _parse_inject_spec(raw: str) -> tuple[float, int]:
@@ -779,18 +825,28 @@ class ContinuousBatchingEngine:
         self._ring_columns = next(
             (leaf.shape[-1] for leaf, spec in held.values() if spec.kind == "ring"), 0
         )
-        if whole:
-            # the one refusal by block kind: a stored K/V segment is useless
-            # without the state at its end, and a verify window that is
-            # rejected would have to rewind it (ROADMAP R7: the state snapshot)
-            if prefix_cache_mb or self.speculative:
-                raise ValueError(
-                    f"a {config.block!r} model's slots hold state that is not K/V columns "
-                    f"({', '.join(whole)}): the prefix cache stores K/V segments without it "
-                    "and speculative decoding cannot rewind it. Serve with "
-                    "prefix_cache_mb=0 (--prefix-cache-mb 0) and without --speculative"
-                )
-            prefix_cache_mb = 0.0  # the default budget does not apply either
+        rings = sorted(n for n, (_, spec) in held.items() if spec.kind == "ring")
+        if whole and self.speculative:
+            # a verify window that is rejected would have to rewind what has no
+            # columns to drop (ROADMAP R7: speculation beside state)
+            raise ValueError(
+                f"a {config.block!r} model's slots hold what is not K/V columns "
+                f"({', '.join(whole)}): speculative decoding cannot rewind it. "
+                "Serve without --speculative"
+            )
+        if rings and prefix_cache_mb:
+            # a ring holds the last `window` columns wherever a prompt ended: a
+            # stored K/V segment says nothing of it, and the prefix cache keeps
+            # snapshots of recurrent state only (ROADMAP R7: snapshots for a ring)
+            raise ValueError(
+                f"a {config.block!r} model's slots hold a ring ({', '.join(rings)}): the "
+                "prefix cache stores K/V segments and snapshots of recurrent state, and "
+                "no ring. Serve with prefix_cache_mb=0 (--prefix-cache-mb 0)"
+            )
+        if whole and prefix_cache_mb is None:
+            # the default budget is sized for K/V segments; a snapshot of state is
+            # tens of megabytes, so such a model's prefix cache is asked for by size
+            prefix_cache_mb = 0.0
         if prefix_cache_mb is None:
             prefix_cache_mb = env_float(
                 "PRIME_SERVE_PREFIX_CACHE_MB", DEFAULT_PREFIX_CACHE_MB
@@ -810,6 +866,32 @@ class ContinuousBatchingEngine:
         # request's GET /admin/kv always finds its path whether admission
         # batched it or not. serve_model flips this on for --role prefill.
         self.prefix_store_all = bool(prefix_store_all)
+        # where the slots hold recurrent state, the prefix cache keeps snapshots
+        # of it at this stride (snapshot_stride: a rule from shapes). The state
+        # is in the row at a chunk's end, so with such a cache on, no chunk is
+        # longer than the stride: every boundary is then the end of a chunk of
+        # real tokens (chunks are powers of two aligned to their own size), and
+        # the chunked delta rule need not hand states out from inside a chunk.
+        # Olmo-Hybrid's stride is its chunk cap (512 = 512), so its plan does not
+        # change for the cache
+        self.state_stride = snapshot_stride(self._cache) if self._state_leaves else 0
+        if self.state_stride and self.prefix_cache_mb > 0:
+            if self.state_stride >= self.capacity:
+                # no prompt that fits a slot reaches the first boundary: the cache
+                # would store every prompt's K/V and never start from any of it
+                raise ValueError(
+                    f"a {config.block!r} model's prefix cache keeps a snapshot of its state "
+                    f"every {self.state_stride} tokens (a snapshot's bytes over the K/V bytes "
+                    f"a token), and its slots hold {self.capacity}: no prompt reaches a "
+                    "snapshot, so nothing stored could be started from. Serve with "
+                    "prefix_cache_mb=0 (--prefix-cache-mb 0) (ROADMAP.md R7)"
+                )
+            self.prefill_chunk = min(self.prefill_chunk, self.state_stride)
+        if self._state_leaves and self.prefix_cache_mb > 0 and self.prefix_cache_host_mb > 0:
+            raise ValueError(
+                "snapshots of state have no host tier: serve a model whose slots hold "
+                "recurrent state with prefix_cache_host_mb=0 (ROADMAP.md R7)"
+            )
         self._host_tier_gated = False
         if self.prefix_cache_host_mb > 0 and mesh is not None and getattr(mesh, "size", 1) > 1:
             # the spill tier's converters are not sharding-preserving:
@@ -834,6 +916,7 @@ class ContinuousBatchingEngine:
                 int(self.prefix_cache_mb * 2**20), block=MIN_BUCKET,
                 host_budget_bytes=int(self.prefix_cache_host_mb * 2**20),
                 to_host=_segment_to_host, to_device=_segment_to_device,
+                state_stride=self.state_stride,
             )
             if self.prefix_cache_mb > 0
             else None
@@ -854,6 +937,8 @@ class ContinuousBatchingEngine:
             bool(paged_prefix) and self.prefix_cache is not None and mesh is None
         )
         self._kv_pool = None  # lazy: leaf specs known at first stored segment
+        self._snapshot_fn: Any = None
+        self._restore_fn: Any = None
         # observability: registry-backed counters + latency histograms
         # (surfaced by stats(), the server's /metrics JSON, and the
         # Prometheus exposition at /metrics?format=prometheus). One Registry
@@ -916,6 +1001,31 @@ class ContinuousBatchingEngine:
         )
         self._m_prefix_reupload_bytes = r.counter(
             "serve_prefix_reupload_bytes_total", "Bytes re-uploaded from the host spill tier"
+        )
+        # snapshots of recurrent state on the radix tree's nodes (a model whose
+        # slots hold state; all zero for a K/V-only one)
+        self._m_state_snapshots = r.counter(
+            "serve_prefix_state_snapshots_total",
+            "Snapshots of a slot's recurrent state in the prefix cache, by event: "
+            "stored | restored (copied into an admission's row) | evicted (with their node)",
+            labelnames=("event",),
+        )
+        self._m_prefix_state_bytes = r.gauge(
+            "serve_prefix_state_bytes",
+            "Device bytes of state snapshots in the prefix cache (a part of serve_prefix_cache_bytes)",
+        )
+        self._m_prefix_match_tokens = r.counter(
+            "serve_prefix_match_tokens_total",
+            "Tokens of K/V the radix walk matched at an admission, by kind: used (seeded; "
+            "under a snapshot where the slots hold state) | beyond_snapshot (matched past "
+            "the deepest snapshot, or on a path with none: prefilled again)",
+            labelnames=("kind",),
+        )
+        self._m_state_starts = r.counter(
+            "serve_state_starts_total",
+            "Admissions into slots that hold recurrent state, by where the state "
+            "started from: zero | snapshot",
+            labelnames=("from",),
         )
         self._m_prefix_assembles = r.counter(
             "serve_prefix_assembles_total",
@@ -1021,6 +1131,7 @@ class ContinuousBatchingEngine:
         # last-seen cache counter values: the cache owns the monotonic truth,
         # _sync_prefix_metrics publishes deltas into the registry counters
         self._prefix_seen = {
+            "snapshots_stored": 0, "snapshots_evicted": 0,
             "spills": 0, "spilled_bytes": 0, "reuploads": 0,
             "reupload_bytes": 0, "evictions": 0,
         }
@@ -1846,12 +1957,25 @@ class ContinuousBatchingEngine:
                             quantized=self.kv_quant,
                         )
                         assembled = self._assemble_fn(
-                            (_capacity_leaves(donor),), (seg_len,), row_cb
+                            (_capacity_leaves(donor),), (seg_len,), row_cb,
+                            _snapshot_leaves(donor) or None,
                         )
                         jax.block_until_ready(assembled.k)
                         dispatches += 1
                         seg_len *= 2
                     _observe_family("assemble")
+            if self.prefix_cache is not None and self.state_stride:
+                # a snapshot out of a row of each wave width, and one restored
+                snapshot, restore = self._state_programs()
+                for n in batch_sizes:
+                    leaves = _snapshot_leaves(
+                        init_cache(self.config, n, MIN_BUCKET, dtype=self._dtype)
+                    )
+                    taken = snapshot(leaves, jnp.asarray(0, dtype=jnp.int32))
+                    dispatches += 1
+                jax.block_until_ready(restore(taken))
+                dispatches += 1
+                _observe_family("state_snapshot")
         if self.speculative:
             # the hist-seed warmups scribbled slot rings (lengths 1, pad
             # rows); restore exact cold history state so a warmed engine is
@@ -2464,9 +2588,14 @@ class ContinuousBatchingEngine:
                     self._fail_admission([req], e)
                     continue
                 key = self._prefix_key(ids, req.adapter_idx)
-                if self._prefix_match_len(key) > 0:
+                usable, matched = self._prefix_match_lens(key)
+                if usable > 0:
                     hits.append((req, row_cb, key))
                 else:
+                    if matched >= self.min_prefix:
+                        # K/V on a path with no snapshot is a miss: all of it is
+                        # prefilled again, and counted as the stride's price
+                        self._m_prefix_match_tokens.inc(matched, kind="beyond_snapshot")
                     plan = tuple(chunk_plan(0, len(ids), self.prefill_chunk, row_cb))
                     groups.setdefault((row_cb, plan), []).append(req)
             for req, row_cb, key in hits:
@@ -2556,6 +2685,11 @@ class ContinuousBatchingEngine:
         adapter_ids = _upload([r.adapter_idx for r in reqs], np.int32)
         logits = None
         moe_stats = None
+        # the members whose rows go to the prefix cache: member 0 by default,
+        # every member on a prefix_store_all (prefill-role) engine, none
+        # without a prefix cache
+        stored = 0 if self.prefix_cache is None else n if self.prefix_store_all else 1
+        snapshots: list[dict[int, Any]] = [{} for _ in range(stored if self.state_stride else 0)]
         with self._clock.span(
             "serve.prefill", "prefill", batch=n, row_capacity=row_cb,
             **self._span_mesh,
@@ -2587,6 +2721,12 @@ class ContinuousBatchingEngine:
                 self._clock.enqueued(logits)
                 moe_stats = self._sum_moe(moe_stats, stats)
                 real_tokens += sum(min(max(length - off, 0), size) for length in lengths)
+                end = off + size
+                if snapshots and end % self.state_stride == 0:
+                    # a stride boundary is this chunk's end: the row holds the
+                    # state after its last token, for the members whose prompt
+                    # reaches it (a shorter one's state stopped at its own end)
+                    self._take_snapshots(row, end, lengths, snapshots)
             # what the plan's programs carried: the prompts' tokens, and fill
             self._m_prefill_chunks.inc(len(plan))
             self._m_prefill_chunk_tokens.inc(real_tokens, kind="prompt")
@@ -2631,18 +2771,16 @@ class ContinuousBatchingEngine:
             # first spec chunk can draft from the prompt immediately
             with self._mesh_ctx():
                 self._seed_hist(reqs, lengths, slots, firsts)
-        # member 0 only by default, EVERY member on a prefix_store_all
-        # (prefill-role) engine, whose exports must cover batched admissions;
-        # none without a prefix cache. A wave of one stores its row as it is:
-        # taking a member out of a wider row is a copy and a program a shape
-        stored = 0 if self.prefix_cache is None else n if self.prefix_store_all else 1
+        # A wave of one stores its row as it is: taking a member out of a
+        # wider row is a copy and a program a shape
         for i in range(stored):
             row_i = row if n == 1 else jax.tree_util.tree_map(
                 lambda x, i=i: x[:, i : i + 1] if x.ndim >= 2 else x[i : i + 1],
                 row,
             )
             self._store_prefix(
-                self._prefix_key(reqs[i].prompt_ids, reqs[i].adapter_idx), row_i
+                self._prefix_key(reqs[i].prompt_ids, reqs[i].adapter_idx), row_i,
+                snapshots[i] if snapshots else None,
             )
         with self._clock.enter("prefill_sync"):
             # host sync: the prefill really finished here
@@ -2668,6 +2806,8 @@ class ContinuousBatchingEngine:
         self._m_admit_batch.observe(n)
         self._m_admitted.inc(n)
         self._m_state_resets.inc(state_resets)  # beside the admissions it is read against
+        if self._state_leaves:
+            self._m_state_starts.inc(n, **{"from": "snapshot" if hit_tokens else "zero"})
         if n > 1:
             self._m_batched_waves.inc()
         with self._clock.enter("emit"):
@@ -2676,6 +2816,48 @@ class ContinuousBatchingEngine:
                 self._active[slot] = True
                 self._requests[slot] = req
                 self._emit(req, [first])
+
+    def _state_programs(self):
+        """(snapshot, restore), built on first use: a wave member's state leaves
+        taken out of a staging row, and a stored snapshot copied for a new row.
+        Both give new buffers: the row is donated to the next chunk's program,
+        and a snapshot on the radix tree must outlive the row it seeds. A
+        program a wave width (the leaves do not grow with the row's capacity),
+        one for the restore."""
+        if self._snapshot_fn is not None:
+            return self._snapshot_fn, self._restore_fn
+        import jax
+        import jax.numpy as jnp
+
+        from prime_tpu.models.llama import CACHE_LEAVES
+
+        def snapshot(leaves, member):
+            with jax.named_scope("serve.state_snapshot"):
+                return {
+                    name: jax.lax.dynamic_slice_in_dim(
+                        leaf, member, 1, axis=CACHE_LEAVES[name].slot_axis
+                    )
+                    for name, leaf in leaves.items()
+                }
+
+        def restore(leaves):
+            with jax.named_scope("serve.state_restore"):
+                return {name: jnp.copy(leaf) for name, leaf in leaves.items()}
+
+        self._snapshot_fn, self._restore_fn = jax.jit(snapshot), jax.jit(restore)
+        return self._snapshot_fn, self._restore_fn
+
+    def _take_snapshots(self, row, position: int, lengths: list[int], snapshots: list) -> None:
+        """Copy the state leaves of the stored members whose prompt reaches
+        ``position`` out of ``row``. The copy is the loop's own work between two
+        chunks: its seconds are ``prefix_store``'s, and the device trace shows it
+        under ``serve.state_snapshot``."""
+        snapshot, _ = self._state_programs()
+        with self._clock.span("serve.state_snapshot", "prefix_store", position=position):
+            leaves = _snapshot_leaves(row)
+            for member, taken in enumerate(snapshots):
+                if position <= lengths[member]:
+                    taken[position] = snapshot(leaves, _upload(member, np.int32))
 
     def _make_finalize_batch(self):
         import jax
@@ -2757,13 +2939,14 @@ class ContinuousBatchingEngine:
             return None
         return match
 
-    def _prefix_match_len(self, ids: list[int]) -> int:
-        """Routing peek for _admit: usable cached-prefix length without
-        pinning or LRU touches (the seeded path re-matches and pins)."""
+    def _prefix_match_lens(self, ids: list[int]) -> tuple[int, int]:
+        """Routing peek for _admit: (usable cached-prefix length, K/V tokens the
+        walk matched: more than the first only past a cache's deepest snapshot)
+        without pinning or LRU touches (the seeded path re-matches and pins)."""
         if self.prefix_cache is None:
-            return 0
-        length = self.prefix_cache.match_len(ids, limit=len(ids) - 1)
-        return length if length >= self.min_prefix else 0
+            return 0, 0
+        length, matched = self.prefix_cache.match_lens(ids, limit=len(ids) - 1)
+        return (length if length >= self.min_prefix else 0), matched
 
     def _make_assemble_row(self):
         """One jitted program per (segment-shape tuple, takes, target
@@ -2778,8 +2961,10 @@ class ContinuousBatchingEngine:
         config, dtype, quantized = self.config, self._dtype, self.kv_quant
         row_constraint = self._row_constraint()
 
-        def assemble(segments, takes, target_cb):
+        def assemble(segments, takes, target_cb, state=None):
             row = init_cache(config, 1, target_cb, dtype=dtype, quantized=quantized)
+            if state is not None:  # the snapshot the match ends at: new buffers, as all of the row
+                row = row._replace(**state)
             out = _capacity_leaves(row)
             off = 0
             for seg, take in zip(segments, takes):
@@ -2796,6 +2981,13 @@ class ContinuousBatchingEngine:
             return _constrain(row._replace(**out), row_constraint)
 
         return jax.jit(assemble, static_argnums=(1, 2))
+
+    def _restored_state(self, match) -> dict[str, Any] | None:
+        """A copy of the snapshot a match ends at, for the admission's row (None
+        for a model whose slots hold no state)."""
+        if match.state is None:
+            return None
+        return self._state_programs()[1](match.state)
 
     def _prefix_seed(self, ids: list[int], row_cb: int, ctx: TraceContext | None = None):
         """Seed an admission's staging row: on a hit, ONE assemble_row
@@ -2832,7 +3024,7 @@ class ContinuousBatchingEngine:
             ) as prof_step:
                 t_seed = self._clock.switched_at  # the span's start
                 if table is not None:
-                    row = self._paged_seed_row(table, row_cb)
+                    row = self._paged_seed_row(table, row_cb, self._restored_state(match))
                 else:
                     if self._assemble_fn is None:
                         self._assemble_fn = self._make_assemble_row()
@@ -2845,7 +3037,7 @@ class ContinuousBatchingEngine:
                         seg.materialize() if hasattr(seg, "materialize") else seg
                         for seg in match.segments()
                     ]
-                    row = self._assemble_fn(segments, match.takes(), row_cb)
+                    row = self._assemble_fn(segments, match.takes(), row_cb, match.state)
                 prof_step.fence(row.k)
         finally:
             self.prefix_cache.release(match)
@@ -2860,6 +3052,10 @@ class ContinuousBatchingEngine:
             self._m_prefix_hit_tokens.observe(match.device_tokens, tier="device")
         if host_tokens:
             self._m_prefix_hit_tokens.observe(host_tokens, tier="host")
+        self._m_prefix_match_tokens.inc(match.length, kind="used")
+        if match.state is not None:
+            self._m_prefix_match_tokens.inc(match.matched - match.length, kind="beyond_snapshot")
+            self._m_state_snapshots.inc(event="restored")
         self._sync_prefix_metrics()
         return match.length, row
 
@@ -2875,10 +3071,17 @@ class ContinuousBatchingEngine:
             # the pool shares the device-tier byte budget: every page the
             # pool holds is a byte the radix accounting already charges
             # (PagedSegment.nbytes == the loose form's bytes), so the LRU
-            # keeps bounding the SUM of pooled and loose segments
-            self._kv_pool = PagedKVPool(
-                int(self.prefix_cache_mb * 2**20), page_tokens=MIN_BUCKET
-            )
+            # keeps bounding the SUM of pooled and loose segments. Where the
+            # cache keeps snapshots of state too, the pool is allocated for
+            # K/V's share of the budget at one snapshot a stride (what the
+            # stride rule bounds them to): budget x stride K/V / (stride K/V +
+            # snapshot), 53% for Olmo-Hybrid; the snapshots take the rest as
+            # they come. The budget then bounds what the device really holds
+            budget = int(self.prefix_cache_mb * 2**20)
+            if self.state_stride:
+                state, token = slot_bytes(self._cache)
+                budget = budget * self.state_stride * token // (self.state_stride * token + state)
+            self._kv_pool = PagedKVPool(budget, page_tokens=MIN_BUCKET)
         return self._kv_pool
 
     def _paged_seed_table(self, match, row_cb: int):
@@ -2905,7 +3108,7 @@ class ContinuousBatchingEngine:
         table[: len(pages)] = pages
         return table
 
-    def _paged_seed_row(self, table, row_cb: int):
+    def _paged_seed_row(self, table, row_cb: int, state: dict[str, Any] | None = None):
         """Seed a staging row by gathering pool pages in place: one
         paged-gather dispatch per leaf, zeros past the table's sentinels —
         element-for-element the row assemble_row would build (the bit-identity
@@ -2921,6 +3124,7 @@ class ContinuousBatchingEngine:
             k=out["k"], v=out["v"],
             lengths=jnp.zeros((1,), dtype=jnp.int32),
             k_scale=out.get("k_scale"), v_scale=out.get("v_scale"),
+            **(state or {}),
         )
 
     def _row_slicer(self, row):
@@ -2956,7 +3160,7 @@ class ContinuousBatchingEngine:
 
         return paged_slicer
 
-    def _store_prefix(self, ids: list[int], row) -> None:
+    def _store_prefix(self, ids: list[int], row, snapshots: dict[int, Any] | None = None) -> None:
         """Split the finalized staging row into block segments and insert
         them along the radix path: blocks already cached are deduplicated
         (shared bytes stored once), only the divergent tail allocates, and
@@ -2973,7 +3177,7 @@ class ContinuousBatchingEngine:
         spilled_bytes_before = cache.spilled_bytes
         spill_s_before = cache.spill_seconds
         with self._clock.enter("prefix_store"):
-            cache.insert(list(ids[:aligned]), self._row_slicer(row))
+            cache.insert(list(ids[:aligned]), self._row_slicer(row), snapshots)
         if cache.spills > spills_before:
             # spills force a device sync (device_get) on the store path —
             # leave trace evidence so the profiler's tier table can price
@@ -2995,18 +3199,21 @@ class ContinuousBatchingEngine:
         cache = self.prefix_cache
         if cache is None:
             return
-        for counter, attr in (
-            (self._m_prefix_spills, "spills"),
-            (self._m_prefix_spilled_bytes, "spilled_bytes"),
-            (self._m_prefix_reuploads, "reuploads"),
-            (self._m_prefix_reupload_bytes, "reupload_bytes"),
-            (self._m_prefix_evictions, "evictions"),
+        for counter, attr, labels in (
+            (self._m_prefix_spills, "spills", {}),
+            (self._m_prefix_spilled_bytes, "spilled_bytes", {}),
+            (self._m_prefix_reuploads, "reuploads", {}),
+            (self._m_prefix_reupload_bytes, "reupload_bytes", {}),
+            (self._m_prefix_evictions, "evictions", {}),
+            (self._m_state_snapshots, "snapshots_stored", {"event": "stored"}),
+            (self._m_state_snapshots, "snapshots_evicted", {"event": "evicted"}),
         ):
             current = getattr(cache, attr)
             delta = current - self._prefix_seen[attr]
             if delta > 0:
-                counter.inc(delta)
+                counter.inc(delta, **labels)
                 self._prefix_seen[attr] = current
+        self._m_prefix_state_bytes.set(cache.state_bytes)
         self._m_prefix_bytes.set(cache.bytes)
         self._m_prefix_host_bytes.set(cache.host_bytes)
         self._m_prefix_nodes.set(cache.nodes)
@@ -3054,6 +3261,7 @@ class ContinuousBatchingEngine:
         one-shot path. Returns None when nothing usable is cached."""
         if self.prefix_cache is None or len(ids) < self.min_prefix:
             return None
+        self.prefix_cache.refuse_wire("export")  # a cache that keeps state, by name
         if self._thread is None or self._thread is threading.current_thread():
             return self._kv_execute("export", list(ids))
         match = self._kv_call("pin", list(ids), timeout)
@@ -3088,6 +3296,7 @@ class ContinuousBatchingEngine:
         is touched). Returns the KV bytes planted after dedup."""
         if self.prefix_cache is None:
             raise ValueError("prefix cache disabled; nothing to import into")
+        self.prefix_cache.refuse_wire("import")
         from prime_tpu.serve.prefix_cache import decode_wire_payload
 
         tokens, leaves = decode_wire_payload(payload, self.prefix_cache.block)

@@ -43,6 +43,24 @@ cached bytes. This module replaces the storage side with a radix tree over
   enqueued. ``promote`` on a pinned match flips its host entries to device in
   place — the radix/refcount/split invariants are tier-agnostic.
 
+- **Snapshots of state ride the path.** A model whose slots hold recurrent
+  state beside K/V (a matrix state and a conv tail a linear-attention layer)
+  cannot start a prompt from stored K/V alone: the state at the segment's end
+  is part of what was computed. A cache built with ``state_stride > 0`` keeps,
+  on a node, *snapshots*: every state leaf of one slot as it stood after a
+  given token of the node's edge (``_Node.states``, keyed by the offset inside
+  the edge, so a snapshot belongs to exactly the token path that ends at it,
+  and a split hands each half the snapshots that lie on it). ``insert`` takes
+  them by absolute position; ``match`` cuts its walk back to the deepest
+  snapshot at or under the matched length and hands it over (``PrefixMatch.
+  state``): K/V matched beyond it is not used, and a path with K/V and no
+  snapshot is a miss. Snapshots are counted in the same byte budget
+  (``bytes`` holds them, ``state_bytes`` says how much of it they are) and go
+  with their node when it is evicted. They have no host tier and no wire
+  format: such a cache is built without a host budget and refuses export and
+  import by name. With ``state_stride`` 0 none of this runs: a K/V-only
+  model's tree and counters are what they were.
+
 The tree is engine-thread-owned (like all engine device state): pin/release
 make the eviction invariant explicit, not the structure thread-safe. The
 module is deliberately jax-light — segments are opaque pytrees; only byte
@@ -210,7 +228,7 @@ class _Node:
 
     __slots__ = (
         "tokens", "segment", "children", "parent", "refs", "last_used",
-        "nbytes", "tier",
+        "nbytes", "tier", "states", "state_nbytes",
     )
 
     def __init__(self, tokens: tuple[int, ...], segment: Any, parent: "_Node | None") -> None:
@@ -222,6 +240,10 @@ class _Node:
         self.last_used = 0
         self.nbytes = segment_nbytes(segment) if segment is not None else 0
         self.tier = TIER_DEVICE
+        # snapshots of a slot's state leaves after token ``offset`` of this edge
+        # (1 .. len(tokens), a multiple of the block); their bytes are in nbytes
+        self.states: dict[int, Any] = {}
+        self.state_nbytes = 0
 
 
 @dataclass
@@ -244,6 +266,10 @@ class PrefixMatch:
     length: int
     entries: list[tuple[_Node, int]] = field(default_factory=list)
     host_tokens: int = 0
+    # a cache that keeps snapshots of state: the snapshot at ``length`` (the
+    # walk was cut back to it) and the K/V tokens the walk matched before the cut
+    state: Any = None
+    matched: int = 0
     segments_snapshot: list = field(default_factory=list)
     tokens_snapshot: list = field(default_factory=list)
     extra_pins: list = field(default_factory=list)
@@ -285,6 +311,9 @@ class BlockPrefixCache:
     ``to_device`` convert segments across tiers (default: identity, which
     keeps the unit tests jax-free — the engine injects ``jax.device_get``
     and a ``jnp.asarray`` tree-map).
+    ``state_stride > 0`` makes it a cache for a model whose slots hold
+    recurrent state: the stride in tokens at which its engine takes snapshots
+    (module docstring); a match then needs one.
     """
 
     def __init__(
@@ -295,10 +324,23 @@ class BlockPrefixCache:
         host_budget_bytes: int = 0,
         to_host: Callable[[Any], Any] | None = None,
         to_device: Callable[[Any], Any] | None = None,
+        state_stride: int = 0,
     ) -> None:
         if block <= 0:
             raise ValueError(f"block must be positive, got {block}")
+        if state_stride % block:
+            raise ValueError(f"state_stride {state_stride} is not a multiple of block {block}")
+        if state_stride and host_budget_bytes > 0:
+            raise ValueError(
+                "a prefix cache that keeps snapshots of state has no host tier: "
+                "host_budget_bytes must be 0 (ROADMAP.md R7)"
+            )
         self.block = block
+        self.state_stride = int(state_stride)
+        self.state_bytes = 0  # the snapshots' part of ``bytes``
+        self.snapshots = 0  # snapshots held
+        self.snapshots_stored = 0  # snapshots taken in (monotonic)
+        self.snapshots_evicted = 0  # snapshots that went with an evicted node (monotonic)
         self.budget_bytes = int(budget_bytes)
         self.host_budget_bytes = int(host_budget_bytes)
         self._to_host = to_host if to_host is not None else (lambda seg: seg)
@@ -348,12 +390,34 @@ class BlockPrefixCache:
             node = child
         return entries
 
-    def match_len(self, ids, limit: int | None = None) -> int:
-        """Longest usable cached prefix length (block-aligned), without
-        pinning — the engine's admission router calls this to decide which
-        requests take the seeded path."""
+    @staticmethod
+    def _cut_to_state(entries: list[tuple[_Node, int]]):
+        """``entries`` cut back to the deepest snapshot on them, and that
+        snapshot: ([], None) where the walk passed none."""
+        for i in range(len(entries) - 1, -1, -1):
+            node, take = entries[i]
+            at = max((offset for offset in node.states if offset <= take), default=0)
+            if at:
+                return entries[:i] + [(node, at)], node.states[at]
+        return [], None
+
+    def match_lens(self, ids, limit: int | None = None) -> tuple[int, int]:
+        """(longest usable cached prefix length, tokens of K/V the walk matched),
+        block-aligned, in one walk and without pinning. Where the cache keeps
+        state the first is the deepest snapshot's position at or under the
+        second; in a K/V-only cache they are equal."""
         limit = len(ids) - 1 if limit is None else limit
-        return sum(take for _, take in self._walk(ids, limit))
+        entries = self._walk(ids, limit)
+        matched = sum(take for _, take in entries)
+        if self.state_stride:
+            entries, _ = self._cut_to_state(entries)
+            return sum(take for _, take in entries), matched
+        return matched, matched
+
+    def match_len(self, ids, limit: int | None = None) -> int:
+        """Longest usable cached prefix length: the engine's admission router
+        calls this to decide which requests take the seeded path."""
+        return self.match_lens(ids, limit)[0]
 
     def match(self, ids, limit: int | None = None) -> PrefixMatch | None:
         """Longest cached prefix of ``ids`` capped at ``limit`` tokens
@@ -362,6 +426,9 @@ class BlockPrefixCache:
         refreshes its LRU stamp; returns None on no usable blocks."""
         limit = len(ids) - 1 if limit is None else limit
         entries = self._walk(ids, limit)
+        matched, state = sum(take for _, take in entries), None
+        if self.state_stride:
+            entries, state = self._cut_to_state(entries)
         if not entries:
             return None
         stamp = next(self._clock)
@@ -373,7 +440,7 @@ class BlockPrefixCache:
                 host_tokens += take
         match = PrefixMatch(
             length=sum(t for _, t in entries), entries=entries,
-            host_tokens=host_tokens,
+            host_tokens=host_tokens, state=state, matched=matched,
             # pin-time snapshot: segments() and tokens() read these, so a
             # concurrent insert's _split of a pinned node cannot change what
             # this match assembles/serializes
@@ -431,14 +498,18 @@ class BlockPrefixCache:
 
     # ---- insert ----
 
-    def insert(self, ids, slicer: Callable[[int, int], Any]) -> int:
+    def insert(self, ids, slicer: Callable[[int, int], Any],
+               states: dict[int, Any] | None = None) -> int:
         """Store the KV for ``ids`` (length MUST be a multiple of the block —
         the engine aligns down so no padded/garbage slot is ever cached)
         along the trie path. ``slicer(start, stop)`` returns the segment
         pytree for slots [start, stop) of the finalized staging row; it is
         only called for the genuinely new tail, so shared blocks cost
         nothing (a host-resident shared block stays on the host — the walk
-        just refreshes its stamp). Returns the bytes added."""
+        just refreshes its stamp). ``states`` maps a position (a multiple of
+        the block, at most ``len(ids)``) to the snapshot of the state after that
+        token; a position that already has one keeps it. Returns the bytes
+        added."""
         block = self.block
         total = len(ids)
         if total == 0:
@@ -473,7 +544,40 @@ class BlockPrefixCache:
             child.last_used = stamp
             pos += m
             node = child
+        if states:
+            added += self._place_states(ids, states)
         self.evict_to_budget()
+        return added
+
+    def _place_states(self, ids: tuple[int, ...], states: dict[int, Any]) -> int:
+        """Hang each snapshot on the node whose edge holds its position, at its
+        offset inside that edge. The path is there: ``insert`` has just walked
+        it, and an edge that ran past ``ids`` was split at their end."""
+        block, added = self.block, 0
+        node, start = self._root, 0  # ``node``'s own edge ends at ``start``
+        for position in sorted(states):
+            if position % block or not 0 < position <= len(ids):
+                raise ValueError(f"snapshot position {position} is not on the path's blocks")
+            child = None
+            while start < position:
+                child = node.children.get(ids[start : start + block])
+                if child is None:
+                    return added  # evicted under the insert (a budget under one path)
+                if position <= start + len(child.tokens):
+                    break
+                node, start = child, start + len(child.tokens)
+            offset = position - start
+            if child is None or offset in child.states:
+                continue
+            nbytes = segment_nbytes(states[position])
+            child.states[offset] = states[position]
+            child.state_nbytes += nbytes
+            child.nbytes += nbytes
+            self.bytes += nbytes
+            self.state_bytes += nbytes
+            self.snapshots += 1
+            self.snapshots_stored += 1
+            added += nbytes
         return added
 
     def _split(self, node: _Node, m: int) -> None:
@@ -512,6 +616,11 @@ class BlockPrefixCache:
             lower_seg = self._cut(node.segment, m, len(node.tokens), copy=copy)
         lower = _Node(node.tokens[m:], lower_seg, node)
         lower.tier = node.tier
+        # a snapshot stays on the token path that ends at it: the half its
+        # offset lies in keeps it (an offset of exactly m is the upper half's end)
+        lower.states = {offset - m: s for offset, s in node.states.items() if offset > m}
+        lower.state_nbytes = sum(segment_nbytes(s) for s in lower.states.values())
+        lower.nbytes += lower.state_nbytes
         if node.refs:
             # transfer pins: each live match pin on this node — whether it
             # pinned it directly (entries) or inherited it from an EARLIER
@@ -531,7 +640,9 @@ class BlockPrefixCache:
         lower.last_used = node.last_used
         if upper_seg is None:
             upper_seg = self._cut(node.segment, 0, m, copy=copy)
-        delta = lower.nbytes + segment_nbytes(upper_seg) - node.nbytes
+        upper_state_nbytes = node.state_nbytes - lower.state_nbytes
+        upper_nbytes = segment_nbytes(upper_seg) + upper_state_nbytes
+        delta = lower.nbytes + upper_nbytes - node.nbytes
         if node.tier == TIER_HOST:
             self.host_bytes += delta
             self.host_nodes += 1
@@ -539,7 +650,9 @@ class BlockPrefixCache:
             self.bytes += delta
         self.nodes += 1
         node.segment = upper_seg
-        node.nbytes = segment_nbytes(upper_seg)
+        node.nbytes = upper_nbytes
+        node.states = {offset: s for offset, s in node.states.items() if offset <= m}
+        node.state_nbytes = upper_state_nbytes
         node.tokens = node.tokens[:m]
         node.children = {lower.tokens[: self.block]: lower}
 
@@ -605,6 +718,17 @@ class BlockPrefixCache:
         finally:
             self.release(match)
 
+    def refuse_wire(self, what: str) -> None:
+        """KV wire version 1 carries K/V segments. A cache whose paths are only
+        usable with the snapshots on them refuses the format by name, in both
+        directions: a K/V-only payload would plant paths no request can start
+        from, or ship a prefix without the state at its end."""
+        if self.state_stride:
+            raise ValueError(
+                f"KV wire version {KV_WIRE_VERSION} carries K/V segments and no state: a prefix "
+                f"cache that keeps snapshots of state does not {what} it (ROADMAP.md R7)"
+            )
+
     def serialize_match(self, match: PrefixMatch) -> bytes:
         """Serialize a PINNED match into the wire payload. Thread-free by
         construction: every read goes through the match's pin-time
@@ -617,6 +741,7 @@ class BlockPrefixCache:
         after (both on the tree-owning thread)."""
         import numpy as np
 
+        self.refuse_wire("export")
         tokens: list[int] = []
         manifests: list[dict] = []
         blobs: list[bytes] = []
@@ -687,6 +812,7 @@ class BlockPrefixCache:
         axis = the token path) along the radix path. Each new-tail slice
         passes through ``to_device`` — a no-op for already-device arrays, an
         upload for host arrays — so only genuinely new bytes ever move."""
+        self.refuse_wire("import")
 
         def slicer(start: int, stop: int):
             seg = {name: leaf[..., start:stop] for name, leaf in leaves.items()}
@@ -821,6 +947,9 @@ class BlockPrefixCache:
         self.nodes -= 1
         self.evicted_bytes += node.nbytes
         self.evictions += 1
+        self.state_bytes -= node.state_nbytes
+        self.snapshots -= len(node.states)
+        self.snapshots_evicted += len(node.states)
 
     def _evict_host_subtrees(self) -> int:
         """Last resort for host-budget pressure: delete whole LRU
@@ -868,3 +997,5 @@ class BlockPrefixCache:
         self.host_bytes = 0
         self.nodes = 0
         self.host_nodes = 0
+        self.state_bytes = 0
+        self.snapshots = 0

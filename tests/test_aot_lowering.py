@@ -200,14 +200,19 @@ def test_write_columns_lowers(topo, variant):
     assert compiled.memory_analysis().temp_size_in_bytes < B * kv_heads * max(dims) * C
 
 
-def test_delta_decode_step_lowers(topo):
-    """The gated delta rule's state kernel at qwen3-next-ep2's shape: 3 linear
-    layers x 128 slots x 32 heads of (128, 128) float32, the stacked leaf in
-    and the same buffer out, a row's 32 heads a grid step under the work
-    list's traced bound."""
+@pytest.mark.parametrize(
+    "layers, slots, heads, d_k, d_v", [(3, 128, 32, 128, 128), (12, 16, 30, 96, 192)],
+    ids=["qwen3-next-ep2", "olmo-hybrid-7b-pp2"],
+)
+def test_delta_decode_step_lowers(topo, layers, slots, heads, d_k, d_v):
+    """The gated delta rule's state kernel at qwen3-next-ep2's shape (3 linear
+    layers x 128 slots x 32 heads of (128, 128) float32) and at
+    olmo-hybrid-7b-pp2's (12 x 16 x 30 heads of (96, 192): keys under a lane
+    tile, values a tile and a half, blocks whose last two dimensions are the
+    array's own): ONE kernel, the stacked leaf in and the same buffer out, a
+    row's heads a grid step under the work list's traced bound."""
     from prime_tpu.ops.pallas_delta import delta_decode_step, delta_step_heads
 
-    layers, slots, heads, d_k, d_v = 3, 128, 32, 128, 128
     leaf = ((layers, slots, heads, d_k, d_v), jnp.float32)
     vector = lambda *shape: ((slots, heads, *shape), jnp.float32)  # noqa: E731
     args = (leaf, ((), jnp.int32), vector(d_k), vector(d_k), vector(d_v), vector(), vector(),
@@ -215,7 +220,7 @@ def test_delta_decode_step_lowers(topo):
     compiled = _compile(topo, delta_decode_step, *args, donate=(0,))
     assert _pallas_grids(delta_decode_step, *args) == [
         (heads // delta_step_heads(heads, d_k, d_v), None)
-    ] == [(1, None)]
+    ] == [(1 if d_v % 128 == 0 else 2, None)]
     # donated and aliased through the kernel: no second state, not one layer's
     assert compiled.memory_analysis().temp_size_in_bytes < slots * heads * d_k * d_v * 4 // 16
 
@@ -648,14 +653,15 @@ def test_sambay_chunk_prefill_fits_beside_the_engines_cache(topo, wave):
 # ---- the gated_delta block at the served size: qwen3-next-ep2, 128 slots x 4096 -----
 
 
-def _qwen3_next_engine_programs():
-    """The engine's decode and chunk_prefill bodies (serve/engine.py) for
-    qwen3-next-ep2, the sampler left out, the expert layer's counts carried."""
+def _qwen3_next_engine_programs(preset="qwen3-next-ep2"):
+    """The engine's decode and chunk_prefill bodies (serve/engine.py) for a
+    gated_delta preset, the sampler left out, the expert layer's counts carried
+    (zeros where the feed-forward is dense)."""
     from prime_tpu.models.config import get_config
     from prime_tpu.models.llama import forward
     from prime_tpu.ops.moe import MOE_STATS
 
-    config = get_config("qwen3-next-ep2")
+    config = get_config(preset)
 
     def decode(params, cache, tok, active):
         def step(carry, _):
@@ -745,6 +751,56 @@ def test_gated_delta_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypat
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 1.9e9
     assert memory.temp_size_in_bytes < temporaries
     assert f"bf16[{tokens * 10},2048]" in compiled.as_text()  # all of the chunk's rows, sorted once
+
+
+# ---- the same block, dense and post-norm: olmo-hybrid-7b-pp2, 16 slots x 2048 -----
+
+
+def test_olmo_hybrid_decode_fits_the_chip_with_state_and_rows_in_place(topo, monkeypatch):
+    """olmo-hybrid-7b-pp2's engine-shaped decode at 16 slots x 2048: four periods
+    at the published widths beside the whole cache (8.2 GB of weights, 2.0 GB of
+    K/V rows for the four full layers, 0.44 GB of float32 state and conv tails).
+    The step holds no copy of the stacked state, tail or rows, and never slices
+    one layer's state out: the state kernel steps the live rows' (30, 96, 192)
+    blocks where they lie."""
+    _chip_takes_the_kernels(monkeypatch)
+    config, decode, _ = _qwen3_next_engine_programs("olmo-hybrid-7b-pp2")
+    params, cache = _abstract_model(config, 16, 2048)
+    compiled = _compile(
+        topo, decode, params, cache, ((16,), jnp.int32), ((16,), jnp.bool_), donate=(1,)
+    )
+    memory = compiled.memory_analysis()
+    assert 10.5e9 < memory.argument_size_in_bytes < 10.8e9
+    assert memory.temp_size_in_bytes < 0.4e9
+    text = compiled.as_text()
+    # write and attend a period, and the state kernel a linear layer of it
+    assert len(re.findall("tpu_custom_call", text)) >= 5
+    for leaf in ("f32[12,16,30,96,192]", "bf16[12,16,3,11520]", "bf16[4,16,30,128,2048]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+    assert not re.findall(r"= f32\[16,30,96,192\]", text)
+
+
+@pytest.mark.parametrize("wave, temporaries", [(1, 0.25e9), (8, 1.9e9)])
+def test_olmo_hybrid_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch, wave, temporaries):
+    """A 512-token chunk of a 2048-column staging row, a wave of one (a prefix
+    hit's suffix from a restored state; the cell's ``max_wave``) and the widest
+    wave the rule from shapes gives 30 heads at 512 x 2048 (8): weights, rows
+    and temporaries under the 15 GB less the 2.45 GB the engine's own slots
+    hold. What a wave of 8 takes (1.2 GB of rows, 1.7 GB of temporaries) is
+    what the cell's prefix cache has instead."""
+    from prime_tpu.serve.engine import prefill_shapes
+
+    _chip_takes_the_kernels(monkeypatch)
+    config, _, chunk_prefill = _qwen3_next_engine_programs("olmo-hybrid-7b-pp2")
+    assert prefill_shapes(config, 2048) == (512, 8)
+    params, row = _abstract_model(config, wave, 2048)
+    compiled = _compile(
+        topo, chunk_prefill, params, row, ((wave, 512), jnp.int32), ((), jnp.int32),
+        ((wave,), jnp.int32), donate=(1,),
+    )
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 - 2.45e9
+    assert memory.temp_size_in_bytes < temporaries
 
 
 def test_gated_delta_finalize_splices_state_and_row_in_place(topo):

@@ -320,8 +320,8 @@ def test_prefix_cache_byte_budget_evicts_lru_and_identical_prompt():
     r = engine.submit([3] * 20, max_new_tokens=2)
     drain(engine, r)
     assert cache.evictions == 1 and cache.bytes <= cache.budget_bytes
-    assert engine._prefix_match_len([1] * 20) == 16  # p1 survived (recently used)
-    assert engine._prefix_match_len([2] * 20) == 0   # p2 was the LRU victim
+    assert engine._prefix_match_lens([1] * 20)[0] == 16  # p1 survived (recently used)
+    assert engine._prefix_match_lens([2] * 20)[0] == 0   # p2 was the LRU victim
     assert engine.stats()["prefix_evictions"] == 1
     # identical prompt re-admission: seeded from its own cached blocks
     r = engine.submit([3] * 20, max_new_tokens=4)
@@ -353,7 +353,7 @@ def test_prefix_cache_partial_hit_and_block_dedup():
     assert cache.dedup_tokens >= 32
     assert cache.bytes == bytes_a * 64 // 48
     c = pre + [999, 998]
-    assert engine._prefix_match_len(c) == 32  # preamble-only partial hit
+    assert engine._prefix_match_lens(c)[0] == 32  # preamble-only partial hit
     rc = engine.submit(list(c), max_new_tokens=4)
     drain(engine, rc)
     assert engine.prefix_hits == 2

@@ -59,7 +59,8 @@ def test_the_kernel_is_the_step_for_live_rows_and_touches_nothing_else(live, lay
 def test_a_row_in_several_head_blocks_and_a_callers_work_list(monkeypatch):
     """Heads that do not fit one step: a grid step a head block, the work list
     the caller's own (made once for all layers)."""
-    monkeypatch.setattr(pallas_delta, "DECODE_VMEM_BUDGET", 4 * 2 * 16 * 8 * 4)
+    # two heads' blocks as VMEM holds them: 8 lanes of values take a whole tile's 128
+    monkeypatch.setattr(pallas_delta, "DECODE_VMEM_BUDGET", 4 * 2 * 16 * 128 * 4)
     assert delta_step_heads(6, 16, 8) == 2
     leaf, *vectors = _inputs(4, 2, 5, 6, 16, 8)
     live = LIVE["some-rows-dead"]
@@ -72,6 +73,8 @@ def test_heads_a_step_follow_the_shapes():
     assert delta_step_heads(32, 256, 256) == 8
     assert delta_step_heads(4, 16, 16) == 4
     assert delta_step_heads(3, 1024, 1024) == 1
+    # counted in whole (8, 128) tiles: 192 lanes take 256, so 30 heads of (96, 192) go 15 a step
+    assert delta_step_heads(30, 96, 192) == 15 and delta_step_heads(30, 96, 256) == 15
 
 
 def test_the_work_list_is_the_live_rows_in_order():
@@ -85,10 +88,11 @@ def test_the_work_list_is_the_live_rows_in_order():
 @pytest.mark.parametrize("impl,interpret,dims,expected", [
     ("pallas", True, (16, 16), (2 * 3, 5 * 3)),
     ("pallas", False, (128, 128), (2 * 3, 5 * 3)),
-    ("pallas", False, (16, 16), (0, 0)),      # no whole tiles: plain XLA on a chip
+    ("pallas", False, (96, 192), (2 * 3, 5 * 3)),  # values end inside a lane tile: padded in VMEM
+    ("pallas", False, (12, 16), (0, 0)),      # keys end inside a sublane tile: plain XLA on a chip
     ("xla", True, (128, 128), (0, 0)),
     ("auto", True, (128, 128), (0, 0)),       # the CPU backend takes no kernel by itself
-], ids=["interpret-any-shape", "whole-tiles", "ragged-tiles", "xla", "auto-on-cpu"])
+], ids=["interpret-any-shape", "whole-tiles", "padded-lanes", "ragged-tiles", "xla", "auto-on-cpu"])
 def test_the_gate_is_the_decode_attentions_and_whole_tiles(monkeypatch, impl, interpret, dims, expected):
     if interpret:
         monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")

@@ -321,7 +321,7 @@ def test_the_engine_counts_what_the_slots_hold_and_what_a_prefill_computed(weigh
 
 def test_the_engine_refuses_a_prefix_cache_and_speculation_for_state_in_the_slots(weights):
     ours, _ = weights
-    with pytest.raises(ValueError, match="prefix cache stores K/V segments without it"):
+    with pytest.raises(ValueError, match="slots hold a ring.*and no ring"):
         ContinuousBatchingEngine(ours, CONFIG, max_slots=2, capacity=128, prefix_cache_mb=8)
     with pytest.raises(ValueError, match="cannot rewind"):
         ContinuousBatchingEngine(ours, CONFIG, max_slots=2, capacity=128, prefix_cache_mb=0,

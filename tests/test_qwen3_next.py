@@ -325,8 +325,10 @@ def test_what_the_block_does_not_serve_is_refused_by_name(weights):
     for quantize in (quantize_params_int8, quantize_params_int4):
         with pytest.raises(ValueError, match="int8 or int4 weights.*R7"):
             quantize(ours)
-    with pytest.raises(ValueError, match="prefix cache stores K/V segments without it"):
-        ContinuousBatchingEngine(ours, CONFIG, max_slots=2, capacity=128, prefix_cache_mb=8)
+    # a prefix cache is served since PR 39 (snapshots of the state: tests/test_prefix_state.py),
+    # at a stride from shapes; speculation over state stays refused
+    served = ContinuousBatchingEngine(ours, CONFIG, max_slots=2, capacity=128, prefix_cache_mb=8)
+    assert served.prefix_cache.state_stride == served.state_stride == served.prefill_chunk == 64
     with pytest.raises(ValueError, match="cannot rewind"):
         ContinuousBatchingEngine(ours, CONFIG, max_slots=2, capacity=128, prefix_cache_mb=0,
                                  speculative=True)
