@@ -614,6 +614,7 @@ def _attention_block(
     attend_lengths: jnp.ndarray | None = None,  # (B,) decode: slots each row attends over
     #                                             (None: its cache and this step's column)
     work: tuple | None = None,                  # decode: the kernel's work list for them
+    column_work: tuple | None = None,           # decode: the column write's list of live rows
     prefill_offset: jnp.ndarray | None = None,  # () chunked prefill: write+attend at offset
     sliding: jnp.ndarray | None = None,  # () traced bool: this layer uses the window
     rope_tables_local: tuple[jnp.ndarray, jnp.ndarray] | None = None,
@@ -681,7 +682,9 @@ def _attention_block(
     if decode:
         assert kv and cache_lengths is not None
         # this step's k/v column goes into each sequence's next free slot
-        kv = write_cache(kv, staged(k, v), layer, cache_lengths, impl=attn_impl)
+        kv = write_cache(
+            kv, staged(k, v), layer, cache_lengths, impl=attn_impl, work=column_work
+        )
         attn = decode_attention(
             q, kv[0], kv[1], cache_lengths + 1 if attend_lengths is None else attend_lengths,
             sm_scale, impl=attn_impl, work=work,
@@ -1003,6 +1006,7 @@ def forward(
     token_mask: jnp.ndarray | None = None,  # (B, S) bool: False rows belong to nobody
     return_moe_stats: bool = False,
     return_attn_steps: bool = False,
+    return_column_writes: bool = False,
     return_state_steps: bool = False,
 ):
     """Run the transformer. Returns (logits (B, S, V) fp32, updated cache),
@@ -1011,9 +1015,11 @@ def forward(
     none ran) when ``return_moe_stats``, plus, when ``return_attn_steps``, a
     (2,) int32 vector: the grid steps this decode step's attention kernel calls
     made and the most they could have made (``ops.attention.decode_work``;
-    zeros where no work list was made), plus, when ``return_state_steps``, the
-    same pair for the gated_delta block's state kernel
-    (``ops.attention.delta_decode_work``).
+    zeros where no work list was made), plus, when ``return_column_writes``, the
+    rows this decode step's column-write kernel calls wrote and the rows they
+    were offered (``ops.attention.column_write_work``; zeros where the write is
+    XLA's), plus, when ``return_state_steps``, the steps' pair for the
+    gated_delta block's state kernel (``ops.attention.delta_decode_work``).
     With ``last_positions`` the head matmul runs on ONE gathered position per
     row and logits are (B, 1, V): a prefill that only needs each sequence's
     next-token logits skips S× the unembedding FLOPs and never materializes
@@ -1032,10 +1038,12 @@ def forward(
     ``token_mask`` marks the rows that belong to somebody (None: all of them).
     The others cost as little as the program can make them: the dropless
     experts skip them, and a decode step attends them at length 0, which is
-    zero and reads no cache block (``ops.attention.decode_attention``). Only
-    the attended length changes: such a row's position, the slot its column is
-    written to and the lengths the cache carries are what they are without a
-    mask, so the bytes a finished row left below its length stay as they are.
+    zero and reads no cache block (``ops.attention.decode_attention``). Such a
+    row's position and the lengths the cache carries are what they are without
+    a mask. Its column is not written where the write is the kernel's
+    (``write_columns`` walks the live rows), and lands in its next free slot
+    where it is XLA's: either way the bytes a finished row left below its
+    length stay as they are, and what lies at or past it is nobody's to read.
 
     In every cached regime the returned cache IS the argument's buffers,
     updated in place: the leaves ride one layer scan as its carry (both
@@ -1053,7 +1061,7 @@ def forward(
         return _forward_sambay(
             params, tokens, config, cache, decode, attn_impl, return_aux,
             prefill_offset, mesh, last_positions, adapters, token_mask, return_moe_stats,
-            return_attn_steps,
+            return_attn_steps, return_column_writes,
         )
     if config.mla:
         from prime_tpu.models.mla import validate_mla_config
@@ -1123,22 +1131,28 @@ def forward(
 
     cache_lengths = cache.lengths if cache is not None else None
     nobody = {}
-    attn_steps = jnp.zeros((2,), jnp.int32)
-    if decode and token_mask is not None:
-        from prime_tpu.ops.attention import decode_work
+    attn_steps = column_writes = jnp.zeros((2,), jnp.int32)
+    if decode:
+        from prime_tpu.ops.attention import column_write_work, decode_work
 
-        # the slots a row attends over: the cache and this step's column, and
-        # none for a row that belongs to nobody; with them the kernel's list of
-        # the grid steps those lengths need. Once, here, for every layer (a
-        # config whose layers alternate a window leaves the list to the kernel,
-        # which makes a layer's from its own window)
-        attend_lengths = jnp.where(token_mask[:, 0], cache_lengths + 1, 0)
-        nobody = dict(attend_lengths=attend_lengths)
-        if not config.sliding_window:
-            nobody["work"], attn_steps = decode_work(
-                attn_impl, config.n_heads, cache.k, cache.quantized, attend_lengths, mesh
-            )
-            attn_steps = attn_steps * cache.k.shape[0]  # a call a cache row
+        # the rows that belong to somebody (None: all), listed once, here, for
+        # every layer's column write
+        live = None if token_mask is None else token_mask[:, 0]
+        nobody["column_work"], column_writes = column_write_work(attn_impl, cache.k, live)
+        column_writes = column_writes * cache.k.shape[0]  # a call a cache row
+        if live is not None:
+            # the slots a row attends over: the cache and this step's column,
+            # and none for a row that belongs to nobody; with them the kernel's
+            # list of the grid steps those lengths need. Once, here, for every
+            # layer (a config whose layers alternate a window leaves the list to
+            # the kernel, which makes a layer's from its own window)
+            attend_lengths = jnp.where(live, cache_lengths + 1, 0)
+            nobody["attend_lengths"] = attend_lengths
+            if not config.sliding_window:
+                nobody["work"], attn_steps = decode_work(
+                    attn_impl, config.n_heads, cache.k, cache.quantized, attend_lengths, mesh
+                )
+                attn_steps = attn_steps * cache.k.shape[0]  # a call a cache row
     # Per-layer sliding flag rides the scan so one compiled body serves both
     # kinds. The pattern is an explicit config field (ModelConfig.sliding_pattern)
     # so non-Gemma2 window schemes can't silently inherit the even alternation.
@@ -1293,6 +1307,7 @@ def forward(
     out = (logits, new_cache) + ((aux_total,) if return_aux else ())
     out += (moe_stats,) if return_moe_stats else ()
     out += (attn_steps,) if return_attn_steps else ()
+    out += (column_writes,) if return_column_writes else ()
     if return_state_steps:
         out += (jnp.zeros((2,), jnp.int32) if state_steps is None else state_steps,)
     return out
@@ -1301,6 +1316,7 @@ def forward(
 def _forward_sambay(
     params, tokens, config, cache, decode, attn_impl, return_aux, prefill_offset,
     mesh, last_positions, adapters, token_mask, return_moe_stats, return_attn_steps,
+    return_column_writes,
 ):
     """``forward`` for the sambay block kind (models/sambay.py): the same
     regimes and results. There are no positions to encode, so ``positions``
@@ -1320,7 +1336,7 @@ def _forward_sambay(
     if adapters is not None:
         raise ValueError("the sambay block serves no LoRA bank")
     seq = tokens.shape[1]
-    x, leaves, attn_steps = sambay_layers(
+    x, leaves, attn_steps, column_writes = sambay_layers(
         params, params["embed"][tokens], config, cache, decode, attn_impl,
         prefill_offset, token_mask, last_positions,
     )
@@ -1337,4 +1353,5 @@ def _forward_sambay(
         from prime_tpu.ops.moe import MOE_STATS
 
         out += (jnp.zeros((len(MOE_STATS),), jnp.int32),)
-    return out + ((attn_steps,) if return_attn_steps else ())
+    out += (attn_steps,) if return_attn_steps else ()
+    return out + ((column_writes,) if return_column_writes else ())

@@ -203,7 +203,7 @@ def _project_out(ctx_latent, lp, config: ModelConfig):
 def mla_attention_block(
     x, lp, positions, rope_tables, config: ModelConfig,
     kv, layer, cache_lengths, decode: bool, attn_impl: str,
-    attend_lengths=None, work=None, prefill_offset=None,
+    attend_lengths=None, work=None, column_work=None, prefill_offset=None,
 ):
     """Drop-in replacement for llama._attention_block on MLA configs.
 
@@ -230,15 +230,15 @@ def mla_attention_block(
     normed = _rms(x, lp["attn_norm"], config) if "attn_norm" in lp else x
     q_joint, latent = _queries_and_latent(normed, lp, config, cos_rows, sin_rows)
 
-    def write(start, impl="xla"):  # the latent block (B, 1, rank+rope, S) at slots [start, start+S)
+    def write(start, impl="xla", work=None):  # the latent block (B, 1, rank+rope, S) at slots [start, start+S)
         (k_cache,) = write_cache(
-            kv[:1], (latent.transpose(0, 2, 1)[:, None],), layer, start, impl=impl
+            kv[:1], (latent.transpose(0, 2, 1)[:, None],), layer, start, impl=impl, work=work
         )
         return k_cache, kv[1]
 
     if decode:
         assert kv and cache_lengths is not None
-        kv = write(cache_lengths, attn_impl)
+        kv = write(cache_lengths, attn_impl, column_work)
         ctx = decode_attention(
             q_joint, kv[0], kv[0], cache_lengths + 1 if attend_lengths is None else attend_lengths,
             sm_scale, impl=attn_impl, layer=layer, work=work,

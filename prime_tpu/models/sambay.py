@@ -56,6 +56,7 @@ from prime_tpu.ops.attention import (
     _decode_uses_kernel,
     attention_at_positions,
     cache_prefill_attention,
+    column_write_work,
     decode_attention,
     decode_work,
     ring_positions,
@@ -238,11 +239,13 @@ def sambay_layers(
     #                                   past a row's last token is
     last_positions: jnp.ndarray | None,
 ):
-    """Returns (x, cache leaves, attention steps). ``x`` is (B, S, d), or
-    (B, 1, d) at ``last_positions`` where they were given. The leaves are
-    ``(k, v, ring_k, ring_v, ssm_state, conv_tail)`` updated, or () without a
-    cache. The steps are ``forward``'s ``return_attn_steps`` vector: a decode
-    step's flash-decode grid steps, made and at most, rings and row summed."""
+    """Returns (x, cache leaves, attention steps, column writes). ``x`` is
+    (B, S, d), or (B, 1, d) at ``last_positions`` where they were given. The
+    leaves are ``(k, v, ring_k, ring_v, ssm_state, conv_tail)`` updated, or ()
+    without a cache. The steps are ``forward``'s ``return_attn_steps`` vector: a
+    decode step's flash-decode grid steps, made and at most, rings and row
+    summed; the writes its ``return_column_writes`` vector, the rows the step's
+    column-write calls wrote and were offered, the rings' and the row's."""
     batch, seq, _ = x.shape
     hd = config.head_dim
     sm_scale = hd**-0.5
@@ -268,13 +271,14 @@ def sambay_layers(
         else jnp.full((batch,), seq, jnp.int32)
     )
 
-    lengths = attend = ring_attend = work = ring_work = ring_impl = None
-    attn_steps = jnp.zeros((2,), jnp.int32)
+    lengths = attend = ring_attend = work = ring_work = ring_impl = column_work = None
+    attn_steps = column_writes = jnp.zeros((2,), jnp.int32)
     if decode:
         lengths = cache.lengths
         attend = lengths + 1
-        if token_mask is not None:
-            attend = jnp.where(token_mask[:, 0], attend, 0)
+        rows_live = None if token_mask is None else token_mask[:, 0]
+        if rows_live is not None:
+            attend = jnp.where(rows_live, attend, 0)
         ring_attend = jnp.minimum(attend, window)
         # the ring takes the path the slot's full row takes: beside the
         # flash-decode kernel, per-row dynamic_update_slice writes of a wide
@@ -287,13 +291,19 @@ def sambay_layers(
         )
         work, steps = decode_work(attn_impl, config.n_heads, cache.k, False, attend)
         attn_steps = ring_steps * n_window + steps * (1 + config.cross_pairs)
+        # the live rows, listed once for the rings' column writes and the row's
+        # (one gate: ``ring_impl`` follows the row's)
+        column_work, column_writes = column_write_work(attn_impl, cache.k, rows_live)
+        column_writes = column_writes * (n_window + 1)
 
     q_pos = offset + jnp.arange(seq, dtype=jnp.int32)[None, :]  # (1, S)
 
     def window_attention(h, lp, row, ring):
         q, k_t, v_t = _project_qkv(h, lp, config)
         if decode:
-            ring = write_cache(ring, (k_t, v_t), row, lengths % window, impl=ring_impl)
+            ring = write_cache(
+                ring, (k_t, v_t), row, lengths % window, impl=ring_impl, work=column_work
+            )
             attn = decode_attention(
                 q, ring[0], ring[1], ring_attend, sm_scale, impl=ring_impl, layer=row, work=ring_work,
             )
@@ -356,7 +366,7 @@ def sambay_layers(
     zero = jnp.zeros((), jnp.int32)
     full = (cache.k, cache.v) if cached else ()
     if decode:
-        full = write_cache(full, (k_t, v_t), zero, lengths, impl=attn_impl)
+        full = write_cache(full, (k_t, v_t), zero, lengths, impl=attn_impl, work=column_work)
         a = decode_attention(q, full[0], full[1], attend, sm_scale, impl=attn_impl, layer=zero, work=work)
     elif cached:
         full = write_cache(full, (k_t, v_t), zero, offset)
@@ -400,4 +410,4 @@ def sambay_layers(
     x, _ = jax.lax.scan(
         cross_pair, x, (jnp.arange(config.cross_pairs, dtype=jnp.int32), params["cross_layers"])
     )
-    return x, ((*full, *ring, state, tail) if cached else ()), attn_steps
+    return x, ((*full, *ring, state, tail) if cached else ()), attn_steps, column_writes

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def write_cache(leaves, blocks, layer, start, impl: str = "xla"):
+def write_cache(leaves, blocks, layer, start, impl: str = "xla", work: tuple | None = None):
     """Write one layer's new ``blocks`` (B, KH, *, S) into the stacked cache
     ``leaves`` (L, B, KH, *, C) IN PLACE, at slots [start, start + S) of
     ``layer``. ``start`` () is one offset for every row (a prompt at 0, a
@@ -25,7 +25,11 @@ def write_cache(leaves, blocks, layer, start, impl: str = "xla"):
     window): one ``dynamic_update_slice`` a row, or, for decode's single
     column where ``impl`` sends the attention to the flash-decode kernel
     (the same gate, so TPU and a long cache), one ``write_columns`` kernel
-    for all rows and leaves. All are forms XLA updates in the buffer the
+    for all leaves and the rows of ``work``: the step's list of the rows that
+    belong to somebody (``column_write_work``; None: every row). There a row
+    that belongs to nobody keeps its bytes, column included; on the other
+    paths its column lands at ``start`` like any row's, at or past its own
+    length, where nothing attends. All are forms XLA updates in the buffer the
     layer scan carries. A scatter over the batch axis
     (``.at[layer, arange(B), :, :, start].set``, or ``vmap`` of the update)
     must NOT be used here: XLA gives the scatter its own layout and copies
@@ -36,7 +40,8 @@ def write_cache(leaves, blocks, layer, start, impl: str = "xla"):
         from prime_tpu.ops.pallas_attention import write_columns
 
         return write_columns(
-            tuple(leaves), tuple(blocks), layer, start, interpret=_pallas_interpret()
+            tuple(leaves), tuple(blocks), layer, start, work=work,
+            interpret=_pallas_interpret(),
         )
 
     def put(leaf, block):
@@ -313,6 +318,28 @@ def decode_work(
     batch, blocks = k_cache.shape[-4], k_cache.shape[-1] // block_c
     work = decode_work_list(lengths, block_c, blocks)
     return work, jnp.stack([work[2] * (most // (batch * blocks)), jnp.int32(most)])
+
+
+def column_write_work(
+    impl: str, k_cache, live: jnp.ndarray | None
+) -> tuple[tuple | None, jnp.ndarray]:
+    """(work, writes) for one decode step's column writes into this cache:
+    ``write_columns``' list of the ``live`` rows (B,) (``pallas_attention.
+    column_work_list``), for a model to make once a step, outside its layer
+    loop, and hand to every layer's ``write_cache``; and a (2,) int32 vector,
+    the rows a call writes with it and the rows it was offered. ``live`` None:
+    every row is live and no list is needed. (None, zeros) where decode takes
+    an XLA path, which writes every row: the gate is the decode attention's own
+    (``_decode_uses_kernel``)."""
+    if not _decode_uses_kernel(impl, k_cache):
+        return None, jnp.zeros((2,), jnp.int32)
+    batch = jnp.int32(k_cache.shape[-4])
+    if live is None:
+        return None, jnp.stack([batch, batch])
+    from prime_tpu.ops.pallas_attention import column_work_list
+
+    work = column_work_list(live)
+    return work, jnp.stack([jnp.sum(work[0]), batch])
 
 
 def delta_decode_work(

@@ -569,16 +569,52 @@ def flash_decode(
     return out.reshape(batch, num_heads, 1, head_dim)
 
 
+def column_work_list(live: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(live, rows, n): ``write_columns``' grid steps for a (B,) bool mask of
+    the rows that belong to somebody. The first ``n`` entries of the (B + 1,)
+    table are the live rows in order and its tail repeats the last (the
+    pipeline reads the index maps a step ahead, and an equal index copies
+    nothing): ``decode_work_list`` over a cache of one block. No live row:
+    ``n`` is 1 and the one step finds its row dead in ``live`` (int32, as the
+    kernel reads it) and passes its block through. A function of the mask
+    alone, so a model makes it once a decode step for every layer's write."""
+    live = live.astype(jnp.int32)
+    rows, _, n = decode_work_list(live, 1, 1)
+    return live, rows, n
+
+
+def column_heads(kv_heads: int, widest: int, lanes: int) -> int:
+    """KV heads of a row one ``write_columns`` program carries: the most (a
+    divisor of ``kv_heads``) whose widest block (``widest`` bytes a head and
+    slot, ``lanes`` slots) stays under 512 KB; each leaf's block is held in,
+    out and double-buffered. qwen3-4b's 8 heads of 128 bf16 (262 KB), phi4's 10
+    and a 576-wide latent's one go whole; 30 heads of 128 (983 KB) go 15 a
+    program, two programs a row."""
+    for heads in range(kv_heads, 0, -1):
+        if kv_heads % heads == 0 and heads * widest * lanes <= 512 * 1024:
+            return heads
+    return 1
+
+
 def _column_kernel(
     slots_ref,  # (B,) scalar-prefetch: the slot each row's column goes to
+    live_ref,   # (B,) scalar-prefetch: 1 where the row belongs to somebody
+    rows_ref,   # (B + 1,) scalar-prefetch: the work list's row of each grid step
     layer_ref,  # (1,) scalar-prefetch: read by the index maps only
     *refs,      # per leaf: the column (1, HB, d, 1); then per leaf the cache
                 # block (1, HB, d, LANES) holding that slot; then the same
                 # blocks again as outputs (aliased to the cache operands)
     lanes: int,
+    capacity: int,
 ):
     count = len(refs) // 3
-    lane_of_slot = slots_ref[pl.program_id(0)] % lanes
+    row = rows_ref[pl.program_id(1)]
+    # every step that runs is a live row's, but for the one step of a call with
+    # no live row: no lane is its column's, and the block goes back as it came
+    # (the outputs alias the cache: a block not written would be written back
+    # as the buffer found it)
+    slot = jnp.clip(slots_ref[row], 0, capacity - 1)
+    lane_of_slot = jnp.where(live_ref[row] > 0, slot % lanes, -1)
     for col_ref, block_ref, out_ref in zip(
         refs[:count], refs[count : 2 * count], refs[2 * count :]
     ):
@@ -597,52 +633,74 @@ def write_columns(
     columns: tuple[jnp.ndarray, ...],  # this step's columns (B, KH, d_i, 1)
     layer: jnp.ndarray | int,          # () the layer to write
     slots: jnp.ndarray,                # (B,) the slot of each row's column
+    work: tuple | None = None,         # column_work_list(live) for the step's live
+    #                                    rows, made once for all its layers; None:
+    #                                    every row is live
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, ...]:
-    """Decode's cache write as ONE kernel for every row and leaf: each program
-    brings the 128-slot block that holds its row's slot into VMEM, puts the
-    column in its lane and writes the block back, into the SAME buffers
+    """Decode's cache write as ONE kernel for every live row and leaf: each
+    program brings the 128-slot block that holds its row's slot into VMEM, puts
+    the column in its lane and writes the block back, into the SAME buffers
     (``input_output_aliases``), so the layer scan still carries one cache. As
     per-row ``dynamic_update_slice`` the same write is 8 operations a leaf of
     4-5 us each, 16 a layer for a bf16 cache of 8 rows: 2.4 ms of a 22.6 ms
     decode step on the v5e (PR 25). A slot past the end is clamped to the
-    last one, as ``dynamic_update_slice`` clamps its start."""
+    last one, as ``dynamic_update_slice`` clamps its start.
+
+    The programs are the call's work list (``column_work_list``) under a
+    dynamic grid bound, the form ``flash_decode`` and the state kernel have: one
+    for every live row and block of its kv heads (``column_heads``), and none
+    for a row that belongs to nobody, whose column is NOT written and whose
+    blocks are neither read nor touched. Without ``work`` every row is live and
+    the list is the static walk over the rows."""
     batch, kv_heads, capacity = leaves[0].shape[1], leaves[0].shape[2], leaves[0].shape[4]
     lanes = BLOCK_C if capacity % BLOCK_C == 0 else capacity
-    # all of a row's kv heads in one program while the widest block stays small
-    # in VMEM (each is held in, out and double-buffered); else a head a program
     widest = max(leaf.shape[3] * leaf.dtype.itemsize for leaf in leaves)
-    heads = kv_heads if kv_heads * widest * lanes <= 512 * 1024 else 1
+    heads = column_heads(kv_heads, widest, lanes)
+    if work is None:
+        live = jnp.ones((batch,), jnp.int32)
+        rows, n = jnp.minimum(jnp.arange(batch + 1, dtype=jnp.int32), batch - 1), batch
+    else:
+        live, rows, n = work
 
     def column_spec(leaf):
-        return pl.BlockSpec((1, heads, leaf.shape[3], 1), lambda b, h, *_: (b, h, 0, 0))
+        return pl.BlockSpec(
+            (1, heads, leaf.shape[3], 1), lambda h, i, slots, live, rows, lay: (rows[i], h, 0, 0)
+        )
 
     def block_spec(leaf):
         return pl.BlockSpec(
             (None, 1, heads, leaf.shape[3], lanes),
-            lambda b, h, slots, lay: (lay[0], b, h, 0, slots[b] // lanes),
+            # (the clamp is here and in the body, on the scalar core: as an
+            # operation before the call it is one more launch a layer)
+            lambda h, i, slots, live, rows, lay: (
+                lay[0], rows[i], h, 0, jnp.clip(slots[rows[i]], 0, capacity - 1) // lanes
+            ),
         )
 
     count = len(leaves)
     blocks = [block_spec(leaf) for leaf in leaves]
     return tuple(
         pl.pallas_call(
-            functools.partial(_column_kernel, lanes=lanes),
+            functools.partial(_column_kernel, lanes=lanes, capacity=capacity),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(batch, kv_heads // heads),
+                num_scalar_prefetch=4,
+                # a program is one listed row with `heads` of its kv heads (all
+                # of them where they fit, so the first axis is 1); the second
+                # axis is the work list, its bound traced: at most `batch`
+                grid=(kv_heads // heads, n),
                 in_specs=[column_spec(leaf) for leaf in leaves] + blocks,
                 out_specs=blocks,
             ),
             out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in leaves],
-            # operands count the two prefetched scalars and the columns first
-            input_output_aliases={2 + count + i: i for i in range(count)},
+            # operands count the four prefetched scalars and the columns first
+            input_output_aliases={4 + count + i: i for i in range(count)},
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel"),
+                dimension_semantics=("parallel", "arbitrary"),
             ),
             interpret=interpret,
         )(
-            jnp.clip(slots.astype(jnp.int32), 0, capacity - 1),
+            slots.astype(jnp.int32), live, rows,
             jnp.asarray(layer, jnp.int32).reshape(1),
             *columns, *leaves,
         )

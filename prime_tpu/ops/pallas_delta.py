@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from prime_tpu.ops.pallas_attention import DECODE_VMEM_BUDGET, decode_work_list
+from prime_tpu.ops.pallas_attention import DECODE_VMEM_BUDGET, column_work_list
 
 
 def delta_step_heads(heads: int, d_k: int, d_v: int) -> int:
@@ -66,10 +66,10 @@ def delta_step_heads(heads: int, d_k: int, d_v: int) -> int:
 def delta_work_list(live: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(rows, n): the live rows of a (B,) bool mask in order, as the first
     ``n`` entries of a (B + 1,) table whose tail repeats the last (the
-    pipeline reads the index maps a step ahead), by ``decode_work_list`` over a
-    cache of one block. No live row: ``n`` is 1 and the one step finds its row
-    dead (``delta_decode_step`` copies it through)."""
-    rows, _, n = decode_work_list(live.astype(jnp.int32), 1, 1)
+    pipeline reads the index maps a step ahead): the list the column write
+    walks (``pallas_attention.column_work_list``). No live row: ``n`` is 1 and
+    the one step finds its row dead (``delta_decode_step`` copies it through)."""
+    _, rows, n = column_work_list(live)
     return rows, n
 
 

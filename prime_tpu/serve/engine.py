@@ -456,6 +456,9 @@ class _InflightChunk:
     # steps, made and at most, summed over the chunk (forward's
     # return_attn_steps; synced with toks)
     attn_steps: Any = None
+    # the same chunks: the (2,) device vector of the column-write kernel's rows,
+    # written and offered (forward's return_column_writes)
+    column_writes: Any = None
     # the gated_delta block's decode chunks: the same pair for its state kernel
     # (forward's return_state_steps)
     state_steps: Any = None
@@ -1089,6 +1092,17 @@ class ContinuousBatchingEngine:
             "could have made, not made)",
             labelnames=("kind",),
         )
+        # the kernel that writes the step's K/V column beside it
+        # (ops/pallas_attention.py::write_columns) walks the rows that belong to
+        # somebody: written / (written + spared) is the share of the slots'
+        # rows whose 128-slot blocks it moved
+        self._m_decode_column_writes = r.counter(
+            "serve_decode_column_writes_total",
+            "Rows x calls of the decode column-write kernel over the decode "
+            "chunks dispatched, by kind: written | spared (offered, belonging "
+            "to nobody, not written); 0 / 0 where the write is XLA's",
+            labelnames=("kind",),
+        )
         # the gated_delta block's state kernel (ops/pallas_delta.py), counted the
         # same way: a step for every live row's head block of every linear layer
         self._m_delta_state_steps = r.counter(
@@ -1584,9 +1598,9 @@ class ContinuousBatchingEngine:
         cache_spec = self._cache_constraint()
         moe_counts = self._moe
         # what the step scan carries out with the tokens: the expert layers'
-        # counts on MoE engines, then the attention kernel's grid steps, then,
-        # for the gated_delta block, its state kernel's
-        kernel_steps = {"return_attn_steps": True}
+        # counts on MoE engines, then the attention kernel's grid steps and the
+        # column write's rows, then, for the gated_delta block, its state kernel's
+        kernel_steps = {"return_attn_steps": True, "return_column_writes": True}
         if config.block == "gated_delta":
             kernel_steps["return_state_steps"] = True
         zero_stats = [*self._moe_zero_stats(), *(jnp.zeros((2,), jnp.int32) for _ in kernel_steps)]
@@ -2445,7 +2459,9 @@ class ContinuousBatchingEngine:
                     carried = {"run_len": run_len}
                 else:
                     self._cache, self._last, toks, *stats = fn(*args)
-                    names = (["moe_stats"] if self._moe else []) + ["attn_steps", "state_steps"]
+                    names = (["moe_stats"] if self._moe else []) + [
+                        "attn_steps", "column_writes", "state_steps"
+                    ]
                     carried = dict(zip(names, stats))
                     self._m_decode_rows.inc(live * steps, state="live")
                     self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
@@ -2475,14 +2491,15 @@ class ContinuousBatchingEngine:
             runs = np.asarray(chunk.run_len) if spec else None
             if chunk.moe_stats is not None:
                 self._count_moe(chunk.moe_stats, "decode")
-            for steps, counter in (
-                (chunk.attn_steps, self._m_decode_attn_steps),
-                (chunk.state_steps, self._m_delta_state_steps),
+            for steps, counter, made_kind in (
+                (chunk.attn_steps, self._m_decode_attn_steps, "walked"),
+                (chunk.column_writes, self._m_decode_column_writes, "written"),
+                (chunk.state_steps, self._m_delta_state_steps, "walked"),
             ):
                 if steps is not None:
-                    walked, most = (int(n) for n in np.asarray(steps))
-                    counter.inc(walked, kind="walked")
-                    counter.inc(most - walked, kind="spared")
+                    made, most = (int(n) for n in np.asarray(steps))
+                    counter.inc(made, kind=made_kind)
+                    counter.inc(most - made, kind="spared")
         t_done = self._clock.switched_at  # the sync_wait phase's end
         if chunk.clean:
             # steady-state decode only: windows that contained an admission

@@ -177,25 +177,35 @@ def test_flash_decode_lowers(topo, variant, monkeypatch):
     assert (variant == "mha") == (grid[1] > 1)  # only 32 MHA heads overflow a step
 
 
-@pytest.mark.parametrize("variant", ["bf16", "int8_with_scales", "mla_latent"])
-def test_write_columns_lowers(topo, variant):
+@pytest.mark.parametrize("listed", [False, True], ids=["every_row", "live_rows_listed"])
+@pytest.mark.parametrize("variant", ["bf16", "int8_with_scales", "mla_latent", "olmo_30_heads"])
+def test_write_columns_lowers(topo, variant, listed):
     """Decode's column write as one kernel, at the served shape: the stacked
-    leaves in, the same buffers out."""
-    from prime_tpu.ops.pallas_attention import write_columns
+    leaves in, the same buffers out, a program a row's block of kv heads (all of
+    them but for Olmo-Hybrid's 30, which go 15 a program) over the rows, or over
+    the step's list of live rows under its traced bound."""
+    from prime_tpu.ops.pallas_attention import column_heads, column_work_list, write_columns
 
     layers, kv_heads, dims, dtypes = 4, KH, (D, D), (jnp.bfloat16,) * 2
     if variant == "int8_with_scales":
         dims, dtypes = (D, D, 1, 1), (jnp.int8, jnp.int8, jnp.float32, jnp.float32)
     elif variant == "mla_latent":  # DeepSeek-V2's one latent head of rank + rope
         kv_heads, dims, dtypes = 1, (576,), (jnp.bfloat16,)
+    elif variant == "olmo_30_heads":
+        kv_heads = 30
     leaves = tuple(((layers, B, kv_heads, d, C), t) for d, t in zip(dims, dtypes))
     columns = tuple(((B, kv_heads, d, 1), t) for d, t in zip(dims, dtypes))
     count = len(leaves)
-    compiled = _compile(
-        topo,
-        lambda *a: write_columns(a[:count], a[count : 2 * count], a[-2], a[-1]),
-        *leaves, *columns, ((), jnp.int32), _LENS, donate=tuple(range(count)),
-    )
+
+    def write(*a):
+        work = column_work_list(a[-1]) if listed else None
+        return write_columns(a[:count], a[count : 2 * count], a[-3], a[-2], work=work)
+
+    args = (*leaves, *columns, ((), jnp.int32), _LENS, ((B,), jnp.bool_))
+    compiled = _compile(topo, write, *args, donate=tuple(range(count)))
+    heads = column_heads(kv_heads, max(d * jnp.dtype(t).itemsize for d, t in zip(dims, dtypes)), 128)
+    assert heads == (15 if variant == "olmo_30_heads" else kv_heads)
+    assert _pallas_grids(write, *args) == [(kv_heads // heads, None if listed else B)]
     # donated and aliased through the kernel: no second cache is allocated
     assert compiled.memory_analysis().temp_size_in_bytes < B * kv_heads * max(dims) * C
 
@@ -337,24 +347,32 @@ def _results(compiled, opcode):
     return _results_in(compiled.as_text(), opcode)
 
 
-def _assert_listed_outside_the_layer_loop(compiled, steps):
-    """The flash-decode call's grid bound, lengths and work list (its first
-    four operands: the list's two tables have ``steps`` + 1 entries) reach the
-    layer loop's body as loop-carried values, and nothing in that body makes a
-    table of that size: the list is made once a step."""
+def _assert_listed_outside_the_layer_loop(compiled, steps, kernel="flash_decode", tables=2):
+    """A kernel call's grid bound, per-row scalars and work list (its first
+    four operands, the last ``tables`` of them the list's tables of ``steps`` +
+    1 entries: flash-decode's rows and blocks, the column write's rows) reach
+    the layer loop's body as loop-carried values, and nothing in that body
+    makes a table of that size: the list is made once a step."""
     text = compiled.as_text()
     table = f"s32[{steps + 1}]"
-    bodies = [c for c in re.split(r"\n\n(?=%|ENTRY)", text) if "flash_decode" in c and "tpu_custom_call" in c]
+    bodies = [c for c in re.split(r"\n\n(?=%|ENTRY)", text) if kernel in c and "tpu_custom_call" in c]
     assert bodies
     for body in bodies:
-        calls = re.findall(r"%flash_decode\S* = \S+ custom-call\(([^)]*)\)", body)
+        calls = re.findall(rf"%{kernel}\S* = .+? custom-call\(([^)]*)\)", body)
         assert calls
+        def carried(name):
+            # the compiler may move a small operand between memory spaces on
+            # its way (copy-start / copy-done): what it moves is what counts
+            while moved := re.search(rf"{re.escape(name)} = .+? copy-(?:start|done)\((%[\w.-]+)\)", body):
+                name = moved[1]
+            return name
+
         for operands in calls:
-            names = [name.strip() for name in operands.split(",")[:4]]
+            names = [carried(name.strip()) for name in operands.split(",")[:4]]
             assert all(name.startswith("%get-tuple-element") for name in names), names
             kinds = [re.search(rf"{re.escape(name)} = (\S+) get-tuple-element", body)[1] for name in names]
-            bound, _, *tables = [kind.split("{")[0] for kind in kinds]
-            assert bound == "s32[]" and tables == [table, table], kinds
+            kinds = [kind.split("{")[0] for kind in kinds]
+            assert kinds[0] == "s32[]" and kinds[-tables:] == [table] * tables, kinds
         made = [r for r in _results_in(body, "fusion") + _results_in(body, "reduce") if r.startswith(table)]
         assert not made, made
     assert table in text
@@ -374,8 +392,8 @@ def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
     scan's xs/ys they exceeded the whole cache), nothing copies the stacked
     cache, and the kernel reads its layer out of the stacked buffer, so no
     one-layer slice is made. With the engine's mask of retired slots the same
-    holds, and the kernel's work list is made outside the layer loop's
-    body."""
+    holds, and the kernels' work lists (the attention's and the column
+    write's) are operands made outside the layer loop's body."""
     from prime_tpu.models.llama import forward
 
     config = _qwen3_4b(n_layers)
@@ -403,6 +421,7 @@ def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
     )
     if masked:
         _assert_listed_outside_the_layer_loop(compiled, steps=B * 4)
+        _assert_listed_outside_the_layer_loop(compiled, steps=B, kernel="write_columns", tables=1)
     one_layer_kv = 2 * B * KH * D * C * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer_kv
     stacked, one_layer = f"bf16[{n_layers},{B},{KH},{D},{C}]", f"bf16[{B},{KH},{D},{C}]"
@@ -493,6 +512,7 @@ def test_shortcut_block_decode_reads_its_weights_in_place(topo, monkeypatch):
     assert not top_level("bf16[2,6144,12288]") and not top_level("bf16[2,12288,6144]")
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
     _assert_listed_outside_the_layer_loop(compiled, steps=64 * 4)  # the latent: at most 64 x 1 x 4 steps
+    _assert_listed_outside_the_layer_loop(compiled, steps=64, kernel="write_columns", tables=1)
 
 
 def test_sharded_decode_lowers_tp4(topo):

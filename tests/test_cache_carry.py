@@ -284,30 +284,40 @@ def test_twelve_decode_steps_match_xs_ys(model):
 # ---- decode's column write as one kernel --------------------------------------
 
 
-@pytest.mark.parametrize(
-    "case", ["bf16", "int8_with_scales", "f32_short_capacity", "one_head_a_program"]
-)
-def test_write_columns_kernel_is_the_per_row_update(case):
-    """``write_columns`` (interpret mode) leaves the bytes that the per-row
-    ``dynamic_update_slice`` form of ``write_cache`` leaves, a slot past the
-    end included (both clamp it to the last slot)."""
-    from prime_tpu.ops.pallas_attention import write_columns
-
+def _column_case(case, batch=B):
+    """(leaves, columns, capacity) of a ``write_columns`` call: the stacked
+    cache's leaves and a step's columns for them, drawn from one seed."""
     rng = np.random.default_rng(12)
     capacity, dims, dtypes, kv_heads = 256, (D, D), (jnp.bfloat16,) * 2, KH
     if case == "int8_with_scales":
         dims, dtypes = (D, D, 1, 1), (jnp.int8, jnp.int8, jnp.float32, jnp.float32)
     elif case == "f32_short_capacity":
         capacity, dtypes = 48, (jnp.float32,) * 2  # no 128-slot block divides it
-    elif case == "one_head_a_program":
-        kv_heads, dims = 4, (640, 640)  # too wide for all heads in one VMEM block
+    elif case == "heads_split_over_programs":
+        kv_heads, dims = 4, (640, 640)  # too wide for all heads in one VMEM block: two a program
+    elif case == "latent_one_leaf":
+        kv_heads, dims, dtypes = 1, (72,), (jnp.bfloat16,)
 
     def draw(shape, dtype):
         values = rng.normal(size=shape) * (50 if dtype == jnp.int8 else 1)
         return jnp.asarray(values.astype(np.float32)).astype(dtype)
 
-    leaves = tuple(draw((L, B, kv_heads, d, capacity), t) for d, t in zip(dims, dtypes))
-    columns = tuple(draw((B, kv_heads, d, 1), t) for d, t in zip(dims, dtypes))
+    leaves = tuple(draw((L, batch, kv_heads, d, capacity), t) for d, t in zip(dims, dtypes))
+    columns = tuple(draw((batch, kv_heads, d, 1), t) for d, t in zip(dims, dtypes))
+    return leaves, columns, capacity
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["bf16", "int8_with_scales", "f32_short_capacity", "heads_split_over_programs", "latent_one_leaf"],
+)
+def test_write_columns_kernel_is_the_per_row_update(case):
+    """``write_columns`` (interpret mode) without a list of live rows leaves the
+    bytes that the per-row ``dynamic_update_slice`` form of ``write_cache``
+    leaves, a slot past the end included (both clamp it to the last slot)."""
+    from prime_tpu.ops.pallas_attention import write_columns
+
+    leaves, columns, capacity = _column_case(case)
     for slots in ([5, capacity - 1], [130 % capacity, capacity + 3]):
         slots = jnp.asarray(slots, dtype=jnp.int32)
         got = write_columns(leaves, columns, jnp.int32(2), slots, interpret=True)
@@ -315,6 +325,64 @@ def test_write_columns_kernel_is_the_per_row_update(case):
         for new, ref in zip(got, want):
             assert new.dtype == ref.dtype
             assert np.array_equal(np.asarray(new), np.asarray(ref))
+
+
+@pytest.mark.parametrize(
+    "live",
+    [(True, False, True, False), (False, False, False, True), (False,) * 4, (True,) * 4],
+    ids=["every_other_row", "the_last_row", "no_row", "every_row"],
+)
+@pytest.mark.parametrize(
+    "case", ["bf16", "int8_with_scales", "heads_split_over_programs", "latent_one_leaf"]
+)
+def test_write_columns_walks_the_live_rows(case, live):
+    """With the step's list of live rows (``column_work_list``) a live row's
+    leaves are the unlisted call's bit for bit, and a row that belongs to nobody
+    keeps the bytes it had, its column's slot included: for the bf16 cache's two
+    leaves, the int8 cache's four, the latent's one, and a row whose kv heads
+    take two programs. With no live row the call's one step passes its block
+    through and every leaf comes back as it went in."""
+    from prime_tpu.ops.pallas_attention import column_work_list, write_columns
+
+    leaves, columns, capacity = _column_case(case, batch=4)
+    slots = jnp.asarray([5, 130, capacity + 3, capacity - 1], dtype=jnp.int32)
+    listed = write_columns(
+        leaves, columns, jnp.int32(1), slots, work=column_work_list(jnp.asarray(live)),
+        interpret=True,
+    )
+    every_row = write_columns(leaves, columns, jnp.int32(1), slots, interpret=True)
+    for got, written, old in zip(listed, every_row, leaves):
+        assert got.dtype == old.dtype
+        for row, alive in enumerate(live):
+            want = written if alive else old
+            assert np.array_equal(np.asarray(got[:, row]), np.asarray(want[:, row])), (row, alive)
+    if not any(live):
+        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(listed, leaves))
+
+
+@pytest.mark.parametrize(
+    "shape, heads",
+    [
+        pytest.param((8, 128, 2), 8, id="qwen3-4b"),
+        pytest.param((10, 128, 2), 10, id="phi4-mini-flash"),
+        pytest.param((2, 256, 2), 2, id="qwen3-next-ep2"),
+        pytest.param((1, 576, 2), 1, id="longcat-latent"),
+        pytest.param((8, 128, 1), 8, id="int8-cache"),
+        pytest.param((30, 128, 2), 15, id="olmo-hybrid-7b-pp2"),
+        pytest.param((32, 128, 2), 16, id="32-mha-heads"),
+        pytest.param((7, 1024, 2), 1, id="7-wide-heads"),
+    ],
+)
+def test_column_heads_is_the_widest_divisor_that_fits(shape, heads):
+    """A ``write_columns`` program carries as many of a row's kv heads as keep
+    its widest 128-slot block under 512 KB, a divisor of the head count: the
+    benchmark's shapes keep all of theirs but Olmo-Hybrid's 30 x 128, which go
+    15 a program (all-or-one gave it 30 programs a row)."""
+    from prime_tpu.ops.pallas_attention import column_heads
+
+    kv_heads, width, itemsize = shape
+    assert column_heads(kv_heads, width * itemsize, 128) == heads
+    assert kv_heads % heads == 0 and heads * width * itemsize * 128 <= 512 * 1024
 
 
 def test_forward_decode_writes_through_the_kernel(monkeypatch):

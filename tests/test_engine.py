@@ -1548,16 +1548,17 @@ def test_decode_step_masks_only_the_attended_length(preset):
         walk(jax.make_jaxpr(step_fn)().jaxpr, outside)
         return outside, inside
 
-    # the kernel's work list (its one cumsum over the rows; the experts' routing
-    # has cumsums of its own, on either path) is made once a step, outside the
-    # layer scan, where the rows are masked and the kernel attends; a config whose
-    # layers alternate a window leaves it to each layer's call
+    # the kernels' work lists (one cumsum over the rows each; the experts' routing
+    # has cumsums of its own, on either path) are made once a step, outside the
+    # layer scan, where the rows are masked and the kernels run: the column
+    # write's always, the attention's unless the config's layers alternate a
+    # window, which leaves it to each layer's call
     (xla_out, xla_in), (outside, inside) = (
         primitives(live[:, None], impl) for impl in ("xla", "pallas")
     )
     assert "pallas_call" in inside and "pallas_call" not in xla_in
     made = (outside.count("cumsum") - xla_out.count("cumsum"), inside.count("cumsum") - xla_in.count("cumsum"))
-    assert made == ((0, 1) if config.sliding_window else (1, 0))
+    assert made == ((1, 1) if config.sliding_window else (2, 0))
 
 
 def test_decode_attn_steps_counter_follows_the_lengths(monkeypatch):
@@ -1589,6 +1590,88 @@ def test_decode_attn_steps_counter_follows_the_lengths(monkeypatch):
     assert more > 0 and counted() == (calls + 2 * more, calls * 5 + 4 * more)
     assert long.all_tokens(timeout=1) == reference_tokens(list(range(3, 143)), 9)
     engine.shutdown()
+
+
+def test_decode_column_writes_counter_follows_the_mask(monkeypatch):
+    """serve_decode_column_writes_total over the synced decode chunks: written
+    is the rows the column-write kernel's calls wrote (the dispatch mask's live
+    rows, a call a layer and step), spared the rest of the slots' rows it was
+    offered: the mask serve_decode_rows_total counts, so the two agree row for
+    row. 2 slots: one request alone writes one row of two a call, two together
+    both. The benchmark's reader (``column_write_spared_share``) over the same
+    snapshots reads the spared share, and nothing where there is no counter."""
+    from benchmark.layer_metrics import column_write_spared_share
+    from benchmark.systems.serve_http import counter_delta
+
+    monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")
+    engine = make_engine(max_slots=2, capacity=384, attn_impl="pallas", overlap=False)
+    writes = engine.registry.get("serve_decode_column_writes_total")
+    rows = engine.registry.get("serve_decode_rows_total")
+    before = engine.registry.snapshot()
+
+    def counted():
+        return writes.value(kind="written"), writes.value(kind="spared")
+
+    def share(pair):
+        return column_write_spared_share.read({"counters": pair, "counter_delta": counter_delta})
+
+    alone = engine.submit(list(range(3, 13)), max_new_tokens=9)
+    drain(engine, alone)
+    written, spared = counted()
+    assert written == spared > 0
+    after = engine.registry.snapshot()
+    assert share((before, after)) == 50.0
+    assert share((after, after)) is None and share(({}, {})) is None  # an idle window; the parent
+    pair = [engine.submit(list(range(3 + i, 13 + i)), max_new_tokens=9) for i in range(2)]
+    drain(engine, *pair)
+    assert counted()[0] - written > counted()[1] - spared >= 0
+    assert counted() == (
+        rows.value(state="live") * CONFIG.n_layers, rows.value(state="retired") * CONFIG.n_layers
+    )
+    engine.shutdown()
+
+
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-longcat", "tiny-phi4flash", "tiny-qwen3next"])
+def test_column_writes_count_the_live_rows(preset, monkeypatch):
+    """forward(decode=True, return_column_writes=True) under the kernel's gate:
+    (live rows, rows) x the step's column-write calls (a cache row each; for the
+    sambay block the rings' and the row's), every row without a mask, zeros on
+    the XLA path. A row masked out keeps its cache bytes, the slot its column
+    would have taken included, and a live row's are the unmasked step's."""
+    import numpy as np
+
+    from prime_tpu.models.llama import forward, init_cache
+
+    monkeypatch.setenv("PRIME_TPU_PALLAS_INTERPRET", "1")
+    config = get_config(preset)
+    params = init_params(jax.random.PRNGKey(1), config, dtype=jnp.float32)
+    batch, capacity = 4, 256
+    cache = init_cache(config, batch, capacity, dtype=jnp.float32)
+    prompt = jax.random.randint(jax.random.PRNGKey(2), (batch, 12), 3, config.vocab_size)
+    _, cache = forward(params, prompt, config, cache=cache)
+    cache = cache._replace(lengths=jnp.asarray([12, 5, 9, 12], jnp.int32))
+    tok = jnp.asarray([[4], [5], [6], [7]], jnp.int32)
+
+    def step(mask, impl="pallas"):
+        _, new, writes = forward(
+            params, tok, config, positions=cache.lengths[:, None], cache=cache, decode=True,
+            attn_impl=impl, token_mask=None if mask is None else jnp.asarray(mask)[:, None],
+            return_column_writes=True,
+        )
+        return new, [int(n) for n in np.asarray(writes)]
+
+    calls = config.window_pairs + 1 if config.block == "sambay" else cache.k.shape[0]
+    live = [True, False, True, False]
+    (masked, writes), (unmasked, every) = step(live), step(None)
+    assert writes == [2 * calls, batch * calls] and every == [batch * calls] * 2
+    assert step(live, "xla")[1] == step(None, "xla")[1] == [0, 0]
+    written = ("k",) if config.mla else ("k", "v")  # the latent's v is a dummy
+    written += ("ring_k", "ring_v") if config.block == "sambay" else ()
+    for name in written:
+        got, ref, old = (np.asarray(getattr(c, name)) for c in (masked, unmasked, cache))
+        assert not np.array_equal(ref, old)
+        for row, alive in enumerate(live):
+            np.testing.assert_array_equal(got[:, row], (ref if alive else old)[:, row])
 
 
 @pytest.mark.parametrize("preset", ["tiny-test", "tiny-longcat", "tiny-phi4flash", "tiny-qwen3next"])
