@@ -198,11 +198,89 @@ class ModelConfig:
     # tokenizer's special rows, so `prime serve --continuous` ends a stream
     # from it at max_tokens and never on an end-of-sequence id (serve_model)
     vocab_published: int | None = None
+    # --- layers of more than one kind on the standard block (Laguna) ---
+    # the published config's own lists, one entry a layer: ``layer_types``
+    # "full_attention" | "sliding_attention", ``mlp_layer_types`` "dense" (a
+    # SwiGLU of width dense_ff) | "sparse" (the expert layer). Set together. The
+    # kinds differ in SHAPE, so each has a parameter stack of its own
+    # (models/llama.py ``_init_kinds_params``) and the layer walk is over runs of
+    # the pattern (``kind_segments``), not one scan under a traced flag: a full
+    # layer has n_heads query heads, a K/V row, rope_theta / partial_rotary /
+    # rope_yarn; a sliding layer window_heads query heads, a ring of
+    # sliding_window columns, rope_local_theta over the whole head, unscaled
+    layer_types: tuple[str, ...] | None = None
+    mlp_layer_types: tuple[str, ...] | None = None
+    window_heads: int | None = None   # a sliding layer's query heads (None: n_heads)
+    # one scalar gate a head and token, sigmoid(u W_g), on the attention's
+    # output before the output projection (Laguna gating "per-head")
+    attn_head_gate: bool = False
+
+    def __post_init__(self) -> None:
+        if self.layer_types is None and self.mlp_layer_types is None:
+            return
+        kinds = (self.layer_types or (), self.mlp_layer_types or ())
+        if (
+            self.block != "standard" or not self.sliding_window
+            or any(len(k) != self.n_layers for k in kinds)
+            or set(kinds[0]) - {"full_attention", "sliding_attention"}
+            or set(kinds[1]) - {"dense", "sparse"}
+            or ("sparse" in kinds[1] and not self.is_moe)
+        ):
+            raise ValueError(
+                f"{self.name}: layer_types and mlp_layer_types are set together on the "
+                "standard block, one entry a layer ('full_attention' | 'sliding_attention', "
+                "'dense' | 'sparse'), with a sliding_window and, for 'sparse', experts"
+            )
+
+    @property
+    def full_layers(self) -> int:
+        """Layers that keep a full-length K/V row, where ``layer_types`` says."""
+        return (self.layer_types or ()).count("full_attention")
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep a ring of ``sliding_window`` columns instead."""
+        return (self.layer_types or ()).count("sliding_attention")
+
+    @property
+    def heads_per_layer(self) -> tuple[int, ...]:
+        """Query heads a layer (the published ``num_attention_heads_per_layer``)."""
+        if self.layer_types is None:
+            return (self.n_heads,) * self.n_layers
+        return tuple(
+            self.n_heads if kind == "full_attention" else self.window_heads or self.n_heads
+            for kind in self.layer_types
+        )
+
+    @property
+    def kind_segments(self) -> tuple[tuple[int, int, int], ...]:
+        """``layer_types`` x ``mlp_layer_types`` cut into (first layer, layers a
+        unit, units) runs, each a unit of the pattern repeated: what the layer
+        walk scans (a run of one unit is unrolled). Greedy from the front, the
+        unit (up to 8 layers) that covers most; the published Laguna lists give
+        (0, 1, 1), (1, 4, 11), (45, 1, 3), their first five layers (0, 1, 1),
+        (1, 1, 3), (4, 1, 1)."""
+        pairs = list(zip(self.layer_types or (), self.mlp_layer_types or ()))
+        out, at = [], 0
+        while at < len(pairs):
+            best = (1, 1)
+            for unit in range(1, min(8, len(pairs) - at) + 1):
+                units = 1
+                while pairs[at + units * unit : at + (units + 1) * unit] == pairs[at : at + unit]:
+                    units += 1
+                if unit * units > best[0] * best[1] and (unit == 1 or units > 1):
+                    best = (unit, units)
+            out.append((at, *best))
+            at += best[0] * best[1]
+        return tuple(out)
 
     @property
     def cache_rows(self) -> int:
         """Rows of the stacked KV cache's leading axis: one an attention that
-        keeps a full-length row (the sambay block has one such layer)."""
+        keeps a full-length row (the sambay block has one such layer; under
+        ``layer_types`` the full layers only, a sliding layer keeps a ring)."""
+        if self.layer_types is not None:
+            return self.full_layers
         if self.block == "sambay":
             return 1
         if self.block == "gated_delta":
@@ -379,6 +457,21 @@ class ModelConfig:
                 embed + head + self.d_model
                 + self.n_layers * (2 * (attn + norms + dense_mlp) + experts)
             )
+        if self.layer_types is not None:
+            # by kind: each attention at its own head count (and its head gate),
+            # a dense layer at dense_ff, a sparse one over the experts HELD here
+            d, kv = self.d_model, 2 * self.n_kv_heads * self.head_dim
+            attn_total = sum(
+                d * (2 * h * self.head_dim + kv) + (d * h if self.attn_head_gate else 0)
+                for h in self.heads_per_layer
+            )
+            sparse = (
+                self.experts_held * 3 * d * self.d_ff + d * self.n_experts
+                + 3 * d * self.shared_ff
+            )
+            dense = 3 * d * (self.dense_ff or self.d_ff)
+            mlp_total = sum(dense if k == "dense" else sparse for k in self.mlp_layer_types)
+            return embed + head + attn_total + mlp_total + self.n_layers * norms + d
         if self.first_k_dense:
             dense_mlp = 3 * self.d_model * (self.dense_ff or self.d_ff)
             mlp_total = (
@@ -1217,6 +1310,79 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         linear_conv=4,
         linear_neg_eigval=True,
         linear_grouped_proj=True,
+    ),
+    # Laguna-S-2.1, one chip's share of a 2-way expert deployment (poolside/
+    # Laguna-S-2.1 config.json): published widths, the first 5 of the 48 layers
+    # by the config's own lists (the dense full-attention layer 0, then one whole
+    # period: three window layers of 72 heads on rings of 512 and a full layer of
+    # 48 on a row, each with per-head output gates and the expert layer), experts
+    # 0-127 of 256 held (rank 0 of 2) beside the shared expert, half the
+    # vocabulary. Full layers rotate half a head under YaRN at theta 5e5, window
+    # layers the whole head at 1e4. benchmark/configs/laguna-s-ep2.json states
+    # the cut and what is assumed.
+    "laguna-s-ep2": ModelConfig(
+        name="laguna-s-ep2",
+        vocab_size=50176,
+        vocab_published=100352,
+        d_model=3072,
+        n_layers=5,
+        n_heads=48,
+        window_heads=72,
+        n_kv_heads=8,
+        d_ff=1024,             # moe_intermediate_size
+        dense_ff=12288,        # intermediate_size (layer 0)
+        max_seq_len=1048576,
+        rope_theta=500000.0,
+        rope_local_theta=10000.0,
+        partial_rotary=0.5,
+        rope_yarn=(128.0, 32.0, 1.0, 8192.0, 1.4852030263919618),
+        rms_eps=1e-6,
+        head_dim_override=128,
+        sliding_window=512,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3 + ("full_attention",),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        attn_head_gate=True,
+        n_experts=256,
+        n_experts_held=128,
+        first_expert=0,
+        experts_per_token=10,
+        norm_topk=True,
+        routed_scaling_factor=2.5,
+        n_shared_experts=1,    # shared_expert_intermediate_size 1024 = 1 x d_ff
+    ),
+    # the same layer walk at test scale: 9 layers, so that the dense first layer
+    # and two whole periods are there; 4 and 6 query heads over 2 K/V heads
+    # (groups of 2 and 3, one no power of two), a window of 8, 8 routed experts
+    # (4 held here, from expert 2), top-3, an eighth of the vocabulary
+    "tiny-laguna": ModelConfig(
+        name="tiny-laguna",
+        vocab_size=512,
+        vocab_published=4096,
+        d_model=64,
+        n_layers=9,
+        n_heads=4,
+        window_heads=6,
+        n_kv_heads=2,
+        d_ff=32,
+        dense_ff=128,
+        max_seq_len=512,
+        rope_theta=500000.0,
+        rope_local_theta=10000.0,
+        partial_rotary=0.5,
+        rope_yarn=(4.0, 32.0, 1.0, 64.0, 1.1386294361119891),
+        rms_eps=1e-6,
+        head_dim_override=16,
+        sliding_window=8,
+        layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 2 + ("full_attention",),
+        mlp_layer_types=("dense",) + ("sparse",) * 8,
+        attn_head_gate=True,
+        n_experts=8,
+        n_experts_held=4,
+        first_expert=2,
+        experts_per_token=3,
+        norm_topk=True,
+        routed_scaling_factor=2.5,
+        n_shared_experts=1,
     ),
     # GPT-OSS architecture at test scale: sinks + biased clamped-GLU MoE +
     # alternating window + non-truncated yarn, all exercised on CPU

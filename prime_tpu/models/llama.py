@@ -69,7 +69,9 @@ class KVCache(NamedTuple):
     k_scale: jnp.ndarray | None = None  # (L, B, KH, 1, C) fp32 when quantized
     v_scale: jnp.ndarray | None = None
     # the sambay block (models/sambay.py): k/v are its ONE full-length row;
-    # beside it a window layer's ring and a state-space layer's carried state
+    # beside it a window layer's ring and a state-space layer's carried state.
+    # The standard block under ``config.layer_types`` (models/kinds.py): k/v are
+    # the FULL layers' rows only, ring_k/ring_v the sliding layers' rings
     ring_k: jnp.ndarray | None = None     # (window layers, B, KH, D, window)
     ring_v: jnp.ndarray | None = None
     ssm_state: jnp.ndarray | None = None  # (state layers, B, d_state, d_inner) float32
@@ -155,6 +157,25 @@ def init_cache(
             conv_tail=jnp.zeros(
                 (state_layers, batch, config.ssm_d_conv - 1, config.d_inner), dtype=dtype
             ),
+        )
+    if config.layer_types is not None:
+        # rows for the full layers, rings of ``sliding_window`` columns for the
+        # sliding ones: a window layer never reads past its window, so a
+        # full-length row for it would be memory nothing attends over
+        if quantized:
+            raise ValueError(
+                "a layer_types model's rings are not quantized: kv_quant is unsupported "
+                "(ROADMAP.md R7)"
+            )
+        heads = (batch, config.n_kv_heads, config.head_dim)
+        row = (config.full_layers, *heads, capacity)
+        ring = (config.window_layers, *heads, config.sliding_window)
+        return KVCache(
+            k=jnp.zeros(row, dtype=dtype),
+            v=jnp.zeros(row, dtype=dtype),
+            lengths=jnp.zeros((batch,), dtype=jnp.int32),
+            ring_k=jnp.zeros(ring, dtype=dtype),
+            ring_v=jnp.zeros(ring, dtype=dtype),
         )
     if config.block == "gated_delta":
         # K/V for the full-attention layers only, state and tail for the linear
@@ -300,6 +321,10 @@ def init_params(rng: jax.Array, config: ModelConfig, dtype=jnp.bfloat16) -> Para
         return init_sambay_params(rng, config, dtype)
     if config.block == "gated_delta":
         return _init_delta_params(rng, config, dtype)
+    if config.layer_types is not None:
+        from prime_tpu.models.kinds import init_params as init_kinds_params
+
+        return init_kinds_params(rng, config, dtype)
     keys = jax.random.split(rng, 17)
     d, hd = config.d_model, config.head_dim
     h, kh, ff, layers = config.n_heads, config.n_kv_heads, config.d_ff, config.n_layers
@@ -621,15 +646,21 @@ def _attention_block(
     mesh=None,  # mesh-aware impls: "ring" (context-parallel training),
     #             "sharded" (serve decode: flash kernel under shard_map)
     adapter_ids: jnp.ndarray | None = None,  # (B,) multi-LoRA bank slots
+    heads: int | None = None,   # this layer's query heads (None: config.n_heads)
+    window: int | None = None,  # this layer's window (None: config.sliding_window
+    #                             under ``sliding``); a layer-kind walk states it
+    ring_attend=None,  # a window layer over a ring (models/kinds.py): (q, staged
+    #                    blocks, ring leaves) -> (attention, ring leaves), in place
+    #                    of the cached regimes below
 ):
     """One layer's attention. Returns (x + attention, kv): the stacked leaves
     with this layer's new K/V written in place (ops.attention.write_cache)."""
     batch, seq, _ = x.shape
-    h, kh, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    h, kh, hd = heads or config.n_heads, config.n_kv_heads, config.head_dim
     sm_scale = (config.query_scale or hd) ** -0.5
     gemma_kw = dict(
-        softcap=config.attn_softcap, window=config.sliding_window, sliding=sliding,
-        sinks=lp.get("sinks"),
+        softcap=config.attn_softcap, sliding=sliding, sinks=lp.get("sinks"),
+        window=config.sliding_window if window is None else window,
     )
     cos, sin = rope_tables
     # gather the seq-sized rows FIRST, then (Gemma3) select local vs global
@@ -679,7 +710,9 @@ def _attention_block(
         (k_q, k_s), (v_q, v_s) = quantize_kv(k_t), quantize_kv(v_t)
         return k_q, v_q, k_s, v_s
 
-    if decode:
+    if ring_attend is not None and kv:
+        attn, kv = ring_attend(q, staged(k, v), kv)
+    elif decode:
         assert kv and cache_lengths is not None
         # this step's k/v column goes into each sequence's next free slot
         kv = write_cache(
@@ -728,6 +761,11 @@ def _attention_block(
     attn = attn.transpose(0, 2, 1, 3).reshape(batch, seq, h * hd)
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
+    if "w_head_gate" in lp:  # Laguna: one scalar a head and token, from the normed input
+        head_gate = jax.nn.sigmoid(_mm(normed, lp["w_head_gate"]).astype(jnp.float32))
+        attn = (attn.reshape(batch, seq, h, hd) * head_gate[..., None].astype(attn.dtype)).reshape(
+            batch, seq, h * hd
+        )
     out = _lora_mm(attn, lp, "wo", adapter_ids)
     if "bo" in lp:  # Llama-arch attention_bias checkpoints bias o_proj too
         out = out + lp["bo"]
@@ -757,7 +795,9 @@ def runs_dropless_experts(params: Params, config: ModelConfig) -> bool:
     the standard block where ``_moe_dropless`` says so."""
     if not config.is_moe:
         return False
-    return config.block != "standard" or _moe_dropless(params["layers"])
+    if config.block != "standard" or config.layer_types is not None:
+        return True
+    return _moe_dropless(params["layers"])
 
 
 def _mlp_block(
@@ -1057,6 +1097,12 @@ def forward(
     it).
     """
     batch, seq = tokens.shape
+    if config.layer_types is not None:
+        return _forward_kinds(
+            params, tokens, config, positions, cache, decode, attn_impl, return_aux,
+            prefill_offset, last_positions, adapters, token_mask,
+            {"moe": return_moe_stats, "steps": return_attn_steps, "writes": return_column_writes},
+        )
     if config.block == "sambay":
         return _forward_sambay(
             params, tokens, config, cache, decode, attn_impl, return_aux,
@@ -1311,6 +1357,35 @@ def forward(
     if return_state_steps:
         out += (jnp.zeros((2,), jnp.int32) if state_steps is None else state_steps,)
     return out
+
+
+def _forward_kinds(
+    params, tokens, config, positions, cache, decode, attn_impl, return_aux,
+    prefill_offset, last_positions, adapters, token_mask, wanted,
+):
+    """``forward`` for the standard block under ``config.layer_types``
+    (models/kinds.py walks the layers): the same regimes and results. ``wanted``
+    says which of the decode step's counts the caller asked for, in the order
+    ``forward`` returns them."""
+    from prime_tpu.models.kinds import kinds_layers
+
+    seq = tokens.shape[1]
+    x, leaves, counts = kinds_layers(
+        params, params["embed"][tokens], config, positions, cache, decode, attn_impl,
+        prefill_offset, token_mask, adapters,
+    )
+    new_cache = None
+    if cache is not None:
+        new_cache = cache._replace(
+            lengths=cache.lengths + (1 if decode else seq),
+            **dict(zip(("k", "v", "ring_k", "ring_v"), leaves)),
+        )
+    x = _norm(x, params["final_norm"], config)
+    if last_positions is not None:
+        x = jnp.take_along_axis(x, last_positions.astype(jnp.int32)[:, None, None], axis=1)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    out = (logits, new_cache) + ((jnp.zeros((), jnp.float32),) if return_aux else ())
+    return out + tuple(counts[name] for name, asked in wanted.items() if asked)
 
 
 def _forward_sambay(

@@ -57,6 +57,8 @@ def _refuse_delta_stack(params: dict) -> None:
     for."""
     if "delta" in params.get("layers", {}):
         raise ValueError("the gated_delta block does not serve int8 or int4 weights: ROADMAP.md R7")
+    if "window" in params.get("layers", {}):  # stacks a kind of layer (models/kinds.py)
+        raise ValueError("a layer_types model does not serve int8 or int4 weights: ROADMAP.md R7")
 
 
 def quantize_weight(w: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -226,7 +226,9 @@ def prefill_shapes(
     capacities and the warm-up's program set all read these two numbers.
 
     **Width first.** A chunk prefill's fp32 score tensor is wave x heads x
-    chunk x row capacity, and past ``SCORE_BUDGET`` (1 GiB) of it a wide
+    chunk x row capacity (by kind of layer where a model has more than one: 48
+    heads over a row of 8192 beside 72 over a ring of 512 and the chunk, the
+    larger counts), and past ``SCORE_BUDGET`` (1 GiB) of it a wide
     model's wave no longer fits beside its weights: the widest wave is the
     largest power of two whose scores at a ``PREFILL_CHUNK``-token chunk (or
     the explicit ``prefill_chunk``) over a whole slot stay inside it (64 heads
@@ -260,7 +262,19 @@ def prefill_shapes(
     from prime_tpu.ops.moe import MOE_ROW_TILE
 
     chunk = PREFILL_CHUNK if prefill_chunk is None else max(MIN_BUCKET, prefill_chunk)
-    row_scores = 4 * config.n_heads * chunk * capacity  # bytes a row of the wave
+
+    def scores(chunk: int) -> int:
+        """Bytes of scores a row of the wave holds in its widest layer: heads x
+        chunk x the columns that kind of layer attends over (a full layer the
+        row's capacity; a window layer of a ``layer_types`` model its ring and
+        the chunk's own columns, at its own head count)."""
+        columns = [config.n_heads * capacity]
+        if getattr(config, "window_layers", 0):
+            heads = config.window_heads or config.n_heads
+            columns.append(heads * (config.sliding_window + chunk))
+        return 4 * chunk * max(columns)
+
+    row_scores = scores(chunk)
     wave = max(1, 2 ** ((SCORE_BUDGET // row_scores).bit_length() - 1))
     if max_wave is not None:
         if max_wave < 1:
@@ -269,10 +283,10 @@ def prefill_shapes(
     if prefill_chunk is None and dropless_experts:
         while (
             chunk * config.experts_per_token < MOE_ROW_TILE * config.n_experts
-            and wave * 2 * row_scores <= SCORE_BUDGET
+            and wave * scores(2 * chunk) <= SCORE_BUDGET
             and capacity % (2 * chunk) == 0
         ):
-            chunk, row_scores = 2 * chunk, 2 * row_scores
+            chunk *= 2
     return chunk, wave
 
 
@@ -462,6 +476,10 @@ class _InflightChunk:
     # the gated_delta block's decode chunks: the same pair for its state kernel
     # (forward's return_state_steps)
     state_steps: Any = None
+    # a layer_types model's decode chunks: the cache columns its attentions
+    # read, in full rows, in rings, and beyond the window (host integers from
+    # the dispatch's mask and lengths, ``_attended_columns``)
+    attn_columns: tuple[int, int, int] | None = None
     # False once an admission prefill ran inside this chunk's window: its
     # dispatch-to-sync wall time then includes host prefill blocking and must
     # not feed the per-step decode histogram
@@ -484,6 +502,9 @@ class EngineRequest:
     adapter_idx: int = 0
     events: queue.Queue = field(default_factory=queue.Queue)
     emitted: int = 0
+    # decode steps dispatched for it so far, ahead of ``emitted`` by what is in
+    # flight (counted for a layer_types model only: ``_attended_columns``)
+    steps_dispatched: int = 0
     slot: int = -1
     done: bool = False
     cancelled: bool = False
@@ -829,6 +850,11 @@ class ContinuousBatchingEngine:
             (leaf.shape[-1] for leaf, spec in held.values() if spec.kind == "ring"), 0
         )
         rings = sorted(n for n, (_, spec) in held.items() if spec.kind == "ring")
+        if config.layer_types is not None and mesh is not None and getattr(mesh, "size", 1) > 1:
+            raise ValueError(
+                f"a layer_types model ({config.name}) does not serve a mesh: its rings and "
+                "expert share have no sharding rule (ROADMAP.md R7)"
+            )
         if whole and self.speculative:
             # a verify window that is rejected would have to rewind what has no
             # columns to drop (ROADMAP R7: speculation beside state)
@@ -1090,6 +1116,16 @@ class ContinuousBatchingEngine:
             "Grid steps of the decode-attention kernel over the decode chunks "
             "dispatched, by kind: walked (made) | spared (of the most the calls "
             "could have made, not made)",
+            labelnames=("kind",),
+        )
+        # a layer_types model's attentions read rows and rings (models/kinds.py):
+        # the columns each kind read, counted where the steps above are
+        self._m_decode_attn_columns = r.counter(
+            "serve_decode_attn_columns_total",
+            "Cache columns the decode steps' attentions read, live rows x layers x "
+            "steps, by kind: full (in the full layers' rows) | window (in the sliding "
+            "layers' rings) | window_spared (beyond the window, which a ring does not "
+            "hold); all 0 for a model of one kind of layer",
             labelnames=("kind",),
         )
         # the kernel that writes the step's K/V column beside it
@@ -2463,6 +2499,8 @@ class ContinuousBatchingEngine:
                         "attn_steps", "column_writes", "state_steps"
                     ]
                     carried = dict(zip(names, stats))
+                    if self.config.layer_types is not None:
+                        carried["attn_columns"] = self._attended_columns(mask, steps)
                     self._m_decode_rows.inc(live * steps, state="live")
                     self._m_decode_rows.inc((mask.size - live) * steps, state="retired")
                 self._clock.enqueued(toks)
@@ -2475,6 +2513,27 @@ class ContinuousBatchingEngine:
             )
         )
         self._m_inflight_depth.set(len(self._inflight))
+
+    def _attended_columns(self, mask: np.ndarray, steps: int) -> tuple[int, int, int]:
+        """The cache columns a decode chunk of ``steps`` steps over the rows of
+        ``mask`` attends, by kind, for a ``config.layer_types`` model: in the
+        full layers' rows, in the sliding layers' rings, and beyond the window
+        where a ring holds nothing. From what the host knows at dispatch: a
+        row's length is its prompt and the steps dispatched for it so far (its
+        request counts them here), and step ``s`` attends ``length + s + 1``
+        columns, its own among them."""
+        config = self.config
+        live = [req for slot, req in self._requests.items() if mask[slot]]
+        lengths = np.asarray([len(req.prompt_ids) + req.steps_dispatched for req in live], np.int64)
+        for req in live:
+            req.steps_dispatched += steps
+        attended = lengths[:, None] + 1 + np.arange(steps)
+        in_ring = np.minimum(attended, config.sliding_window)
+        return (
+            config.full_layers * int(attended.sum()),
+            config.window_layers * int(in_ring.sum()),
+            config.window_layers * int((attended - in_ring).sum()),
+        )
 
     def _sync_decode(self) -> None:
         """Fetch the oldest in-flight chunk's tokens and emit them. Tokens
@@ -2500,6 +2559,9 @@ class ContinuousBatchingEngine:
                     made, most = (int(n) for n in np.asarray(steps))
                     counter.inc(made, kind=made_kind)
                     counter.inc(most - made, kind="spared")
+            if chunk.attn_columns is not None:
+                for kind, columns in zip(("full", "window", "window_spared"), chunk.attn_columns):
+                    self._m_decode_attn_columns.inc(columns, kind=kind)
         t_done = self._clock.switched_at  # the sync_wait phase's end
         if chunk.clean:
             # steady-state decode only: windows that contained an admission

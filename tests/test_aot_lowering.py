@@ -855,3 +855,87 @@ def test_gated_delta_finalize_splices_state_and_row_in_place(topo):
     assert memory.temp_size_in_bytes < 0.1e9
     for leaf in ("f32[3,128,32,128,128]", "bf16[1,128,2,256,4096]"):
         assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+
+
+# ---- layers of more than one kind on the standard block: laguna-s-ep2, 32 slots x 8192 ----
+
+
+@pytest.mark.parametrize("heads, cache", [(48, (2, 32, 8, 128, 8192)), (72, (3, 32, 8, 128, 512))],
+                         ids=["group_6_row_of_8192", "group_9_ring_of_512"])
+def test_flash_decode_and_write_columns_lower_at_query_groups_6_and_9(topo, monkeypatch, heads, cache):
+    """laguna-s-ep2's two attentions in one decode step: 48 query heads over 8
+    K/V heads of 128 on a row of 8192 (a group of 6), 72 on a ring of 512 (a
+    group of 9; the cells before it ran groups of 1, 2, 4 and 8), each under its
+    own work list, and the column write beside each."""
+    from prime_tpu.ops.pallas_attention import (
+        column_work_list, decode_call_tiling, decode_work_list, flash_decode, write_columns,
+    )
+
+    monkeypatch.delenv("PRIME_TPU_BLOCK_C", raising=False)
+    layers, batch, kv_heads, d, columns = cache
+    kv = (cache, jnp.bfloat16)
+    tile_heads, block_c = decode_call_tiling(heads, jax.ShapeDtypeStruct(cache[1:], jnp.bfloat16), False)
+    assert (tile_heads, block_c) == (8, min(columns, 512))  # every K/V head of a row a step
+
+    def attend(q, k, v, n, layer):
+        work = decode_work_list(n, block_c, columns // block_c)
+        return flash_decode(q, k, v, n, layer=layer, work=work)
+
+    args = (((batch, heads, 1, d), jnp.bfloat16), kv, kv, ((batch,), jnp.int32), ((), jnp.int32))
+    _compile(topo, attend, *args)
+    assert _pallas_grids(attend, *args) == [(1, None)]
+
+    def write(k, v, k_col, v_col, layer, at, live):
+        return write_columns((k, v), (k_col, v_col), layer, at, work=column_work_list(live))
+
+    column = ((batch, kv_heads, d, 1), jnp.bfloat16)
+    args = (kv, kv, column, column, ((), jnp.int32), ((batch,), jnp.int32), ((batch,), jnp.bool_))
+    compiled = _compile(topo, write, *args, donate=(0, 1))
+    assert _pallas_grids(write, *args) == [(1, None)]
+    assert compiled.memory_analysis().temp_size_in_bytes < batch * kv_heads * d * columns
+
+
+def test_laguna_decode_fits_the_chip_with_rows_and_rings_in_place(topo, monkeypatch):
+    """laguna-s-ep2's engine-shaped decode at 32 slots x 8192: the dense layer 0
+    and one period at the published widths, 128 experts a layer, beside the whole
+    cache (11.14 GB of weights, 2.15 GB of rows for the two full layers, 0.20 GB
+    of rings for the three sliding ones). Five column writes and five flash-decode
+    calls at two head counts, the 12 grouped products read the expert stacks in
+    place, and the step holds no copy of a stacked row, ring or expert matrix."""
+    _chip_takes_the_kernels(monkeypatch)
+    config, decode, _ = _qwen3_next_engine_programs("laguna-s-ep2")
+    params, cache = _abstract_model(config, 32, 8192)
+    compiled = _compile(
+        topo, decode, params, cache, ((32,), jnp.int32), ((32,), jnp.bool_), donate=(1,)
+    )
+    memory = compiled.memory_analysis()
+    assert 13.4e9 < memory.argument_size_in_bytes < 13.6e9
+    assert memory.temp_size_in_bytes < 0.4e9
+    text = compiled.as_text()
+    # layer 0 and layer 4 unrolled, the three sliding layers one scanned body: a column
+    # write and a flash-decode each, and three grouped products an expert layer
+    assert len(re.findall("tpu_custom_call", text)) >= 12
+    for leaf in ("bf16[2,32,8,128,8192]", "bf16[3,32,8,128,512]", "bf16[4,128,3072,1024]",
+                 "bf16[4,128,1024,3072]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
+
+
+def test_laguna_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
+    """The widest chunk prefill the rule from shapes gives (512 tokens, a wave of
+    one) over a staging row of 8192 columns and its three rings: weights, row and
+    temporaries (0.8 GB of scores a full layer, the experts' row buffers) under
+    the 15.75 GB the chip gives less the 2.35 GB the engine's own slots hold."""
+    from prime_tpu.serve.engine import prefill_shapes
+
+    _chip_takes_the_kernels(monkeypatch)
+    config, _, chunk_prefill = _qwen3_next_engine_programs("laguna-s-ep2")
+    assert prefill_shapes(config, 8192, dropless_experts=True) == (512, 1)
+    params, row = _abstract_model(config, 1, 8192)
+    compiled = _compile(
+        topo, chunk_prefill, params, row, ((1, 512), jnp.int32), ((), jnp.int32),
+        ((1,), jnp.int32), donate=(1,),
+    )
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.8e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 - 2.35e9
+    assert f"bf16[{512 * 10},3072]" in compiled.as_text()  # all of the chunk's rows, sorted once

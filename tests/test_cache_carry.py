@@ -297,6 +297,10 @@ def _column_case(case, batch=B):
         kv_heads, dims = 4, (640, 640)  # too wide for all heads in one VMEM block: two a program
     elif case == "latent_one_leaf":
         kv_heads, dims, dtypes = 1, (72,), (jnp.bfloat16,)
+    elif case == "laguna_ring":  # 8 K/V heads of 128 on a ring of 512, written at position mod 512
+        capacity, kv_heads, dims = 512, 8, (128, 128)
+    elif case == "laguna_row":   # ... and on a row of 8192
+        capacity, kv_heads, dims = 8192, 8, (128, 128)
 
     def draw(shape, dtype):
         values = rng.normal(size=shape) * (50 if dtype == jnp.int8 else 1)
@@ -309,7 +313,8 @@ def _column_case(case, batch=B):
 
 @pytest.mark.parametrize(
     "case",
-    ["bf16", "int8_with_scales", "f32_short_capacity", "heads_split_over_programs", "latent_one_leaf"],
+    ["bf16", "int8_with_scales", "f32_short_capacity", "heads_split_over_programs", "latent_one_leaf",
+     "laguna_ring", "laguna_row"],
 )
 def test_write_columns_kernel_is_the_per_row_update(case):
     """``write_columns`` (interpret mode) without a list of live rows leaves the
@@ -366,6 +371,7 @@ def test_write_columns_walks_the_live_rows(case, live):
         pytest.param((8, 128, 2), 8, id="qwen3-4b"),
         pytest.param((10, 128, 2), 10, id="phi4-mini-flash"),
         pytest.param((2, 256, 2), 2, id="qwen3-next-ep2"),
+        pytest.param((8, 128, 2), 8, id="laguna-s-ep2"),
         pytest.param((1, 576, 2), 1, id="longcat-latent"),
         pytest.param((8, 128, 1), 8, id="int8-cache"),
         pytest.param((30, 128, 2), 15, id="olmo-hybrid-7b-pp2"),

@@ -1714,9 +1714,10 @@ def test_a_batch_of_full_rows_walks_every_step(preset, monkeypatch):
 
 # ---- the chunk cap and the wave width: one rule from shapes (prefill_shapes) ----
 
-# the four benchmark configurations as their cells serve them: (preset, slot
-# capacity, max_wave asked) -> (chunk cap, widest wave, row capacities). The three
-# that keep 512 keep the parent's wave width and its row set, number for number.
+# the six benchmark configurations as their cells serve them: (preset, slot
+# capacity, max_wave asked) -> (chunk cap, widest wave, row capacities). Those
+# that keep 512 keep the parent's wave width and its row set, number for number:
+# a change to the rule that moves another configuration's programs fails here.
 _ROWS_512_2048 = [16, 32, 64, 128, 256, 512, 1024, 1536, 2048]
 _ROWS_512_4096 = _ROWS_512_2048 + [2560, 3072, 3584, 4096]
 RULE_TABLE = {
@@ -1729,6 +1730,10 @@ RULE_TABLE = {
     "longcat-flash-ep32": ("longcat-flash-ep32", 2048, None, 512, 4, _ROWS_512_2048),
     "qwen3-4b": ("qwen3-4b", 2048, None, 512, 8, _ROWS_512_2048),
     "phi4-mini-flash": ("phi4-mini-flash", 4096, None, 512, 2, _ROWS_512_4096),
+    "olmo-hybrid-7b-pp2": ("olmo-hybrid-7b-pp2", 2048, 1, 512, 1, _ROWS_512_2048),
+    # layers of two kinds: the full layers' 48 heads over the row bind, not the
+    # window layers' 72 over a ring and the chunk (tests/test_laguna.py)
+    "laguna-s-ep2": ("laguna-s-ep2", 8192, None, 512, 1, _ROWS_512_4096 + list(range(4608, 8193, 512))),
 }
 
 

@@ -555,6 +555,10 @@ _SERVED_CALLS = {
     "qwen3_4b_int4": (8, 32, 8, 128, 2048, "int4"),
     "latent": (64, 64, 1, 576, 2048, "bfloat16"),
     "phi4_row": (12, 40, 10, 128, 4096, "bfloat16"),
+    # laguna-s-ep2's two attentions of one step: query groups of 6 on a row of
+    # 8192 and of 9 on a ring of 512 (the calls above run groups of 4 and 64)
+    "laguna_row_group_6": (7, 48, 8, 128, 8192, "bfloat16"),
+    "laguna_ring_group_9": (10, 72, 8, 128, 512, "bfloat16"),
 }
 
 
@@ -575,7 +579,7 @@ def test_flash_decode_at_the_served_shapes_matches_xla(call, monkeypatch):
     )
     rng = np.random.default_rng(len(call))
     lengths = rng.integers(1, c + 1, size=batch)
-    lengths[:6] = [0, 1, 512, 513, c, 0]
+    lengths[:6] = [0, 1, 512, min(513, c), c, 0]
     lengths[-1] = 0
     lengths[rng.permutation(np.arange(6, batch - 1))[: (batch - 7) // 3]] = 0
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -617,6 +621,10 @@ _SERVED = dict(itemsize=2, capacity=2048)
         ("capacity_1536", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=1536, itemsize=4), 4, (2, 512), 12),
         ("capacity_640", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=640, itemsize=2), 4, (2, 128), 20),
         ("capacity_no_width_divides", dict(kv_heads=2, kv_dim=64, group=4, head_dim=64, capacity=320, itemsize=2), 4, (2, 320), 4),
+        # laguna-s-ep2: 32 rows, groups of 6 on a row of 8192 and of 9 on a ring of
+        # 512, no multiple of 8 either: every K/V head of a row a step as the others
+        ("laguna_row_group_6", dict(kv_heads=8, kv_dim=128, group=6, head_dim=128, capacity=8192, itemsize=2), 32, (8, 512), 512),
+        ("laguna_ring_group_9", dict(kv_heads=8, kv_dim=128, group=9, head_dim=128, capacity=512, itemsize=2), 32, (8, 512), 32),
         # tp=4 hands the kernel a device's 2 local kv heads
         ("two_local_heads", dict(kv_heads=2, kv_dim=128, group=4, head_dim=128, **_SERVED), 4, (2, 512), 16),
     ],
