@@ -242,6 +242,18 @@ class JaxGenerator:
         top_p: float = 1.0,
         templated: bool = False,  # prompts already carry BOS/chat headers
     ) -> list[str]:
+        ids = self.generate_ids(prompts, max_new_tokens, temperature, top_p, templated)
+        return [self.tokenizer.decode(row) for row in ids]
+
+    def generate_ids(
+        self,
+        prompts: list[str],
+        max_new_tokens: int,
+        temperature: float,
+        top_p: float = 1.0,
+        templated: bool = False,
+    ) -> list[list[int]]:
+        """``generate`` before the tokenizer decodes: each prompt's new token ids."""
         import jax
         import jax.numpy as jnp
 
@@ -339,7 +351,7 @@ class JaxGenerator:
                 )
         tokens = jax.device_get(result.tokens).tolist()[:n_real]
         lens = jax.device_get(result.lengths).tolist()[:n_real]
-        return [self.tokenizer.decode(t[:n]) for t, n in zip(tokens, lens)]
+        return [t[:n] for t, n in zip(tokens, lens)]
 
 
 def run_eval(

@@ -288,6 +288,43 @@ class ModelConfig:
         return self.n_layers * (2 if self.block == "shortcut_moe" else 1)
 
     @property
+    def row_attentions(self) -> int:
+        """Attentions one forward makes over a full-length K/V row: one a cache
+        row, and on the sambay block one more a cross pair (each reads the one
+        row the middle layer wrote)."""
+        return self.cache_rows + self.cross_pairs
+
+    @property
+    def sliding_layers(self) -> tuple[bool, ...]:
+        """Which layers ``sliding_pattern`` makes slide, one entry a layer (all
+        False without a ``sliding_window``): the pattern's one owner, read by
+        ``models/llama.py::sliding_layer_flags`` and by the count below."""
+        layers = range(self.n_layers)
+        if not self.sliding_window:
+            return (False,) * self.n_layers
+        if self.sliding_pattern == "even":  # Gemma2: even layers slide
+            return tuple(i % 2 == 0 for i in layers)
+        if self.sliding_pattern == "uniform":  # Mistral-style: all layers slide
+            return (True,) * self.n_layers
+        if self.sliding_pattern.endswith(":1"):  # Gemma3 "5:1": every (N+1)th is global
+            period = int(self.sliding_pattern[:-2]) + 1
+            return tuple((i + 1) % period != 0 for i in layers)
+        raise ValueError(
+            f"Unknown sliding_pattern {self.sliding_pattern!r} "
+            "(want 'even' | 'uniform' | 'N:1')"
+        )
+
+    @property
+    def sliding_row_attentions(self) -> int:
+        """Of ``row_attentions``, those whose window runs over a full-length
+        row: a standard block's sliding layers (under ``layer_types`` a sliding
+        layer keeps a ring and is no row attention; the other blocks' row
+        attentions have no window)."""
+        if self.block != "standard" or self.layer_types is not None or self.mla:
+            return 0
+        return sum(self.sliding_layers)
+
+    @property
     def linear_layers(self) -> int:
         """gated_delta: layers that carry a matrix state; 0 for other blocks."""
         if self.block != "gated_delta":

@@ -600,23 +600,14 @@ def _init_delta_params(rng: jax.Array, config: ModelConfig, dtype) -> Params:
 
 
 def sliding_layer_flags(config: ModelConfig) -> jnp.ndarray:
-    """(n_layers,) bool: which layers use the sliding window. The pattern is
-    an explicit config field so non-Gemma2 window schemes can't silently
-    inherit the even alternation. Shared by forward() and the pipeline (where
-    the flags shard over pp alongside the layer stack)."""
+    """(n_layers,) bool: which layers use the sliding window
+    (``ModelConfig.sliding_layers``: the pattern is an explicit config field so
+    non-Gemma2 window schemes can't silently inherit the even alternation).
+    Shared by forward() and the pipeline (where the flags shard over pp
+    alongside the layer stack)."""
     if not config.sliding_window:
         return jnp.zeros((config.n_layers,), dtype=bool)
-    if config.sliding_pattern == "even":  # Gemma2: even layers slide
-        return jnp.arange(config.n_layers) % 2 == 0
-    if config.sliding_pattern == "uniform":  # Mistral-style: all layers slide
-        return jnp.ones((config.n_layers,), dtype=bool)
-    if config.sliding_pattern.endswith(":1"):  # Gemma3 "5:1": every (N+1)th is global
-        period = int(config.sliding_pattern[:-2]) + 1
-        return (jnp.arange(config.n_layers) + 1) % period != 0
-    raise ValueError(
-        f"Unknown sliding_pattern {config.sliding_pattern!r} "
-        "(want 'even' | 'uniform' | 'N:1')"
-    )
+    return jnp.asarray(config.sliding_layers, dtype=bool)
 
 
 def _scales(kv: tuple[jnp.ndarray, ...]) -> dict[str, jnp.ndarray]:
@@ -1072,7 +1063,8 @@ def forward(
                        prefill_offset=offset — writes this chunk's KV at
                        [offset, offset+S) and attends over the cache, so a
                        long prompt (or a suffix after a reused prefix) feeds
-                       in S-token chunks with O(S·C) attention memory
+                       in S-token chunks with O(S x 512) attention memory
+                       (a column block of scores at a time)
     - decode step:     cache=<filled>, decode=True, S must be 1
 
     ``token_mask`` marks the rows that belong to somebody (None: all of them).

@@ -498,6 +498,51 @@ def decode_attention(
     return out.reshape(batch, num_heads, 1, head_dim)
 
 
+# columns one step of ``cache_prefill_attention``'s walk takes: the engine's
+# smallest chunk cap, which divides every row longer than itself
+PREFILL_BLOCK = 512
+
+
+def prefill_attention_block(capacity: int) -> int:
+    """Columns in one block of ``cache_prefill_attention``'s walk over a row of
+    ``capacity`` columns, from that shape alone: 512 where it divides the row,
+    the whole row where it does not (a row under one block, a capacity the
+    engine does not build). It has to divide: ``lax.dynamic_slice`` clamps a
+    start past the end in silence, and a ragged last block would be folded in
+    twice. The engine's rows are powers of two up to the chunk cap (512 or a
+    multiple) and the cap's multiples after
+    (``serve/engine.py::row_capacities``), so 512 divides every row that is
+    longer than a block. On the v5e 512 keeps a chunk's score tile on the chip
+    at a wave of one (48 heads x 512 x 512 of float32 is 50 MB) and is as fast
+    as 1024 there and faster than 256; at 2048 columns and more the tile goes
+    through HBM and a call takes two to three times as long (PERF.md, PR 43)."""
+    return capacity if capacity % PREFILL_BLOCK else PREFILL_BLOCK
+
+
+def prefill_block_span(first_offset, last_offset, seq, block, window=0):
+    """[first, last) blocks of ``block`` columns that hold a column some query
+    of a chunk of ``seq`` sees, its rows starting at ``first_offset`` ..
+    ``last_offset``: none past the last row's last query, none wholly behind
+    the first row's ``window`` where one applies. Operators alone (the clamp at
+    0 too), so the walk's traced bounds and the host's count of them
+    (``prefill_columns_visited``) are these same lines."""
+    last = (last_offset + seq + block - 1) // block
+    if not window:
+        return 0, last
+    behind = first_offset - window + 1
+    return (behind * (behind > 0)) // block, last
+
+
+def prefill_columns_visited(offset: int, seq: int, capacity: int, window: int = 0) -> int:
+    """Columns of a row of ``capacity`` that one call of
+    ``cache_prefill_attention`` visits with a chunk of ``seq`` queries at
+    ``offset``, on the host: the walk's trip count x its block. ``window``: the
+    call's window where it applies (a layer that slides)."""
+    block = prefill_attention_block(capacity)
+    first, last = prefill_block_span(offset, offset, seq, block, window)
+    return (last - first) * block
+
+
 def cache_prefill_attention(
     q: jnp.ndarray,          # (B, H, S, D) queries for a prompt CHUNK
     k_cache: jnp.ndarray,    # ([L,] B, KH, D, C) feature-major, chunk already written
@@ -513,63 +558,113 @@ def cache_prefill_attention(
     layer: jnp.ndarray | int = 0,        # () layer of a stacked cache to attend over
 ) -> jnp.ndarray:
     """Attention for chunked prefill: the chunk's K/V are first *written* into
-    the cache at ``offset``, then each chunk query attends over the whole
-    cache with the mask ``slot < offset + q_index + 1`` — causal within the
+    the cache at ``offset``, then each chunk query attends over the cache
+    with the mask ``slot < offset + q_index + 1`` — causal within the
     chunk, full visibility of everything before it (earlier chunks, a reused
     prefix). One code path serves chunk 0 (offset 0 ≡ plain causal) and every
     later chunk, so chunked prefill composes with prefix caching for free.
 
+    The row is walked in column blocks (``prefill_attention_block``) under a
+    running softmax: float32 running maximum, sum and output accumulator, as
+    the kernels keep them (``pallas_attention._finalize_attention``), one
+    rolled loop whose bounds are traced. It visits the blocks that hold a
+    column some query sees (``prefill_block_span``): up to the chunk's own
+    last column, and from the window's first where one applies. A chunk holds
+    S x block scores at a time, never S x C, and the columns past the chunk
+    are neither scored nor weighed. The layer comes out of the stacked leaf
+    once, before the loop (``_layer_of``), and a step slices its block out of
+    that: a block sliced from the stacked leaf inside the loop carries the
+    einsum's operand layout up into the buffer the layer scan carries, and
+    XLA re-lays the whole stacked K leaf, a copy in and a copy out of every
+    chunk prefill (compiler, PR 43; the barrier on the slice does not stop it
+    across the loop's boundary).
+
     Grouped-einsum GQA like the decode path — the cache is never materialized
-    per-query-head. O(S·C) scores per chunk keeps peak memory bounded for
-    long prompts (vs O(S_total²) for one-shot prefill).
+    per-query-head.
     """
-    k_cache, v_cache = _layer_of(k_cache, layer), _layer_of(v_cache, layer)
+    shared = v_cache is k_cache  # one latent serves as keys and values (models/mla.py)
+    k_cache = _layer_of(k_cache, layer)
+    v_cache = k_cache if shared else _layer_of(v_cache, layer)
     k_scale, v_scale = _layer_of(k_scale, layer), _layer_of(v_scale, layer)
     batch, num_heads, seq, head_dim = q.shape
-    kv_heads = k_cache.shape[1]
+    kv_heads, capacity = k_cache.shape[-3], k_cache.shape[-1]
     group = num_heads // kv_heads
+    block = prefill_attention_block(capacity)
     qg = q.reshape(batch, kv_heads, group, seq, head_dim)
     if k_scale is not None:
-        # int8 cache: per-slot scales are constant over the contracted d axis,
-        # so dequant folds into the score epilogue exactly (decode path's
-        # scheme; k_scale (B, KH, 1, C) broadcasts over the g and s dims)
-        scores = jnp.einsum(
-            "bkgsd,bkdc->bkgsc",
-            qg.astype(jnp.float32),
-            k_cache.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        ) * (k_scale[:, :, None, :, :] * sm_scale)
-    else:
-        scores = (
-            jnp.einsum("bkgsd,bkdc->bkgsc", qg, k_cache, preferred_element_type=jnp.float32)
-            * sm_scale
-        )
-    scores = _apply_softcap(scores, softcap)
-    capacity = k_cache.shape[3]
+        qg = qg.astype(jnp.float32)
     # offset () = one shared chunk start; (B,) = per-sequence starts (the
     # speculative verify window sits at each row's own cache length)
     offset_b = jnp.reshape(offset.astype(jnp.int32), (-1,))
-    slot_ids = jnp.arange(capacity)[None, None, :]            # (1, 1, C)
     q_pos = offset_b[:, None, None] + jnp.arange(seq)[None, :, None]  # (B|1, S, 1)
-    visible = slot_ids < q_pos + 1                            # (B|1, S, C)
-    if window:
-        visible = visible & _window_ok(q_pos - slot_ids, window, sliding)
-    scores = jnp.where(visible[:, None, None], scores, NEG_INF)
+    first, last = prefill_block_span(jnp.min(offset_b), jnp.max(offset_b), seq, block, window)
+    first = jnp.asarray(first, jnp.int32)  # both bounds traced int32: the loop is one ``while``
+    if window and sliding is not None:
+        first = jnp.where(sliding, first, 0)  # the layer's traced flag: a full layer starts at 0
+
+    def columns(leaf, start):
+        """(B, KH, *, block) of one layer's ``leaf`` at [start, start + block)."""
+        return jax.lax.dynamic_slice_in_dim(leaf, start, block, axis=3)
+
+    def fold(i, carry):
+        m, l, acc = carry
+        start = i * block
+        k_blk = columns(k_cache, start)
+        v_blk = k_blk if shared else columns(v_cache, start)
+        if k_scale is not None:
+            # int8 cache: per-slot scales are constant over the contracted d axis,
+            # so dequant folds into the score epilogue exactly (decode path's
+            # scheme; k_scale (B, KH, 1, block) broadcasts over the g and s dims)
+            scores = jnp.einsum(
+                "bkgsd,bkdc->bkgsc", qg, k_blk.astype(jnp.float32),
+                preferred_element_type=jnp.float32,
+            ) * (columns(k_scale, start)[:, :, None, :, :] * sm_scale)
+        else:
+            scores = (
+                jnp.einsum("bkgsd,bkdc->bkgsc", qg, k_blk, preferred_element_type=jnp.float32)
+                * sm_scale
+            )
+        scores = _apply_softcap(scores, softcap)
+        slot_ids = start + jnp.arange(block)[None, None, :]       # (1, 1, block)
+        visible = slot_ids < q_pos + 1                            # (B|1, S, block)
+        if window:
+            visible = visible & _window_ok(q_pos - slot_ids, window, sliding)
+        scores = jnp.where(visible[:, None, None], scores, NEG_INF)
+        # a query that has seen nothing yet folds its masked columns in at
+        # weight 1; the first block it does see scales them away (alpha 0)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(scores - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if v_scale is not None:
+            weighted = (p * columns(v_scale, start)[:, :, None, :, :]).astype(jnp.float32)
+            pv = jnp.einsum(
+                "bkgsc,bkdc->bkgsd", weighted, v_blk.astype(jnp.float32),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            pv = jnp.einsum(
+                "bkgsc,bkdc->bkgsd", p.astype(q.dtype), v_blk,
+                preferred_element_type=jnp.float32,
+            )
+        return m_new, l, acc * alpha + pv
+
+    rows = (batch, kv_heads, group, seq)
+    m, l, acc = jax.lax.fori_loop(
+        first, last, fold,
+        (
+            jnp.full((*rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((*rows, 1), jnp.float32),
+            jnp.zeros((*rows, v_cache.shape[-2]), jnp.float32),
+        ),
+    )
     if sinks is not None:
-        probs = _sink_softmax(
-            scores, sinks.astype(jnp.float32).reshape(1, kv_heads, group, 1, 1)
-        )
-    else:
-        probs = jax.nn.softmax(scores, axis=-1)
-    if v_scale is not None:
-        weighted = (probs * v_scale[:, :, None, :, :]).astype(jnp.float32)
-        out = jnp.einsum(
-            "bkgsc,bkdc->bkgsd", weighted, v_cache.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        ).astype(q.dtype)
-    else:
-        out = jnp.einsum("bkgsc,bkdc->bkgsd", probs.astype(q.dtype), v_cache)
-    return out.reshape(batch, num_heads, seq, head_dim)
+        # the sink joins the denominator once (``_sink_softmax``'s arithmetic)
+        sink = sinks.astype(jnp.float32).reshape(1, kv_heads, group, 1, 1)
+        m_final = jnp.maximum(m, sink)
+        scale = jnp.exp(m - m_final)
+        acc, l = acc * scale, l * scale + jnp.exp(sink - m_final)
+    return (acc / l).astype(q.dtype).reshape(batch, num_heads, seq, -1)
 
 
 def multi_head_attention(

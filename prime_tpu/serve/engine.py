@@ -235,7 +235,11 @@ def prefill_shapes(
     at 512 x 2048: 4 rows; 32 heads: 8). ``max_wave`` asks for narrower waves
     (a power of two, rounded down): the warm-up builds a chunk prefill and a
     finalize a row capacity AND width, so a deployment whose programs are slow
-    to build or large to keep starts sooner on fewer widths.
+    to build or large to keep starts sooner on fewer widths. (Since PR 43 a
+    full layer's scores are a column block at a time,
+    ``ops/attention.py::cache_prefill_attention``, so the term prices a tensor
+    that is gone and the rule is conservative; it stands so that every
+    configuration keeps its chunks, waves and warm-up set: PERF.md section 7.)
 
     **Then length**, where nobody gave one. A chunk-prefill program reads
     every held expert's weights once whatever its length, and a dropless
@@ -1307,6 +1311,16 @@ class ContinuousBatchingEngine:
             "Token positions the chunk-prefill programs carried, by kind: prompt "
             "(a request's own token) | pad (the fill of a last chunk, or of a "
             "shorter member of the wave)",
+            labelnames=("kind",),
+        )
+        # a chunk's attention walks its row in column blocks and stops at the
+        # last one it can see (ops/attention.py::cache_prefill_attention):
+        # visited / (visited + spared) is the share of the rows it scored
+        self._m_prefill_attn_columns = r.counter(
+            "serve_prefill_attn_columns_total",
+            "Row columns of the chunk-prefill programs' attentions, wave rows x "
+            "layers that attend over a row x chunks, by kind: visited (in a block "
+            "the walk scored) | spared (the rest of the row's capacity)",
             labelnames=("kind",),
         )
         self._m_ring_wraps = r.counter(
@@ -2812,6 +2826,9 @@ class ContinuousBatchingEngine:
             self._m_prefill_chunk_tokens.inc(
                 n * sum(size for _, size in plan) - real_tokens, kind="pad"
             )
+            visited, spared = self._prefill_columns(n, row_cb, plan)
+            self._m_prefill_attn_columns.inc(visited, kind="visited")
+            self._m_prefill_attn_columns.inc(spared, kind="spared")
             # a chunk computes the cross half at one position a row
             # (last_positions), every other layer at each real token
             cross_half = self.config.cross_half_layers
@@ -2895,6 +2912,26 @@ class ContinuousBatchingEngine:
                 self._active[slot] = True
                 self._requests[slot] = req
                 self._emit(req, [first])
+
+    def _prefill_columns(
+        self, rows: int, row_capacity: int, plan: list[tuple[int, int]]
+    ) -> tuple[int, int]:
+        """(visited, spared) row columns of a plan's chunk prefills over a wave of
+        ``rows``: what ``cache_prefill_attention``'s walk scores of each row
+        (``prefill_columns_visited``: its trip count x its block, from the
+        chunk's offset and length and the row's capacity) and the rest of the
+        row, a layer that attends over a row; those that slide over a row
+        (``ModelConfig.sliding_row_attentions``) start at their window."""
+        from prime_tpu.ops.attention import prefill_columns_visited
+
+        config, sliding = self.config, self.config.sliding_row_attentions
+        visited = sum(
+            (config.row_attentions - sliding) * prefill_columns_visited(off, size, row_capacity)
+            + sliding * prefill_columns_visited(off, size, row_capacity, config.sliding_window)
+            for off, size in plan
+        )
+        offered = config.row_attentions * len(plan) * row_capacity
+        return rows * visited, rows * (offered - visited)
 
     def _state_programs(self):
         """(snapshot, restore), built on first use: a wave member's state leaves

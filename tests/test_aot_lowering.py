@@ -430,6 +430,43 @@ def test_decode_keeps_the_cache_in_place(topo, n_layers, masked):
     assert "tpu_custom_call" in compiled.as_text()  # the kernel is in the program
 
 
+# (queries, stacked K/V leaf, one leaf for K and V, bytes of temporaries the parent's
+# dense form held): the full layer's chunk prefill of the benchmark's configurations
+_PREFILL_ATTENTIONS = {
+    "laguna_48_over_8_heads_512_x_8192": ((1, 48, 512, 128), (2, 1, 8, 128, 8192), False, 1.61e9),
+    "qwen3_4b_wave_of_8_512_x_2048": ((8, 32, 512, 128), (36, 8, 8, 128, 2048), False, 1.07e9),
+    "qwen3_next_16_over_2_heads_2048_x_4096": ((1, 16, 2048, 256), (12, 1, 2, 256, 4096), False, 1.07e9),
+    "longcat_64_over_one_latent_512_x_2048": ((4, 64, 512, 576), (56, 4, 1, 576, 2048), True, 2.15e9),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_PREFILL_ATTENTIONS))
+def test_chunk_prefill_attention_walks_its_row_in_one_rolled_loop(topo, shape):
+    """``cache_prefill_attention`` at a served shape, for a v5e: one ``while``
+    whose body scores one column block (no Python loop over blocks: one body a
+    program), no float32 value over chunk x row capacity anywhere in the text,
+    no copy of the stacked leaf, and fewer temporaries than the dense form held
+    (compiler, PR 43: 1.61 GB at Laguna's 48 heads over 512 x 8192, where the
+    walk's tile stays on the chip)."""
+    from prime_tpu.ops.attention import cache_prefill_attention, prefill_attention_block
+
+    q, kv, shared, dense_temporaries = _PREFILL_ATTENTIONS[shape]
+    seq, capacity = q[2], kv[-1]
+    assert capacity % prefill_attention_block(capacity) == 0
+
+    def attend(q, k, v, offset, layer):
+        return cache_prefill_attention(q, k, k if shared else v, offset, 0.08, layer=layer)
+
+    leaf = (kv, jnp.bfloat16)
+    compiled = _compile(topo, attend, (q, jnp.bfloat16), leaf, leaf, ((), jnp.int32), ((), jnp.int32))
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert not re.findall(rf"f32\[[0-9,]*{seq},{capacity}\]", text)
+    stacked = "bf16[" + ",".join(map(str, kv)) + "]"
+    assert not [r for r in _results(compiled, "copy") if r.startswith(stacked)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * dense_temporaries
+
+
 @pytest.mark.parametrize("n_layers", [2, 4])
 def test_chunked_prefill_copies_no_staging_row(topo, n_layers):
     """The engine's chunk prefill (one staging row, donated): its attention
@@ -923,8 +960,10 @@ def test_laguna_decode_fits_the_chip_with_rows_and_rings_in_place(topo, monkeypa
 def test_laguna_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
     """The widest chunk prefill the rule from shapes gives (512 tokens, a wave of
     one) over a staging row of 8192 columns and its three rings: weights, row and
-    temporaries (0.8 GB of scores a full layer, the experts' row buffers) under
-    the 15.75 GB the chip gives less the 2.35 GB the engine's own slots hold."""
+    temporaries (the experts' row buffers; a full layer's scores are a column
+    block at a time and the 0.8 GB tensor over the whole row is gone: 1.67 GB of
+    temporaries before the walk, 0.21 after; compiler, PR 43) under the 15.75 GB
+    the chip gives less the 2.35 GB the engine's own slots hold."""
     from prime_tpu.serve.engine import prefill_shapes
 
     _chip_takes_the_kernels(monkeypatch)
@@ -936,6 +975,8 @@ def test_laguna_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
         ((1,), jnp.int32), donate=(1,),
     )
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 1.8e9
+    assert memory.temp_size_in_bytes < 0.4e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 - 2.35e9
-    assert f"bf16[{512 * 10},3072]" in compiled.as_text()  # all of the chunk's rows, sorted once
+    text = compiled.as_text()
+    assert f"bf16[{512 * 10},3072]" in text  # all of the chunk's rows, sorted once
+    assert not re.findall(r"f32\[[0-9,]*512,8192\]", text)  # no scores over the whole row

@@ -201,15 +201,48 @@ def test_spec_sampled_requires_rng(samp_params):
         )
 
 
-def test_jax_generator_speculative_matches_plain():
+def _ulps_under_the_best(generator, prompt, ids):
+    """How far under the model's best logit each token of ``ids`` lay where it
+    was chosen, in bfloat16 ulps of that best logit: one causal forward over the
+    prompt and ``ids`` themselves, in the generator's own dtype."""
+    from prime_tpu.models.llama import forward
+
+    start = generator.tokenizer.encode(prompt, add_special_tokens=True)
+    logits, _ = forward(generator.params, jnp.asarray([start + ids], jnp.int32), generator.config)
+    rows = np.asarray(logits[0, len(start) - 1 : len(start) - 1 + len(ids)], np.float32)
+    best = rows.max(axis=-1)
+    ulp = 2.0 ** (np.floor(np.log2(np.abs(best))) - 7)
+    return (best - rows[np.arange(len(ids)), ids]) / ulp
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_jax_generator_speculative_matches_plain(dtype):
+    """float32: speculation emits plain decode's tokens, to the token. bfloat16:
+    the verify window (``cache_prefill_attention`` at per-row offsets, which
+    rounds P before it divides by the sum) and the decode step (which divides
+    first) are two programs, and where the model's best two logits lie within
+    one bfloat16 ulp the argmax may fall either way. So the two agree up to the
+    first such place, and every token either emits lies within one ulp of the
+    model's best logit over that sequence's own prefix: a wrong mask or offset
+    in the window's walk picks tokens far under the best and fails this."""
     from prime_tpu.evals.runner import JaxGenerator
 
-    plain = JaxGenerator("tiny-test")
-    spec = JaxGenerator("tiny-test", speculative=True, draft_len=4)
+    plain = JaxGenerator("tiny-test", dtype=dtype)
+    spec = JaxGenerator("tiny-test", speculative=True, draft_len=4, dtype=dtype)
     prompts = ["12+34=46 12+34=", "hello hello hello "]
-    a = plain.generate(prompts, max_new_tokens=12, temperature=0.0)
-    b = spec.generate(prompts, max_new_tokens=12, temperature=0.0)
-    assert a == b
+    a = plain.generate_ids(prompts, max_new_tokens=12, temperature=0.0)
+    b = spec.generate_ids(prompts, max_new_tokens=12, temperature=0.0)
+    if dtype == jnp.float32:
+        assert a == b
+        assert plain.generate(prompts, 12, 0.0) == spec.generate(prompts, 12, 0.0)
+        return
+    vocab = plain.config.vocab_size
+    for prompt, x, y in zip(prompts, a, b):
+        assert len(x) == len(y) == 12
+        for ids in (x, y):
+            assert _ulps_under_the_best(plain, prompt, ids).max() <= 1.0, (prompt, x, y)
+        # the yardstick has teeth: tokens that are not the model's lie far under
+        assert _ulps_under_the_best(plain, prompt, [(t + 1) % vocab for t in y]).max() > 8.0
 
 
 def test_spec_kv_quant_matches_plain_kv_quant(params):
