@@ -18,9 +18,11 @@ reference model computes every token-expert pair, so a capacity drop there is
 left-out mathematics. Pairs are sorted by expert and the expert products run
 as grouped matmuls over the sorted rows (megablox ``gmm`` on TPU, which skips
 row tiles no group owns, so work follows the pairs routed here and not
-tokens x experts; ``jax.lax.ragged_dot`` elsewhere). The layer is told which
-experts it holds: it routes over the router's full width and computes only
-its own experts' part, plus the zero-compute experts' ``gate * input``.
+tokens x experts, at a tile that holds the whole contraction, so each touched
+expert's weights are read once; ``jax.lax.ragged_dot`` elsewhere). The layer
+is told which experts it holds: it routes over the router's full width and
+computes only its own experts' part, plus the zero-compute experts'
+``gate * input``.
 """
 
 from __future__ import annotations
@@ -266,10 +268,19 @@ MOE_ROW_TILE = 32  # rows a grouped-matmul tile holds (the kernel's tm)
 # each that or narrower, all linear in tokens (the training einsum's dispatch,
 # MOE_GROUP_SIZE's, is quadratic)
 MOE_ROW_BUFFER_BYTES = 256 * 2**20
+# what the two buffers of a grouped product's weight tile may take of VMEM. The
+# kernel takes no limit of its own, so the default scoped 16 MiB decides, and the
+# row tile, the output tile and the accumulator lie beside the weights (compiled
+# for the v5e at every served shape: tests/test_aot_lowering.py)
+GMM_WEIGHT_VMEM_BYTES = 12 * 2**20
 # what moe_infer counts, in the order of its stats vector: the token-expert
 # pairs by where they went, the held experts that had a token, the rows the
-# grouped products computed, and the (layer x token-group) calls counted
-MOE_STATS = ("local", "zero", "elsewhere", "experts_touched", "rows_computed", "calls")
+# grouped products computed, the (layer x token-group) calls counted, and the
+# times the three grouped products fetched an expert's matrix (``weight_reads``:
+# 3 x experts_touched where each product's contraction is one tile)
+MOE_STATS = (
+    "local", "zero", "elsewhere", "experts_touched", "rows_computed", "calls", "weight_reads",
+)
 
 
 def select_experts(
@@ -309,6 +320,25 @@ def _kernel_path() -> tuple[bool, bool]:
     return interpret or jax.default_backend() == "tpu", interpret
 
 
+def grouped_matmul_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """The kernel's (tm, tk, tn) for weights (G, k, n). The products are bound by
+    reading each touched expert's weights, and the kernel's weight block is
+    ``(group, k_i, n_i)`` on a grid ``(n tiles, row-tile visits, k tiles)``: with
+    the contraction in ONE tile a group's consecutive visits keep their block
+    and the copy is skipped, so an expert crosses HBM once however many row
+    tiles its group spans or straddles; with more, every visit fetches the
+    expert again. So ``tk = k`` wherever two buffers of a ``(k, tn)`` tile fit
+    ``GMM_WEIGHT_VMEM_BYTES``, and ``tn`` is the widest multiple of 128 dividing
+    ``n`` that fits; only a contraction too long for the narrowest tile is
+    walked in tiles."""
+    tile = GMM_WEIGHT_VMEM_BYTES // 2 // itemsize  # elements of one weight tile
+    widths = [w for w in range(128, n + 1, 128) if n % w == 0] or [n]
+    fits = [w for w in widths if k * w <= tile]
+    if fits:
+        return MOE_ROW_TILE, k, fits[-1]
+    return MOE_ROW_TILE, max(128, tile // widths[0] // 128 * 128), widths[0]
+
+
 def grouped_matmul(
     rows: jnp.ndarray,         # (M, K) sorted by group; M a multiple of MOE_ROW_TILE
     weights: jnp.ndarray,      # (G, K, N), or a layer stack (L, G, K, N) with ``layer``
@@ -335,10 +365,7 @@ def grouped_matmul(
             jnp.zeros((layers * held,), jnp.int32), group_sizes, (layer * held,)
         )
 
-    k, n = weights.shape[1], weights.shape[2]
-    # weight tiles as large as VMEM comfortably double-buffers: the products
-    # are bound by reading each touched expert's weights once
-    tiling = (MOE_ROW_TILE, min(k, 1024), min(n, 1024))
+    tiling = grouped_matmul_tiling(*weights.shape[1:], weights.dtype.itemsize)
     return gmm(
         rows, weights, group_sizes, preferred_element_type=rows.dtype,
         tiling=tiling, interpret=interpret,
@@ -364,6 +391,29 @@ def _expert_rows(rows, expert_of_row, w_gate, w_up, w_down, group_sizes, layer,
     if b_down is not None:
         out = out + b_down[expert_of_row].astype(out.dtype)
     return out
+
+
+def tile_aligned_groups(tokens: int, k: int, router_width: int) -> bool:
+    """Whether ``moe_infer`` lays each group's rows from a multiple of
+    ``MOE_ROW_TILE``, from the shapes alone: where, under even routing, a token
+    group gives an expert ``tokens x k / router_width`` rows, half a tile or
+    more. A group then takes ``ceil(rows / tile)`` visits of the kernel where a
+    group laid wherever the one before ended overlaps ``1 + (rows - 1) / tile``
+    on average; the price is a row buffer that holds a tile less a row more for
+    every held expert. A decode step's groups of one to three rows seldom
+    straddle a tile and its buffer would grow severalfold, so it stays dense."""
+    return 2 * tokens * k >= router_width * MOE_ROW_TILE
+
+
+def row_budget(tokens: int, k: int, held: int, router_width: int) -> int:
+    """Rows of ``moe_infer``'s sorted buffer for a group of ``tokens``: the most
+    pairs the held experts can get, in whole row tiles, and in the tile-aligned
+    layout a tile less a row more for every group that can be non-empty."""
+    pairs = tokens * min(k, held)
+    rows = -(-pairs // MOE_ROW_TILE) * MOE_ROW_TILE
+    if tile_aligned_groups(tokens, k, router_width):
+        rows += min(held, pairs) * (MOE_ROW_TILE - 1) // MOE_ROW_TILE * MOE_ROW_TILE
+    return rows
 
 
 def infer_group_size(rows_per_token: int, d_model: int, itemsize: int) -> int:
@@ -411,7 +461,9 @@ def moe_infer(
     are sorted by expert into ``tokens * min(k, held)`` rows, which holds the
     worst case, and the grouped matmul computes only the row tiles a group
     owns, so the work follows the pairs (padded to ``MOE_ROW_TILE``), not
-    the row budget. Tokens go through in groups of ``group_size`` so that a
+    the row budget. Where ``tile_aligned_groups`` says so every group starts
+    on a row tile, and the budget holds a tile less a row more for each held
+    expert. Tokens go through in groups of ``group_size`` so that a
     long prefill's row buffers stay bounded, and every group reads the touched
     experts' weights once: by default a group is as long as
     ``MOE_ROW_BUFFER_BYTES`` allows (``infer_group_size``), so that a chunk of
@@ -433,7 +485,15 @@ def moe_infer(
     if pad:
         x_flat = jnp.concatenate([x_flat, jnp.zeros((pad, d_model), x.dtype)])
         mask = jnp.concatenate([mask, jnp.zeros((pad,), bool)])
-    rows_m = -(-group * per_token // MOE_ROW_TILE) * MOE_ROW_TILE
+    aligned = tile_aligned_groups(group, k, router_w.shape[-1])
+    rows_m = row_budget(group, k, held, router_w.shape[-1])
+    # the products whose contraction is one tile fetch a touched expert once,
+    # the others once a row-tile visit (``grouped_matmul_tiling``)
+    d_ff = w_gate.shape[-1]
+    once = sum(
+        grouped_matmul_tiling(a, b, w_gate.dtype.itemsize)[1] == a
+        for a, b in ((d_model, d_ff), (d_model, d_ff), (d_ff, d_model))
+    )
 
     def one_group(xg, mg):
         # float32 all the way: the TPU's default rounds a float32 matmul's
@@ -456,39 +516,59 @@ def moe_infer(
         y = (jnp.sum(jnp.where(zero, gates, 0.0), axis=-1)[:, None] * xg).astype(x.dtype)
         # sort the pairs by held expert; pairs that are not local sort last
         key = jnp.where(local, experts - first_expert, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        spare = max(0, rows_m - key.size)  # the row budget is a whole tile
-        sorted_key = jnp.pad(key[order], (0, spare), constant_values=held)[:rows_m]
-        token_of_row = jnp.pad(order // k, (0, spare))[:rows_m]
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
         )
-        out = _expert_rows(
-            xg[token_of_row], jnp.minimum(sorted_key, held - 1), w_gate, w_up, w_down,
-            group_sizes, layer, b_gate, b_up, b_down, glu_clamp, glu_alpha,
+        spans = group_sizes
+        if aligned:
+            # each group's rows start on a row tile: spare rows sorted in at
+            # every group's end fill its last tile, and the kernel is given the
+            # sizes rounded up to whole tiles, so a group of s rows is
+            # ceil(s / tile) visits and none of them shared with a neighbour
+            spare = (-group_sizes) % MOE_ROW_TILE
+            spans = group_sizes + spare
+            filler = jnp.where(
+                jnp.arange(MOE_ROW_TILE - 1)[None, :] < spare[:, None],
+                jnp.arange(held)[:, None], held,
+            )
+            key = jnp.concatenate([key, filler.reshape(-1)])
+        order = jnp.argsort(key, stable=True)
+        short = max(0, rows_m - key.size)  # the row budget is a whole tile
+        expert_of_row = jnp.minimum(
+            jnp.pad(key[order], (0, short), constant_values=held)[:rows_m], held - 1
         )
-        # back to (token, pick) order by the inverse permutation; a pair that
-        # is not local has no row (or an uncomputed one) and is masked
-        inverse = jnp.argsort(order)
-        picked = out[jnp.minimum(inverse, rows_m - 1)].reshape(group, k, d_model)
+        token_of_row = order // k
+        if aligned:  # a spare row carries the group's last token: computed, never picked
+            token_of_row = jnp.minimum(token_of_row, group - 1)
+        token_of_row = jnp.pad(token_of_row, (0, short))[:rows_m]
+        row_of_pair = jnp.argsort(order)[: group * k]
+        out = _expert_rows(
+            xg[token_of_row], expert_of_row, w_gate, w_up, w_down,
+            spans, layer, b_gate, b_up, b_down, glu_clamp, glu_alpha,
+        )
+        # back to (token, pick) order by each pair's row; a pair that is not
+        # local has no row (or an uncomputed one) and is masked
+        picked = out[jnp.minimum(row_of_pair, rows_m - 1)].reshape(group, k, d_model)
         weight = jnp.where(local, gates, 0.0).astype(jnp.float32)
         y = y + jnp.sum(
             jnp.where(local[..., None], picked.astype(jnp.float32), 0.0) * weight[..., None],
             axis=1,
         ).astype(x.dtype)
         # what ran, as counts: the pairs by where they went, the held experts
-        # with a token, and the rows the grouped products computed (tiles a
-        # non-empty group overlaps x MOE_ROW_TILE)
-        ends = jnp.cumsum(group_sizes)
-        starts = ends - group_sizes
-        tiles = jnp.where(
+        # with a token, the rows the grouped products computed (tiles a
+        # non-empty group overlaps x MOE_ROW_TILE), and the expert matrices
+        # the three products fetched
+        starts = jnp.cumsum(spans) - spans
+        ends = starts + group_sizes
+        visits = jnp.sum(jnp.where(
             group_sizes > 0,
             -(-ends // MOE_ROW_TILE) - starts // MOE_ROW_TILE, 0,
-        )
+        ))
         n_local, n_zero_picks = jnp.sum(local), jnp.sum(zero)
+        touched = jnp.sum(group_sizes > 0)
         stats = jnp.stack([
             n_local, n_zero_picks, jnp.sum(live) * k - n_local - n_zero_picks,
-            jnp.sum(group_sizes > 0), jnp.sum(tiles) * MOE_ROW_TILE, 1,
+            touched, visits * MOE_ROW_TILE, 1, once * touched + (3 - once) * visits,
         ]).astype(jnp.int32)
         return y, stats
 

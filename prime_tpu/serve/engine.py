@@ -1270,6 +1270,12 @@ class ContinuousBatchingEngine:
             "Rows the grouped expert products computed (row tiles a non-empty group overlaps)",
             labelnames=("phase",),
         )
+        self._m_moe_weight_reads = r.counter(
+            "serve_moe_weight_reads_total",
+            "Expert matrices the grouped products fetched (3 x experts touched where "
+            "every product's contraction is one tile; a row-tile visit each otherwise)",
+            labelnames=("phase",),
+        )
         self._m_moe_calls = r.counter(
             "serve_moe_layer_calls_total",
             "Expert-layer calls (layers x token groups x steps) the counts above sum over",
@@ -1731,6 +1737,7 @@ class ContinuousBatchingEngine:
             self._m_moe_pairs.inc(counts[kind], kind=kind, phase=phase)
         self._m_moe_touched.inc(counts["experts_touched"], phase=phase)
         self._m_moe_rows.inc(counts["rows_computed"], phase=phase)
+        self._m_moe_weight_reads.inc(counts["weight_reads"], phase=phase)
         self._m_moe_calls.inc(counts["calls"], phase=phase)
 
     def _make_spec_decode(self):

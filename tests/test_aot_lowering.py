@@ -491,21 +491,37 @@ def test_chunked_prefill_copies_no_staging_row(topo, n_layers):
     assert not [r for r in _results(compiled, "copy") if r.startswith(stacked)]
 
 
-def test_grouped_matmul_lowers(topo, monkeypatch):
-    """The dropless expert layer's grouped matmul (megablox) at LongCat-Flash's
-    widths: a decode step's 768 rows against the whole (4, 16, 6144, 2048)
+# configuration: (slots of the served engine, tokens of its widest prompt chunk's group)
+_EXPERT_LAYERS = {
+    "longcat-flash-ep32": (64, 1024), "laguna-s-ep2": (32, 512), "qwen3-next-ep2": (128, 2048),
+}
+
+
+@pytest.mark.parametrize("preset", sorted(_EXPERT_LAYERS))
+def test_grouped_matmul_lowers(topo, monkeypatch, preset):
+    """The dropless expert layer's grouped matmul (megablox) at the three expert
+    configurations' widths and the tile ``grouped_matmul_tiling`` gives them (the
+    whole contraction, as wide as 12 MiB of VMEM double-buffer): a decode step's
+    rows and a prompt chunk's (tile-aligned) against the whole (4, held, k, n)
     expert stack and a traced layer."""
+    from prime_tpu.models import get_config
     from prime_tpu.ops import moe
 
     monkeypatch.setattr(moe, "_kernel_path", lambda: (True, False))  # the CPU backend would take ragged_dot
-    for k, n in ((6144, 2048), (2048, 6144)):
-        compiled = _compile(
-            topo, moe.grouped_matmul, ((768, k), jnp.bfloat16), ((4, 16, k, n), jnp.bfloat16),
-            ((16,), jnp.int32), ((), jnp.int32),
-        )
-        assert "tpu_custom_call" in compiled.as_text()
-        # the stack goes to the kernel as it is: no copy of a layer's experts
-        assert compiled.memory_analysis().temp_size_in_bytes < 16 * k * n
+    config = get_config(preset)
+    held, top_k, width = config.experts_held, config.experts_per_token, config.router_width
+    budgets = [moe.row_budget(tokens, top_k, held, width) for tokens in _EXPERT_LAYERS[preset]]
+    assert budgets[0] == -(-_EXPERT_LAYERS[preset][0] * min(top_k, held) // 32) * 32 < budgets[1]
+    for k, n in ((config.d_model, config.d_ff), (config.d_ff, config.d_model)):
+        assert moe.grouped_matmul_tiling(k, n, 2)[1] == k
+        for rows in budgets:
+            compiled = _compile(
+                topo, moe.grouped_matmul, ((rows, k), jnp.bfloat16), ((4, held, k, n), jnp.bfloat16),
+                ((held,), jnp.int32), ((), jnp.int32),
+            )
+            assert "tpu_custom_call" in compiled.as_text()
+            # the stack goes to the kernel as it is: no copy of a layer's experts
+            assert compiled.memory_analysis().temp_size_in_bytes < held * k * n
 
 
 def test_shortcut_block_decode_reads_its_weights_in_place(topo, monkeypatch):
@@ -964,6 +980,7 @@ def test_laguna_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
     block at a time and the 0.8 GB tensor over the whole row is gone: 1.67 GB of
     temporaries before the walk, 0.21 after; compiler, PR 43) under the 15.75 GB
     the chip gives less the 2.35 GB the engine's own slots hold."""
+    from prime_tpu.ops import moe
     from prime_tpu.serve.engine import prefill_shapes
 
     _chip_takes_the_kernels(monkeypatch)
@@ -978,5 +995,11 @@ def test_laguna_chunk_prefill_fits_beside_the_engines_cache(topo, monkeypatch):
     assert memory.temp_size_in_bytes < 0.4e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 - 2.35e9
     text = compiled.as_text()
-    assert f"bf16[{512 * 10},3072]" in text  # all of the chunk's rows, sorted once
+    # all of the chunk's rows, sorted once, every group from a row tile: the 5120
+    # the pairs may take and a tile less a row for each of the 128 held experts
+    assert moe.row_budget(512, 10, 128, 256) == 512 * 10 + 128 * 31
+    assert f"bf16[{512 * 10 + 128 * 31},3072]" in text
+    # the grouped products read the expert stacks in place: no second copy
+    for leaf in ("bf16[4,128,3072,1024]", "bf16[4,128,1024,3072]"):
+        assert not [r for r in _results(compiled, "copy") if r.startswith(leaf)], leaf
     assert not re.findall(r"f32\[[0-9,]*512,8192\]", text)  # no scores over the whole row

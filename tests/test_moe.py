@@ -529,3 +529,186 @@ def test_the_token_group_comes_from_the_row_buffers_bytes():
     assert infer_group_size(512, 8192, 4) == 32  # never under a row tile of tokens
     assert inspect.signature(moe_infer).parameters["group_size"].default is None
     assert inspect.signature(moe_mlp).parameters["group_size"].default == MOE_GROUP_SIZE == 1024
+
+
+# -- the grouped products' tile and moe_infer's row layout ----------------------
+
+@pytest.mark.parametrize("product", ["gate_up", "down"])
+@pytest.mark.parametrize("preset", ["laguna-s-ep2", "qwen3-next-ep2", "longcat-flash-ep32"])
+def test_the_grouped_products_tile_holds_the_whole_contraction(preset, product):
+    """``tk = k`` at every served shape: a group's consecutive row-tile visits
+    keep their weight block, so an expert crosses HBM once. ``tn`` is a multiple
+    of 128 that divides the width, as wide as two buffers of the tile allow."""
+    from prime_tpu.ops.moe import GMM_WEIGHT_VMEM_BYTES, MOE_ROW_TILE, grouped_matmul_tiling
+
+    config = get_config(preset)
+    k, n = (config.d_model, config.d_ff)[:: 1 if product == "gate_up" else -1]
+    tm, tk, tn = grouped_matmul_tiling(k, n, 2)
+    assert (tm, tk) == (MOE_ROW_TILE, k)
+    assert tn % 128 == 0 and n % tn == 0
+    assert 2 * tk * tn * 2 <= GMM_WEIGHT_VMEM_BYTES
+    wider = [w for w in range(tn + 128, n + 1, 128) if n % w == 0]
+    assert all(2 * k * w * 2 > GMM_WEIGHT_VMEM_BYTES for w in wider)
+
+
+@pytest.mark.parametrize(
+    "k, n, itemsize, want",
+    [(64, 48, 4, (32, 64, 48)),            # a width under a lane tile is taken whole
+     (32768, 256, 2, (32, 24576, 128)),    # too long for the narrowest tile: walked
+     (3072, 1024, 4, (32, 3072, 512))],    # float32 weights: half the width
+    ids=["narrow", "contraction_walked", "float32"],
+)
+def test_the_tile_follows_the_shapes(k, n, itemsize, want):
+    from prime_tpu.ops.moe import grouped_matmul_tiling
+
+    assert grouped_matmul_tiling(k, n, itemsize) == want
+
+
+@pytest.mark.parametrize(
+    "preset, chunk, slots",
+    [("laguna-s-ep2", 512, 32), ("qwen3-next-ep2", 2048, 128), ("longcat-flash-ep32", 1024, 64)],
+)
+def test_a_prompts_chunk_is_tile_aligned_and_a_decode_step_is_not(preset, chunk, slots):
+    """From the shapes alone: a group of tokens that gives an expert half a tile
+    of rows or more under even routing starts every group on a row tile; a
+    decode step (every slot live) stays dense, LongCat's 768 rows over 16 held
+    experts among them."""
+    from prime_tpu.ops.moe import row_budget, tile_aligned_groups
+
+    config = get_config(preset)
+    k, held, width = config.experts_per_token, config.experts_held, config.router_width
+    assert tile_aligned_groups(chunk, k, width) and not tile_aligned_groups(slots, k, width)
+    # dropless either way: the pairs' worst case, and aligned a tile less a row an expert
+    assert row_budget(slots, k, held, width) == -(-slots * min(k, held) // 32) * 32
+    assert row_budget(chunk, k, held, width) == chunk * min(k, held) + held * 31 // 32 * 32
+
+
+_GROUPS = {
+    "an_empty_group": [5, 0, 30, 9],
+    "one_row": [1, 0, 0, 1],
+    "straddles_a_tile": [20, 20, 20, 4],
+    "spans_three_tiles": [3, 90, 0, 3],
+    "every_row_owned": [32, 32, 32, 32],
+}
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["one_layer", "layer_stack"])
+@pytest.mark.parametrize("groups", sorted(_GROUPS))
+def test_grouped_matmul_at_the_new_tile_agrees_with_ragged_dot(groups, stacked, monkeypatch):
+    """The kernel (interpret mode) with the contraction in one tile and the
+    width in two, against ``jax.lax.ragged_dot``, over groups that are empty, of
+    one row, that straddle a row tile and that span three."""
+    from prime_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_kernel_path", lambda: (True, True))
+    monkeypatch.setattr(moe, "GMM_WEIGHT_VMEM_BYTES", 2 * 256 * 128 * 4)
+    assert moe.grouped_matmul_tiling(256, 256, 4) == (32, 256, 128)
+    rng = np.random.default_rng(7)
+    sizes = jnp.asarray(_GROUPS[groups], jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(128, 256)), jnp.float32)
+    weights = jnp.asarray(rng.normal(size=(3, 4, 256, 256)), jnp.float32) * 256**-0.5
+    if stacked:
+        out = moe.grouped_matmul(rows, weights, sizes, jnp.asarray(2, jnp.int32))
+    else:
+        out = moe.grouped_matmul(rows, weights[2], sizes)
+    want = jax.lax.ragged_dot(rows, weights[2], sizes)
+    used = int(sizes.sum())
+    assert np.abs(np.asarray(out - want))[:used].max() < 1e-4
+
+
+def _layout_case(shape):
+    """(inputs of ``_infer_inputs``, k, held range) at a prompt-chunk shape (an
+    expert gets a tile of rows under even routing) and at a decode shape (two)."""
+    tokens, experts, k = {"prompt_chunk": (96, 12, 4), "decode_step": (8, 12, 3)}[shape]
+    return _infer_inputs(tokens, d=64, ff=32, experts=experts, seed=3), k, (3, 9)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "megablox_interpret"])
+@pytest.mark.parametrize("layout", ["dense", "tile_aligned"])
+@pytest.mark.parametrize("shape", ["prompt_chunk", "decode_step"])
+def test_moe_infer_in_either_layout_matches_the_float32_reference(shape, layout, kernel, monkeypatch):
+    """The same picks and the same output whether a group starts where the one
+    before ended or on a row tile, on both product paths, against the plain
+    float32 reference (every held expert over every token, gated)."""
+    from benchmark.reference import longcat_flash as ref
+    from prime_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_kernel_path", lambda: (kernel, kernel))
+    monkeypatch.setattr(moe, "tile_aligned_groups", lambda *_: layout == "tile_aligned")
+    (x, router, gate, up, down), k, (first, last) = _layout_case(shape)
+    held = (gate[first:last], up[first:last], down[first:last])
+    out, stats = moe.moe_infer(x, router, *held, k=k, first_expert=first, norm_topk=False)
+    want = ref.moe(x[0], router, jnp.zeros((router.shape[1],)), held, first, router.shape[1],
+                   k, 1.0, lambda m: m)
+    assert np.abs(np.asarray(out[0] - want)).max() < 1e-5
+    assert np.abs(np.asarray(want)).max() > 0.1
+    # the picks, by hand
+    picks = np.asarray(jax.lax.top_k(jax.nn.softmax(x[0] @ router, axis=-1), k)[1])
+    counts = dict(zip(moe.MOE_STATS, np.asarray(stats).tolist()))
+    local = int(((picks >= first) & (picks < last)).sum())
+    assert (counts["local"], counts["zero"], counts["elsewhere"]) == (local, 0, picks.size - local)
+
+
+@pytest.mark.parametrize("tile_mib, one_tile_products", [(6.0, 3), (1 / 64, 1)],
+                         ids=["contraction_in_one_tile", "gate_and_up_walked"])
+@pytest.mark.parametrize("layout", ["dense", "tile_aligned"])
+def test_weight_reads_against_a_count_by_hand(layout, tile_mib, one_tile_products, monkeypatch):
+    """``weight_reads``: a product whose contraction is one tile fetches each
+    touched expert once, one that walks it fetches the expert at every row-tile
+    visit; ``rows_computed`` is the visits x 32 in the layout in use."""
+    from prime_tpu.ops import moe
+
+    tile = moe.MOE_ROW_TILE
+    monkeypatch.setattr(moe, "tile_aligned_groups", lambda *_: layout == "tile_aligned")
+    monkeypatch.setattr(moe, "GMM_WEIGHT_VMEM_BYTES", int(2 * tile_mib * 2**20))
+    x, router, gate, up, down = _infer_inputs(96, d=256, ff=128, experts=12, seed=5)
+    # float32 (256, 128): 16 KiB a buffer holds gate / up's contraction in two
+    # tiles and down's (128, 128) in one
+    walked = [moe.grouped_matmul_tiling(a, b, 4)[1] < a for a, b in ((256, 128), (128, 256))]
+    assert walked == ([False, False] if one_tile_products == 3 else [True, False])
+    _, stats = moe.moe_infer(x, router, gate[3:9], up[3:9], down[3:9], k=4, first_expert=3)
+    counts = dict(zip(moe.MOE_STATS, np.asarray(stats).tolist()))
+    picks = np.asarray(jax.lax.top_k(jax.nn.softmax(x[0] @ router, axis=-1), 4)[1])
+    sizes = np.bincount(picks[(picks >= 3) & (picks < 9)] - 3, minlength=6)
+    if layout == "tile_aligned":
+        visits = int(np.sum(-(-sizes // tile)))
+    else:
+        ends = np.cumsum(sizes)
+        starts = ends - sizes
+        visits = int(np.sum(np.where(sizes > 0, -(-ends // tile) - starts // tile, 0)))
+    touched = int((sizes > 0).sum())
+    assert counts["experts_touched"] == touched and counts["rows_computed"] == visits * tile
+    assert visits > touched  # some group takes a second tile in either layout
+    assert counts["weight_reads"] == one_tile_products * touched + (3 - one_tile_products) * visits
+
+
+@pytest.mark.parametrize(
+    "reads, touched, want",
+    [(3 * 4864, 4864, 1.0),                       # every product in one tile
+     (2 * 7540 + 4864, 4864, (2 * 7540 + 4864) / (3 * 4864)),  # gate and up fetched at 1.55 visits
+     (None, 4864, None),                          # the parent: no such counter
+     (0, 0, None)],                               # a window with no prefill
+    ids=["once", "gate_and_up_walked", "no_counter", "no_prefill"],
+)
+def test_the_weight_reads_reader_divides_the_windows_deltas(reads, touched, want):
+    """``moe_prefill_weight_reads_per_touched_expert`` from two registry
+    snapshots, prefill phase only; nothing where the program has no counter."""
+    from benchmark.harness.manifest import load_manifest
+    from benchmark.layer_metrics import moe_prefill_weight_reads_per_touched_expert as reader
+    from benchmark.systems.serve_http import counter_delta
+
+    def family(prefill, decode):
+        return {"type": "counter", "series": [
+            {"labels": {"phase": "prefill"}, "value": prefill},
+            {"labels": {"phase": "decode"}, "value": decode}]}
+
+    def snapshot(scale):
+        counters = {"serve_moe_experts_touched_total": family(scale * touched, 1000 * scale)}
+        if reads is not None:
+            counters["serve_moe_weight_reads_total"] = family(scale * reads, 4000 * scale)
+        return counters
+
+    value = reader.read({"counters": (snapshot(1), snapshot(3)), "counter_delta": counter_delta})
+    assert value == want if want is None else abs(value - want) < 1e-12
+    entry = {m["name"]: m for m in load_manifest()["per_layer"]}[reader.__name__.rsplit(".", 1)[1]]
+    assert (entry["moves"], entry["better"], entry["source"]) == ("latency_mean_ms", "lower", "program_counter")
